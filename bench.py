@@ -20,21 +20,27 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-# persistent XLA compilation cache (utils/jitcache.py): default dir under
-# ~/.cache/opensim-tpu so the cold_s trajectory is comparable across runs;
-# OPENSIM_JIT_CACHE=0 opts out, JAX_COMPILATION_CACHE_DIR still wins
+# persistent XLA compilation cache (utils/jitcache.py): the directory is
+# JAX_COMPILATION_CACHE_DIR when set, else the fixed .jit_cache/ in this
+# checkout, so cold_s is comparable across runs; OPENSIM_JIT_CACHE=0 opts out
 from opensim_tpu.utils.jitcache import maybe_enable  # noqa: E402
 
 maybe_enable(default=True)
 
-from opensim_tpu.utils.probe import ensure_accelerator_or_cpu  # noqa: E402
-
-BACKEND_NOTE = ensure_accelerator_or_cpu()
+# The measurement path fails without a chip; it never falls back. The
+# platform is whatever JAX selects from JAX_PLATFORMS, and a CPU run has to
+# be asked for by name. Anything but that runs strict, as `--backend tpu`
+# does: a megakernel that does not compile fails the run instead of being
+# demoted to a slower engine (children — bench servers — inherit it).
+CPU_REQUESTED = os.environ.get("JAX_PLATFORMS") == "cpu"
+if not CPU_REQUESTED:
+    os.environ["OPENSIM_REQUIRE_TPU"] = "1"
 
 import numpy as np  # noqa: E402
 
 from opensim_tpu.engine.simulator import AppResource, simulate  # noqa: E402
 from opensim_tpu.models import ResourceTypes, fixtures as fx  # noqa: E402
+from opensim_tpu.obs.profile import device_stamp  # noqa: E402
 
 
 # failure contract (NOTES invariant: the driver parses exactly ONE JSON
@@ -46,6 +52,23 @@ _STAGE = ["startup"]
 
 def _stage(name: str) -> None:
     _STAGE[0] = name
+
+
+def _check_device(device: dict) -> dict:
+    if device["platform"] != "tpu" and not CPU_REQUESTED:
+        raise RuntimeError(
+            f"no TPU found (platform={device['platform']!r}, JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS', '')!r}); a CPU run must be "
+            "requested explicitly with JAX_PLATFORMS=cpu"
+        )
+    return device
+
+
+def _emit(record: dict, device: dict = None) -> None:
+    """THE row printer: every row names the device its numbers came from —
+    this process's (obs.profile.device_stamp) unless a server's is passed."""
+    record.update(_check_device(device or device_stamp()))
+    print(json.dumps(record))
 
 
 def _fmt(n: int) -> str:
@@ -331,7 +354,7 @@ def bench_defrag(n_scenarios: int, n_nodes: int, n_pods: int, warmup: bool) -> i
         record["vs_serial"] = round(record["value"] / serial["scenarios_per_sec"], 1)
     if cxx and cxx.get("scenarios_per_sec"):
         record["vs_serial_cxx"] = round(record["value"] / cxx["scenarios_per_sec"], 1)
-    print(json.dumps(record))
+    _emit(record)
     return 0
 
 
@@ -445,9 +468,7 @@ def bench_campaign(n_nodes: int, n_pods: int, warmup: bool) -> int:
         )
         if not record["verified_vs_cold"]:
             raise RuntimeError("campaign warm-delta fingerprints diverged from cold per-step prepares")
-    if BACKEND_NOTE:
-        record["backend"] = BACKEND_NOTE
-    print(json.dumps(record))
+    _emit(record)
     return 0
 
 
@@ -527,15 +548,13 @@ def bench_reference_example(config_path: str, extended: str, warmup: bool, label
     if warmup:
         run()
     dt = run()
-    print(
-        json.dumps(
-            {
-                "metric": f"simon apply {label} wall-clock",
-                "value": round(dt, 3),
-                "unit": "s",
-                "vs_baseline": round(1.0 / dt, 2) if dt > 0 else 0.0,  # reference trace threshold: 1 s
-            }
-        )
+    _emit(
+        {
+            "metric": f"simon apply {label} wall-clock",
+            "value": round(dt, 3),
+            "unit": "s",
+            "vs_baseline": round(1.0 / dt, 2) if dt > 0 else 0.0,  # reference trace threshold: 1 s
+        }
     )
     return 0
 
@@ -584,6 +603,7 @@ def bench_serving(concurrency: int, duration_s: float) -> int:
         concurrency=concurrency, duration_s=duration_s, base_port=18980,
         client_procs=client_procs,
     )
+    _check_device(report["device"])
     _stage("serving-pipeline")
     pipe = run_pipeline_benchmark(
         concurrency=concurrency, duration_s=duration_s, base_port=19080,
@@ -624,9 +644,7 @@ def bench_serving(concurrency: int, duration_s: float) -> int:
     note = _core_guard_note("serving", record["host_cores"])
     if note:
         record["baseline_comparison"] = note
-    if BACKEND_NOTE:
-        record["backend_note"] = BACKEND_NOTE
-    print(json.dumps(record))
+    _emit(record, device=pipe["device"])
     return 0
 
 
@@ -681,9 +699,7 @@ def bench_serving_fleet(workers: int, concurrency: int, duration_s: float) -> in
     note = _core_guard_note("serving-fleet", record["host_cores"])
     if note:
         record["baseline_comparison"] = note
-    if BACKEND_NOTE:
-        record["backend_note"] = BACKEND_NOTE
-    print(json.dumps(record))
+    _emit(record, device=report["device"])
     return 0
 
 
@@ -802,9 +818,7 @@ def _bench_replay_run(journal_path: str, label: str, speed: float) -> int:
         record["pods_bound"] = sample.pods_bound
         record["pods_pending"] = sample.pods_pending
         record["cpu_utilization"] = round(sample.utilization.get("cpu", 0.0), 4)
-    if BACKEND_NOTE:
-        record["backend_note"] = BACKEND_NOTE
-    print(json.dumps(record))
+    _emit(record)
     return 0
 
 
@@ -857,9 +871,7 @@ def bench_steady(n_pods: int, n_nodes: int, repeats: int) -> int:
         "scheduled": scheduled0,
         "unscheduled": len(r0.unscheduled_pods),
     }
-    if BACKEND_NOTE:
-        record["backend"] = BACKEND_NOTE
-    print(json.dumps(record))
+    _emit(record)
     return 0
 
 
@@ -941,9 +953,13 @@ def main() -> int:
 
     repo = os.path.dirname(os.path.abspath(__file__))
     if args.config == "serving":
+        # the servers own the device; this process never initializes JAX
         if args.workers >= 2:
             return bench_serving_fleet(args.workers, args.concurrency, args.duration)
         return bench_serving(args.concurrency, args.duration)
+    _stage("device")
+    _check_device(device_stamp())  # fail before measuring, not after
+    _stage("measure")
     if args.config == "replay":
         return bench_replay(args.journal, args.events, args.nodes, args.speed)
     if args.config == "steady":
@@ -1100,9 +1116,7 @@ def main() -> int:
         # the two must agree (acceptance: within 10%)
         record["trace_file"] = args.trace
         record["trace_span_s"] = round(tr.root.duration_s, 3)
-    if BACKEND_NOTE:
-        record["backend"] = BACKEND_NOTE
-    print(json.dumps(record))
+    _emit(record)
     return 0
 
 

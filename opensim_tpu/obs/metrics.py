@@ -223,6 +223,9 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Backend (XLA) compile seconds from jax monitoring, all call sites", "counter",
     ),
     "simon_backend_compile_total": ("Backend (XLA) compiles from jax monitoring", "counter"),
+    "simon_device_info": (
+        "The JAX backend this process computes on, by platform and device_kind (value = device count)", "gauge",
+    ),
     "simon_jitcache_persistent_files": ("Entries in the persistent XLA compile cache dir", "gauge"),
     "simon_jitcache_persistent_bytes": ("Bytes in the persistent XLA compile cache dir", "gauge"),
     "simon_jitcache_events_total": ("jax compilation-cache monitoring events by leaf name", "counter"),

@@ -49,6 +49,7 @@ __all__ = [
     "PROFILE",
     "CompileWatch",
     "PhaseProfile",
+    "device_stamp",
     "observed_jit_call",
 ]
 
@@ -266,6 +267,7 @@ class CompileWatch:
                     f'simon_jitcache_events_total{{event="{esc(ev)}"}} {n}'
                     for ev, n in sorted(self._cache_events.items())
                 ]
+        lines += _device_info_lines()
         stats = jitcache.cache_stats()
         if stats is not None:
             lines += [
@@ -282,6 +284,36 @@ class CompileWatch:
             self._backend_compiles = 0
             self._backend_seconds = 0.0
             self._cache_events.clear()
+
+
+def device_stamp() -> Dict[str, Any]:
+    """platform / device_kind / device count of the backend JAX selected in
+    THIS process — the one shape every consumer stamps (bench rows, the
+    `apply ran on …` line, ``simon_device_info``). Initializes the backend."""
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+    }
+
+
+def _device_info_lines() -> List[str]:
+    """``simon_device_info``: which backend this process's numbers come from
+    (a load generator stamps its rows from this, never from its own JAX)."""
+    try:
+        dev = device_stamp()
+    except RuntimeError as e:  # no backend could initialize: say so once per scrape
+        log.debug("device info unavailable: %s", e)
+        return []
+    esc = escape_label_value
+    return [
+        *family_header("simon_device_info"),
+        f'simon_device_info{{device_kind="{esc(dev["device_kind"])}",'
+        f'platform="{esc(dev["platform"])}"}} {dev["device_count"]}',
+    ]
 
 
 COMPILES = CompileWatch()

@@ -19,8 +19,9 @@ templates the kernel switches to big-U mode: the [U, N]/[X, U] template
 tables stay in HBM and each pod step DMAs its row/column into VMEM scratch,
 so VMEM no longer scales with U (cap 2048, bounded by SMEM scalars). The kernel is
 generated per feature-flag combination so absent features cost nothing, and
-node validity is a runtime row so scenario sweeps re-dispatch with nothing
-but a new mask and spread-weight table.
+node validity is a runtime row: a scenario sweep is the same kernel with a
+leading scenario grid axis, each scenario reading its own mask, spread-weight
+table and pod streams (`run_fast_scan`; a plain schedule is one scenario).
 
 Layouts (N = padded node axis, lanes; rows padded to sublane multiples):
   alloc_T     [R, N]    f32  allocatable per resource row
@@ -48,8 +49,28 @@ from ..encoding import vocab as V
 
 NEG = -1e30
 MAX_SCORE = 100.0
-# SMEM int32 streams tile at 1024 on current Mosaic; block shapes must match
+# pod-stream block: 1-D SMEM windows of CHUNK int32 (compiles for v5e on the
+# installed JAX/libtpu; the grid's chunk axis steps through them)
 CHUNK = 1024
+# the scoped-VMEM limit the kernel is compiled under: half of a v5e core's
+# 128 MiB, stated instead of left to the 16 MiB default. fastpath.why_not
+# admits a shape when its resident-row ESTIMATE is under
+# fastpath._VMEM_BUDGET (10 MB); the compiler then needs about twice that —
+# double-buffered per-scenario blocks plus the operand splits of the exact
+# (fp32-contract) matmuls: the affinity plan at 5,120 lanes takes 21.6 MB.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _dot(a, b):
+    """Every contraction in the kernel is a gather or a count-weighted sum
+    written as a matmul (one-hot selectors × counts or weights), so it has to
+    be EXACT. The MXU's default for f32 operands is a reduced-precision pass
+    that cannot hold a count past 256; at 50k pods the zone counts pass it and
+    the compiled kernel's placements left the XLA scan's (found on the chip,
+    PR 21). fp32 contract precision is the setting that keeps them identical."""
+    return jnp.dot(
+        a, b, preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST
+    )
 
 
 class FastInputs(NamedTuple):
@@ -64,7 +85,7 @@ class FastInputs(NamedTuple):
     zone_ZN: np.ndarray  # [K*Z, N]
     has_zone: np.ndarray  # [K, N] f32 — node has key k's label
     matches_AU: np.ndarray  # [A, U]
-    node_valid: np.ndarray  # [1, N] f32
+    node_valid: np.ndarray  # [1, N] f32 (run_fast_scan takes [S, 1, N])
     # SMEM scalar tables
     req: np.ndarray  # [U, R] f32
     cpu_nz: np.ndarray  # [U] f32 nonzero-default cpu (milli)
@@ -77,7 +98,7 @@ class FastInputs(NamedTuple):
     spr_skew: np.ndarray  # f32
     spr_hard: np.ndarray  # i32 0/1
     spr_self: np.ndarray  # f32 0/1 template matches own selector
-    spr_weight: np.ndarray  # f32 log(size+2)
+    spr_weight: np.ndarray  # f32 log(size+2) (run_fast_scan takes [S, U, Cs])
     # inter-pod affinity (all zero-shaped semantics when has_interpod=False)
     at_active: np.ndarray  # [U, Ti] i32 — incoming required affinity terms
     at_key: np.ndarray  # [U, Ti] i32 key index (0 = hostname, 1..K = zone)
@@ -278,7 +299,10 @@ def _make_kernel(
             Tn = ana_ref.shape[0]
             Tp = pta_ref.shape[0]
 
-        @pl.when(pl.program_id(0) == 0)
+        # grid = (scenario, chunk): the carried state lives in scratch that
+        # persists across the whole grid, so every scenario re-initializes it
+        # at its first chunk
+        @pl.when(pl.program_id(1) == 0)
         def _init():
             used_ref[:] = used0_ref[:]
             node_cnt_ref[:] = jnp.zeros_like(node_cnt_ref)
@@ -333,9 +357,7 @@ def _make_kernel(
             host_cnt = node_cnt_ref[pl.ds(sel, 1), :]  # [1, N]
             k = jnp.maximum(key - 1, 0)
             zrow = zone_cnt_ref[pl.ds(k * A_rows + sel, 1), :]  # [1, Zk]
-            zone_gather = jnp.dot(
-                zrow, zone_zn_ref[pl.ds(k * Zk, Zk), :], preferred_element_type=jnp.float32
-            )
+            zone_gather = _dot(zrow, zone_zn_ref[pl.ds(k * Zk, Zk), :])
             has = has_zone_ref[pl.ds(k, 1), :]
             return jnp.where(key == 0, host_cnt, zone_gather), jnp.where(
                 key == 0, ones_1n, has
@@ -346,10 +368,14 @@ def _make_kernel(
             if big_u:
                 # template tables live in HBM: DMA this step's row (for
                 # [U, N] tables) / 128-lane column block (for [X, U] tables
-                # — a 1-lane HBM slice violates the (8,128) tiling, so the
-                # aligned block containing column u is copied and the single
-                # column extracted in VMEM by a one-hot dot) — all copies in
-                # flight together, one wait. VMEM stays independent of U.
+                # — the aligned block containing column u is copied and the
+                # single column extracted in VMEM by a one-hot dot) — all
+                # copies in flight together, one wait. VMEM stays
+                # independent of U. Both kinds are stored 3-D with the
+                # indexed axis LEADING ([U, 1, N] rows, [U/128, X, 128]
+                # column blocks): a DMA may index a leading axis freely,
+                # while a 1-row or lane-offset slice of a 2-D table breaks
+                # the (8, 128) HBM tiling and Mosaic refuses it.
                 sems = u_scratch[-1]
                 bufs = list(u_scratch[:-1])
                 dma_state = {"k": 0}
@@ -360,7 +386,7 @@ def _make_kernel(
                     k = dma_state["k"]
                     dma_state["k"] = k + 1
                     scratch = bufs[k]
-                    src = ref.at[:, pl.ds(u_blk, 128)] if col else ref.at[pl.ds(u, 1)]
+                    src = ref.at[u // 128] if col else ref.at[u]
                     cp = pltpu.make_async_copy(src, scratch, sems.at[k])
                     cp.start()
                     copies.append(cp)
@@ -388,7 +414,7 @@ def _make_kernel(
                 ).astype(jnp.float32)
 
                 def col_of(scratch):  # [X, 128] block -> [X, 1] column u
-                    return jnp.dot(scratch[:], lane_oh, preferred_element_type=jnp.float32)
+                    return _dot(scratch[:], lane_oh)
 
                 static_row = s_static[:]
             else:
@@ -430,13 +456,9 @@ def _make_kernel(
                     my_ports = col_of(s_portc)  # [Hp, 1]
                 else:
                     onehot_u_p = (iota_u == u).astype(jnp.float32)
-                    my_ports = jnp.dot(
-                        port_conf_hu_ref[:], onehot_u_p, preferred_element_type=jnp.float32
-                    )  # [Hp, 1]
-                conflicts = jnp.dot(
-                    my_ports.reshape(1, -1),
-                    (port_used_ref[:] > 0).astype(jnp.float32),
-                    preferred_element_type=jnp.float32,
+                    my_ports = _dot(port_conf_hu_ref[:], onehot_u_p)  # [Hp, 1]
+                conflicts = _dot(
+                    my_ports.reshape(1, -1), (port_used_ref[:] > 0).astype(jnp.float32)
                 )  # [1, N]
                 feasible = feasible * (conflicts == 0).astype(jnp.float32)
 
@@ -550,16 +572,14 @@ def _make_kernel(
                 if big_u:
                     my_gmatch = col_of(s_gmatch)
                 else:
-                    my_gmatch = jnp.dot(gmatch_ref[:], onehot_u_col, preferred_element_type=jnp.float32)
+                    my_gmatch = _dot(gmatch_ref[:], onehot_u_col)
                 m_row = my_gmatch.reshape(1, n_anti)
                 m_host = m_row * (g_key_row == 0).astype(jnp.float32)
-                sym_cnt = jnp.dot(m_host, anti_node_ref[:], preferred_element_type=jnp.float32)
+                sym_cnt = _dot(m_host, anti_node_ref[:])
                 for zk in range(n_zkeys):
                     m_k = m_row * (g_key_row == zk + 1).astype(jnp.float32)
-                    sym_cnt = sym_cnt + jnp.dot(
-                        jnp.dot(m_k, anti_zone_ref[:], preferred_element_type=jnp.float32),
-                        zone_zn_ref[pl.ds(zk * Zk, Zk), :],
-                        preferred_element_type=jnp.float32,
+                    sym_cnt = sym_cnt + _dot(
+                        _dot(m_k, anti_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
                     )
                 feasible = feasible * (1.0 - (sym_cnt > 0).astype(jnp.float32))
                 # score: incoming preferred terms
@@ -573,16 +593,14 @@ def _make_kernel(
                 if big_u:
                     my_pmatch = col_of(s_pmatch)
                 else:
-                    my_pmatch = jnp.dot(pmatch_ref[:], onehot_u_col, preferred_element_type=jnp.float32)
+                    my_pmatch = _dot(pmatch_ref[:], onehot_u_col)
                 pm_row = my_pmatch.reshape(1, n_pref)
                 pm_host = pm_row * (p_key_row == 0).astype(jnp.float32)
-                ip_raw = ip_raw + jnp.dot(pm_host, prefw_node_ref[:], preferred_element_type=jnp.float32)
+                ip_raw = ip_raw + _dot(pm_host, prefw_node_ref[:])
                 for zk in range(n_zkeys):
                     pm_k = pm_row * (p_key_row == zk + 1).astype(jnp.float32)
-                    ip_raw = ip_raw + jnp.dot(
-                        jnp.dot(pm_k, prefw_zone_ref[:], preferred_element_type=jnp.float32),
-                        zone_zn_ref[pl.ds(zk * Zk, Zk), :],
-                        preferred_element_type=jnp.float32,
+                    ip_raw = ip_raw + _dot(
+                        _dot(pm_k, prefw_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
                     )
 
             # --- scores
@@ -738,7 +756,7 @@ def _make_kernel(
                     m_col = col_of(s_match)  # [A, 1]
                 else:
                     onehot_u = (iota_u == u).astype(jnp.float32)  # [U, 1]
-                    m_col = jnp.dot(matches_ref[:], onehot_u, preferred_element_type=jnp.float32)
+                    m_col = _dot(matches_ref[:], onehot_u)
                 # per-key [1, Zk] one-hot rows of the chosen node's zones —
                 # read from the 3-D [K, N, Z] table so every key's row sits
                 # at lane offset 0 (a lane-offset slice can't broadcast)
@@ -752,9 +770,7 @@ def _make_kernel(
                         + m_col * zrow_k[zk]
                     )
                 if has_ports:
-                    p_col = col_of(s_port) if big_u else jnp.dot(
-                        port_hu_ref[:], onehot_u, preferred_element_type=jnp.float32
-                    )
+                    p_col = col_of(s_port) if big_u else _dot(port_hu_ref[:], onehot_u)
                     port_used_ref[:] = port_used_ref[:] + p_col * onehot
                 if has_gpu:
                     # device packing on the chosen node (computed for all
@@ -834,18 +850,14 @@ def _make_kernel(
                                 taken_rows[d] = jnp.maximum(taken_rows[d], take_d)
                                 dev_free_ref[pl.ds(d, 1), :] = free_d * (1.0 - take_d * onehot)
                 if has_interpod:
-                    a_col = col_of(s_antig) if big_u else jnp.dot(
-                        antig_ref[:], onehot_u, preferred_element_type=jnp.float32
-                    )
+                    a_col = col_of(s_antig) if big_u else _dot(antig_ref[:], onehot_u)
                     anti_node_ref[:] = anti_node_ref[:] + a_col * onehot
                     for zk in range(n_zkeys):
                         key_mask = (g_key_col == zk + 1).astype(jnp.float32)
                         anti_zone_ref[:] = (
                             anti_zone_ref[:] + a_col * key_mask * zrow_k[zk]
                         )
-                    p_col = col_of(s_prefg) if big_u else jnp.dot(
-                        prefg_ref[:], onehot_u, preferred_element_type=jnp.float32
-                    )
+                    p_col = col_of(s_prefg) if big_u else _dot(prefg_ref[:], onehot_u)
                     prefw_node_ref[:] = prefw_node_ref[:] + p_col * onehot
                     for zk in range(n_zkeys):
                         key_mask = (p_key_col == zk + 1).astype(jnp.float32)
@@ -882,16 +894,23 @@ def run_fast_scan(
     big_u: bool = False,
     gc_row: int = -1,
 ):
-    """Execute the megakernel. tmpl_ids/pod_valid/forced are [P] (P a
-    multiple of CHUNK). Returns (chosen [P] i32, used_final [R, N],
-    gpu_take [P, Gd], gpu_final [Gd, N], vg_final [Vg, N], dev_final [Dv, N]).
+    """Execute the megakernel over S scenarios in ONE dispatch. The scenario
+    axis is the leading grid dimension: tmpl_ids is [P] (P a multiple of
+    CHUNK, shared), pod_valid/forced are [S, P], ``fi.node_valid`` is
+    [S, 1, N] and ``fi.spr_weight`` [S, U, Cs]; every other table is shared.
+    A plain schedule is S = 1. Returns (chosen [S, P] i32, used_final
+    [S, R, N], gpu_take [S, P, Gd], gpu_final [S, Gd, N], vg_final
+    [S, Vg, N], dev_final [S, Dv, N]).
 
     `big_u` keeps the [U, N] / [X, U] template tables in HBM and DMAs one
     row/column per pod step into VMEM scratch — VMEM use then no longer
     scales with U, lifting the template cap (fastpath.applicable)."""
     P = tmpl_ids.shape[0]
     assert P % CHUNK == 0, P
+    S = pod_valid.shape[0]
+    assert pod_valid.shape == forced.shape == (S, P), (pod_valid.shape, forced.shape)
     R, N = fi.alloc_T.shape
+    assert fi.node_valid.shape == (S, 1, N), fi.node_valid.shape
     A = fi.matches_AU.shape[0]
     K = fi.has_zone.shape[0]  # number of non-hostname topology keys (>= 1)
     Z = fi.zone_NZ.shape[2]
@@ -901,22 +920,33 @@ def run_fast_scan(
     Vg = fi.vg0_VN.shape[0]
     Dv = fi.dev0_DN.shape[0]
     Hp = fi.port_HU.shape[0]
-    grid = (P // CHUNK,)
+    n_chunks = P // CHUNK
+    grid = (S, n_chunks)
 
     smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
-    # big-U tables are pinned to HBM (not ANY): if Mosaic places an ANY
-    # buffer in VMEM — which it does when the table happens to fit — the
-    # per-step 1-row DMA slice violates the (8,128) VMEM tiling alignment
-    # and the kernel fails to compile
+    # big-U tables are pinned to HBM (not ANY): the point of the mode is
+    # that they stay out of VMEM even when one happens to fit
     anyspace = lambda: pl.BlockSpec(memory_space=pltpu.HBM)
-    stream = lambda: pl.BlockSpec((CHUNK,), lambda i: (i,), memory_space=pltpu.SMEM)
+    # per-scenario pod streams are FLAT [S·P] with 1-D CHUNK blocks: a
+    # [S, P] array blocked (1, CHUNK) breaks the TPU rule that a block's
+    # second-minor dim is a multiple of 8 or the whole axis (which is why
+    # jax.vmap over the pallas_call never lowered)
+    shared_stream = lambda: pl.BlockSpec((CHUNK,), lambda s, i: (i,), memory_space=pltpu.SMEM)
+    scen_stream = lambda: pl.BlockSpec(
+        (CHUNK,), lambda s, i: (s * n_chunks + i,), memory_space=pltpu.SMEM
+    )
+
+    def per_scenario(space, *tail):  # [S, *tail] → one scenario's [*tail] block
+        return pl.BlockSpec(
+            (pl.Squeezed(), *tail), lambda s, i: (s,) + (0,) * len(tail), memory_space=space
+        )
 
     _I32 = {"tmpl", "valid", "forced", "pin", "spr_active", "spr_key", "spr_sel",
             "spr_hard", "at_active", "at_key", "at_sel", "an_active", "an_key",
             "an_sel", "pt_active", "pt_key", "pt_sel", "anti_g_key", "prefg_key"}
-    # [X, U] tables whose big-U DMA copies an aligned 128-lane column block:
-    # pad U to a 128 multiple so the block at (u // 128)·128 never overruns
+    # [X, U] tables whose big-U DMA copies an aligned 128-lane column block
+    # (U padded to a 128 multiple so the last block is whole)
     _COL_TABLES = {"matches_AU", "port_HU", "port_conf_HU",
                    "antig_GU", "gmatch_GU", "prefg_GU", "pmatch_GU"}
     # 2-D SMEM scalar tables are stored TRANSPOSED ([X, U], U minor): an
@@ -933,38 +963,46 @@ def run_fast_scan(
     in_specs, args = [], []
     for name, kind in layout:
         if kind == "stream":
-            in_specs.append(stream())
             src = {"tmpl": tmpl_ids, "valid": pod_valid, "forced": forced}[name]
         else:
-            in_specs.append({"smem": smem, "vmem": vmem, "any": anyspace}[kind]())
             src = getattr(fi, name)
         arr = jnp.asarray(src, jnp.int32 if name in _I32 else jnp.float32)
         if name in _SMEM_T:
-            arr = arr.T
-        if big_u and name in _COL_TABLES:
-            pad_u = (-arr.shape[1]) % 128
-            if pad_u:
-                arr = jnp.pad(arr, ((0, 0), (0, pad_u)))
+            arr = jnp.swapaxes(arr, -1, -2)
+        if kind == "any" and name in _COL_TABLES:
+            # [X, U] → [U/128, X, 128]: the DMA indexes the leading block axis
+            arr = jnp.pad(arr, ((0, 0), (0, (-arr.shape[1]) % 128)))
+            arr = arr.reshape(arr.shape[0], -1, 128).transpose(1, 0, 2)
+        elif kind == "any":
+            arr = arr[:, None, :]  # [U, N] → [U, 1, N]: the DMA indexes the row axis
+        if name == "tmpl":
+            spec = shared_stream()
+        elif kind == "stream":
+            arr, spec = arr.reshape(S * P), scen_stream()
+        elif name in ("node_valid", "spr_weight"):  # the per-scenario tables
+            spec = per_scenario({"smem": pltpu.SMEM, "vmem": pltpu.VMEM}[kind], *arr.shape[1:])
+        else:
+            spec = {"smem": smem, "vmem": vmem, "any": anyspace}[kind]()
+        in_specs.append(spec)
         args.append(arr)
 
-    # outputs: feature-gated, like the inputs. gpu_take is [Gd, P] (device
+    # outputs: feature-gated, like the inputs. gpu_take is [Gd, S·P] (device
     # rows × pod lanes): an SMEM window's minor dim pads to 128 lanes, so the
     # natural [P, Gd] layout would burn 1 MB of the chip's 1 MB SMEM on
     # 8-lane rows — transposed, the window is [Gd, CHUNK] = 32 KB.
-    out_shape = [jax.ShapeDtypeStruct((P,), jnp.int32),
-                 jax.ShapeDtypeStruct((R, N), jnp.float32)]
-    out_specs = [pl.BlockSpec((CHUNK,), lambda i: (i,), memory_space=pltpu.SMEM),
-                 pl.BlockSpec((R, N), lambda i: (0, 0), memory_space=pltpu.VMEM)]
+    out_shape = [jax.ShapeDtypeStruct((S * P,), jnp.int32),
+                 jax.ShapeDtypeStruct((S, R, N), jnp.float32)]
+    out_specs = [scen_stream(), per_scenario(pltpu.VMEM, R, N)]
     if has_gpu:
-        out_shape += [jax.ShapeDtypeStruct((Gd, P), jnp.float32),
-                      jax.ShapeDtypeStruct((Gd, N), jnp.float32)]
-        out_specs += [pl.BlockSpec((Gd, CHUNK), lambda i: (0, i), memory_space=pltpu.SMEM),
-                      pl.BlockSpec((Gd, N), lambda i: (0, 0), memory_space=pltpu.VMEM)]
+        out_shape += [jax.ShapeDtypeStruct((Gd, S * P), jnp.float32),
+                      jax.ShapeDtypeStruct((S, Gd, N), jnp.float32)]
+        out_specs += [pl.BlockSpec((Gd, CHUNK), lambda s, i: (0, s * n_chunks + i),
+                                   memory_space=pltpu.SMEM),
+                      per_scenario(pltpu.VMEM, Gd, N)]
     if has_local:
-        out_shape += [jax.ShapeDtypeStruct((Vg, N), jnp.float32),
-                      jax.ShapeDtypeStruct((Dv, N), jnp.float32)]
-        out_specs += [pl.BlockSpec((Vg, N), lambda i: (0, 0), memory_space=pltpu.VMEM),
-                      pl.BlockSpec((Dv, N), lambda i: (0, 0), memory_space=pltpu.VMEM)]
+        out_shape += [jax.ShapeDtypeStruct((S, Vg, N), jnp.float32),
+                      jax.ShapeDtypeStruct((S, Dv, N), jnp.float32)]
+        out_specs += [per_scenario(pltpu.VMEM, Vg, N), per_scenario(pltpu.VMEM, Dv, N)]
 
     scratch = [pltpu.VMEM((R, N), jnp.float32),
                pltpu.VMEM((A, N), jnp.float32),
@@ -1016,26 +1054,30 @@ def run_fast_scan(
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch,
+        # both axes carry state through scratch: strictly sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
     )(*args)
 
-    # normalize to the fixed 6-tuple callers expect: (chosen, used_T,
-    # gpu_take [P, Gd], gpu_final, vg_final, dev_final) — absent features
-    # report their initial state / zero takes
+    # normalize to the fixed 6-tuple callers expect — absent features report
+    # their initial state / zero takes
     res = list(out)
-    chosen, used_T = res[0], res[1]
+    chosen, used_T = res[0].reshape(S, P), res[1]
     idx = 2
     if has_gpu:
-        gpu_take = res[idx].T
+        gpu_take = res[idx].reshape(Gd, S, P).transpose(1, 2, 0)
         gpu_T = res[idx + 1]
         idx += 2
     else:
-        gpu_take = jnp.zeros((P, Gd), jnp.float32)
-        gpu_T = jnp.asarray(fi.gpu0_DN, jnp.float32)
+        gpu_take = jnp.zeros((S, P, Gd), jnp.float32)
+        gpu_T = jnp.broadcast_to(jnp.asarray(fi.gpu0_DN, jnp.float32), (S, Gd, N))
     if has_local:
         vg_T = res[idx]
         dev_T = res[idx + 1]
     else:
-        vg_T = jnp.asarray(fi.vg0_VN, jnp.float32)
-        dev_T = jnp.asarray(fi.dev0_DN, jnp.float32)
+        vg_T = jnp.broadcast_to(jnp.asarray(fi.vg0_VN, jnp.float32), (S, Vg, N))
+        dev_T = jnp.broadcast_to(jnp.asarray(fi.dev0_DN, jnp.float32), (S, Dv, N))
     return chosen, used_T, gpu_take, gpu_T, vg_T, dev_T

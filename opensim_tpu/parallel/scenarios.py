@@ -129,14 +129,16 @@ def sweep_auto(
             )
         config = distinct.pop() if distinct else None
     from ..engine import nativepath
+    from ..obs import trace as obs
 
     if len(jax.devices()) == 1 and nativepath.applicable(prep, config):
         # accelerator-less (or --backend native): sequential C++ scans —
         # no XLA scan compile; the incremental template cache makes each
-        # scenario ms-scale on small configs (VERDICT r3 weak #4)
-        unscheduled, used, chosen, vg_used = nativepath.sweep(
-            prep, node_valid_masks, pod_valid_masks, forced_masks, config=config
-        )
+        # scenario ms-scale on small configs
+        with obs.span("sweep.native", scenarios=S):
+            unscheduled, used, chosen, vg_used = nativepath.sweep(
+                prep, node_valid_masks, pod_valid_masks, forced_masks, config=config
+            )
         return SweepResult(
             unscheduled=jnp.asarray(unscheduled), used=jnp.asarray(used),
             chosen=jnp.asarray(chosen), vg_used=jnp.asarray(vg_used),
@@ -154,9 +156,10 @@ def sweep_auto(
         miss = fastpath.why_not(prep)
         if miss is None:
             try:
-                unscheduled, used, chosen, vg_used = fastpath.sweep(
-                    prep, node_valid_masks, pod_valid_masks, forced_masks
-                )
+                with obs.span("sweep.megakernel", scenarios=S):
+                    unscheduled, used, chosen, vg_used = fastpath.sweep(
+                        prep, node_valid_masks, pod_valid_masks, forced_masks
+                    )
                 return SweepResult(
                     unscheduled=unscheduled, used=used, chosen=chosen, vg_used=vg_used
                 )
@@ -184,18 +187,21 @@ def sweep_auto(
             logging.getLogger("opensim_tpu").info(
                 "megakernel sweep envelope miss: %s", miss
             )
-    return sweep(
-        prep.ec,
-        prep.st0,
-        prep.tmpl_ids,
-        prep.forced,
-        node_valid_masks,
-        pod_valid_masks,
-        mesh=default_mesh(),
-        features=prep.features,
-        forced_masks=np.asarray(forced_masks),
-        config=config,
-    )
+    with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices())):
+        res = sweep(
+            prep.ec,
+            prep.st0,
+            prep.tmpl_ids,
+            prep.forced,
+            node_valid_masks,
+            pod_valid_masks,
+            mesh=default_mesh(),
+            features=prep.features,
+            forced_masks=np.asarray(forced_masks),
+            config=config,
+        )
+        jax.block_until_ready(res.chosen)  # dispatch is async; trace real device time
+    return res
 
 
 @functools.partial(jax.jit, static_argnames=("features", "config", "unroll"))

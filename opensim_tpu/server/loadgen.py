@@ -39,7 +39,8 @@ __all__ = [
     "run_stub_benchmark",
     "run_fleet_benchmark",
     "run_pipeline_benchmark",
-    "placement_parity",
+    "parity_answers",
+    "server_device",
     "parse_metrics",
     "histogram_quantile",
     "scrape_metrics",
@@ -398,9 +399,10 @@ def _boot_server(kubeconfig: str, port: int, admission: bool, batch_max: int,
     import sys
 
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    # the parent's platform choice (JAX_PLATFORMS, set or unset) passes
+    # through unchanged: a bench on the chip measures chip servers
     env = dict(
         os.environ,
-        JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
         OPENSIM_ADMISSION="on" if admission else "off",
         OPENSIM_BATCH_MAX=str(batch_max),
         PYTHONPATH=repo + os.pathsep + os.environ.get("PYTHONPATH", ""),
@@ -528,6 +530,7 @@ def run_stub_benchmark(
         try:
             _warm_concurrent(url, min(16, concurrency), 60.0)
             batched = drive(url)
+            device = server_device(url)
         finally:
             _stop_server(proc)
     finally:
@@ -550,6 +553,7 @@ def run_stub_benchmark(
         "mean_batch_size": batched["mean_batch_size"],
         "shed": batched["shed"],
         "shed_single_flight": single["shed"],
+        "device": device,
         "single_flight": single,
         "admission": batched,
     }
@@ -595,6 +599,10 @@ def run_pipeline_benchmark(
         try:
             _warm_concurrent(url, min(16, concurrency), 60.0)
             serial = drive(url)
+            # parity gate between the two modes, against the same stub
+            # cluster: each side answers the same probes while it is the
+            # only server up
+            serial_answers = parity_answers(url)
         finally:
             _stop_server(proc)
         pproc, purl = _boot_server(
@@ -606,17 +614,8 @@ def run_pipeline_benchmark(
             before = scrape_metrics(purl)
             piped = drive(purl)
             after = scrape_metrics(purl)
-            # parity gate between the two modes, against the same stub
-            # cluster: a fresh non-pipelined server answers the same
-            # probes the pipelined one does
-            sproc, surl = _boot_server(
-                kc, base_port + 40, admission=True, batch_max=batch_max,
-                pipeline=False,
-            )
-            try:
-                parity = placement_parity(surl, purl)
-            finally:
-                _stop_server(sproc)
+            parity = parity_answers(purl) == serial_answers
+            device = server_device(purl)
         finally:
             _stop_server(pproc)
     finally:
@@ -650,6 +649,7 @@ def run_pipeline_benchmark(
         "shed": piped["shed"],
         "errors": piped["errors"],
         "placements_identical": parity,
+        "device": device,
         "non_pipelined": serial,
         "pipelined": piped,
     }
@@ -681,7 +681,6 @@ def run_loadgen_sharded(
         shares[i] += 1
     before = scrape_metrics(metrics_url)
     t_start = time.monotonic()
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
     children = [
         subprocess.Popen(
             [
@@ -689,7 +688,7 @@ def run_loadgen_sharded(
                 "--mode", "closed", "--concurrency", str(share),
                 "--duration", str(duration_s), "--timeout", str(timeout_s),
             ],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, env=env,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
         )
         for share in shares
         if share > 0
@@ -752,23 +751,25 @@ def run_loadgen_sharded(
 # ---------------------------------------------------------------------------
 
 
+def canon_pod_ref(ref: str) -> str:
+    """``ns/name`` of a pod → ``ns/<owning workload>``: strips every trailing
+    generated segment (10-hex expansion counters). A Deployment pod carries
+    TWO — the ReplicaSet's and its own — and the counters are process-global,
+    so they differ across servers by design."""
+    ns, _, name = ref.partition("/")
+    parts = name.split("-")
+    while len(parts) > 1 and re.fullmatch(r"[0-9a-f]{10}", parts[-1]):
+        parts.pop()
+    return f"{ns}/{'-'.join(parts)}"
+
+
 def _canon_response(body: dict) -> tuple:
     """Placement identity view of a deploy response: expanded pod names
     carry per-process random suffixes (NOTES invariant), so pods are
     canonicalized onto their owning workload (the name minus the final
     suffix segment) and compared as (node, workload, count) triples plus
     the unscheduled (workload, reason) set."""
-    def canon(ref: str) -> str:
-        # strip every trailing generated segment (10-hex expansion
-        # counters): a Deployment pod carries TWO — the ReplicaSet's and
-        # its own — and the counters are process-global, so they differ
-        # across servers by design
-        ns, _, name = ref.partition("/")
-        parts = name.split("-")
-        while len(parts) > 1 and re.fullmatch(r"[0-9a-f]{10}", parts[-1]):
-            parts.pop()
-        return f"{ns}/{'-'.join(parts)}"
-
+    canon = canon_pod_ref
     placed = sorted(
         (e["node"], sorted(canon(p) for p in e["pods"]))
         for e in body.get("nodeStatus", [])
@@ -788,18 +789,32 @@ def _post_deploy(url: str, payload: bytes, timeout_s: float = 60.0) -> dict:
         return json.loads(resp.read().decode())
 
 
-def placement_parity(url_a: str, url_b: str, n_probes: int = 4) -> bool:
-    """The fleet bit-identity gate, end to end over HTTP: the same deploy
-    payloads against both servers must place onto the same nodes with the
-    same per-workload counts and the same unschedulable reasons."""
-    for i in range(n_probes):
-        payload = _payload(777, i, 3, "500m", "1Gi")
-        a = _canon_response(_post_deploy(url_a, payload))
-        b = _canon_response(_post_deploy(url_b, payload))
-        if a != b:
-            log.warning("placement parity failed on probe %d: %r != %r", i, a, b)
-            return False
-    return True
+def parity_answers(url: str, n_probes: int = 4) -> list:
+    """One server's side of the bit-identity gate, end to end over HTTP:
+    the canonical placements of a fixed set of deploy payloads. Two servers
+    (or modes) agree when their answer lists are equal — same nodes, same
+    per-workload counts, same unschedulable reasons. Taken from one server
+    at a time: on an accelerator the two sides cannot be up together (one
+    process per chip)."""
+    return [
+        _canon_response(_post_deploy(url, _payload(777, i, 3, "500m", "1Gi")))
+        for i in range(n_probes)
+    ]
+
+
+def server_device(url: str) -> dict:
+    """platform / device_kind / device count of the backend the SERVER
+    computes on, from its ``simon_device_info`` series — the load generator
+    stamps its rows from this and never initializes JAX itself."""
+    for (name, labels), count in scrape_metrics(url).items():
+        if name == "simon_device_info":
+            ld = dict(labels)
+            return {
+                "platform": ld.get("platform", ""),
+                "device_kind": ld.get("device_kind", ""),
+                "device_count": int(count),
+            }
+    raise RuntimeError(f"{url}/metrics carries no simon_device_info series")
 
 
 def run_fleet_benchmark(
@@ -859,6 +874,9 @@ def run_fleet_benchmark(
         try:
             _warm_concurrent(url, min(16, concurrency), 60.0)
             single = drive(url)
+            # parity gate: both sides answer the same probes against the
+            # same stub cluster, each while it is the only server up
+            single_answers = parity_answers(url)
         finally:
             _stop_server(proc)
         fproc, furl = _boot_server(
@@ -874,15 +892,8 @@ def run_fleet_benchmark(
                 f"{admin_url}/api/fleet/status", timeout=5.0
             ) as resp:
                 status = json.loads(resp.read().decode())
-            # parity gate: re-boot a fresh single-process server so both
-            # sides answer the same probes against the same stub cluster
-            pproc, purl = _boot_server(
-                kc, base_port + 40, admission=True, batch_max=batch_max,
-            )
-            try:
-                parity = placement_parity(purl, furl)
-            finally:
-                _stop_server(pproc)
+            parity = parity_answers(furl) == single_answers
+            device = server_device(furl)
         finally:
             _stop_server(fproc)
     finally:
@@ -917,6 +928,7 @@ def run_fleet_benchmark(
             fleet_metrics.get(("simon_fleet_publishes_total", ()), 0.0)
         ),
         "respawns": status.get("respawns_total", 0),
+        "device": device,
         "single_process": single,
         "fleet": fleet,
     }

@@ -19,7 +19,8 @@ Two modes (``OPENSIM_WORKERS_MODE``):
   (payload → serialized JSON-safe response) so nothing unpicklable crosses
   the pipe. Platforms without ``fork`` (or where the probe task fails —
   e.g. an XLA runtime that does not survive forking) fall back to threads
-  with a warning, never a broken server.
+  with a warning, never a broken server. On a TPU backend process mode
+  (like the ``--workers`` fleet) refuses to start: one process per chip.
 
 The pool never owns correctness: per-entry prep-cache locks still
 serialize touches of shared pod objects, exactly as on the solo path.
@@ -37,7 +38,33 @@ from ..utils import envknobs
 
 log = logging.getLogger("opensim_tpu.server")
 
-__all__ = ["WorkerPool", "worker_count", "worker_mode"]
+__all__ = [
+    "OneProcessPerChip", "WorkerPool", "one_process_per_chip", "worker_count",
+    "worker_mode",
+]
+
+
+class OneProcessPerChip(RuntimeError):
+    """A multi-process serving mode was asked for on a TPU backend."""
+
+
+def one_process_per_chip(what: str) -> Optional[str]:
+    """The refusal message for a multi-process serving mode on a TPU
+    backend, None elsewhere. A chip belongs to one process at a time: N
+    engine processes with no device assigned would crash-loop on the chip or
+    serve from whatever platform each one lands on, so start-up refuses
+    instead. (Initializes the JAX backend — callers that fork do it AFTER
+    forking.)"""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    return (
+        f"{what} puts more than one engine process on the accelerator, but a "
+        "TPU chip belongs to one process at a time; refusing to start. Run "
+        "one single-process `simon server` per chip (mapping a fleet onto "
+        "chips is not supported yet)"
+    )
 
 
 def worker_count() -> int:
@@ -120,7 +147,15 @@ class WorkerPool:
             if pool.submit(_probe).result(timeout=10.0) != 42:
                 pool.shutdown(wait=False)
                 return None
+            # checked only now: the workers forked above with no JAX backend
+            # initialized, exactly as on a CPU host before this check existed
+            refusal = one_process_per_chip("OPENSIM_WORKERS_MODE=process")
+            if refusal is not None:
+                pool.shutdown(wait=False)
+                raise OneProcessPerChip(refusal)
             return pool
+        except OneProcessPerChip:
+            raise
         except Exception as e:  # platform-specific fork/pipe failures
             log.warning(
                 "process worker pool probe failed (%s: %s)", type(e).__name__, e

@@ -1922,6 +1922,16 @@ def serve(
         from .fleet import run_worker
 
         return run_worker(port)
+    n_fleet = fleet_workers(workers)
+    from .pool import OneProcessPerChip, one_process_per_chip
+
+    if standby or n_fleet >= 2:
+        # one process per chip: the fleet (and a standby, a fleet owner in
+        # waiting) spawns N engine processes with no device assigned
+        refusal = one_process_per_chip("--standby" if standby else f"--workers {n_fleet}")
+        if refusal is not None:
+            print(f"simon server: {refusal}", flush=True)
+            return 1
     if standby:
         # HA hot standby (docs/serving.md "Surviving owner loss & rolling
         # upgrades"): tail the owner's journal, take over on lease expiry
@@ -1930,9 +1940,8 @@ def serve(
 
         return serve_standby(
             kubeconfig, master, port, watch, journal,
-            fleet_workers(workers) or 2, handover=ha_handover,
+            n_fleet or 2, handover=ha_handover,
         )
-    n_fleet = fleet_workers(workers)
     if n_fleet >= 2:
         from .fleet import serve_fleet
 
@@ -1943,9 +1952,13 @@ def serve(
     except ValueError as e:
         print(f"simon server: {e}", flush=True)
         return 1
-    server = SimonServer(
-        kubeconfig=kubeconfig, master=master, watch=supervisor, journal=jrnl
-    )
+    try:
+        server = SimonServer(
+            kubeconfig=kubeconfig, master=master, watch=supervisor, journal=jrnl
+        )
+    except OneProcessPerChip as e:  # OPENSIM_WORKERS_MODE=process on a TPU
+        print(f"simon server: {e}", flush=True)
+        return 1
     # low-rate RSS/device watermark sampler (OPENSIM_MEM_TICKER_S): only
     # the long-lived server process runs it — library/test constructions
     # of SimonServer sample on demand instead

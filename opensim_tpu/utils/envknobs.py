@@ -173,13 +173,13 @@ _ENGINE = [
     Knob("OPENSIM_NATIVE", "flag", "", "`1` forces the C++ scan engine (exact value; `--backend native` sets it).", None, section="engine"),
     Knob("OPENSIM_DISABLE_NATIVE", "flag", "", "Any non-empty value disables the C++ scan engine (pure XLA/Pallas paths only).", None, section="engine"),
     Knob("OPENSIM_DISABLE_FASTPATH", "flag", "", "Any non-empty value disables the Pallas megakernel fast path (`--backend xla` sets it).", None, section="engine"),
-    Knob("OPENSIM_FASTPATH", "enum", "", "Megakernel mode override; `interpret` runs the Pallas kernels in interpret mode (CI parity without a TPU).", None, choices=("", "interpret"), section="engine"),
+    Knob("OPENSIM_FASTPATH", "enum", "", "Megakernel mode override; `interpret` runs the Pallas kernels in interpret mode (CI parity without a TPU). Never inferred from the backend; `--backend tpu` refuses it.", None, choices=("", "interpret"), section="engine"),
     Knob("OPENSIM_REQUIRE_TPU", "flag", "", "`1` fails hard instead of falling back when the TPU engine cannot run (exact value; `--backend tpu` sets it).", None, section="engine"),
     Knob("OPENSIM_NATIVE_PROFILE", "flag", "", "Any non-empty value enables C++ engine per-stage profiling; populates `native_profile` in bench rows and engine traces.", None, section="engine"),
     Knob("OPENSIM_NATIVE_FORCE_GENERIC", "flag", "", "Disable the C++ engine's incremental cache (read inside scan_engine.cc; parity harness).", _flag, section="engine"),
     Knob("OPENSIM_SCAN_UNROLL", "int", "1", "XLA scan unroll factor (accelerator tuning; resolved outside jit so it keys the jit cache).", _int(lo=1), on_error="raise", section="engine"),
     Knob("OPENSIM_BATCH_ENGINE", "enum", "auto", "Request-axis batch engine: `auto` (C++ scans on accelerator-less hosts, vmapped XLA otherwise), `xla`, or `native`.", _enum("auto", "xla", "native"), on_error="raise", section="engine"),
-    Knob("OPENSIM_JIT_CACHE", "spec", "", "Persistent XLA compile cache: `1` = default dir (~/.cache/opensim-tpu/jit), `0` = force off, a path = enable there. bench/CLI default it on.", None, section="engine"),
+    Knob("OPENSIM_JIT_CACHE", "flag", "", "`0` turns the persistent XLA compile cache off; bench/CLI/tests default it on. The directory is `JAX_COMPILATION_CACHE_DIR` when set, else the git-ignored `.jit_cache/` in the checkout (utils/jitcache.py).", None, section="engine"),
 ]
 
 _RESILIENCE = [
@@ -265,7 +265,6 @@ _DEBUG = [
     Knob("OPENSIM_LOCKWATCH_HOLD_MS", "float", "500", "Lockwatch hold-time outlier threshold in ms (floor 1; a typo degrades to the default with a warning).", _float(lo=1.0), section="debug"),
     Knob("OPENSIM_LOCKWATCH_HOLD_EXEMPT", "spec", "", "Comma-separated site substrings exempt from lockwatch hold-time checks (inversions are never exempt).", None, section="debug"),
     Knob("OPENSIM_NO_PROGRESS", "flag", "", "Any non-empty value suppresses interactive progress spinners.", None, section="debug"),
-    Knob("OPENSIM_PROBE_CACHE", "path", "", "Accelerator-probe verdict cache file (default: under XDG_RUNTIME_DIR/tmp).", None, section="debug"),
 ]
 
 for _knob in _ENGINE + _RESILIENCE + _SERVER + _OBSERVABILITY + _PLANNER + _DEBUG:

@@ -1,19 +1,23 @@
 """JAX persistent compilation cache wiring.
 
-The headline 50k/5k plan spends ~1 s of cold start compiling the scan/
+The headline 50k/5k plan spends its cold start compiling the scan/
 megakernel pipelines; the persistent cache amortizes that across processes
-(CI runs, repeated `simon apply` invocations, server restarts).
+(CI runs, repeated `simon apply` invocations, server restarts, the phases of
+``chip_smoke.py``).
 
-Opt-in via environment:
-  OPENSIM_JIT_CACHE=1        enable at the default dir (~/.cache/opensim-tpu/jit)
-  OPENSIM_JIT_CACHE=<path>   enable at <path>
-  OPENSIM_JIT_CACHE=0        force-disable (even for callers that default on)
+One resolver, :func:`cache_dir`:
 
-``bench.py`` and test conftest enable it by default (JAX_COMPILATION_CACHE_DIR
-wins if already set so existing workflows keep their cache location).
-Call ``maybe_enable`` BEFORE the first jax import when possible — the env
-var route is the most portable across jax versions; the config.update calls
-cover an already-imported jax.
+- ``JAX_COMPILATION_CACHE_DIR`` set → JAX itself reads it; this module sets
+  no directory in code and creates none, so a cache placed from outside (a
+  CI volume, the chip machine's own setting) is the only one touched;
+- unset → the fixed, git-ignored ``.jit_cache/`` at the checkout root. The
+  path is part of nothing random (no pid, time or temp name): two processes
+  started from the same checkout resolve the same directory, so the second
+  one hits what the first one compiled.
+
+``OPENSIM_JIT_CACHE=0`` turns the cache off; any other value (or unset)
+leaves the caller's default in effect. ``bench.py``, the CLI and the test
+conftest default it on.
 """
 
 from __future__ import annotations
@@ -23,27 +27,28 @@ from typing import Optional
 
 from . import envknobs
 
+#: the in-checkout default (listed in .gitignore)
 DEFAULT_DIR = os.path.join(
-    os.path.expanduser(os.environ.get("XDG_CACHE_HOME", "~/.cache")),
-    "opensim-tpu",
-    "jit",
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jit_cache",
 )
 
-#: the directory maybe_enable() actually activated (None = disabled) —
-#: cache_stats() reports it to the compile-telemetry surface (obs/profile)
-_ACTIVE_DIR: Optional[str] = None
+
+def cache_dir() -> str:
+    """The one persistent-cache directory this process may use."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_DIR
 
 
 def cache_stats() -> Optional[dict]:
     """Footprint of the persistent compilation cache directory, or None
     when disabled. O(entries) directory scan — called from debug/metrics
     reads, never the serving hot path."""
-    cache_dir = _ACTIVE_DIR or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not cache_dir or not os.path.isdir(cache_dir):
+    if envknobs.raw("OPENSIM_JIT_CACHE") == "0":
         return None
+    d = cache_dir()
     files = total = 0
     try:
-        with os.scandir(cache_dir) as it:
+        with os.scandir(d) as it:
             for entry in it:
                 try:
                     if entry.is_file():
@@ -53,46 +58,37 @@ def cache_stats() -> Optional[dict]:
                     continue  # entry raced away mid-scan
     except OSError:
         return None
-    return {"dir": cache_dir, "files": files, "bytes": total}
+    return {"dir": d, "files": files, "bytes": total}
 
 
-def maybe_enable(default: bool = False, path: Optional[str] = None) -> Optional[str]:
-    """Enable the persistent compilation cache if opted in.
+def maybe_enable(default: bool = False) -> Optional[str]:
+    """Enable the persistent compilation cache unless opted out.
 
     Returns the cache directory in effect, or None when disabled. `default`
     is the behavior with OPENSIM_JIT_CACHE unset: benches/CLIs that always
-    benefited from a warm cache pass True."""
+    benefit from a warm cache pass True."""
     raw = envknobs.raw("OPENSIM_JIT_CACHE")
-    if raw == "0":
+    if raw == "0" or (not raw and not default):
         return None
-    if not raw and not default and not path:
-        return None
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or (
-        raw if raw not in ("", "1") else None
-    ) or path or DEFAULT_DIR
+    import jax
+
+    # cache every compilation, not only the slow ones: the scan pipeline
+    # recompiles per (P, N, feature) signature and each one matters
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed  # JAX reads the variable itself; nothing set in code
     try:
-        os.makedirs(cache_dir, exist_ok=True)
+        os.makedirs(DEFAULT_DIR, exist_ok=True)
     except OSError as e:
         # an unwritable cache dir degrades to cold compiles, it must never
         # fail the caller — but silently eating it hid real misconfiguration
-        # (a wrong OPENSIM_JIT_CACHE path looked identical to disabled)
         import logging
 
         logging.getLogger("opensim_tpu").warning(
-            "persistent jit cache disabled: cannot create %s (%s)", cache_dir, e
+            "persistent jit cache disabled: cannot create %s (%s)", DEFAULT_DIR, e
         )
         return None
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
-    global _ACTIVE_DIR
-    _ACTIVE_DIR = cache_dir
-    try:  # jax may already be imported: set the config knobs directly too
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every compilation, not only the slow ones: the scan pipeline
-        # recompiles per (P, N, feature) signature and each one matters
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except (ImportError, AttributeError, ValueError, KeyError):
-        pass  # pre-import usage / older jax without the knob: the env var alone is enough
-    return cache_dir
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return DEFAULT_DIR

@@ -21,7 +21,8 @@ _INTERPRET = os.environ.get("OPENSIM_TEST_BACKEND") != "tpu"
 def _enable_interpret_fastpath(monkeypatch):
     """applicable() requires a TPU backend unless interpret mode is forced
     (the rest of the suite intentionally exercises the XLA path on CPU)."""
-    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    if _INTERPRET:  # the chip run compiles; the interpreter is asked for by name
+        monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
 
 
 def _prep(n_nodes=16, with_spread=True, with_zone=True, replicas=64):
@@ -511,7 +512,6 @@ def test_fastpath_failure_reasons_without_rescan(monkeypatch):
     from opensim_tpu.engine import simulator as sim_mod
     from opensim_tpu.engine.simulator import simulate
 
-    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
     scans = []
     orig_scan = sim_mod.schedule_pods
 
@@ -532,7 +532,8 @@ def test_fastpath_failure_reasons_without_rescan(monkeypatch):
     assert len(res.unscheduled_pods) == 4
     fast_reasons = sorted(u.reason for u in res.unscheduled_pods)
 
-    monkeypatch.delenv("OPENSIM_FASTPATH")
+    monkeypatch.delenv("OPENSIM_FASTPATH", raising=False)
+    monkeypatch.setenv("OPENSIM_DISABLE_FASTPATH", "1")  # on the chip the kernel would engage again
     res2 = simulate(cluster, [AppResource("a", app)])
     assert sorted(u.reason for u in res2.unscheduled_pods) == fast_reasons
     assert "Insufficient cpu" in fast_reasons[0]
@@ -540,12 +541,11 @@ def test_fastpath_failure_reasons_without_rescan(monkeypatch):
 
 def test_fastpath_engages_through_simulate(monkeypatch):
     """End-to-end: simulate() must take the fast branch (interpret mode on
-    CPU via OPENSIM_FASTPATH) and produce the same placements as the XLA
-    path."""
+    CPU via OPENSIM_FASTPATH, compiled on the chip) and produce the same
+    placements as the engine below it."""
     from opensim_tpu.engine import fastpath as fp
     from opensim_tpu.engine.simulator import simulate
 
-    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
     calls = []
     orig = fp.schedule
 
@@ -568,7 +568,8 @@ def test_fastpath_engages_through_simulate(monkeypatch):
 
     # same workload through the XLA path gives identical placement (pod
     # names get fresh suffixes per expansion; compare in name order)
-    monkeypatch.delenv("OPENSIM_FASTPATH")
+    monkeypatch.delenv("OPENSIM_FASTPATH", raising=False)
+    monkeypatch.setenv("OPENSIM_DISABLE_FASTPATH", "1")  # on the chip the kernel would engage again
     res2 = simulate(cluster, [AppResource("a", app)])
 
     def placement_seq(r):

@@ -206,7 +206,8 @@ def test_jitcache_stats_counts_files(tmp_path, monkeypatch):
     cache_dir.mkdir()
     (cache_dir / "a.bin").write_bytes(b"x" * 100)
     (cache_dir / "b.bin").write_bytes(b"y" * 50)
-    monkeypatch.setattr(jitcache, "_ACTIVE_DIR", str(cache_dir))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+    monkeypatch.delenv("OPENSIM_JIT_CACHE", raising=False)
     stats = jitcache.cache_stats()
     assert stats == {"dir": str(cache_dir), "files": 2, "bytes": 150}
 

@@ -18,7 +18,6 @@ pytestmark = pytest.mark.slow  # nightly tier (README: test tiering)
 _CHILD = r"""
 import os, sys
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 
 from opensim_tpu.parallel import multihost
@@ -75,7 +74,7 @@ def test_two_process_dcn_sweep(tmp_path):
                 os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
             ),
         )
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # virtual host devices exist only on the CPU platform
         procs.append(
             subprocess.Popen(
                 [sys.executable, str(script)],
@@ -110,7 +109,6 @@ def test_two_process_dcn_sweep(tmp_path):
 _PLANNER_CHILD = r"""
 import os, sys
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 from opensim_tpu.parallel import multihost
 
@@ -187,7 +185,7 @@ def test_two_process_capacity_planner(tmp_path):
                 os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
             ),
         )
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # virtual host devices exist only on the CPU platform
         scratch = tmp_path / f"p{pid}"
         scratch.mkdir()
         procs.append(

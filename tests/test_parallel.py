@@ -1,4 +1,8 @@
-"""Scenario sweep + defragmentation tests on the 8-device virtual CPU mesh."""
+"""Scenario sweep + defragmentation tests on the 8-device virtual CPU mesh.
+The megakernel sweeps run in interpret mode on CPU; OPENSIM_TEST_BACKEND=tpu
+compiles the batched kernel through Mosaic for real."""
+
+import os
 
 import numpy as np
 import pytest
@@ -8,6 +12,15 @@ from opensim_tpu.models import ResourceTypes
 from opensim_tpu.models import fixtures as fx
 from opensim_tpu.parallel import scenarios
 from opensim_tpu.planner.defrag import plan_drains
+
+_INTERPRET = os.environ.get("OPENSIM_TEST_BACKEND") != "tpu"
+
+
+def _arm_megakernel(monkeypatch):
+    """fastpath.applicable() needs a TPU backend unless the interpreter is
+    asked for by name — which only the CPU run does."""
+    if _INTERPRET:
+        monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
 
 
 def _setup(n_nodes=6, replicas=8):
@@ -68,7 +81,7 @@ def test_defrag_reschedules_prebound_pods():
 def test_fastpath_sweep_matches_xla_sweep(monkeypatch):
     """The megakernel-backed sweep must agree with the vmapped XLA sweep on
     unscheduled counts, placements, and final usage."""
-    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    _arm_megakernel(monkeypatch)
     from opensim_tpu.engine import fastpath
 
     cluster, apps = _setup(n_nodes=6, replicas=16)
@@ -88,7 +101,7 @@ def test_fastpath_sweep_matches_xla_sweep(monkeypatch):
         features=prep.features,
     )
     got_unsched, got_used, got_chosen, got_vg = fastpath.sweep(
-        prep, node_valid, pod_valid, forced, interpret=True
+        prep, node_valid, pod_valid, forced, interpret=_INTERPRET
     )
     np.testing.assert_array_equal(got_unsched, np.asarray(want.unscheduled))
     np.testing.assert_array_equal(got_chosen, np.asarray(want.chosen)[:, :P])
@@ -101,7 +114,7 @@ def test_fastpath_sweep_large_batch(monkeypatch):
     """A larger scenario batch (S=40) through the single-dispatch vmapped
     megakernel still matches the XLA sweep — guards the batched-grid path
     (scratch reinit per scenario, unbatched table sharing)."""
-    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    _arm_megakernel(monkeypatch)
     from opensim_tpu.engine import fastpath
 
     cluster, apps = _setup(n_nodes=8, replicas=24)
@@ -125,7 +138,7 @@ def test_fastpath_sweep_large_batch(monkeypatch):
         features=prep.features,
     )
     got_unsched, got_used, got_chosen, got_vg = fastpath.sweep(
-        prep, node_valid, pod_valid, forced, interpret=True
+        prep, node_valid, pod_valid, forced, interpret=_INTERPRET
     )
     np.testing.assert_array_equal(got_unsched, np.asarray(want.unscheduled))
     np.testing.assert_array_equal(got_chosen, np.asarray(want.chosen)[:, :P])
@@ -144,7 +157,7 @@ def test_fastpath_sweep_fuzz_feature_rich(monkeypatch, seed):
     the batched kernel awaiting compiled-Mosaic validation."""
     import random as _random
 
-    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    _arm_megakernel(monkeypatch)
     from opensim_tpu.engine import fastpath
     from test_fastpath_fuzz import random_app, random_cluster
 
@@ -176,7 +189,7 @@ def test_fastpath_sweep_fuzz_feature_rich(monkeypatch, seed):
         features=prep.features, forced_masks=forced,
     )
     got_unsched, got_used, got_chosen, got_vg = fastpath.sweep(
-        prep, node_valid, pod_valid, forced, interpret=True
+        prep, node_valid, pod_valid, forced, interpret=_INTERPRET
     )
     np.testing.assert_array_equal(got_unsched, np.asarray(want.unscheduled))
     np.testing.assert_array_equal(got_chosen, np.asarray(want.chosen)[:, :P])
@@ -189,7 +202,7 @@ def test_fastpath_sweep_big_u_mode(monkeypatch):
     """Batched sweep with the template tables in HBM (big-U per-step DMA)
     — the combination of the two round-3 envelope features, previously
     only tested separately."""
-    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    _arm_megakernel(monkeypatch)
     from opensim_tpu.engine import fastpath
 
     cluster, apps = _setup(n_nodes=6, replicas=8)
@@ -214,7 +227,7 @@ def test_fastpath_sweep_big_u_mode(monkeypatch):
         features=prep.features,
     )
     got_unsched, got_used, got_chosen, got_vg = fastpath.sweep(
-        prep, node_valid, pod_valid, forced, interpret=True, big_u=True
+        prep, node_valid, pod_valid, forced, interpret=_INTERPRET, big_u=True
     )
     np.testing.assert_array_equal(got_unsched, np.asarray(want.unscheduled))
     np.testing.assert_array_equal(got_chosen, np.asarray(want.chosen)[:, :P])

@@ -249,15 +249,15 @@ def test_jitcache_unwritable_dir_logs_and_disables(monkeypatch, caplog, tmp_path
 
     from opensim_tpu.utils import jitcache
 
-    blocked = tmp_path / "blocked" / "jit"
-
     def deny(path, exist_ok=False):
         raise OSError(13, "Permission denied")
 
     monkeypatch.setattr(os, "makedirs", deny)
+    monkeypatch.setattr(jitcache, "DEFAULT_DIR", str(tmp_path / "blocked" / "jit"))
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("OPENSIM_JIT_CACHE", raising=False)
     with caplog.at_level(logging.WARNING, logger="opensim_tpu"):
-        assert jitcache.maybe_enable(path=str(blocked)) is None
+        assert jitcache.maybe_enable(default=True) is None
     assert any("persistent jit cache disabled" in r.message for r in caplog.records)
 
 

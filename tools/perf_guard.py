@@ -2,8 +2,8 @@
 """Perf-regression sentinel (ISSUE 12): guard bench rows against the
 committed baseline manifest.
 
-The bench trajectory (BENCH_r01..r06) was append-only JSON no gate ever
-read — a perf or memory regression shipped silently. This tool closes the
+The bench trajectory (the BENCH_r*.json rows) was append-only JSON no gate
+ever read — a perf or memory regression shipped silently. This tool closes the
 loop against ``BENCH_BASELINE.json``:
 
 - every baseline entry carries the committed row plus per-metric

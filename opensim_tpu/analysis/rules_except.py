@@ -67,5 +67,5 @@ class ExceptionSwallowRule(Rule):
                     ctx,
                     node,
                     f"`{caught}` swallows the failure (no raise, no log); "
-                    "narrow the exception or log via utils/trace's logger",
+                    "narrow the exception or log the failure",
                 )

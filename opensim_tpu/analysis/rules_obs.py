@@ -9,13 +9,13 @@ a boundary the flight recorder has no span for, and the latency histograms
 go dark exactly where requests die.
 
 The rule flags any function that calls a Deadline API but opens no span in
-the same function body (``obs.span`` / ``record_span`` / ``event`` /
+the same function body (``obs.span`` / ``event`` /
 ``start_trace`` / ``trace_scope``). Nested ``def``/``lambda`` bodies are
 not credited to the outer function — a span opened inside a callback does
 not cover the enclosing boundary.
 
-Fix by wrapping the phase in ``with obs.span("phase"):`` (or recording a
-measured duration with ``obs.record_span``); see docs/observability.md.
+Fix by wrapping the phase in ``with obs.span("phase"):``; see
+docs/observability.md.
 """
 
 from __future__ import annotations
@@ -28,7 +28,6 @@ from .core import FileContext, Finding, Rule, dotted_name, register
 _DEADLINE_CALLS = {"check_deadline", "deadline_scope"}
 _SPAN_CALLS = {
     "span",
-    "record_span",
     "event",
     "start_trace",
     "trace_scope",
@@ -86,6 +85,6 @@ class DeadlineSpanRule(Rule):
                     first_deadline,
                     f"function {fn.name!r} opens a Deadline phase boundary "
                     "but records no trace span; wrap the phase in "
-                    "`with obs.span(...)` (or obs.record_span) so the "
+                    "`with obs.span(...)` so the "
                     "flight recorder and latency histograms cover it",
                 )

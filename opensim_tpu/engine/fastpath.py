@@ -13,9 +13,12 @@ import math
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..encoding import vocab as V
+from ..obs import trace as obs
+from ..obs.profile import launch_span
 from ..ops import kernels
 from ..ops.pallas_scan import CHUNK, FastInputs, run_fast_scan
 from ..utils import envknobs
@@ -220,8 +223,6 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
     # static tables computed with ALL nodes valid: validity is applied as a
     # runtime row inside the kernel so scenario sweeps can mask nodes without
     # re-marshalling (static filters are per-node, so this is equivalent)
-    import jax.numpy as jnp
-
     ec_all_valid = prep.ec._replace(node_valid=jnp.ones_like(prep.ec.node_valid))
     stat = jax.device_get(_precompute_jit(ec_all_valid))
     # static_fail diagnostics must count over the REAL valid set (the
@@ -461,7 +462,7 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
     meta = {"static_fail": static_fail_real, "n_orig": N_orig}
     # device-resident copies so repeated runs (capacity loops, sweeps) skip
     # the host→device transfer of ~25 arrays
-    fi = FastInputs(*[jax.numpy.asarray(a) for a in fi])
+    fi = FastInputs(*[jnp.asarray(a) for a in fi])
     try:
         prep._fast_inputs = (fi, meta)
     except AttributeError:
@@ -524,48 +525,53 @@ def sweep(
     heuristic (tests override it to exercise the HBM-DMA path on small
     shapes)."""
     interpret = _resolve_interpret(interpret)
-    fi, meta = build_inputs(prep)
-    if big_u is None:
-        big_u = use_big_u(*fi.static_pass.shape)
     S = node_valid_masks.shape[0]
     P = pod_valid_masks.shape[1]
-    pad = (-P) % CHUNK
-    tmpl = np.asarray(prep.tmpl_ids)
-    if pad:
-        tmpl = np.concatenate([tmpl, np.zeros(pad, tmpl.dtype)])
-    ctx = _SweepContext(prep)
-    vg0 = np.asarray(fi.vg0_VN)
-    N_orig = meta["n_orig"]
-    N_pad = int(fi.node_valid.shape[1])
+    with obs.span("mk.inputs", scenarios=S):
+        fi, meta = build_inputs(prep)
+        if big_u is None:
+            big_u = use_big_u(*fi.static_pass.shape)
+        pad = (-P) % CHUNK
+        tmpl = np.asarray(prep.tmpl_ids)
+        if pad:
+            tmpl = np.concatenate([tmpl, np.zeros(pad, tmpl.dtype)])
+        ctx = _SweepContext(prep)
+        vg0 = np.asarray(fi.vg0_VN)
+        N_orig = meta["n_orig"]
+        N_pad = int(fi.node_valid.shape[1])
 
-    nv_all = np.zeros((S, N_pad), bool)
-    nv_all[:, :N_orig] = np.asarray(node_valid_masks, dtype=bool)
-    pv_all = np.zeros((S, P + pad), bool)
-    pv_all[:, :P] = np.asarray(pod_valid_masks, dtype=bool)
-    fm_all = np.zeros((S, P + pad), bool)
-    fm_all[:, :P] = np.asarray(forced_masks, dtype=bool)
-    sw_all = np.stack(
-        [ctx.spread_weights(nv_all[s, :N_orig]) for s in range(S)]
-    )
-
-    import jax.numpy as jnp
-
-    chosen_b, used_b, _gt, _gf, vg_b, _dev = run_fast_scan(
-        fi._replace(
+        nv_all = np.zeros((S, N_pad), bool)
+        nv_all[:, :N_orig] = np.asarray(node_valid_masks, dtype=bool)
+        pv_all = np.zeros((S, P + pad), bool)
+        pv_all[:, :P] = np.asarray(pod_valid_masks, dtype=bool)
+        fm_all = np.zeros((S, P + pad), bool)
+        fm_all[:, :P] = np.asarray(forced_masks, dtype=bool)
+        sw_all = np.stack(
+            [ctx.spread_weights(nv_all[s, :N_orig]) for s in range(S)]
+        )
+        fi = fi._replace(
             node_valid=jnp.asarray(nv_all.astype(np.float32)[:, None, :]),
             spr_weight=jnp.asarray(sw_all),
-        ),
-        tmpl, pv_all, fm_all,
-        interpret=interpret, big_u=big_u, **_kernel_flags(prep),
-    )
+        )
 
-    chosen_all = np.asarray(chosen_b)[:, :P]
-    unscheduled = ((chosen_all < 0) & pv_all[:, :P]).sum(axis=1).astype(np.int32)
-    used = np.asarray(used_b).transpose(0, 2, 1)[:, :N_orig]
-    # per the XLA sweep, VG usage counts only scenario-valid nodes
-    vg_used = ((vg0[None] - np.asarray(vg_b)) * nv_all[:, None, :]).sum(
-        axis=(1, 2)
-    ).astype(np.float32)
+    with launch_span(  # as in schedule(): no helper frame round the kernel
+        "mk.launch", scenarios=S, pods=P + pad, nodes=fi.alloc_T.shape[1],
+        templates=fi.static_pass.shape[0], big_u=big_u,
+    ):
+        chosen_b, used_b, _gt, _gf, vg_b, _dev = outs = run_fast_scan(
+            fi, tmpl, pv_all, fm_all,
+            interpret=interpret, big_u=big_u, **_kernel_flags(prep),
+        )
+    with obs.span("mk.wait"):
+        jax.block_until_ready(outs)
+    with obs.span("mk.fetch"):
+        chosen_all = np.asarray(chosen_b)[:, :P]
+        unscheduled = ((chosen_all < 0) & pv_all[:, :P]).sum(axis=1).astype(np.int32)
+        used = np.asarray(used_b).transpose(0, 2, 1)[:, :N_orig]
+        # per the XLA sweep, VG usage counts only scenario-valid nodes
+        vg_used = ((vg0[None] - np.asarray(vg_b)) * nv_all[:, None, :]).sum(
+            axis=(1, 2)
+        ).astype(np.float32)
     return unscheduled, used, chosen_all, vg_used
 
 
@@ -584,36 +590,48 @@ def schedule(
     # fails hard under OPENSIM_REQUIRE_TPU=1 (chaos suite)
     faults.fault_point("engine.compile")
     interpret = _resolve_interpret(interpret)
-    fi, meta = build_inputs(prep)
-    if big_u is None:
-        big_u = use_big_u(*fi.static_pass.shape)
-    tmpl_ids = np.asarray(tmpl_ids)
-    pod_valid = np.asarray(pod_valid)
-    forced = np.asarray(forced)
-    P = len(tmpl_ids)
-    pad = (-P) % CHUNK
-    if pad:
-        tmpl_ids = np.concatenate([tmpl_ids, np.zeros(pad, tmpl_ids.dtype)])
-        pod_valid = np.concatenate([pod_valid, np.zeros(pad, bool)])
-        forced = np.concatenate([forced, np.zeros(pad, bool)])
-    chosen, used_T, gpu_take, gpu_T, vg_T, dev_T = (
-        out[0]
-        for out in run_fast_scan(
-            fi._replace(node_valid=fi.node_valid[None], spr_weight=fi.spr_weight[None]),
-            tmpl_ids, pod_valid[None], forced[None],
+    with obs.span("mk.inputs"):
+        fi, meta = build_inputs(prep)
+        if big_u is None:
+            big_u = use_big_u(*fi.static_pass.shape)
+        tmpl_ids = np.asarray(tmpl_ids)
+        pod_valid = np.asarray(pod_valid)
+        forced = np.asarray(forced)
+        P = len(tmpl_ids)
+        pad = (-P) % CHUNK
+        if pad:
+            tmpl_ids = np.concatenate([tmpl_ids, np.zeros(pad, tmpl_ids.dtype)])
+            pod_valid = np.concatenate([pod_valid, np.zeros(pad, bool)])
+            forced = np.concatenate([forced, np.zeros(pad, bool)])
+        fi = fi._replace(node_valid=fi.node_valid[None], spr_weight=fi.spr_weight[None])
+    # mk.launch: everything the host does to get the kernel onto the device
+    # (the eager pallas_call's trace, lowering and cache lookup, argument
+    # transfer, enqueue, the lazy reshapes of its outputs); the device's own
+    # time is mk.wait. The span is opened here and not in a helper round
+    # run_fast_scan: one more Python frame between the caller and the
+    # kernel made its lowering 0.3 s longer on the chip's host (PERF.md §6).
+    with launch_span(
+        "mk.launch", scenarios=1, pods=len(tmpl_ids), nodes=fi.alloc_T.shape[1],
+        templates=fi.static_pass.shape[0], big_u=big_u,
+    ):
+        outs = run_fast_scan(
+            fi, tmpl_ids, pod_valid[None], forced[None],
             interpret=interpret, big_u=big_u, **_kernel_flags(prep),
         )
-    )
-    Gd = int(prep.st0.gpu_free.shape[1])
-    Vg = int(prep.st0.vg_free.shape[1])
-    Dv = int(prep.st0.dev_free.shape[1])
-    No = meta["n_orig"]  # lane padding added in build_inputs is trimmed here
-    return (
-        np.asarray(chosen)[:P],
-        np.asarray(used_T).T[:No],
-        meta["static_fail"],
-        np.asarray(gpu_take)[:P, :Gd],
-        np.asarray(gpu_T)[:Gd].T[:No],
-        np.asarray(vg_T)[:Vg].T[:No],
-        np.asarray(dev_T)[:Dv].T[:No],
-    )
+    with obs.span("mk.wait"):
+        jax.block_until_ready(outs)
+    with obs.span("mk.fetch"):
+        chosen, used_T, gpu_take, gpu_T, vg_T, dev_T = (out[0] for out in outs)
+        Gd = int(prep.st0.gpu_free.shape[1])
+        Vg = int(prep.st0.vg_free.shape[1])
+        Dv = int(prep.st0.dev_free.shape[1])
+        No = meta["n_orig"]  # lane padding added in build_inputs is trimmed here
+        return (
+            np.asarray(chosen)[:P],
+            np.asarray(used_T).T[:No],
+            meta["static_fail"],
+            np.asarray(gpu_take)[:P, :Gd],
+            np.asarray(gpu_T)[:Gd].T[:No],
+            np.asarray(vg_T)[:Vg].T[:No],
+            np.asarray(dev_T)[:Dv].T[:No],
+        )

@@ -32,7 +32,6 @@ from __future__ import annotations
 import hashlib
 import json
 import threading
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Tuple, Union
@@ -42,6 +41,7 @@ import numpy as np
 
 from ..encoding.state import ClusterEncoder, EncodedCluster, ScanState
 from ..models import expand
+from ..obs import trace as obs
 from ..models.objects import (
     ANNO_WORKLOAD_KIND,
     LABEL_APP_NAME,
@@ -379,8 +379,6 @@ class PrepareCache:
         if doomed:
             # trace event outside the cache lock (the span sink shares the
             # metrics recorder lock; never hold both)
-            from ..obs import trace as obs
-
             obs.event("prepcache.invalidate", dropped=len(doomed))
         return len(doomed)
 
@@ -400,8 +398,6 @@ class PrepareCache:
             faults.fault_point("cache.stale")
             entry.check_fresh()
         except StaleFingerprintError as e:
-            from ..obs import trace as obs
-
             obs.event("prepcache.stale", status="error", key=entry.key)
             if e.obj is not None:
                 self.invalidate(e.obj)
@@ -448,37 +444,40 @@ def _assemble_delta(
     n_bare: int,
     ds_group_sizes: List[int],
 ) -> Prepared:
-    ec_np, st0_np, meta = enc.build()
-    features = kernels.features_of(ec_np)
-    ec, st0 = _to_device_reusing(ec_np, st0_np, base_entry)
-    tmpl_ids = np.concatenate(
-        [np.asarray(p, dtype=np.int32) for p in tmpl_parts]
-    ) if tmpl_parts else np.zeros((0,), np.int32)
-    forced = np.concatenate(
-        [np.asarray(p, dtype=bool) for p in forced_parts]
-    ) if forced_parts else np.zeros((0,), bool)
-    node_idx = {name: i for i, name in enumerate(meta.node_names)}
-    ds_target = [
-        node_idx.get(pinned_node_name(p), -1)
-        if p.metadata.annotations.get(ANNO_WORKLOAD_KIND) == "DaemonSet"
-        else -1
-        for p in ordered
-    ]
-    return Prepared(
-        ec=ec,
-        st0=st0,
-        meta=meta,
-        ordered=ordered,
-        tmpl_ids=tmpl_ids,
-        forced=forced,
-        ds_target=ds_target,
-        features=features,
-        ec_np=ec_np,
-        encoder=enc,
-        n_cluster=n_cluster,
-        n_bare=n_bare,
-        ds_group_sizes=ds_group_sizes,
-    )
+    # prep.assemble: the arenas rebuilt, what changed sent to the device,
+    # and the list and array concatenations over every base pod
+    with obs.span("prep.assemble", pods=len(ordered)):
+        ec_np, st0_np, meta = enc.build()
+        features = kernels.features_of(ec_np)
+        ec, st0 = _to_device_reusing(ec_np, st0_np, base_entry)
+        tmpl_ids = np.concatenate(
+            [np.asarray(p, dtype=np.int32) for p in tmpl_parts]
+        ) if tmpl_parts else np.zeros((0,), np.int32)
+        forced = np.concatenate(
+            [np.asarray(p, dtype=bool) for p in forced_parts]
+        ) if forced_parts else np.zeros((0,), bool)
+        node_idx = {name: i for i, name in enumerate(meta.node_names)}
+        ds_target = [
+            node_idx.get(pinned_node_name(p), -1)
+            if p.metadata.annotations.get(ANNO_WORKLOAD_KIND) == "DaemonSet"
+            else -1
+            for p in ordered
+        ]
+        return Prepared(
+            ec=ec,
+            st0=st0,
+            meta=meta,
+            ordered=ordered,
+            tmpl_ids=tmpl_ids,
+            forced=forced,
+            ds_target=ds_target,
+            features=features,
+            ec_np=ec_np,
+            encoder=enc,
+            n_cluster=n_cluster,
+            n_bare=n_bare,
+            ds_group_sizes=ds_group_sizes,
+        )
 
 
 def _expand_app(cluster: ResourceTypes, app: AppResource, use_greed: bool) -> List[Pod]:
@@ -530,35 +529,36 @@ def derive_with_app_slices(
     app alone (gated by tests/test_admission.py)."""
     if isinstance(base, CacheEntry):  # convenience: entry accepted directly
         base_entry, base = base, base.prep
-    t0 = time.monotonic()
-    enc = base.encoder.fork()
-    new_pods: List = []
-    forced_new: List[bool] = []
-    slices: List[Tuple[int, int]] = []
-    n_base = len(base.ordered)
-    for app in apps:
-        lo = n_base + len(new_pods)
-        for p in _expand_app(cluster, app, use_greed):
-            new_pods.append(p)
-            forced_new.append(bool(p.spec.node_name))
-        slices.append((lo, n_base + len(new_pods)))
-    if not new_pods and not base.ordered:
-        return None
-    tmpl_new = [
-        enc.add_pod(p, (lambda p=p: _owner_selector(p)), hint=_tmpl_hint(p))
-        for p in new_pods
-    ]
-    prep = _assemble_delta(
-        base_entry,
-        enc,
-        ordered=list(base.ordered) + new_pods,
-        tmpl_parts=[base.tmpl_ids, tmpl_new] if len(base.tmpl_ids) else [tmpl_new],
-        forced_parts=[base.forced, forced_new] if len(base.forced) else [forced_new],
-        n_cluster=base.n_cluster,
-        n_bare=base.n_bare,
-        ds_group_sizes=list(base.ds_group_sizes or []),
-    )
-    PREP_STATS.record("delta_apps", time.monotonic() - t0)
+    with PREP_STATS.timed("delta_apps") as timed:
+        enc = base.encoder.fork()
+        new_pods: List = []
+        forced_new: List[bool] = []
+        slices: List[Tuple[int, int]] = []
+        n_base = len(base.ordered)
+        with obs.span("prep.expand"):
+            for app in apps:
+                lo = n_base + len(new_pods)
+                for p in _expand_app(cluster, app, use_greed):
+                    new_pods.append(p)
+                    forced_new.append(bool(p.spec.node_name))
+                slices.append((lo, n_base + len(new_pods)))
+        if not new_pods and not base.ordered:
+            timed.declined()
+            return None
+        tmpl_new = [
+            enc.add_pod(p, (lambda p=p: _owner_selector(p)), hint=_tmpl_hint(p))
+            for p in new_pods
+        ]
+        prep = _assemble_delta(
+            base_entry,
+            enc,
+            ordered=list(base.ordered) + new_pods,
+            tmpl_parts=[base.tmpl_ids, tmpl_new] if len(base.tmpl_ids) else [tmpl_new],
+            forced_parts=[base.forced, forced_new] if len(base.forced) else [forced_new],
+            n_cluster=base.n_cluster,
+            n_bare=base.n_bare,
+            ds_group_sizes=list(base.ds_group_sizes or []),
+        )
     return prep, slices
 
 
@@ -586,51 +586,51 @@ def extend_with_nodes(
         return None
     if base_prep is None or base_prep.encoder is None or base_prep.ds_group_sizes is None:
         return None
-    t0 = time.monotonic()
-    enc = base_prep.encoder.fork()
-    enc.extend_nodes(new_nodes)
+    with PREP_STATS.timed("delta_nodes") as timed:
+        enc = base_prep.encoder.fork()
+        enc.extend_nodes(new_nodes)
 
-    # per-DaemonSet pods for the new nodes, in cluster.daemon_sets order —
-    # the same expansion order _cluster_pods uses
-    groups_new = [expand.pods_from_daemon_set(ds, new_nodes) for ds in cluster.daemon_sets]
-    if len(groups_new) != len(base_prep.ds_group_sizes):
-        return None  # cluster's DS set changed vs the base prep: not a pure node delta
+        # per-DaemonSet pods for the new nodes, in cluster.daemon_sets order —
+        # the same expansion order _cluster_pods uses
+        groups_new = [expand.pods_from_daemon_set(ds, new_nodes) for ds in cluster.daemon_sets]
+        if len(groups_new) != len(base_prep.ds_group_sizes):
+            timed.declined()
+            return None  # cluster's DS set changed vs the base prep: not a pure node delta
 
-    b = base_prep.n_cluster - sum(base_prep.ds_group_sizes)
-    ordered: List = list(base_prep.ordered[:b])
-    tmpl_parts: List = [base_prep.tmpl_ids[:b]]
-    forced_parts: List = [base_prep.forced[:b]]
-    ds_group_sizes: List[int] = []
-    off = b
-    for size, pods_k in zip(base_prep.ds_group_sizes, groups_new):
-        ordered.extend(base_prep.ordered[off : off + size])
-        tmpl_parts.append(base_prep.tmpl_ids[off : off + size])
-        forced_parts.append(base_prep.forced[off : off + size])
-        off += size
-        ids = [
-            enc.add_pod(p, (lambda p=p: _owner_selector(p)), hint=_tmpl_hint(p))
-            for p in pods_k
-        ]
-        ordered.extend(pods_k)
-        tmpl_parts.append(ids)
-        forced_parts.append([bool(p.spec.node_name) for p in pods_k])
-        ds_group_sizes.append(size + len(pods_k))
-    # the app region rides along unchanged (apps have no DaemonSets here)
-    ordered.extend(base_prep.ordered[base_prep.n_cluster :])
-    tmpl_parts.append(base_prep.tmpl_ids[base_prep.n_cluster :])
-    forced_parts.append(base_prep.forced[base_prep.n_cluster :])
+        b = base_prep.n_cluster - sum(base_prep.ds_group_sizes)
+        ordered: List = list(base_prep.ordered[:b])
+        tmpl_parts: List = [base_prep.tmpl_ids[:b]]
+        forced_parts: List = [base_prep.forced[:b]]
+        ds_group_sizes: List[int] = []
+        off = b
+        for size, pods_k in zip(base_prep.ds_group_sizes, groups_new):
+            ordered.extend(base_prep.ordered[off : off + size])
+            tmpl_parts.append(base_prep.tmpl_ids[off : off + size])
+            forced_parts.append(base_prep.forced[off : off + size])
+            off += size
+            ids = [
+                enc.add_pod(p, (lambda p=p: _owner_selector(p)), hint=_tmpl_hint(p))
+                for p in pods_k
+            ]
+            ordered.extend(pods_k)
+            tmpl_parts.append(ids)
+            forced_parts.append([bool(p.spec.node_name) for p in pods_k])
+            ds_group_sizes.append(size + len(pods_k))
+        # the app region rides along unchanged (apps have no DaemonSets here)
+        ordered.extend(base_prep.ordered[base_prep.n_cluster :])
+        tmpl_parts.append(base_prep.tmpl_ids[base_prep.n_cluster :])
+        forced_parts.append(base_prep.forced[base_prep.n_cluster :])
 
-    prep = _assemble_delta(
-        base_entry,
-        enc,
-        ordered=ordered,
-        tmpl_parts=[p for p in tmpl_parts if len(p)],
-        forced_parts=[p for p in forced_parts if len(p)],
-        n_cluster=base_prep.n_cluster + sum(len(g) for g in groups_new),
-        n_bare=base_prep.n_bare,
-        ds_group_sizes=ds_group_sizes,
-    )
-    PREP_STATS.record("delta_nodes", time.monotonic() - t0)
+        prep = _assemble_delta(
+            base_entry,
+            enc,
+            ordered=ordered,
+            tmpl_parts=[p for p in tmpl_parts if len(p)],
+            forced_parts=[p for p in forced_parts if len(p)],
+            n_cluster=base_prep.n_cluster + sum(len(g) for g in groups_new),
+            n_bare=base_prep.n_bare,
+            ds_group_sizes=ds_group_sizes,
+        )
     return prep
 
 
@@ -704,66 +704,67 @@ def twin_pod_delta(
     prep = base_entry.prep
     if prep is None or prep.encoder is None or prep.ds_group_sizes is None:
         return None
-    t0 = time.monotonic()
-    nb = prep.n_bare
-    drop = (
-        np.array(base_entry.base_drop, dtype=bool, copy=True)
-        if base_entry.base_drop is not None
-        else np.zeros((len(prep.ordered),), dtype=bool)
-    )
-    if removed_keys:
-        found = set()
-        for i in range(nb):
-            p = prep.ordered[i]
-            k = (p.metadata.namespace, p.metadata.name)
-            if k in removed_keys:
-                drop[i] = True
-                found.add(k)
-        missing = removed_keys - found
-        if missing:
-            # a deletion we cannot locate in the bare prefix (e.g. the pod
-            # was never admissible, or it lives in a workload expansion) —
-            # only the full rebuild knows how to express it
-            return None
-    if added:
-        enc = prep.encoder.fork()
-        ids_new = [
-            enc.add_pod(p, (lambda p=p: _owner_selector(p)), hint=_tmpl_hint(p))
-            for p in added
-        ]
-        new_prep = _assemble_delta(
-            base_entry,
-            enc,
-            ordered=list(prep.ordered[:nb]) + list(added) + list(prep.ordered[nb:]),
-            tmpl_parts=[
-                prep.tmpl_ids[:nb],
-                np.asarray(ids_new, dtype=np.int32),
-                prep.tmpl_ids[nb:],
-            ],
-            forced_parts=[
-                prep.forced[:nb],
-                np.asarray([bool(p.spec.node_name) for p in added], dtype=bool),
-                prep.forced[nb:],
-            ],
-            n_cluster=prep.n_cluster + len(added),
-            n_bare=nb + len(added),
-            ds_group_sizes=list(prep.ds_group_sizes),
+    with PREP_STATS.timed("twin_delta") as timed:
+        nb = prep.n_bare
+        drop = (
+            np.array(base_entry.base_drop, dtype=bool, copy=True)
+            if base_entry.base_drop is not None
+            else np.zeros((len(prep.ordered),), dtype=bool)
         )
-        drop = np.concatenate([drop[:nb], np.zeros((len(added),), bool), drop[nb:]])
-    else:
-        new_prep = prep  # drops alone never re-encode: the mask is the delta
-    # compaction threshold: deleted pods stay in the stream as masked rows,
-    # so pure add/delete churn would otherwise grow the stream (and every
-    # engine pass over it) without bound. Past the threshold the delta is
-    # refused and the caller's full rebuild re-prepares the compacted
-    # cluster — amortized O(cluster / threshold) per churned pod.
-    n_dropped = int(drop.sum())
-    if n_dropped > max(64, len(drop) // 4):
-        note_compaction()
-        return None
-    entry = CacheEntry(key, new_prep, base=base_entry, watch=watch)
-    entry.base_drop = drop if n_dropped else None
-    PREP_STATS.record("twin_delta", time.monotonic() - t0)
+        if removed_keys:
+            found = set()
+            for i in range(nb):
+                p = prep.ordered[i]
+                k = (p.metadata.namespace, p.metadata.name)
+                if k in removed_keys:
+                    drop[i] = True
+                    found.add(k)
+            missing = removed_keys - found
+            if missing:
+                # a deletion we cannot locate in the bare prefix (e.g. the pod
+                # was never admissible, or it lives in a workload expansion) —
+                # only the full rebuild knows how to express it
+                timed.declined()
+                return None
+        if added:
+            enc = prep.encoder.fork()
+            ids_new = [
+                enc.add_pod(p, (lambda p=p: _owner_selector(p)), hint=_tmpl_hint(p))
+                for p in added
+            ]
+            new_prep = _assemble_delta(
+                base_entry,
+                enc,
+                ordered=list(prep.ordered[:nb]) + list(added) + list(prep.ordered[nb:]),
+                tmpl_parts=[
+                    prep.tmpl_ids[:nb],
+                    np.asarray(ids_new, dtype=np.int32),
+                    prep.tmpl_ids[nb:],
+                ],
+                forced_parts=[
+                    prep.forced[:nb],
+                    np.asarray([bool(p.spec.node_name) for p in added], dtype=bool),
+                    prep.forced[nb:],
+                ],
+                n_cluster=prep.n_cluster + len(added),
+                n_bare=nb + len(added),
+                ds_group_sizes=list(prep.ds_group_sizes),
+            )
+            drop = np.concatenate([drop[:nb], np.zeros((len(added),), bool), drop[nb:]])
+        else:
+            new_prep = prep  # drops alone never re-encode: the mask is the delta
+        # compaction threshold: deleted pods stay in the stream as masked rows,
+        # so pure add/delete churn would otherwise grow the stream (and every
+        # engine pass over it) without bound. Past the threshold the delta is
+        # refused and the caller's full rebuild re-prepares the compacted
+        # cluster — amortized O(cluster / threshold) per churned pod.
+        n_dropped = int(drop.sum())
+        if n_dropped > max(64, len(drop) // 4):
+            note_compaction()
+            timed.declined()
+            return None
+        entry = CacheEntry(key, new_prep, base=base_entry, watch=watch)
+        entry.base_drop = drop if n_dropped else None
     return entry
 
 
@@ -871,11 +872,10 @@ def simulate_cached(
         prep = prepare(cluster, apps, use_greed=use_greed, node_pad=node_pad)
         entry = cache.put(full_key, CacheEntry(full_key, prep, watch=watch))
     else:
-        t0 = time.monotonic()
-        cache.check_fresh(entry)
-        with entry.lock:
-            entry.restore()
-        PREP_STATS.record("hit", time.monotonic() - t0)
+        with PREP_STATS.timed("hit"):
+            cache.check_fresh(entry)
+            with entry.lock:
+                entry.restore()
     if entry.prep is None:
         return simulate(
             cluster, apps, use_greed=use_greed, node_pad=node_pad,

@@ -337,23 +337,25 @@ def dispatch_request_batch(prep: Prepared, items: List[BatchItem]) -> BatchDispa
         with obs.span("engine.xla", requests=len(items), pods=P):
             import jax.numpy as jnp
 
-            from ..obs.profile import observed_jit_call
+            from ..obs.profile import launch_span, observed_jit_call
 
             # the batch dispatch is the outer jit boundary: the compile
             # watch observes it HERE, on the host, never under the trace
-            batched = observed_jit_call(
-                "batched_schedule",
-                _batched_schedule,
-                args=(
-                    prep.ec, prep.st0, jnp.asarray(tmpl_p), jnp.asarray(pv_all),
-                    jnp.asarray(forced_p),
-                ),
-                static={
-                    "features": prep.features, "unroll": scan_unroll(),
-                    "explain": explain_any,
-                },
-            )
-            jax.block_until_ready(batched.chosen)
+            with launch_span("xla.launch", requests=len(items), pods=len(tmpl_p)):
+                batched = observed_jit_call(
+                    "batched_schedule",
+                    _batched_schedule,
+                    args=(
+                        prep.ec, prep.st0, jnp.asarray(tmpl_p), jnp.asarray(pv_all),
+                        jnp.asarray(forced_p),
+                    ),
+                    static={
+                        "features": prep.features, "unroll": scan_unroll(),
+                        "explain": explain_any,
+                    },
+                )
+            with obs.span("xla.wait"):
+                jax.block_until_ready(batched.chosen)
         # ONE device→host conversion per output field for the whole batch
         # (N redundant full-tensor transfers before — the vectorized path)
         outs = list(_slice_outputs(batched, len(items), P))

@@ -352,47 +352,47 @@ def prepare(
     from ..utils.trace import PREP_STATS
 
     check_deadline("prepare")
-    t0 = time.monotonic()
-    with obs.span("prepare"), gc_paused():
-        prep = _prepare_inner(cluster, apps, use_greed, node_pad, patch_pods_fn)
-    PREP_STATS.record("full", time.monotonic() - t0)
-    return prep
+    # prepare > prep.full > prep.expand, encode: `prepare` keeps its place
+    # under the root; the kind's span is a real one round the same work
+    with obs.span("prepare"), PREP_STATS.timed("full"), gc_paused():
+        return _prepare_inner(cluster, apps, use_greed, node_pad, patch_pods_fn)
 
 
 def _prepare_inner(cluster, apps, use_greed, node_pad, patch_pods_fn):
+    from ..obs import trace as obs
+
     enc = ClusterEncoder(node_pad=node_pad)
     enc.add_nodes(cluster.nodes)
 
     ordered: List[Pod] = []
     forced: List[bool] = []
 
-    cluster_pods, n_bare, ds_group_sizes = _cluster_pods(cluster)
-    for p in cluster_pods:
-        ordered.append(p)
-        forced.append(bool(p.spec.node_name))
-    n_cluster = len(ordered)  # pods below went through patch_pods_fn
-
-    app_slices: List[Tuple[int, int]] = []
-    for app in apps:
-        lo = len(ordered)
-        app_pods = expand.generate_pods_from_resources(app.resources, cluster.nodes)
-        for p in app_pods:
-            p.metadata.labels.setdefault(LABEL_APP_NAME, app.name)
-        # simulator.go:238-241: affinity sort then toleration sort
-        app_pods = queues.toleration_sort(queues.affinity_sort(app_pods))
-        if use_greed:
-            app_pods = queues.greed_sort(cluster.nodes, app_pods)
-        if patch_pods_fn is not None:
-            patch_pods_fn(app.name, app_pods)
-        for p in app_pods:
+    with obs.span("prep.expand"):
+        cluster_pods, n_bare, ds_group_sizes = _cluster_pods(cluster)
+        for p in cluster_pods:
             ordered.append(p)
             forced.append(bool(p.spec.node_name))
-        app_slices.append((lo, len(ordered)))
+        n_cluster = len(ordered)  # pods below went through patch_pods_fn
+
+        app_slices: List[Tuple[int, int]] = []
+        for app in apps:
+            lo = len(ordered)
+            app_pods = expand.generate_pods_from_resources(app.resources, cluster.nodes)
+            for p in app_pods:
+                p.metadata.labels.setdefault(LABEL_APP_NAME, app.name)
+            # simulator.go:238-241: affinity sort then toleration sort
+            app_pods = queues.toleration_sort(queues.affinity_sort(app_pods))
+            if use_greed:
+                app_pods = queues.greed_sort(cluster.nodes, app_pods)
+            if patch_pods_fn is not None:
+                patch_pods_fn(app.name, app_pods)
+            for p in app_pods:
+                ordered.append(p)
+                forced.append(bool(p.spec.node_name))
+            app_slices.append((lo, len(ordered)))
 
     if not ordered:
         return None
-
-    from ..obs import trace as obs
 
     # expansion is done; the encode pass below is the expensive half of a
     # cold prepare — an exhausted deadline bails here rather than encoding
@@ -449,6 +449,29 @@ def _prepare_inner(cluster, apps, use_greed, node_pad, patch_pods_fn):
     )
 
 
+def _xla_scan(ec, st, tmpl_ids, pod_valid, forced, nv_mask, **kwargs):
+    """The XLA scan under the caller's ``engine.xla`` span, in the three
+    parts an idle device can be waiting for: ``xla.pad`` (the pod stream to
+    its bucket, the node mask and the carry to the device), ``xla.launch``
+    (``schedule_pods`` until it returns: signature, trace and compile or
+    cache lookup on a new shape, enqueue), ``xla.wait`` (the device)."""
+    from ..obs import trace as obs
+    from ..obs.profile import launch_span
+
+    with obs.span("xla.pad"):
+        tmpl_p, valid_p, forced_p = pad_pod_stream(tmpl_ids, pod_valid, forced)
+        if nv_mask is not None:
+            ec = ec._replace(node_valid=jnp.asarray(nv_mask))
+        st = ScanState(*[jnp.asarray(a) for a in st])
+    with launch_span("xla.launch", pods=len(tmpl_p)):
+        out = schedule_pods(
+            ec, st, tmpl_p, valid_p, forced_p, unroll=scan_unroll(), **kwargs
+        )
+    with obs.span("xla.wait"):
+        jax.block_until_ready(out.chosen)  # dispatch is async; trace real device time
+    return out
+
+
 def _run_segments(
     prep, segments, pod_valid, forced, tmpl_ids, extra_plugins, tie_seed,
     nv_mask, skips, log, explain=False,
@@ -464,7 +487,6 @@ def _run_segments(
     and failure attribution resolves per segment."""
     from ..obs import trace as obs
     from . import nativepath
-    from .scheduler import pad_pod_stream, schedule_pods, scan_unroll
 
     P = len(tmpl_ids)
     n_dyn = kernels.NUM_FILTERS - kernels.F_PORTS
@@ -506,19 +528,11 @@ def _run_segments(
                 if out.native_stats is not None:
                     seg_stats.append(out.native_stats)
             else:
-                tmpl_p, valid_p, forced_p = pad_pod_stream(tmpl_ids, seg_valid, forced)
-                ec_run = (
-                    prep.ec._replace(node_valid=jnp.asarray(nv_mask))
-                    if nv_mask is not None
-                    else prep.ec
-                )
-                st_dev = ScanState(*[jnp.asarray(a) for a in st])
-                out = schedule_pods(
-                    ec_run, st_dev, tmpl_p, valid_p, forced_p,
+                out = _xla_scan(
+                    prep.ec, st, tmpl_ids, seg_valid, forced, nv_mask,
                     features=prep.features, config=cfg, extra_plugins=extra_plugins,
-                    unroll=scan_unroll(), tie_seed=tie_seed, explain=explain,
+                    tie_seed=tie_seed, explain=explain,
                 )
-                jax.block_until_ready(out.chosen)
         chosen[lo:hi] = np.asarray(out.chosen)[lo:hi]
         fail_counts[lo:hi] = np.asarray(out.fail_counts)[lo:hi]
         insufficient[lo:hi] = np.asarray(out.insufficient)[lo:hi]
@@ -719,16 +733,11 @@ def _run_engine_ladder(
             log.info("native engine skipped: %s", miss)
     if out is None:
         with obs.span("engine.xla"):
-            tmpl_p, valid_p, forced_p = pad_pod_stream(tmpl_ids, pod_valid, forced)
-            ec_run = (
-                ec._replace(node_valid=jnp.asarray(nv_mask)) if nv_mask is not None else ec
-            )
-            out = schedule_pods(
-                ec_run, st0, tmpl_p, valid_p, forced_p,
+            out = _xla_scan(
+                ec, st0, tmpl_ids, pod_valid, forced, nv_mask,
                 features=prep.features, config=sched_config, extra_plugins=extra_plugins,
-                unroll=scan_unroll(), tie_seed=tie_seed, explain=explain,
+                tie_seed=tie_seed, explain=explain,
             )
-            jax.block_until_ready(out.chosen)  # dispatch is async; trace real device time
     return out, engine_name, skips, sf_rows
 
 
@@ -850,7 +859,6 @@ def simulate(
     gate — and costs nothing when False (the default compiled scan and the
     incremental C++ path are untouched)."""
     from ..obs import trace as obs
-    from ..utils.trace import Trace
 
     if deadline is not None:
         # install the request deadline as the ambient scope once, then run
@@ -871,111 +879,106 @@ def simulate(
         raise ValueError("prep reuse does not support enable_preemption; pass prep=None")
     if drop_pods is not None and prep is None:
         raise ValueError("drop_pods is a mask over an existing Prepared; pass prep=")
-    with Trace("Simulate", threshold_s=1.0) as tr:
-        if prep is None:
-            prep = prepare(
-                cluster, apps, use_greed=use_greed, node_pad=node_pad, patch_pods_fn=patch_pods_fn
-            )
-            tr.step("expand and encode")
-        else:
-            tr.step("reuse prepared encoding")
-        if prep is None:
-            return SimulateResult(
-                node_status=[NodeStatus(node=n, pods=[]) for n in cluster.nodes]
-            )
-        ec, st0, meta = prep.ec, prep.st0, prep.meta
-        ordered, tmpl_ids, forced = prep.ordered, prep.tmpl_ids, prep.forced
+    if prep is None:
+        prep = prepare(
+            cluster, apps, use_greed=use_greed, node_pad=node_pad, patch_pods_fn=patch_pods_fn
+        )
+    if prep is None:
+        return SimulateResult(
+            node_status=[NodeStatus(node=n, pods=[]) for n in cluster.nodes]
+        )
+    ec, st0, meta = prep.ec, prep.st0, prep.meta
+    ordered, tmpl_ids, forced = prep.ordered, prep.tmpl_ids, prep.forced
 
-        nv_mask: Optional[np.ndarray] = None
-        drops: set = set()
-        if drop_pods is not None:
-            dm = np.asarray(drop_pods, dtype=bool)
-            if dm.shape[0] != len(prep.ordered):
-                raise ValueError("drop_pods mask must cover the prepared pod stream")
-            drops |= {int(i) for i in np.nonzero(dm)[0]}
-        if node_valid is not None:
-            nv_mask = np.asarray(node_valid, dtype=bool)
-            if nv_mask.shape[0] != int(np.asarray(prep.ec_np.node_valid).shape[0]):
-                raise ValueError("node_valid mask must cover the prepared (padded) node axis")
-            names = [n.metadata.name for n in cluster.nodes]
-            if names != list(meta.node_names[: len(names)]):
-                raise ValueError(
-                    "cluster.nodes must be the valid prefix of the prepared node order"
-                )
-            n_valid = int(nv_mask.sum())
-            if n_valid != len(names) or not nv_mask[:n_valid].all():
-                raise ValueError("node_valid must select exactly cluster.nodes as a prefix")
-            # DaemonSet pods pinned to masked-out nodes would not exist in a
-            # fresh expansion of the sub-cluster: drop them from the stream
-            drops |= {
-                i for i, t in enumerate(prep.ds_target) if t >= 0 and not nv_mask[t]
-            }
+    nv_mask: Optional[np.ndarray] = None
+    drops: set = set()
+    if drop_pods is not None:
+        dm = np.asarray(drop_pods, dtype=bool)
+        if dm.shape[0] != len(prep.ordered):
+            raise ValueError("drop_pods mask must cover the prepared pod stream")
+        drops |= {int(i) for i in np.nonzero(dm)[0]}
+    if node_valid is not None:
+        nv_mask = np.asarray(node_valid, dtype=bool)
+        if nv_mask.shape[0] != int(np.asarray(prep.ec_np.node_valid).shape[0]):
+            raise ValueError("node_valid mask must cover the prepared (padded) node axis")
+        names = [n.metadata.name for n in cluster.nodes]
+        if names != list(meta.node_names[: len(names)]):
+            raise ValueError(
+                "cluster.nodes must be the valid prefix of the prepared node order"
+            )
+        n_valid = int(nv_mask.sum())
+        if n_valid != len(names) or not nv_mask[:n_valid].all():
+            raise ValueError("node_valid must select exactly cluster.nodes as a prefix")
+        # DaemonSet pods pinned to masked-out nodes would not exist in a
+        # fresh expansion of the sub-cluster: drop them from the stream
+        drops |= {
+            i for i, t in enumerate(prep.ds_target) if t >= 0 and not nv_mask[t]
+        }
 
-        pod_valid = np.ones((len(ordered),), dtype=bool)
-        for i in drops:
+    pod_valid = np.ones((len(ordered),), dtype=bool)
+    for i in drops:
+        pod_valid[i] = False
+    # multi-profile KubeSchedulerConfiguration: route the stream onto one
+    # effective config; pods naming an unknown profile never enter any
+    # scheduling queue (kube event-handler filtering) and are reported
+    # unschedulable with an explicit reason. Force-bound pods bypass the
+    # scheduler entirely (simulator.go:329-331) — profiles don't apply.
+    custom_reasons: Dict[int, str] = {}
+    segments = None
+    if sched_config is not None:
+        from .schedconfig import DEFAULT_CONFIG, resolve_profile_segments
+
+        segs, custom_reasons = resolve_profile_segments(
+            sched_config, ordered, meta.resource_names, forced=forced
+        )
+        for i in custom_reasons:
             pod_valid[i] = False
-        # multi-profile KubeSchedulerConfiguration: route the stream onto one
-        # effective config; pods naming an unknown profile never enter any
-        # scheduling queue (kube event-handler filtering) and are reported
-        # unschedulable with an explicit reason. Force-bound pods bypass the
-        # scheduler entirely (simulator.go:329-331) — profiles don't apply.
-        custom_reasons: Dict[int, str] = {}
-        segments = None
-        if sched_config is not None:
-            from .schedconfig import DEFAULT_CONFIG, resolve_profile_segments
-
-            segs, custom_reasons = resolve_profile_segments(
-                sched_config, ordered, meta.resource_names, forced=forced
-            )
-            for i in custom_reasons:
-                pod_valid[i] = False
-            if len(segs) == 1:
-                sched_config = segs[0][0]
-                if sched_config == DEFAULT_CONFIG:
-                    sched_config = None  # fast-path eligible
-            else:
-                # differing profiles (utils.go:304-381): consecutive scans
-                # per contiguous same-profile segment, sharing the carry
-                if enable_preemption:
-                    raise ValueError(
-                        "segmented multi-profile simulation does not support "
-                        "enable_preemption"
-                    )
-                segments = [
-                    (None if c == DEFAULT_CONFIG else c, lo, hi) for c, lo, hi in segs
-                ]
-                sched_config = None
-        import logging
-
-        log = logging.getLogger("opensim_tpu")
-        check_deadline("schedule")
-        with obs.span("schedule", pods=len(ordered)) as _sched_span:
-            out, engine_name, skips, sf_rows = _run_engine_ladder(
-                prep, segments, sched_config, pod_valid, forced, tmpl_ids,
-                extra_plugins, tie_seed, nv_mask, ec, st0, log, explain=explain,
-            )
-            nstats = getattr(out, "native_stats", None)
-            engine = EngineDecision(
-                name=engine_name,
-                skipped=skips,
-                native_path=nstats["path"] if nstats else None,
-                native_steps=dict(nstats["steps"]) if nstats else None,
-            )
-            # every rung that did NOT run is an instant demotion span, so
-            # the flight-recorder tree carries exactly the attribution
-            # EngineDecision.skipped reports (tests assert they match)
-            for k, v in sorted(skips.items()):
-                obs.event(f"engine.{k}.skipped", status="demoted", engine=k, reason=v)
-            engine_label = engine_name if nstats is None else f"{engine_name}/{nstats['path']}"
-            _sched_span.set(engine=engine_label)
-            if not enable_preemption:
-                # preemption rewrites `chosen` in decode: emitting here
-                # would report pods the preempt pass later schedules
-                _schedule_reason_events(
-                    obs, out, ordered, tmpl_ids, pod_valid, forced, sf_rows,
-                    meta, nv_mask,
+        if len(segs) == 1:
+            sched_config = segs[0][0]
+            if sched_config == DEFAULT_CONFIG:
+                sched_config = None  # fast-path eligible
+        else:
+            # differing profiles (utils.go:304-381): consecutive scans
+            # per contiguous same-profile segment, sharing the carry
+            if enable_preemption:
+                raise ValueError(
+                    "segmented multi-profile simulation does not support "
+                    "enable_preemption"
                 )
-        tr.step(f"schedule {len(ordered)} pods [engine={engine_label}]")
+            segments = [
+                (None if c == DEFAULT_CONFIG else c, lo, hi) for c, lo, hi in segs
+            ]
+            sched_config = None
+    import logging
+
+    log = logging.getLogger("opensim_tpu")
+    check_deadline("schedule")
+    with obs.span("schedule", pods=len(ordered)) as _sched_span:
+        out, engine_name, skips, sf_rows = _run_engine_ladder(
+            prep, segments, sched_config, pod_valid, forced, tmpl_ids,
+            extra_plugins, tie_seed, nv_mask, ec, st0, log, explain=explain,
+        )
+        nstats = getattr(out, "native_stats", None)
+        engine = EngineDecision(
+            name=engine_name,
+            skipped=skips,
+            native_path=nstats["path"] if nstats else None,
+            native_steps=dict(nstats["steps"]) if nstats else None,
+        )
+        # every rung that did NOT run is an instant demotion span, so
+        # the flight-recorder tree carries exactly the attribution
+        # EngineDecision.skipped reports (tests assert they match)
+        for k, v in sorted(skips.items()):
+            obs.event(f"engine.{k}.skipped", status="demoted", engine=k, reason=v)
+        engine_label = engine_name if nstats is None else f"{engine_name}/{nstats['path']}"
+        _sched_span.set(engine=engine_label)
+        if not enable_preemption:
+            # preemption rewrites `chosen` in decode: emitting here
+            # would report pods the preempt pass later schedules
+            _schedule_reason_events(
+                obs, out, ordered, tmpl_ids, pod_valid, forced, sf_rows,
+                meta, nv_mask,
+            )
     check_deadline("decode")
     with obs.span("decode", pods=len(ordered)):
         out = out._replace(
@@ -1167,29 +1170,37 @@ def snapshot_bind_state(prep: "Prepared") -> list:
     sequential differing-profile probes) can restore between runs. Kept
     NEXT TO ``_decode`` on purpose: any new bind-time pod mutation must be
     added to both."""
-    return [
-        (
-            p.spec.node_name,
-            p.phase,
-            p.metadata.annotations.get(ANNO_GPU_INDEX),
-            p.metadata.annotations.get(ANNO_GPU_ASSUME_TIME),
-        )
-        for p in prep.ordered
-    ]
+    from ..obs import trace as obs
+
+    # bind.snapshot / bind.restore: a pass over every prepared pod each, and
+    # most of what a served request's root and a plan spend outside a phase
+    with obs.span("bind.snapshot", pods=len(prep.ordered)):
+        return [
+            (
+                p.spec.node_name,
+                p.phase,
+                p.metadata.annotations.get(ANNO_GPU_INDEX),
+                p.metadata.annotations.get(ANNO_GPU_ASSUME_TIME),
+            )
+            for p in prep.ordered
+        ]
 
 
 def restore_bind_state(prep: "Prepared", snap: list) -> None:
-    for p, (node_name, phase, gpu_idx, assume) in zip(prep.ordered, snap):
-        p.spec.node_name = node_name
-        p.phase = phase
-        if gpu_idx is None:
-            p.metadata.annotations.pop(ANNO_GPU_INDEX, None)
-        else:
-            p.metadata.annotations[ANNO_GPU_INDEX] = gpu_idx
-        if assume is None:
-            p.metadata.annotations.pop(ANNO_GPU_ASSUME_TIME, None)
-        else:
-            p.metadata.annotations[ANNO_GPU_ASSUME_TIME] = assume
+    from ..obs import trace as obs
+
+    with obs.span("bind.restore", pods=len(prep.ordered)):
+        for p, (node_name, phase, gpu_idx, assume) in zip(prep.ordered, snap):
+            p.spec.node_name = node_name
+            p.phase = phase
+            if gpu_idx is None:
+                p.metadata.annotations.pop(ANNO_GPU_INDEX, None)
+            else:
+                p.metadata.annotations[ANNO_GPU_INDEX] = gpu_idx
+            if assume is None:
+                p.metadata.annotations.pop(ANNO_GPU_ASSUME_TIME, None)
+            else:
+                p.metadata.annotations[ANNO_GPU_ASSUME_TIME] = assume
 
 
 def _drop_mask(drop_pods, n: int) -> Optional[np.ndarray]:

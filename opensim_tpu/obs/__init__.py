@@ -7,7 +7,8 @@
 - :mod:`opensim_tpu.obs.recorder` — the flight recorder behind
   ``GET /api/debug/requests``.
 
-Import-light on purpose: stdlib only, imported from the engine hot path.
+Import-light on purpose: stdlib only at import, imported from the engine hot
+path (the first TraceContext imports ``jax.profiler`` for the write-through).
 """
 
 from .trace import (  # noqa: F401
@@ -19,7 +20,6 @@ from .trace import (  # noqa: F401
     enabled,
     event,
     new_request_id,
-    record_span,
     sanitize_request_id,
     span,
     start_trace,

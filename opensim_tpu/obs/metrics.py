@@ -223,6 +223,9 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Backend (XLA) compile seconds from jax monitoring, all call sites", "counter",
     ),
     "simon_backend_compile_total": ("Backend (XLA) compiles from jax monitoring", "counter"),
+    "simon_compile_stage_seconds_total": (
+        "Seconds on the compile path by stage (trace/lower/backend/cache_retrieval), compiled or not", "counter",
+    ),
     "simon_device_info": (
         "The JAX backend this process computes on, by platform and device_kind (value = device count)", "gauge",
     ),

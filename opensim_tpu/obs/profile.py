@@ -15,7 +15,10 @@ padding failed to hold the signature), ``new``/``first`` otherwise.
 Backend-wide compile seconds and the persistent compilation cache's
 monitoring events come from ``jax.monitoring`` listeners, and the
 persistent cache directory's file/byte footprint from
-``utils/jitcache.cache_stats``.
+``utils/jitcache.cache_stats``. The same listeners count what the compile
+PATH costs when nothing compiles: calls and seconds per stage (``trace``,
+``lower``, ``backend``, ``cache_retrieval``), the stages an eager
+``pallas_call`` re-enters on every plan (ISSUE 27).
 
 **Cumulative phase profiles.** The flight recorder answers "why was THAT
 request slow"; capacity questions need "where do requests spend time in
@@ -41,6 +44,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from .metrics import DEFAULT_BUCKETS, escape_label_value, family_header
+from .trace import NOOP_SPAN, _SpanScope, current_trace
 
 log = logging.getLogger("opensim_tpu.obs")
 
@@ -50,6 +54,7 @@ __all__ = [
     "CompileWatch",
     "PhaseProfile",
     "device_stamp",
+    "launch_span",
     "observed_jit_call",
 ]
 
@@ -59,6 +64,14 @@ __all__ = [
 _MAX_SIGNATURES = 256
 
 _BUCKETS: Tuple[float, ...] = tuple(DEFAULT_BUCKETS) + (math.inf,)
+
+#: ``jax.monitoring`` duration events (by leaf name) -> compile-path stage
+_STAGES = {
+    "jaxpr_trace_duration": "trace",
+    "jaxpr_to_mlir_module_duration": "lower",
+    "backend_compile_duration": "backend",
+    "cache_retrieval_time_sec": "cache_retrieval",
+}
 
 
 def _quantile(counts: List[int], total: int, q: float) -> float:
@@ -122,8 +135,9 @@ class CompileWatch:
         # name -> {"compiles", "seconds", "causes": {cause: n},
         #          "signatures": {sig_key: {"count", "seconds"}}, "last_sig"}
         self._fns: Dict[str, dict] = {}  # guarded-by: _lock
-        self._backend_compiles = 0  # guarded-by: _lock
-        self._backend_seconds = 0.0  # guarded-by: _lock
+        # stage -> [calls, seconds]; "backend" counts every entry into the
+        # backend compiler, those the persistent cache answered included
+        self._stages: Dict[str, list] = {st: [0, 0.0] for st in _STAGES.values()}  # guarded-by: _lock
         self._cache_events: Dict[str, int] = {}  # guarded-by: _lock
         self._installed = False  # guarded-by: _lock
 
@@ -146,10 +160,12 @@ class CompileWatch:
             log.debug("jax monitoring unavailable: %s", e)
 
     def _on_duration(self, name: str, duration: float, **_kw) -> None:
-        if name.endswith("backend_compile_duration"):
+        stage = _STAGES.get(name.rsplit("/", 1)[-1])
+        if stage is not None:
             with self._lock:
-                self._backend_compiles += 1
-                self._backend_seconds += float(duration)
+                rec = self._stages[stage]
+                rec[0] += 1
+                rec[1] += float(duration)
 
     def _on_event(self, name: str, **_kw) -> None:
         if "/compilation_cache/" in name:
@@ -206,6 +222,13 @@ class CompileWatch:
 
     # -- views ---------------------------------------------------------------
 
+    def counts(self) -> Tuple[int, int]:
+        """(entries into the backend compiler, persistent-cache hits) so far:
+        cheap enough to read at both ends of a span (``launch_span``), where
+        ``snapshot()`` would walk the cache directory."""
+        with self._lock:
+            return self._stages["backend"][0], self._cache_events.get("cache_hits", 0)
+
     def snapshot(self) -> dict:
         from ..utils import jitcache
 
@@ -222,8 +245,12 @@ class CompileWatch:
             out = {
                 "boundaries": fns,
                 "backend": {
-                    "compiles": self._backend_compiles,
-                    "seconds": round(self._backend_seconds, 6),
+                    "compiles": self._stages["backend"][0],
+                    "seconds": round(self._stages["backend"][1], 6),
+                },
+                "stages": {
+                    st: {"count": n, "seconds": round(secs, 6)}
+                    for st, (n, secs) in self._stages.items()
                 },
                 "cache_events": dict(sorted(self._cache_events.items())),
             }
@@ -257,9 +284,14 @@ class CompileWatch:
                     lines += cause_lines
             lines += [
                 *family_header("simon_backend_compile_total"),
-                f"simon_backend_compile_total {self._backend_compiles}",
+                f"simon_backend_compile_total {self._stages['backend'][0]}",
                 *family_header("simon_backend_compile_seconds_total"),
-                f"simon_backend_compile_seconds_total {self._backend_seconds:.6f}",
+                f"simon_backend_compile_seconds_total {self._stages['backend'][1]:.6f}",
+                *family_header("simon_compile_stage_seconds_total"),
+                *[
+                    f'simon_compile_stage_seconds_total{{stage="{st}"}} {secs:.6f}'
+                    for st, (_n, secs) in sorted(self._stages.items())
+                ],
             ]
             if self._cache_events:
                 lines += family_header("simon_jitcache_events_total")
@@ -281,8 +313,8 @@ class CompileWatch:
     def reset(self) -> None:
         with self._lock:
             self._fns.clear()
-            self._backend_compiles = 0
-            self._backend_seconds = 0.0
+            for rec in self._stages.values():
+                rec[:] = [0, 0.0]
             self._cache_events.clear()
 
 
@@ -317,6 +349,33 @@ def _device_info_lines() -> List[str]:
 
 
 COMPILES = CompileWatch()
+
+
+class _LaunchScope(_SpanScope):
+    """A span that also counts, as attributes, the entries into the backend
+    compiler and the persistent-cache hits inside it."""
+
+    __slots__ = ("_before",)
+
+    def __enter__(self):
+        self._before = COMPILES.counts()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        compiles, hits = COMPILES.counts()
+        self.span.attrs.update(
+            backend_compiles=compiles - self._before[0], cache_hits=hits - self._before[1]
+        )
+        return super().__exit__(exc_type, exc, tb)
+
+
+def launch_span(name: str, **attrs: Any):
+    """``obs.span`` for a call that may enter the compile path (the
+    megakernel's ``mk.launch``, the scans' ``xla.launch``): the span says
+    whether its seconds held a compile, a cache hit or neither. The shared
+    no-op without an ambient trace, like every instrumentation point."""
+    tr = current_trace()
+    return NOOP_SPAN if tr is None else _LaunchScope(tr, name, attrs)
 
 
 def observed_jit_call(name: str, fn, args: tuple, static: Optional[dict] = None):

@@ -6,18 +6,24 @@ snapshot fetch, prepare, encode, schedule (with one child per engine-ladder
 rung actually attempted), decode — plus instant *events* for the things the
 resilience layer does on the way: snapshot retries, breaker trips, engine
 demotions, prep-cache invalidations, fault injections. The C++ engine's
-``profile_out`` phase timings and ``PREP_STATS`` host-prepare timings attach
-as child spans, so C++ scan time and host encode time appear in one tree.
+``profile_out`` phase timings attach as child spans, so C++ scan time and
+host encode time appear in one tree.
 
 Design constraints (the tentpole's "allocation-light and dormant-cheap"):
 
-- Spans are plain host-side objects timed with ``time.monotonic``; nothing
-  here ever touches JAX tracing/jit internals, so instrumented functions
-  stay jit-safe and the tracer works identically under every engine.
+- Spans are plain host-side objects timed with ``time.monotonic``. Every
+  real span is also written through to the profiler's clock: it enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a capture
+  (``/debug/profiler``, ``jax.profiler.start_trace``) shows the program's
+  spans in the host plane beside the device operations. That is a host-side
+  profiler event (under a microsecond with no capture live), never JAX
+  tracing or jit: instrumented functions stay jit-safe and the tracer works
+  identically under every engine. ``jax`` is imported by the first
+  :class:`TraceContext`, not by this module.
 - The ambient trace travels in ONE :mod:`contextvars` variable. With no
   active trace (library callers, ``OPENSIM_TRACE=0``), every instrumentation
-  point — :func:`span`, :func:`event`, :func:`record_span` — is a single
-  contextvar read returning a shared no-op; no objects are allocated.
+  point — :func:`span`, :func:`event` — is a single contextvar read
+  returning a shared no-op; no objects are allocated.
 - One trace == one thread (the HTTP server handles each request on its own
   thread), so the span stack needs no lock; finished traces are immutable
   and safe to read from the flight-recorder endpoints on other threads.
@@ -31,6 +37,7 @@ from __future__ import annotations
 
 import contextvars
 import re
+import threading
 import time
 import uuid
 from typing import Any, Dict, Iterator, List, Optional
@@ -46,7 +53,6 @@ __all__ = [
     "enabled",
     "event",
     "new_request_id",
-    "record_span",
     "sanitize_request_id",
     "span",
     "start_trace",
@@ -104,6 +110,17 @@ class Span:
         self.children.append(child)
         return child
 
+    def child_at(self, name: str, start: float, end: float, **attrs: Any) -> "Span":
+        """Attach a completed child at its true interval on the monotonic
+        clock, clamped to this span and kept in start order — for a wait
+        whose two stamps were taken elsewhere (the admission ticket's
+        enqueue and admit)."""
+        child = Span(name, max(start, self.start), attrs or None)
+        child.end = max(child.start, end)
+        at = sum(1 for c in self.children if c.start <= child.start)
+        self.children.insert(at, child)
+        return child
+
     def walk(self) -> Iterator["Span"]:
         yield self
         for c in self.children:
@@ -139,17 +156,19 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
-
 class _SpanScope:
-    """Context manager opening a real span on the ambient trace's stack."""
+    """Context manager opening a real span on the ambient trace's stack,
+    and the profiler annotation of the same name round the same body."""
 
-    __slots__ = ("trace", "span")
+    __slots__ = ("trace", "span", "_ann")
 
     def __init__(self, trace: "TraceContext", name: str, attrs: Optional[dict]) -> None:
         self.trace = trace
+        self._ann = trace._annotate(name)
         self.span = Span(name, time.monotonic(), attrs)
 
     def __enter__(self) -> Span:
+        self._ann.__enter__()
         stack = self.trace._stack
         stack[-1].children.append(self.span)
         stack.append(self.span)
@@ -157,6 +176,7 @@ class _SpanScope:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         sp = self.span
+        self._ann.__exit__(None, None, None)
         stack = self.trace._stack
         if stack and stack[-1] is sp:
             stack.pop()
@@ -178,7 +198,16 @@ class TraceContext:
         self.request_id = sanitize_request_id(request_id) or new_request_id()
         self.endpoint = endpoint
         self.started_unix = time.time()
+        # imported here so that importing this module stays stdlib-only
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
+        # the root's annotation is closed by finish(), and only on the thread
+        # that opened it: the profiler pairs an event's two ends per thread
+        self._ann = TraceAnnotation(endpoint, request_id=self.request_id)
+        self._ann_thread = threading.get_ident()
         self.root = Span(endpoint, time.monotonic())
+        self._ann.__enter__()
         self.http_status: Optional[int] = None
         self._stack: List[Span] = [self.root]
 
@@ -194,6 +223,9 @@ class TraceContext:
         """Close the root (and any span an escaped exception left open —
         they inherit the final status so a crash never yields a tree that
         claims its interrupted phases succeeded)."""
+        if self._ann is not None and threading.get_ident() == self._ann_thread:
+            self._ann.__exit__(None, None, None)
+        self._ann = None
         now = time.monotonic()
         while len(self._stack) > 1:
             sp = self._stack.pop()
@@ -222,6 +254,10 @@ class TraceContext:
             "status": self.root.status,
             "http_status": self.http_status,
             "started_unix": round(self.started_unix, 3),
+            # the root's start on this process's monotonic clock: with the
+            # tree's ``start_s`` a reader in the same process places every
+            # span exactly, with no guess about the socket's share
+            "started_monotonic": round(self.root.start, 6),
             "duration_s": round(self.root.duration_s, 6),
             "spans": sum(1 for _ in self.walk()) - 1,
         }
@@ -373,19 +409,6 @@ def event(name: str, status: str = "ok", **attrs: Any) -> None:
         return
     now = time.monotonic()
     sp = Span(name, now, attrs or None)
-    sp.end = now
-    sp.status = status
-    tr.current_span().children.append(sp)
-
-
-def record_span(name: str, seconds: float, status: str = "ok", **attrs: Any) -> None:
-    """Append a completed span that ended *now* and lasted ``seconds`` —
-    for code that measured a duration itself (``PREP_STATS.record``)."""
-    tr = _CURRENT.get()
-    if tr is None:
-        return
-    now = time.monotonic()
-    sp = Span(name, now - seconds, attrs or None)
     sp.end = now
     sp.status = status
     tr.current_span().children.append(sp)

@@ -187,20 +187,24 @@ def sweep_auto(
             logging.getLogger("opensim_tpu").info(
                 "megakernel sweep envelope miss: %s", miss
             )
+    from ..obs.profile import launch_span
+
     with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices())):
-        res = sweep(
-            prep.ec,
-            prep.st0,
-            prep.tmpl_ids,
-            prep.forced,
-            node_valid_masks,
-            pod_valid_masks,
-            mesh=default_mesh(),
-            features=prep.features,
-            forced_masks=np.asarray(forced_masks),
-            config=config,
-        )
-        jax.block_until_ready(res.chosen)  # dispatch is async; trace real device time
+        with launch_span("xla.launch", scenarios=S, pods=len(prep.tmpl_ids)):
+            res = sweep(
+                prep.ec,
+                prep.st0,
+                prep.tmpl_ids,
+                prep.forced,
+                node_valid_masks,
+                pod_valid_masks,
+                mesh=default_mesh(),
+                features=prep.features,
+                forced_masks=np.asarray(forced_masks),
+                config=config,
+            )
+        with obs.span("xla.wait"):
+            jax.block_until_ready(res.chosen)  # dispatch is async; trace real device time
     return res
 
 

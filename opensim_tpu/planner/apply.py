@@ -33,6 +33,7 @@ from ..engine.simulator import (
 )
 from ..models import expand
 from ..models.objects import ENV_MAX_CPU, ENV_MAX_MEMORY, ENV_MAX_VG, Node, ResourceTypes
+from ..obs import trace as obs
 from ..parallel import scenarios
 from . import report as report_mod
 
@@ -365,9 +366,10 @@ class Applier:
                     )
                 if prep_full is None:
                     prep_full = prepare(full, apps, use_greed=self.opts.use_greed)
-                n_new = self.find_min_nodes_batched(
-                    prep_full, len(cluster.nodes)
-                )
+                with obs.span("capacity.search", max_new_nodes=self.opts.max_new_nodes):
+                    n_new = self.find_min_nodes_batched(
+                        prep_full, len(cluster.nodes)
+                    )
             if n_new is None:
                 print(
                     f"Simulation failed: still unschedulable after adding {self.opts.max_new_nodes} node(s)",
@@ -402,17 +404,18 @@ class Applier:
         print("Simulation success!", file=self.out)
         if n_new:
             print(f"(added {n_new} new node(s))", file=self.out)
-        report_mod.report(
-            result,
-            extended_resources=self.opts.extended_resources,
-            app_names=[a.name for a in apps],
-            out=self.out,
-            pod_nodes=[] if self.opts.report_pods else None,
-        )
-        if result.engine is not None:
-            print(f"Scheduling engine: {result.engine.describe()}", file=self.out)
-        if self.opts.explain and result.engine is not None:
-            self._print_placement_audit(result.engine)
+        with obs.span("report"):
+            report_mod.report(
+                result,
+                extended_resources=self.opts.extended_resources,
+                app_names=[a.name for a in apps],
+                out=self.out,
+                pod_nodes=[] if self.opts.report_pods else None,
+            )
+            if result.engine is not None:
+                print(f"Scheduling engine: {result.engine.describe()}", file=self.out)
+            if self.opts.explain and result.engine is not None:
+                self._print_placement_audit(result.engine)
         return 0
 
     def _print_placement_audit(self, engine) -> None:

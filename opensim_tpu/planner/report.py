@@ -27,6 +27,7 @@ from ..models.objects import (
     RES_GPU_MEM,
 )
 from ..models.quantity import format_milli, format_quantity
+from ..obs import trace as obs
 
 
 def _table(rows: List[List[str]], out: TextIO) -> None:
@@ -54,7 +55,8 @@ def report(
 ) -> None:
     report_cluster_info(result, extended_resources, out)
     if pod_nodes is not None:
-        report_node_info(result, extended_resources, pod_nodes, out)
+        with obs.span("report.pods"):  # the one table that grows with the pods
+            report_node_info(result, extended_resources, pod_nodes, out)
     report_app_info(result, app_names, out)
 
 

@@ -912,10 +912,10 @@ class SimonServer:
         """
         from ..engine import prepcache
         from ..utils.trace import PREP_STATS
-        import time as _time
 
-        new_nodes = _decode_new_nodes(payload)
-        app = _decode_app(payload)
+        with tracing.span("http.parse"):  # the JSON body to node and app objects
+            new_nodes = _decode_new_nodes(payload)
+            app = _decode_app(payload)
         apps = [AppResource(kind, app)]
         scaled: set = set()
         if kind == "scale":
@@ -955,10 +955,9 @@ class SimonServer:
         entry = self.prep_cache.get(full_key) if not new_nodes else None
         if entry is not None and entry.prep is not None:
             self.prep_cache.check_fresh(entry)
-            t0 = _time.monotonic()
             with entry.lock:
-                entry.restore()
-                PREP_STATS.record("hit", _time.monotonic() - t0)
+                with PREP_STATS.timed("hit"):
+                    entry.restore()
                 try:
                     return simulate(
                         cluster, apps, prep=entry.prep,
@@ -1318,7 +1317,10 @@ class SimonServer:
                     tr.root.set(engine=result.engine.describe())
                     if ticket.batch_size:
                         tr.root.set(batch_size=ticket.batch_size)
-            code, body = 200, _response(result, explain=explain)
+            # the trace's scope was the worker's: install it here for the
+            # answer (the bytes and the socket write follow the root's end)
+            with tracing.trace_scope(tr), tracing.span("http.respond"):
+                code, body = 200, _response(result, explain=explain)
             if explain and tr is not None and result.engine is not None:
                 tr.placements = _placements_payload(rid, result)
         except admission_mod.QueueFull as e:
@@ -1354,9 +1356,12 @@ class SimonServer:
                 RECORDER.observe_request(endpoint, seconds, status=status)
             if tr is not None:
                 if ticket is not None and ticket.queue_s:
-                    # real time-in-queue on the span tree (also histogrammed
-                    # as simon_queue_wait_seconds by the controller)
-                    tr.root.child_from_seconds("queue", ticket.queue_s)
+                    # real time-in-queue on the span tree, where the ticket's
+                    # own stamps put it (also histogrammed as
+                    # simon_queue_wait_seconds by the controller)
+                    tr.root.child_at(
+                        "queue", ticket.enqueued, ticket.enqueued + ticket.queue_s
+                    )
                 self._stamp_fleet_trace(tr)
                 tr.finish(status=status, http_status=code)
                 FLIGHT_RECORDER.record(tr)
@@ -1422,7 +1427,8 @@ class SimonServer:
                 result.engine.request_id = rid
                 if tr is not None:
                     tr.root.set(engine=result.engine.describe())
-            code, body = 200, _response(result, explain=explain)
+            with tracing.trace_scope(tr), tracing.span("http.respond"):
+                code, body = 200, _response(result, explain=explain)
             if explain and tr is not None and result.engine is not None:
                 # the decision audit joins the flight recorder: served later
                 # at GET /api/debug/placements/<request-id> (serialized and

@@ -1,56 +1,50 @@
-"""Latency tracing — parity with the ``k8s.io/utils/trace`` spans the
-reference sprinkles through the hot paths: ``Simulate`` traced at a 1 s
-threshold (``pkg/simulator/core.go:72-73``), the cluster snapshot at 100 ms
-(``simulator.go:511-512``), per-pod scheduling at 100 ms
-(``generic_scheduler.go:132-133``). Spans log only when they exceed their
-threshold, with step breakdowns.
+"""Host-prepare attribution (``PREP_STATS``) and the JAX profiler server.
 
-For device-side profiling the reference exposes pprof on its HTTP server
-(``pkg/server/server.go:152``); the analogue here is the JAX profiler —
-``start_profiler()`` serves the TensorBoard-compatible trace endpoint.
+Latency tracing lives in ``obs/trace.py``, the one span API: the reference's
+``k8s.io/utils/trace`` spans (``Simulate`` at ``pkg/simulator/core.go:72-73``,
+the snapshot, per-pod scheduling) are the ``prepare`` / ``schedule`` /
+``decode`` spans of a request's tree there. For device-side profiling the
+reference exposes pprof on its HTTP server (``pkg/server/server.go:152``);
+the analogue here is the JAX profiler: ``start_profiler()`` serves the
+TensorBoard-compatible capture endpoint, and a capture shows the program's
+spans in the host plane beside the device operations.
 """
 
 from __future__ import annotations
 
-import logging
 import time
-from typing import List, Optional, Tuple
+import threading
+from typing import Optional, Tuple
 
-log = logging.getLogger("opensim_tpu.trace")
+from ..obs import trace as _obs
 
 
-class Trace:
-    """Threshold-gated span with sub-steps.
+class _PrepScope:
+    """``with PREP_STATS.timed(kind) as t:`` — see :meth:`PrepStats.timed`."""
 
-    with Trace("Simulate", threshold_s=1.0) as tr:
-        ...
-        tr.step("expand workloads")
-        ...
-    """
+    __slots__ = ("stats", "kind", "_scope", "_t0", "_declined")
 
-    def __init__(self, name: str, threshold_s: float = 1.0) -> None:
-        self.name = name
-        self.threshold_s = threshold_s
-        self.start = 0.0
-        self.steps: List[Tuple[str, float]] = []
+    def __init__(self, stats: "PrepStats", kind: str) -> None:
+        self.stats = stats
+        self.kind = kind
+        self._declined = False
 
-    def __enter__(self) -> "Trace":
-        self.start = time.monotonic()
+    def declined(self) -> None:
+        """The delta handed the work back (it returns None and the caller
+        prepares in full): its seconds stay out of the stats."""
+        self._declined = True
+
+    def __enter__(self) -> "_PrepScope":
+        self._t0 = time.monotonic()
+        self._scope = _obs.span("prep." + self.kind, kind=self.kind)
+        self._scope.__enter__()
         return self
 
-    def step(self, msg: str) -> None:
-        self.steps.append((msg, time.monotonic()))
-
-    def __exit__(self, *exc) -> None:
-        total = time.monotonic() - self.start
-        if total < self.threshold_s:
-            return
-        lines = [f'Trace "{self.name}": total {total * 1000:.0f}ms (threshold {self.threshold_s * 1000:.0f}ms):']
-        prev = self.start
-        for msg, ts in self.steps:
-            lines.append(f"  step {msg}: {(ts - prev) * 1000:.0f}ms")
-            prev = ts
-        log.warning("\n".join(lines))
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._scope.__exit__(exc_type, exc, tb)
+        if exc_type is None and not self._declined:
+            self.stats.record(self.kind, time.monotonic() - self._t0)
+        return False
 
 
 class PrepStats:
@@ -66,11 +60,10 @@ class PrepStats:
 
     ``bench.py`` emits these as ``host_prep_s``; the REST server exports
     them as ``simon_prepare_seconds_total``; tests use ``last`` to assert a
-    request skipped re-encoding."""
+    request skipped re-encoding. Each also stands in the request's span
+    tree as a real ``prep.<kind>`` span round the work (:meth:`timed`)."""
 
     def __init__(self) -> None:
-        import threading
-
         self._lock = threading.Lock()
         self.reset()
 
@@ -84,12 +77,12 @@ class PrepStats:
             self.seconds[kind] = self.seconds.get(kind, 0.0) + seconds
             self.counts[kind] = self.counts.get(kind, 0) + 1
             self.last = (kind, seconds)
-        # host-prepare attribution as trace spans (ISSUE 5): every way a
-        # simulation obtained its Prepared appears in the request's span
-        # tree. No-op (one contextvar read) without an ambient trace.
-        from ..obs import trace as _obs
 
-        _obs.record_span(f"prep.{kind}", seconds, kind=kind)
+    def timed(self, kind: str) -> _PrepScope:
+        """One prepare of ``kind``: opens ``obs.span("prep.<kind>")`` round
+        the body (a no-op without an ambient trace) and records the body's
+        seconds on a clean exit."""
+        return _PrepScope(self, kind)
 
     def total_seconds(self) -> float:
         with self._lock:

@@ -621,12 +621,10 @@ def test_deadline_span_silent_when_span_present():
         with obs.span("prepare"):
             return encode(cluster)
 
-    def measured(cluster):
+    def marked(cluster):
         check_deadline("encode")
-        t0 = now()
-        out = encode(cluster)
-        obs.record_span("encode", now() - t0)
-        return out
+        obs.event("encode.skipped")
+        return cluster
     """
     assert _codes(src, path="opensim_tpu/engine/fixture.py", rules=["deadline-span"]) == []
 

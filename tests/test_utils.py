@@ -1,23 +1,10 @@
-"""Tracing and checkpoint/resume tests."""
-
-import logging
+"""Checkpoint/resume tests."""
 
 import numpy as np
 
 from opensim_tpu.encoding.state import ClusterEncoder
 from opensim_tpu.models import ResourceTypes, fixtures as fx
 from opensim_tpu.utils.checkpoint import load_state, save_state
-from opensim_tpu.utils.trace import Trace
-
-
-def test_trace_logs_only_over_threshold(caplog):
-    with caplog.at_level(logging.WARNING, logger="opensim_tpu.trace"):
-        with Trace("fast", threshold_s=10.0) as tr:
-            tr.step("noop")
-        assert not caplog.records
-        with Trace("slow", threshold_s=0.0) as tr:
-            tr.step("one")
-        assert any("slow" in r.message for r in caplog.records)
 
 
 def test_checkpoint_roundtrip(tmp_path):

@@ -263,6 +263,11 @@ class CacheEntry:
         # here instead of forcing a full re-encode; the REST layer unions
         # this into every simulate() drop mask derived from the entry
         self.base_drop: Optional[np.ndarray] = None  # guarded-by: lock
+        # the scan state after this entry's leading run of bound pods, built
+        # by the first XLA scan over a stream derived from it and read by
+        # every later one (engine/resident.py). A twin event makes a new
+        # entry (twin_pod_delta), which builds its own.
+        self.resident = None  # guarded-by: lock
         # (object, local_version at fingerprint time) — the stale-entry
         # guard; see VersionedObject (models/objects.py) and
         # watch_snapshot(). Derived entries share the base's list: their
@@ -559,6 +564,8 @@ def derive_with_app_slices(
             n_bare=base.n_bare,
             ds_group_sizes=list(base.ds_group_sizes or []),
         )
+        if base_entry is not None and base_entry.prep is base:
+            prep.resident_base = base_entry
     return prep, slices
 
 

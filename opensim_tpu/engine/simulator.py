@@ -322,6 +322,10 @@ class Prepared:
     # slice each app's expanded pods occupy, in `apps` order — lets the
     # admission batcher mask per-request regions without re-deriving
     app_slices: Optional[List[Tuple[int, int]]] = None
+    # the base CacheEntry whose stream this one extends at the end
+    # (prepcache.derive_with_app_slices): the XLA scan may start from that
+    # entry's resident carry instead of replaying its pods (engine/resident.py)
+    resident_base: object = None
 
 
 def pinned_node_name(pod: Pod) -> str:
@@ -449,16 +453,21 @@ def _prepare_inner(cluster, apps, use_greed, node_pad, patch_pods_fn):
     )
 
 
-def _xla_scan(ec, st, tmpl_ids, pod_valid, forced, nv_mask, **kwargs):
+def _xla_scan(ec, st, tmpl_ids, pod_valid, forced, nv_mask, head=None, **kwargs):
     """The XLA scan under the caller's ``engine.xla`` span, in the three
     parts an idle device can be waiting for: ``xla.pad`` (the pod stream to
     its bucket, the node mask and the carry to the device), ``xla.launch``
     (``schedule_pods`` until it returns: signature, trace and compile or
-    cache lookup on a new shape, enqueue), ``xla.wait`` (the device)."""
+    cache lookup on a new shape, enqueue), ``xla.wait`` (the device). With a
+    ``head`` (``resident.fetch``) only the stream behind the resident pods is
+    scanned, from the head's state, and the head's outputs stand in front."""
     from ..obs import trace as obs
     from ..obs.profile import launch_span
 
     with obs.span("xla.pad"):
+        if head is not None:
+            st, n = head.state, head.n_res
+            tmpl_ids, pod_valid, forced = tmpl_ids[n:], pod_valid[n:], forced[n:]
         tmpl_p, valid_p, forced_p = pad_pod_stream(tmpl_ids, pod_valid, forced)
         if nv_mask is not None:
             ec = ec._replace(node_valid=jnp.asarray(nv_mask))
@@ -469,7 +478,7 @@ def _xla_scan(ec, st, tmpl_ids, pod_valid, forced, nv_mask, **kwargs):
         )
     with obs.span("xla.wait"):
         jax.block_until_ready(out.chosen)  # dispatch is async; trace real device time
-    return out
+    return out if head is None else head.in_front_of(out)
 
 
 def _run_segments(
@@ -486,7 +495,7 @@ def _run_segments(
     with sf_rows=arange) because static filter tables are config-dependent
     and failure attribution resolves per segment."""
     from ..obs import trace as obs
-    from . import nativepath
+    from . import nativepath, resident
 
     P = len(tmpl_ids)
     n_dyn = kernels.NUM_FILTERS - kernels.F_PORTS
@@ -528,6 +537,7 @@ def _run_segments(
                 if out.native_stats is not None:
                     seg_stats.append(out.native_stats)
             else:
+                resident.fetch(prep, seg_valid, segments=True)  # declines, and says so
                 out = _xla_scan(
                     prep.ec, st, tmpl_ids, seg_valid, forced, nv_mask,
                     features=prep.features, config=cfg, extra_plugins=extra_plugins,
@@ -732,9 +742,16 @@ def _run_engine_ladder(
             skips["native"] = miss
             log.info("native engine skipped: %s", miss)
     if out is None:
-        with obs.span("engine.xla"):
+        from . import resident
+
+        with obs.span("engine.xla", pods=len(tmpl_ids)) as rung:
+            head = resident.fetch(
+                prep, pod_valid, nv_mask=nv_mask, sched_config=sched_config,
+                extra_plugins=extra_plugins, tie_seed=tie_seed, explain=explain,
+            )
+            rung.set(scanned=len(tmpl_ids) - (head.n_res if head is not None else 0))
             out = _xla_scan(
-                ec, st0, tmpl_ids, pod_valid, forced, nv_mask,
+                ec, st0, tmpl_ids, pod_valid, forced, nv_mask, head=head,
                 features=prep.features, config=sched_config, extra_plugins=extra_plugins,
                 tie_seed=tie_seed, explain=explain,
             )

@@ -169,6 +169,10 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Nodes rejected per filter plugin while attributing unschedulable pods", "counter",
     ),
     "simon_unschedulable_total": ("Unschedulable pods by primary (most-rejecting) reason code", "counter"),
+    # outcome: hit | built | declined
+    "simon_resident_carry_total": (
+        "XLA scans by outcome of the resident carry: started from it, built it first, or replayed in full", "counter",
+    ),
     # capacity observatory (obs/capacity.py, docs/observability.md) —
     # cardinality contract: every family below is label-free or bounded
     # (resource ∈ {cpu, memory, pods}; profile = registered headroom
@@ -550,6 +554,8 @@ class MetricsRecorder:
         # bumped by every simulate() regardless of explain mode
         self.filter_rejects = make_counter("simon_filter_reject_total", ("filter",))
         self.unschedulable = make_counter("simon_unschedulable_total", ("reason",))
+        # XLA scans by what became of the resident carry (engine/resident.py)
+        self.resident_carry = make_counter("simon_resident_carry_total", ("outcome",))
         # watch-pipeline latency (ISSUE 9 satellite): event receipt → twin
         # applied, fed from the supervisor's dispatch (server/watch.py)
         self.watch_apply = make_histogram(
@@ -611,11 +617,16 @@ class MetricsRecorder:
             for name, n in by_reason.items():
                 self.unschedulable.inc((name,), int(n))
 
+    def count_resident_carry(self, outcome: str) -> None:
+        with self.lock:
+            self.resident_carry.inc((outcome,))
+
     def render_lines(self) -> List[str]:
         with self.lock:
             return (
                 self.filter_rejects.render_lines()
                 + self.unschedulable.render_lines()
+                + self.resident_carry.render_lines()
                 + self.phase_seconds.render_lines()
                 + self.request_seconds.render_lines()
                 + self.watch_apply.render_lines()
@@ -627,6 +638,7 @@ class MetricsRecorder:
             self.request_seconds.reset()
             self.filter_rejects.reset()
             self.unschedulable.reset()
+            self.resident_carry.reset()
             self.watch_apply.reset()
 
 

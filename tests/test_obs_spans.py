@@ -191,12 +191,14 @@ def test_a_megakernel_sweep_has_the_same_four_parts():
     assert_spans_nest(tr.root)
 
 
-def test_the_xla_rung_is_pad_launch_wait(monkeypatch):
+def test_the_xla_rung_is_resident_pad_launch_wait(monkeypatch):
     monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     tr, _ = _traced(lambda: simulate(_cluster(), _apps()))
     (rung,) = _find(tr, "engine.xla")
-    assert [c.name for c in rung.children] == ["xla.pad", "xla.launch", "xla.wait"]
-    assert {"backend_compiles", "cache_hits", "pods"} <= set(rung.children[1].attrs)
+    assert [c.name for c in rung.children] == ["xla.resident", "xla.pad", "xla.launch", "xla.wait"]
+    assert rung.children[0].attrs["outcome"] == "declined"  # a plan has no base entry to start from
+    assert rung.attrs["pods"] == rung.attrs["scanned"] > 0
+    assert {"backend_compiles", "cache_hits", "pods"} <= set(rung.children[2].attrs)
     assert_spans_nest(tr.root)
 
 

@@ -29,9 +29,10 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from ..models.objects import Node, Pod
+from ..obs import trace as obs
 from . import vocab as V
 from .dtypes import log_size_table
-from .templates import SchedTemplate, TemplateSet
+from .templates import PodAffinityTerm, SchedTemplate, TemplateSet
 
 _NAN = float("nan")
 
@@ -150,6 +151,7 @@ class ClusterMeta:
     node_vg_cap: Optional[np.ndarray] = None  # [N, Vg] f32
     node_dev_cap: Optional[np.ndarray] = None  # [N, Dv] f32
     node_dev_media: Optional[np.ndarray] = None  # [N, Dv] i32
+    interpod_terms: int = 0  # rows of the global inter-pod term tables (anti + scoring)
 
 
 def _pad_to(n: int, mult: int) -> int:
@@ -578,6 +580,82 @@ class ClusterEncoder:
             avoid_entries=avoid_entries,
         )
 
+    @staticmethod
+    def _interpod_tables(
+        templates: List[SchedTemplate], topo_idx: Dict[str, int]
+    ) -> Tuple[int, Dict[str, np.ndarray]]:
+        """The inter-pod term tables, as (global rows, EncodedCluster fields):
+        the global rows placed pods are counted under (one per distinct
+        (selector, topology key) among the required anti-affinity terms, and
+        one among the scoring terms), and per template its own terms and
+        which rows a pod of it carries."""
+        U = len(templates)
+        with obs.span("encode.interpod", templates=U) as sp:
+            Ti = max([len(t.aff_terms) for t in templates] + [1])
+            Tn = max([len(t.anti_terms) for t in templates] + [1])
+            Tpp = max([len(t.pref_terms) for t in templates] + [1])
+
+            def row(term: PodAffinityTerm) -> Tuple[int, int]:
+                return (term.sel_id, topo_idx.get(term.topo_key, -1))
+
+            anti_table: Dict[Tuple[int, int], int] = {}
+            pref_table: Dict[Tuple[int, int], int] = {}
+            for t in templates:
+                for term in t.anti_terms:
+                    anti_table.setdefault(row(term), len(anti_table))
+                for term in t.pref_terms:
+                    pref_table.setdefault(row(term), len(pref_table))
+                # existing pods' REQUIRED affinity terms score with hard weight 1
+                for term in t.aff_terms:
+                    pref_table.setdefault(row(term), len(pref_table))
+            sp.set(terms=len(anti_table) + len(pref_table))
+            G = max(len(anti_table), 1)
+            Gp = max(len(pref_table), 1)
+            anti_g_sel = np.zeros((G,), dtype=np.int32)
+            anti_g_topo = np.zeros((G,), dtype=np.int32)
+            for (sid, tki), g in anti_table.items():
+                anti_g_sel[g] = sid
+                anti_g_topo[g] = max(tki, 0)
+            prefg_sel = np.zeros((Gp,), dtype=np.int32)
+            prefg_topo = np.zeros((Gp,), dtype=np.int32)
+            for (sid, tki), g in pref_table.items():
+                prefg_sel[g] = sid
+                prefg_topo[g] = max(tki, 0)
+
+            at_sel = np.full((U, Ti), -1, dtype=np.int32)
+            at_topo = np.zeros((U, Ti), dtype=np.int32)
+            an_sel = np.full((U, Tn), -1, dtype=np.int32)
+            an_topo = np.zeros((U, Tn), dtype=np.int32)
+            pt_sel = np.full((U, Tpp), -1, dtype=np.int32)
+            pt_topo = np.zeros((U, Tpp), dtype=np.int32)
+            pt_w = np.zeros((U, Tpp), dtype=np.float32)
+            anti_g = np.zeros((U, G), dtype=bool)
+            prefg_w = np.zeros((U, Gp), dtype=np.float32)
+            for u, t in enumerate(templates):
+                for j, term in enumerate(t.aff_terms):
+                    # filter counts pods matching ALL terms — use the conjunction
+                    # selector when the template has several (templates.py)
+                    at_sel[u, j] = t.aff_conj if t.aff_conj >= 0 else term.sel_id
+                    at_topo[u, j] = max(row(term)[1], 0)
+                    # symmetric hard-affinity weight (HardPodAffinityWeight = 1)
+                    prefg_w[u, pref_table[row(term)]] += 1.0
+                for j, term in enumerate(t.anti_terms):
+                    an_sel[u, j] = term.sel_id
+                    an_topo[u, j] = max(row(term)[1], 0)
+                    anti_g[u, anti_table[row(term)]] = True
+                for j, term in enumerate(t.pref_terms):
+                    pt_sel[u, j] = term.sel_id
+                    pt_topo[u, j] = max(row(term)[1], 0)
+                    pt_w[u, j] = term.weight
+                    prefg_w[u, pref_table[row(term)]] += term.weight
+        return len(anti_table) + len(pref_table), {
+            "anti_g_sel": anti_g_sel, "anti_g_topo": anti_g_topo,
+            "prefg_sel": prefg_sel, "prefg_topo": prefg_topo,
+            "at_sel": at_sel, "at_topo": at_topo, "an_sel": an_sel, "an_topo": an_topo,
+            "pt_sel": pt_sel, "pt_topo": pt_topo, "pt_w": pt_w,
+            "anti_g": anti_g, "prefg_w": prefg_w,
+        }
+
     def _assemble(
         self, ar: NodeArenas, templates: List[SchedTemplate]
     ) -> Tuple[EncodedCluster, ScanState, ClusterMeta]:
@@ -642,9 +720,6 @@ class ClusterEncoder:
         Qmax = max(Q, Qp)
         Hp = max([len(t.host_ports) for t in templates] + [1])
         Cs = max([len(t.spread) for t in templates] + [1])
-        Ti = max([len(t.aff_terms) for t in templates] + [1])
-        Tn = max([len(t.anti_terms) for t in templates] + [1])
-        Tpp = max([len(t.pref_terms) for t in templates] + [1])
 
         # ---- topology domains: trash-row substitution over the raw arena
         # ids (the arena keeps -1 for absent so D can keep growing)
@@ -660,30 +735,8 @@ class ClusterEncoder:
         for (tki, _vid), did in ar.domain_ids.items():
             domain_topo[did] = tki
 
-        # ---- global inter-pod term tables
         topo_idx = {k: i for i, k in enumerate(vb.topo_keys.items())}
-        anti_table: Dict[Tuple[int, int], int] = {}
-        pref_table: Dict[Tuple[int, int], int] = {}
-        for t in templates:
-            for term in t.anti_terms:
-                anti_table.setdefault((term.sel_id, topo_idx.get(term.topo_key, -1)), len(anti_table))
-            for term in t.pref_terms:
-                pref_table.setdefault((term.sel_id, topo_idx.get(term.topo_key, -1)), len(pref_table))
-            # existing pods' REQUIRED affinity terms score with hard weight 1
-            for term in t.aff_terms:
-                pref_table.setdefault((term.sel_id, topo_idx.get(term.topo_key, -1)), len(pref_table))
-        G = max(len(anti_table), 1)
-        Gp = max(len(pref_table), 1)
-        anti_g_sel = np.zeros((G,), dtype=np.int32)
-        anti_g_topo = np.zeros((G,), dtype=np.int32)
-        for (sid, tki), g in anti_table.items():
-            anti_g_sel[g] = sid
-            anti_g_topo[g] = max(tki, 0)
-        prefg_sel = np.zeros((Gp,), dtype=np.int32)
-        prefg_topo = np.zeros((Gp,), dtype=np.int32)
-        for (sid, tki), g in pref_table.items():
-            prefg_sel[g] = sid
-            prefg_topo[g] = max(tki, 0)
+        interpod_terms, interpod = self._interpod_tables(templates, topo_idx)
 
         # ---- template tensors
         req = np.zeros((U, R), dtype=np.float32)
@@ -710,15 +763,6 @@ class ClusterEncoder:
         spr_sel = np.zeros((U, Cs), dtype=np.int32)
         spr_skew = np.zeros((U, Cs), dtype=np.int32)
         spr_hard = np.zeros((U, Cs), dtype=bool)
-        at_sel = np.full((U, Ti), -1, dtype=np.int32)
-        at_topo = np.zeros((U, Ti), dtype=np.int32)
-        an_sel = np.full((U, Tn), -1, dtype=np.int32)
-        an_topo = np.zeros((U, Tn), dtype=np.int32)
-        pt_sel = np.full((U, Tpp), -1, dtype=np.int32)
-        pt_topo = np.zeros((U, Tpp), dtype=np.int32)
-        pt_w = np.zeros((U, Tpp), dtype=np.float32)
-        anti_g = np.zeros((U, G), dtype=bool)
-        prefg_w = np.zeros((U, Gp), dtype=np.float32)
         pin = np.full((U,), -1, dtype=np.int32)
         gpu_mem = np.zeros((U,), dtype=np.float32)
         gpu_count = np.zeros((U,), dtype=np.int32)
@@ -755,23 +799,6 @@ class ClusterEncoder:
                 spr_sel[u, j] = c.sel_id
                 spr_skew[u, j] = c.max_skew
                 spr_hard[u, j] = c.hard
-            for j, term in enumerate(t.aff_terms[:Ti]):
-                # filter counts pods matching ALL terms — use the conjunction
-                # selector when the template has several (templates.py)
-                at_sel[u, j] = t.aff_conj if t.aff_conj >= 0 else term.sel_id
-                at_topo[u, j] = max(topo_idx.get(term.topo_key, -1), 0)
-            for j, term in enumerate(t.anti_terms[:Tn]):
-                an_sel[u, j] = term.sel_id
-                an_topo[u, j] = max(topo_idx.get(term.topo_key, -1), 0)
-                anti_g[u, anti_table[(term.sel_id, topo_idx.get(term.topo_key, -1))]] = True
-            for j, term in enumerate(t.pref_terms[:Tpp]):
-                pt_sel[u, j] = term.sel_id
-                pt_topo[u, j] = max(topo_idx.get(term.topo_key, -1), 0)
-                pt_w[u, j] = term.weight
-                prefg_w[u, pref_table[(term.sel_id, topo_idx.get(term.topo_key, -1))]] += term.weight
-            for term in t.aff_terms:
-                # symmetric hard-affinity weight (HardPodAffinityWeight = 1)
-                prefg_w[u, pref_table[(term.sel_id, topo_idx.get(term.topo_key, -1))]] += 1.0
             gpu_mem[u] = t.gpu_mem
             gpu_count[u] = t.gpu_count
 
@@ -842,22 +869,9 @@ class ClusterEncoder:
             spr_sel=spr_sel,
             spr_skew=spr_skew,
             spr_hard=spr_hard,
-            at_sel=at_sel,
-            at_topo=at_topo,
-            an_sel=an_sel,
-            an_topo=an_topo,
-            pt_sel=pt_sel,
-            pt_topo=pt_topo,
-            pt_w=pt_w,
             matches_sel=matches_sel,
-            anti_g=anti_g,
-            prefg_w=prefg_w,
             pin=pin,
             avoid_score=avoid_score,
-            anti_g_sel=anti_g_sel,
-            anti_g_topo=anti_g_topo,
-            prefg_sel=prefg_sel,
-            prefg_topo=prefg_topo,
             gpu_mem=gpu_mem,
             gpu_count=gpu_count,
             node_gpu_mem=node_gpu_mem,
@@ -870,14 +884,15 @@ class ClusterEncoder:
             node_dev_cap=node_dev_cap,
             node_dev_media=node_dev_media,
             log_sizes=log_size_table(N),
+            **interpod,
         )
 
         state0 = ScanState(
             used=np.zeros((N, R), dtype=np.float32),
             port_used=np.zeros((N, Hports), dtype=np.float32),
             dom_sel=np.zeros((D + 1, A), dtype=np.float32),
-            dom_anti=np.zeros((D + 1, G), dtype=np.float32),
-            dom_prefw=np.zeros((D + 1, Gp), dtype=np.float32),
+            dom_anti=np.zeros((D + 1, interpod["anti_g_sel"].shape[0]), dtype=np.float32),
+            dom_prefw=np.zeros((D + 1, interpod["prefg_sel"].shape[0]), dtype=np.float32),
             gpu_free=node_gpu_mem.copy(),
             vg_free=node_vg_cap.copy(),
             dev_free=node_dev_cap.copy(),
@@ -897,5 +912,6 @@ class ClusterEncoder:
             node_vg_cap=node_vg_cap.copy(),
             node_dev_cap=node_dev_cap.copy(),
             node_dev_media=node_dev_media.copy(),
+            interpod_terms=interpod_terms,
         )
         return cluster, state0, meta

@@ -114,6 +114,23 @@ def why_not(prep, config=None) -> Optional[str]:
         return "disabled by --backend native (OPENSIM_NATIVE=1)"
     if jax.default_backend() != "tpu" and envknobs.raw("OPENSIM_FASTPATH") != "interpret":
         return f"no TPU backend (jax.default_backend()={jax.default_backend()!r})"
+    vmem = vmem_estimate(prep)
+    if vmem > _VMEM_BUDGET:
+        return f"VMEM estimate {vmem / 1e6:.1f} MB exceeds the {_VMEM_BUDGET / 1e6:.0f} MB budget"
+    return None
+
+
+def vmem_estimate(prep) -> int:
+    """Bytes of the kernel's resident VMEM rows, as `why_not` reckons them
+    and `mk.inputs` reports them."""
+    f = prep.features
+    ec = prep.ec_np if prep.ec_np is not None else prep.ec
+    N = 128 * math.ceil(int(ec.node_valid.shape[0]) / 128)
+    U = int(ec.req.shape[0])
+    A = int(ec.matches_sel.shape[1])
+    R = int(ec.alloc.shape[1])
+    topo_keys = prep.meta.vocab.topo_keys.items()
+    non_host = [k for k in topo_keys if k != HOSTNAME]
     # VMEM budget. The pallas_call signature is generated per feature-flag
     # combination (_input_layout): a feature that is off contributes ZERO
     # rows — its buffers don't exist in the program. Resident rows ([x, N]):
@@ -168,10 +185,7 @@ def why_not(prep, config=None) -> Optional[str]:
         rows += U_resident
     if f.prefer_avoid:
         rows += U_resident
-    vmem = (rows * N + (2 * K * N + zone_z_rows) * Z + u_rows * u_cols) * 4
-    if vmem > _VMEM_BUDGET:
-        return f"VMEM estimate {vmem / 1e6:.1f} MB exceeds the {_VMEM_BUDGET / 1e6:.0f} MB budget"
-    return None
+    return (rows * N + (2 * K * N + zone_z_rows) * Z + u_rows * u_cols) * 4
 
 
 def _gc_row(prep) -> int:
@@ -527,7 +541,7 @@ def sweep(
     interpret = _resolve_interpret(interpret)
     S = node_valid_masks.shape[0]
     P = pod_valid_masks.shape[1]
-    with obs.span("mk.inputs", scenarios=S):
+    with obs.span("mk.inputs", scenarios=S, vmem_estimate_bytes=vmem_estimate(prep)):
         fi, meta = build_inputs(prep)
         if big_u is None:
             big_u = use_big_u(*fi.static_pass.shape)
@@ -590,7 +604,7 @@ def schedule(
     # fails hard under OPENSIM_REQUIRE_TPU=1 (chaos suite)
     faults.fault_point("engine.compile")
     interpret = _resolve_interpret(interpret)
-    with obs.span("mk.inputs"):
+    with obs.span("mk.inputs", vmem_estimate_bytes=vmem_estimate(prep)):
         fi, meta = build_inputs(prep)
         if big_u is None:
             big_u = use_big_u(*fi.static_pass.shape)

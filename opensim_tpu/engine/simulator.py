@@ -591,6 +591,11 @@ def _run_engine_ladder(
     out = None
     engine_name = "xla"
     skips: Dict[str, str] = {}
+    # what chose the kernels' signature, on the span of the rung that ran
+    # and on /metrics: the active feature flags and the rows of the
+    # inter-pod term tables the stream's pods are counted under
+    features = "+".join(n for n, on in zip(prep.features._fields, prep.features) if on) or "none"
+    shape = {"features": features, "interpod_terms": prep.meta.interpod_terms}
     require_tpu = envknobs.raw("OPENSIM_REQUIRE_TPU") == "1"
     interpret = envknobs.raw("OPENSIM_FASTPATH") == "interpret"
     sf_rows = tmpl_ids  # decode: static_fail row per pod
@@ -656,7 +661,7 @@ def _run_engine_ladder(
             # --backend tpu demanded the TPU engine, where silently
             # benchmarking a fallback would be a lie (VERDICT r4 #3).
             try:
-                with obs.span("engine.megakernel"):
+                with obs.span("engine.megakernel", **shape):
                     f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev = fastpath.schedule(
                         prep, tmpl_ids, pod_valid, forced
                     )
@@ -744,7 +749,7 @@ def _run_engine_ladder(
     if out is None:
         from . import resident
 
-        with obs.span("engine.xla", pods=len(tmpl_ids)) as rung:
+        with obs.span("engine.xla", pods=len(tmpl_ids), **shape) as rung:
             head = resident.fetch(
                 prep, pod_valid, nv_mask=nv_mask, sched_config=sched_config,
                 extra_plugins=extra_plugins, tie_seed=tie_seed, explain=explain,
@@ -755,6 +760,9 @@ def _run_engine_ladder(
                 features=prep.features, config=sched_config, extra_plugins=extra_plugins,
                 tie_seed=tie_seed, explain=explain,
             )
+    from ..obs.metrics import RECORDER
+
+    RECORDER.count_engine_features(engine_name, features)
     return out, engine_name, skips, sf_rows
 
 

@@ -173,6 +173,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_resident_carry_total": (
         "XLA scans by outcome of the resident carry: started from it, built it first, or replayed in full", "counter",
     ),
+    # engine: megakernel | native | xla; features: the active
+    # feature flags joined by "+" (ops/kernels.py Features), or "none"
+    "simon_engine_features_total": (
+        "Scheduled streams by the engine that answered and the feature set that chose its kernels", "counter",
+    ),
     # capacity observatory (obs/capacity.py, docs/observability.md) —
     # cardinality contract: every family below is label-free or bounded
     # (resource ∈ {cpu, memory, pods}; profile = registered headroom
@@ -556,6 +561,8 @@ class MetricsRecorder:
         self.unschedulable = make_counter("simon_unschedulable_total", ("reason",))
         # XLA scans by what became of the resident carry (engine/resident.py)
         self.resident_carry = make_counter("simon_resident_carry_total", ("outcome",))
+        # streams by answering engine and feature set (engine/simulator.py's ladder)
+        self.engine_features = make_counter("simon_engine_features_total", ("engine", "features"))
         # watch-pipeline latency (ISSUE 9 satellite): event receipt → twin
         # applied, fed from the supervisor's dispatch (server/watch.py)
         self.watch_apply = make_histogram(
@@ -621,12 +628,17 @@ class MetricsRecorder:
         with self.lock:
             self.resident_carry.inc((outcome,))
 
+    def count_engine_features(self, engine: str, features: str) -> None:
+        with self.lock:
+            self.engine_features.inc((engine, features))
+
     def render_lines(self) -> List[str]:
         with self.lock:
             return (
                 self.filter_rejects.render_lines()
                 + self.unschedulable.render_lines()
                 + self.resident_carry.render_lines()
+                + self.engine_features.render_lines()
                 + self.phase_seconds.render_lines()
                 + self.request_seconds.render_lines()
                 + self.watch_apply.render_lines()
@@ -639,6 +651,7 @@ class MetricsRecorder:
             self.filter_rejects.reset()
             self.unschedulable.reset()
             self.resident_carry.reset()
+            self.engine_features.reset()
             self.watch_apply.reset()
 
 

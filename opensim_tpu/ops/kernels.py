@@ -88,6 +88,36 @@ def _requirements_match(ec, keys, ops, vals, nums):
     return result
 
 
+def _split(x):
+    """Veltkamp's split of a float32 into two halves of 12 bits whose
+    products are exact."""
+    c = x * 4097.0
+    hi = c - (c - x)
+    return hi, x - hi
+
+
+def div32(a, b):
+    """``a / b`` rounded as IEEE float32 division rounds it, on any backend.
+
+    A TPU's float32 quotient (XLA's and Mosaic's alike) is up to 2 ulp off
+    in a third of cases; the scores are compared with a float32 reference
+    down to the last bit, and one node chosen otherwise changes the pods
+    after it. The hardware quotient is corrected once by its own residual,
+    which Dekker's product gives exactly without a fused multiply-add. On a
+    backend whose quotient is already IEEE's the correction changes nothing."""
+    return _corrected(a, b, a / b)
+
+
+def _corrected(a, b, q):
+    """``q``, a quotient of ``a / b`` within a few ulp, moved to the float32
+    nearest ``a / b``: q + (a - q*b) / b with the residual taken exactly."""
+    qh, ql = _split(q)
+    bh, bl = _split(b)
+    p = q * b
+    e = ((qh * bh - p) + qh * bl + ql * bh) + ql * bl
+    return q + ((a - p) - e) / b
+
+
 def _minmax_normalize(scores, feasible):
     """SimonPlugin.NormalizeScore (plugin/simon.go:76-101): min-max over the
     feasible set to [0, 100]; degenerate range → 0."""
@@ -95,7 +125,7 @@ def _minmax_normalize(scores, feasible):
     lo = jnp.min(jnp.where(feasible, scores, big))
     hi = jnp.max(jnp.where(feasible, scores, -big))
     rng = hi - lo
-    return jnp.where(rng > 0, (scores - lo) * MAX_NODE_SCORE / rng, 0.0)
+    return jnp.where(rng > 0, div32((scores - lo) * MAX_NODE_SCORE, rng), 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -336,15 +366,15 @@ def least_allocated_score(ec, st, u):
 
 
 def _least_requested(requested, capacity):
-    score = (capacity - requested) * MAX_NODE_SCORE / jnp.maximum(capacity, 1.0)
+    score = div32((capacity - requested) * MAX_NODE_SCORE, jnp.maximum(capacity, 1.0))
     return jnp.where((capacity == 0) | (requested > capacity), 0.0, score)
 
 
 def balanced_allocation_score(ec, st, u):
     """NodeResourcesBalancedAllocation (balanced_allocation.go:82-112)."""
     cpu_req, mem_req = _nonzero_req(ec, u)
-    cpu_frac = (st.used[:, V.RES_CPU] + cpu_req) / jnp.maximum(ec.alloc[:, V.RES_CPU], 1.0)
-    mem_frac = (st.used[:, V.RES_MEMORY] + mem_req) / jnp.maximum(ec.alloc[:, V.RES_MEMORY], 1.0)
+    cpu_frac = div32(st.used[:, V.RES_CPU] + cpu_req, jnp.maximum(ec.alloc[:, V.RES_CPU], 1.0))
+    mem_frac = div32(st.used[:, V.RES_MEMORY] + mem_req, jnp.maximum(ec.alloc[:, V.RES_MEMORY], 1.0))
     score = (1.0 - jnp.abs(cpu_frac - mem_frac)) * MAX_NODE_SCORE
     return jnp.where((cpu_frac >= 1.0) | (mem_frac >= 1.0), 0.0, score)
 
@@ -407,7 +437,7 @@ def interpod_score(ec, st, u, feasible):
     hi = jnp.maximum(jnp.max(masked), 0.0)
     lo = jnp.minimum(jnp.min(masked), 0.0)
     rng = hi - lo
-    return jnp.where(rng > 0, MAX_NODE_SCORE * (raw - lo) / jnp.maximum(rng, 1.0), 0.0)
+    return jnp.where(rng > 0, div32(MAX_NODE_SCORE * (raw - lo), jnp.maximum(rng, 1.0)), 0.0)
 
 
 def spread_score(ec, stat: StaticTables, st, u, feasible):
@@ -437,7 +467,7 @@ def spread_score(ec, stat: StaticTables, st, u, feasible):
     mn = jnp.min(jnp.where(scored, raw, big))
     mx = jnp.max(jnp.where(scored, raw, -big))
     norm = jnp.where(
-        mx <= 0, MAX_NODE_SCORE, MAX_NODE_SCORE * (mx + mn - raw) / jnp.maximum(mx, 1.0)
+        mx <= 0, MAX_NODE_SCORE, div32(MAX_NODE_SCORE * (mx + mn - raw), jnp.maximum(mx, 1.0))
     )
     norm = jnp.where(ignored, 0.0, norm)
     return jnp.where(any_soft, norm, 0.0)
@@ -454,7 +484,7 @@ def share_raw(ec, u):
     req = ec.req[u].at[V.RES_PODS].set(0.0)  # 'pods' request is not in PodRequestsAndLimits
     avail = ec.alloc - req[None, :]
     share = jnp.where(
-        avail == 0, jnp.where(req[None, :] == 0, 0.0, 1.0), req[None, :] / avail
+        avail == 0, jnp.where(req[None, :] == 0, 0.0, 1.0), div32(jnp.broadcast_to(req[None, :], avail.shape), avail)
     )
     # only resources the node actually declares participate; negative shares
     # (req > allocatable) floor at 0 like the Go accumulator starting at 0
@@ -490,7 +520,7 @@ def gc_share_dyn(ec, st, u):
     dyn, has_dev = gc_dynamic_alloc(ec, st)
     declared = jnp.sum(jnp.where(ec.gc_mask[None, :], ec.alloc, 0.0), axis=-1) > 0
     avail = dyn - gc_req
-    share = jnp.where(avail == 0, jnp.where(gc_req == 0, 0.0, 1.0), gc_req / avail)
+    share = jnp.where(avail == 0, jnp.where(gc_req == 0, 0.0, 1.0), div32(gc_req, avail))
     share = jnp.where(declared & has_dev, jnp.maximum(share, 0.0), 0.0)
     return jnp.where(gc_req > 0, share * MAX_NODE_SCORE, 0.0)
 
@@ -829,7 +859,7 @@ def local_score(ec, st, u):
     # capacity of the chosen VG: gather via argmin over masked free
     choice = jnp.argmin(jnp.where(fits, st.vg_free, big), axis=-1)  # [N]
     vg_cap = jnp.take_along_axis(ec.node_vg_cap, choice[:, None], axis=-1)[:, 0]
-    lvm_part = jnp.where((lvm > 0) & (tight_free < big), lvm / jnp.maximum(vg_cap, 1.0), 0.0)
+    lvm_part = jnp.where((lvm > 0) & (tight_free < big), div32(lvm, jnp.maximum(vg_cap, 1.0)), 0.0)
 
     parts = lvm_part
     count = (lvm > 0).astype(jnp.float32)
@@ -839,10 +869,10 @@ def local_score(ec, st, u):
         fitting = (ec.node_dev_media == media) & (st.dev_free >= size) & (st.dev_free > 0)
         dev_cap = jnp.where(fitting, ec.node_dev_cap, big)
         first_cap = jnp.min(dev_cap, axis=-1)  # first-fit proxy: smallest fitting device
-        parts = parts + jnp.where(size > 0, n_dev * size / jnp.maximum(first_cap, 1.0), 0.0)
+        parts = parts + jnp.where(size > 0, div32(n_dev * size, jnp.maximum(first_cap, 1.0)), 0.0)
         count = count + jnp.where(size > 0, n_dev, 0.0)
 
-    raw = jnp.where(count > 0, parts / jnp.maximum(count, 1.0) * 10.0, 0.0)
+    raw = jnp.where(count > 0, div32(parts, jnp.maximum(count, 1.0)) * 10.0, 0.0)
     return raw
 
 
@@ -941,14 +971,14 @@ def score_parts(
         na_raw = stat.na_raw[u]
         na_max = jnp.max(jnp.where(feasible, na_raw, 0.0))
         parts["NodeAffinity"] = cfg.w_node_affinity * jnp.where(
-            na_max > 0, na_raw * MAX_NODE_SCORE / jnp.maximum(na_max, 1.0), na_raw
+            na_max > 0, div32(na_raw * MAX_NODE_SCORE, jnp.maximum(na_max, 1.0)), na_raw
         )
     if feat.prefer_taints and cfg.w_taint_toleration:
         tt_raw = stat.tt_raw[u]
         tt_max = jnp.max(jnp.where(feasible, tt_raw, 0.0))
         parts["TaintToleration"] = cfg.w_taint_toleration * jnp.where(
             tt_max > 0,
-            MAX_NODE_SCORE - tt_raw * MAX_NODE_SCORE / jnp.maximum(tt_max, 1.0),
+            MAX_NODE_SCORE - div32(tt_raw * MAX_NODE_SCORE, jnp.maximum(tt_max, 1.0)),
             MAX_NODE_SCORE,
         )
     if (feat.prefg or feat.interpod) and cfg.w_interpod:

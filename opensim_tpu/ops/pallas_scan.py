@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..encoding import vocab as V
+from .kernels import div32
 
 NEG = -1e30
 MAX_SCORE = 100.0
@@ -71,6 +72,26 @@ def _dot(a, b):
     return jnp.dot(
         a, b, preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST
     )
+
+
+def _div_rows(pairs):
+    """Exactly rounded quotients (kernels.div32) of [1, N] rows, eight at a
+    time: a [1, N] row uses one of the eight sublanes of each vector register
+    it lies in, so the quotients of a step are stacked into [8, N] blocks and
+    eight of them cost one division. A denominator may be a scalar. Returned
+    in the order given."""
+    out = []
+    for at in range(0, len(pairs), 8):
+        group = pairs[at:at + 8]
+        nums = [a for a, _ in group]
+        dens = [jnp.broadcast_to(b, a.shape) for a, b in group]
+        fill = 8 - len(group)
+        q = div32(
+            jnp.concatenate(nums + nums[:1] * fill, axis=0),
+            jnp.concatenate(dens + dens[:1] * fill, axis=0),
+        )
+        out += [q[j:j + 1, :] for j in range(len(group))]
+    return out
 
 
 class FastInputs(NamedTuple):
@@ -610,24 +631,16 @@ def _make_kernel(
             alloc_mem = alloc_ref[pl.ds(V.RES_MEMORY, 1), :]
             used_cpu = used_ref[pl.ds(V.RES_CPU, 1), :] + cpu_req
             used_mem = used_ref[pl.ds(V.RES_MEMORY, 1), :] + mem_req
-            l_cpu = jnp.where(
-                (alloc_cpu == 0) | (used_cpu > alloc_cpu),
-                0.0,
-                (alloc_cpu - used_cpu) * MAX_SCORE / jnp.maximum(alloc_cpu, 1.0),
-            )
-            l_mem = jnp.where(
-                (alloc_mem == 0) | (used_mem > alloc_mem),
-                0.0,
-                (alloc_mem - used_mem) * MAX_SCORE / jnp.maximum(alloc_mem, 1.0),
-            )
-            least = (l_cpu + l_mem) / 2.0
-            cpu_frac = used_cpu / jnp.maximum(alloc_cpu, 1.0)
-            mem_frac = used_mem / jnp.maximum(alloc_mem, 1.0)
-            balanced = jnp.where(
-                (cpu_frac >= 1.0) | (mem_frac >= 1.0),
-                0.0,
-                (1.0 - jnp.abs(cpu_frac - mem_frac)) * MAX_SCORE,
-            )
+            # every quotient of the step is taken in one stacked block
+            # below (_div_rows): its operands are gathered here first
+            cap_cpu = jnp.maximum(alloc_cpu, 1.0)
+            cap_mem = jnp.maximum(alloc_mem, 1.0)
+            quotients = [
+                ((alloc_cpu - used_cpu) * MAX_SCORE, cap_cpu),
+                ((alloc_mem - used_mem) * MAX_SCORE, cap_mem),
+                (used_cpu, cap_cpu),
+                (used_mem, cap_mem),
+            ]
 
             share_row = s_share[:] if big_u else shraw_ref[pl.ds(u, 1), :]
             if use_gc:
@@ -640,7 +653,7 @@ def _make_kernel(
                 sh = jnp.where(
                     avail == 0,
                     jnp.where(gc_req == 0, 0.0, 1.0),
-                    gc_req / jnp.where(avail == 0, 1.0, avail),
+                    div32(gc_req, jnp.where(avail == 0, 1.0, avail)),
                 )
                 sh = jnp.where(
                     (declared > 0) & (gc_has_dev > 0), jnp.maximum(sh, 0.0), 0.0
@@ -650,39 +663,64 @@ def _make_kernel(
             lo = jnp.min(jnp.where(feas_b, share_row, jnp.float32(1e30)))
             hi = jnp.max(jnp.where(feas_b, share_row, jnp.float32(-1e30)))
             rng = hi - lo
-            share_norm = jnp.where(rng > 0, (share_row - lo) * MAX_SCORE / rng, 0.0)
+            quotients.append(((share_row - lo) * MAX_SCORE, rng))
 
             scored = feas_b & (ignored == 0)
             smn = jnp.min(jnp.where(scored, soft_raw, jnp.float32(1e30)))
             smx = jnp.max(jnp.where(scored, soft_raw, jnp.float32(-1e30)))
-            spread_norm = jnp.where(
-                smx <= 0, MAX_SCORE, MAX_SCORE * (smx + smn - soft_raw) / jnp.maximum(smx, 1.0)
-            )
-            spread_norm = jnp.where(ignored > 0, 0.0, spread_norm)
-            spread_norm = jnp.where(any_soft > 0, spread_norm, 0.0)
-
-            score = least + balanced + 2.0 * share_norm + 2.0 * spread_norm
+            quotients.append((MAX_SCORE * (smx + smn - soft_raw), jnp.maximum(smx, 1.0)))
+            if has_interpod:
+                # interpod_score normalization: min/max seeded with 0
+                ip_masked = jnp.where(feas_b, ip_raw, 0.0)
+                ip_hi = jnp.maximum(jnp.max(ip_masked), 0.0)
+                ip_lo = jnp.minimum(jnp.min(ip_masked), 0.0)
+                ip_rng = ip_hi - ip_lo
+                quotients.append((MAX_SCORE * (ip_raw - ip_lo), jnp.maximum(ip_rng, 1.0)))
             if has_na:
                 # NodeAffinity preferred-term weights, max-normalized over
                 # the feasible set (DefaultNormalizeScore)
                 na_row = s_na[:] if big_u else na_ref[pl.ds(u, 1), :]
                 na_max = jnp.max(jnp.where(feas_b, na_row, 0.0))
-                score = score + jnp.where(
-                    na_max > 0, na_row * MAX_SCORE / jnp.maximum(na_max, 1.0), na_row
-                )
+                quotients.append((na_row * MAX_SCORE, jnp.maximum(na_max, 1.0)))
             if has_tt:
                 # TaintToleration: intolerable PreferNoSchedule counts,
                 # reverse-normalized
                 tt_row = s_tt[:] if big_u else tt_ref[pl.ds(u, 1), :]
                 tt_max = jnp.max(jnp.where(feas_b, tt_row, 0.0))
-                score = score + jnp.where(
-                    tt_max > 0, MAX_SCORE - tt_row * MAX_SCORE / jnp.maximum(tt_max, 1.0), MAX_SCORE
-                )
-            if has_avoid:
-                # NodePreferAvoidPods (w=10000, no NormalizeScore): raw
-                # 0/100 static table, same shape class as na_raw
-                av_row = s_av[:] if big_u else av_ref[pl.ds(u, 1), :]
-                score = score + 10000.0 * av_row
+                quotients.append((tt_row * MAX_SCORE, jnp.maximum(tt_max, 1.0)))
+            quotients = iter(_div_rows(quotients))
+
+            l_cpu = jnp.where((alloc_cpu == 0) | (used_cpu > alloc_cpu), 0.0, next(quotients))
+            l_mem = jnp.where((alloc_mem == 0) | (used_mem > alloc_mem), 0.0, next(quotients))
+            least = (l_cpu + l_mem) / 2.0
+            cpu_frac = next(quotients)
+            mem_frac = next(quotients)
+            balanced = jnp.where(
+                (cpu_frac >= 1.0) | (mem_frac >= 1.0),
+                0.0,
+                (1.0 - jnp.abs(cpu_frac - mem_frac)) * MAX_SCORE,
+            )
+            share_norm = jnp.where(rng > 0, next(quotients), 0.0)
+            spread_norm = jnp.where(smx <= 0, MAX_SCORE, next(quotients))
+            spread_norm = jnp.where(ignored > 0, 0.0, spread_norm)
+            spread_norm = jnp.where(any_soft > 0, spread_norm, 0.0)
+            if has_interpod:
+                ip_norm = jnp.where(ip_rng > 0, next(quotients), 0.0)
+
+            # the weighted sum is taken in kernels.score_parts' order
+            # (balanced, least, node affinity, taints, inter-pod, spread,
+            # share, open-local, prefer-avoid): float32 addition does not
+            # associate, and a sum taken in another order leaves the XLA
+            # scan's by an ulp wherever three terms differ over the nodes
+            score = least + balanced
+            if has_na:
+                score = score + jnp.where(na_max > 0, next(quotients), na_row)
+            if has_tt:
+                score = score + jnp.where(tt_max > 0, MAX_SCORE - next(quotients), MAX_SCORE)
+            if has_interpod:
+                score = score + ip_norm
+            score = score + 2.0 * spread_norm
+            score = score + 2.0 * share_norm
             if has_local:
                 # Open-Local binpack score (local_score in kernels.py):
                 # mean over units of used/capacity × 10, min-max normalized
@@ -697,7 +735,7 @@ def _make_kernel(
                     best_free = jnp.where(better, free_v, best_free)
                     best_cap = jnp.where(better, vgcap_ref[pl.ds(v, 1), :], best_cap)
                 parts = jnp.where(
-                    (lvm > 0) & (best_free < big_f), lvm / jnp.maximum(best_cap, 1.0), 0.0
+                    (lvm > 0) & (best_free < big_f), div32(lvm, jnp.maximum(best_cap, 1.0)), 0.0
                 )
                 count = jnp.where(lvm > 0, 1.0, 0.0)
                 for m in range(2):
@@ -711,22 +749,18 @@ def _make_kernel(
                         first_cap = jnp.where(
                             fitting, jnp.minimum(first_cap, devcap_ref[pl.ds(d, 1), :]), first_cap
                         )
-                    parts = parts + jnp.where(size > 0, need * size / jnp.maximum(first_cap, 1.0), 0.0)
+                    parts = parts + jnp.where(size > 0, div32(need * size, jnp.maximum(first_cap, 1.0)), 0.0)
                     count = count + jnp.where(size > 0, need, 0.0)
-                local_raw = jnp.where(count > 0, parts / jnp.maximum(count, 1.0) * 10.0, 0.0)
+                local_raw = jnp.where(count > 0, div32(parts, jnp.maximum(count, 1.0)) * 10.0, 0.0)
                 l_lo = jnp.min(jnp.where(feas_b, local_raw, big_f))
                 l_hi = jnp.max(jnp.where(feas_b, local_raw, -big_f))
                 l_rng = l_hi - l_lo
-                score = score + jnp.where(l_rng > 0, (local_raw - l_lo) * MAX_SCORE / l_rng, 0.0)
-            if has_interpod:
-                # interpod_score normalization: min/max seeded with 0
-                ip_masked = jnp.where(feas_b, ip_raw, 0.0)
-                ip_hi = jnp.maximum(jnp.max(ip_masked), 0.0)
-                ip_lo = jnp.minimum(jnp.min(ip_masked), 0.0)
-                ip_rng = ip_hi - ip_lo
-                score = score + jnp.where(
-                    ip_rng > 0, MAX_SCORE * (ip_raw - ip_lo) / jnp.maximum(ip_rng, 1.0), 0.0
-                )
+                score = score + jnp.where(l_rng > 0, div32((local_raw - l_lo) * MAX_SCORE, l_rng), 0.0)
+            if has_avoid:
+                # NodePreferAvoidPods (w=10000, no NormalizeScore): raw
+                # 0/100 static table, same shape class as na_raw
+                av_row = s_av[:] if big_u else av_ref[pl.ds(u, 1), :]
+                score = score + 10000.0 * av_row
 
             # --- selectHost: lowest index among maxima — Mosaic's argmax
             # breaks ties by HIGHEST index, diverging from the XLA scan

@@ -525,6 +525,24 @@ class _SweepContext:
         ).astype(np.float32)
 
 
+def _scenario_rows(prep, fi: FastInputs, masks):
+    """`fi` with the kernel's two per-scenario rows set for [S, N_orig] node
+    masks: validity padded to the 128-lane node axis as [S, 1, N] and the
+    spread weights of each valid set as [S, U, Cs]. Also returns the padded
+    masks as [S, N] bool. Everything else in `fi` was built with all nodes
+    valid (build_inputs) and is shared by every scenario."""
+    masks = np.asarray(masks, dtype=bool)
+    nv = np.zeros((masks.shape[0], int(fi.node_valid.shape[1])), bool)
+    nv[:, : masks.shape[1]] = masks
+    ctx = _SweepContext(prep)
+    sw = np.stack([ctx.spread_weights(m) for m in masks])
+    fi = fi._replace(
+        node_valid=jnp.asarray(nv.astype(np.float32)[:, None, :]),
+        spr_weight=jnp.asarray(sw),
+    )
+    return fi, nv
+
+
 def sweep(
     prep, node_valid_masks, pod_valid_masks, forced_masks,
     interpret: Optional[bool] = None, big_u: Optional[bool] = None,
@@ -549,24 +567,13 @@ def sweep(
         tmpl = np.asarray(prep.tmpl_ids)
         if pad:
             tmpl = np.concatenate([tmpl, np.zeros(pad, tmpl.dtype)])
-        ctx = _SweepContext(prep)
         vg0 = np.asarray(fi.vg0_VN)
         N_orig = meta["n_orig"]
-        N_pad = int(fi.node_valid.shape[1])
-
-        nv_all = np.zeros((S, N_pad), bool)
-        nv_all[:, :N_orig] = np.asarray(node_valid_masks, dtype=bool)
         pv_all = np.zeros((S, P + pad), bool)
         pv_all[:, :P] = np.asarray(pod_valid_masks, dtype=bool)
         fm_all = np.zeros((S, P + pad), bool)
         fm_all[:, :P] = np.asarray(forced_masks, dtype=bool)
-        sw_all = np.stack(
-            [ctx.spread_weights(nv_all[s, :N_orig]) for s in range(S)]
-        )
-        fi = fi._replace(
-            node_valid=jnp.asarray(nv_all.astype(np.float32)[:, None, :]),
-            spr_weight=jnp.asarray(sw_all),
-        )
+        fi, nv_all = _scenario_rows(prep, fi, node_valid_masks)
 
     with launch_span(  # as in schedule(): no helper frame round the kernel
         "mk.launch", scenarios=S, pods=P + pad, nodes=fi.alloc_T.shape[1],
@@ -590,13 +597,19 @@ def sweep(
 
 
 def schedule(
-    prep, tmpl_ids, pod_valid, forced,
+    prep, tmpl_ids, pod_valid, forced, node_valid=None,
     interpret: Optional[bool] = None, big_u: Optional[bool] = None,
 ):
-    """Run the megakernel on a padded pod stream (P % CHUNK == 0).
+    """Run the megakernel on a pod stream (padded here to P % CHUNK == 0).
     Returns (chosen [P] i32, used_final [N, R], static_fail [U, 4],
     gpu_take [P, Gd], gpu_free [N, Gd], vg_free [N, Vg], dev_free [N, Dv]).
-    `big_u=None` defers to the use_big_u heuristic."""
+    `node_valid` ([N] bool over the prepared node axis; the planner's prep
+    reuse) runs the stream over that subset of `prep`'s nodes, as one
+    scenario of `sweep` does: the mask is the kernel's validity row, the
+    spread weights are those of the masked set's domains, `static_fail`
+    counts over the masked set, and the marshalled tables (`build_inputs`,
+    all nodes valid) are reused as they are. `big_u=None` defers to the
+    use_big_u heuristic."""
     from ..resilience import faults
 
     # stands in for a Mosaic compile failure (a construct passing interpret
@@ -617,7 +630,17 @@ def schedule(
             tmpl_ids = np.concatenate([tmpl_ids, np.zeros(pad, tmpl_ids.dtype)])
             pod_valid = np.concatenate([pod_valid, np.zeros(pad, bool)])
             forced = np.concatenate([forced, np.zeros(pad, bool)])
-        fi = fi._replace(node_valid=fi.node_valid[None], spr_weight=fi.spr_weight[None])
+        if node_valid is None:
+            static_fail = meta["static_fail"]
+            fi = fi._replace(node_valid=fi.node_valid[None], spr_weight=fi.spr_weight[None])
+        else:
+            mask = np.asarray(node_valid, dtype=bool)
+            fi, _ = _scenario_rows(prep, fi, mask[None])
+            # as build_inputs counts it over prep's own valid set: same
+            # shapes, so the cached precompute serves and nothing recompiles
+            static_fail = np.asarray(
+                _precompute_jit(prep.ec._replace(node_valid=jnp.asarray(mask))).static_fail
+            )
     # mk.launch: everything the host does to get the kernel onto the device
     # (the eager pallas_call's trace, lowering and cache lookup, argument
     # transfer, enqueue, the lazy reshapes of its outputs); the device's own
@@ -643,7 +666,7 @@ def schedule(
         return (
             np.asarray(chosen)[:P],
             np.asarray(used_T).T[:No],
-            meta["static_fail"],
+            static_fail,
             np.asarray(gpu_take)[:P, :Gd],
             np.asarray(gpu_T)[:Gd].T[:No],
             np.asarray(vg_T)[:Vg].T[:No],

@@ -188,13 +188,17 @@ def _failure_eval(ec, stat, st, us, feat):
     return res.fail_counts, res.insufficient
 
 
-def _fast_failure_details(out, prep: "Prepared", failed_idx: np.ndarray):
+def _fast_failure_details(out, prep: "Prepared", failed_idx: np.ndarray, nv_mask=None):
     """Per-pod failure attribution without re-scanning the whole stream:
     evaluate ``pod_step`` once per distinct failed template against the
     final carry. Exact when no bind landed after the first failure (the
     caller checks) — the state a failed pod saw is then the final state,
-    since failed pods mutate nothing (simulator.go:333-342 deletes them)."""
+    since failed pods mutate nothing (simulator.go:333-342 deletes them).
+    Under a node mask the reasons count over the masked node set, as the
+    XLA scan's do (``_xla_scan`` masks ``ec`` the same way)."""
     from . import fastpath
+
+    ec = prep.ec if nv_mask is None else prep.ec._replace(node_valid=jnp.asarray(nv_mask))
 
     port_used, dom_sel, dom_anti, dom_prefw = _rebuilt_counts(prep, np.asarray(out.chosen))
     st = out.final_state._replace(
@@ -202,9 +206,9 @@ def _fast_failure_details(out, prep: "Prepared", failed_idx: np.ndarray):
     )
     out = out._replace(final_state=st)
     st = ScanState(*[jnp.asarray(a) for a in st])
-    stat = fastpath._precompute_jit(prep.ec)  # jit-cached for this ec
+    stat = fastpath._precompute_jit(ec)  # jit-cached for this ec's shapes
     us = np.unique(prep.tmpl_ids[failed_idx])
-    fc_u, ins_u = _failure_eval(prep.ec, stat, st, jnp.asarray(us), prep.features)
+    fc_u, ins_u = _failure_eval(ec, stat, st, jnp.asarray(us), prep.features)
     fc_u, ins_u = np.asarray(fc_u), np.asarray(ins_u)
     pos = {int(u): k for k, u in enumerate(us)}
     fail_counts = np.array(out.fail_counts, copy=True)
@@ -593,9 +597,13 @@ def _run_engine_ladder(
     skips: Dict[str, str] = {}
     # what chose the kernels' signature, on the span of the rung that ran
     # and on /metrics: the active feature flags and the rows of the
-    # inter-pod term tables the stream's pods are counted under
+    # inter-pod term tables the stream's pods are counted under; and
+    # whether the stream runs over a masked subset of the prepared nodes
     features = "+".join(n for n, on in zip(prep.features._fields, prep.features) if on) or "none"
-    shape = {"features": features, "interpod_terms": prep.meta.interpod_terms}
+    shape = {
+        "features": features, "interpod_terms": prep.meta.interpod_terms,
+        "masked": nv_mask is not None,
+    }
     require_tpu = envknobs.raw("OPENSIM_REQUIRE_TPU") == "1"
     interpret = envknobs.raw("OPENSIM_FASTPATH") == "interpret"
     sf_rows = tmpl_ids  # decode: static_fail row per pod
@@ -618,9 +626,10 @@ def _run_engine_ladder(
     # the tests' interpret mode); CPU hosts go straight to the C++ path.
     # These pre-import gates mirror the first checks of fastpath.why_not
     # (which stays authoritative once the module is imported) — they
-    # exist only so the import itself can be skipped.
-    elif nv_mask is not None:
-        skips["megakernel"] = "masked re-simulation (planner prep reuse) runs on the C++/XLA engines"
+    # exist only so the import itself can be skipped. A node mask (the
+    # planner's prep reuse) is no gate: the kernel takes validity as a
+    # runtime row, so a masked stream is a schedule of one scenario, the
+    # same one the capacity sweeps run under that mask.
     elif sched_config is not None:
         skips["megakernel"] = "non-default scheduler config"
     elif extra_plugins:
@@ -663,7 +672,7 @@ def _run_engine_ladder(
             try:
                 with obs.span("engine.megakernel", **shape):
                     f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev = fastpath.schedule(
-                        prep, tmpl_ids, pod_valid, forced
+                        prep, tmpl_ids, pod_valid, forced, node_valid=nv_mask
                     )
                 # a clean kernel RUN is a breaker success even if the
                 # result is later discarded for mid-stream attribution —
@@ -704,7 +713,9 @@ def _run_engine_ladder(
                         out = _fast_output(
                             f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev, prep
                         )
-                        out = _fast_failure_details(out, prep, np.nonzero(failed)[0])
+                        out = _fast_failure_details(
+                            out, prep, np.nonzero(failed)[0], nv_mask=nv_mask
+                        )
                         engine_name = "megakernel"
                     else:
                         skips["megakernel"] = (
@@ -763,6 +774,8 @@ def _run_engine_ladder(
     from ..obs.metrics import RECORDER
 
     RECORDER.count_engine_features(engine_name, features)
+    if nv_mask is not None:
+        RECORDER.count_masked_pass(engine_name)
     return out, engine_name, skips, sf_rows
 
 

@@ -178,6 +178,10 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_engine_features_total": (
         "Scheduled streams by the engine that answered and the feature set that chose its kernels", "counter",
     ),
+    # engine: megakernel | native | xla
+    "simon_masked_pass_total": (
+        "Simulations over a masked node set (the planner's prep reuse) by the engine that answered", "counter",
+    ),
     # capacity observatory (obs/capacity.py, docs/observability.md) —
     # cardinality contract: every family below is label-free or bounded
     # (resource ∈ {cpu, memory, pods}; profile = registered headroom
@@ -563,6 +567,9 @@ class MetricsRecorder:
         self.resident_carry = make_counter("simon_resident_carry_total", ("outcome",))
         # streams by answering engine and feature set (engine/simulator.py's ladder)
         self.engine_features = make_counter("simon_engine_features_total", ("engine", "features"))
+        # masked simulations by answering engine; megakernel over all is
+        # how often the planner's final pass engages the kernel
+        self.masked_pass = make_counter("simon_masked_pass_total", ("engine",))
         # watch-pipeline latency (ISSUE 9 satellite): event receipt → twin
         # applied, fed from the supervisor's dispatch (server/watch.py)
         self.watch_apply = make_histogram(
@@ -632,6 +639,10 @@ class MetricsRecorder:
         with self.lock:
             self.engine_features.inc((engine, features))
 
+    def count_masked_pass(self, engine: str) -> None:
+        with self.lock:
+            self.masked_pass.inc((engine,))
+
     def render_lines(self) -> List[str]:
         with self.lock:
             return (
@@ -639,6 +650,7 @@ class MetricsRecorder:
                 + self.unschedulable.render_lines()
                 + self.resident_carry.render_lines()
                 + self.engine_features.render_lines()
+                + self.masked_pass.render_lines()
                 + self.phase_seconds.render_lines()
                 + self.request_seconds.render_lines()
                 + self.watch_apply.render_lines()
@@ -652,6 +664,7 @@ class MetricsRecorder:
             self.unschedulable.reset()
             self.resident_carry.reset()
             self.engine_features.reset()
+            self.masked_pass.reset()
             self.watch_apply.reset()
 
 

@@ -202,6 +202,31 @@ def test_the_xla_rung_is_resident_pad_launch_wait(monkeypatch):
     assert_spans_nest(tr.root)
 
 
+@pytest.mark.parametrize("engine", ["xla", "megakernel"])
+def test_a_rung_says_whether_its_node_set_was_masked_and_metrics_counts_it(monkeypatch, engine):
+    """ISSUE 30: `masked` on the rung that ran, and on `/metrics` the masked
+    simulations by the engine that answered (an unmasked one is not counted)."""
+    from opensim_tpu.server.rest import METRICS
+
+    if engine == "xla":
+        monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
+    else:
+        monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    prep = prepare(_cluster(), _apps())
+    mask = np.zeros(np.asarray(prep.ec_np.node_valid).shape[0], bool)
+    mask[:4] = True
+    sub = _cluster(4)
+    tr, res = _traced(lambda: (simulate(_cluster(), _apps()), simulate(sub, _apps(), prep=prep, node_valid=mask))[1])
+    assert res.engine.name == engine and not res.unscheduled_pods
+    assert [sp.attrs["masked"] for sp in _find(tr, "engine." + engine)] == [False, True]
+    series = {
+        dict(labels)["engine"]: value
+        for (name, labels), value in parse_metrics(METRICS.render()).items()
+        if name == "simon_masked_pass_total"
+    }
+    assert series == {engine: 1}
+
+
 # ---------------------------------------------------------------------------
 # (c) every prepare kind is a real span round its work
 # ---------------------------------------------------------------------------

@@ -1,10 +1,11 @@
-"""Fast-path selection and input marshalling for the Pallas megakernel.
+"""The Pallas megakernel's envelope and input marshalling.
 
-`applicable()` decides whether a prepared simulation can run on
-`ops/pallas_scan.run_fast_scan` (feature subset + layout constraints);
-`schedule()` marshals the encoded cluster into the kernel's VMEM/SMEM
-layouts and runs it. Placements are identical to the XLA scan — the tests
-in tests/test_fastpath.py assert equality — so callers can switch freely.
+`why_not()` is the kernel's envelope (`ops/pallas_scan.run_fast_scan`'s
+feature subset, table sizes and VMEM); whether the kernel is tried at all
+is `engine/select.py`'s. `schedule()` marshals the encoded cluster into the
+kernel's VMEM/SMEM layouts and runs it. Placements are identical to the XLA
+scan — the tests in tests/test_fastpath.py assert equality — so callers can
+switch freely.
 """
 
 from __future__ import annotations
@@ -21,7 +22,7 @@ from ..obs import trace as obs
 from ..obs.profile import launch_span
 from ..ops import kernels
 from ..ops.pallas_scan import CHUNK, FastInputs, run_fast_scan
-from ..utils import envknobs
+from . import select
 from .schedconfig import DEFAULT_CONFIG
 
 HOSTNAME = "kubernetes.io/hostname"
@@ -39,7 +40,7 @@ def _pad8_static(n: int) -> int:
 
 
 def applicable(prep, config=None) -> bool:
-    return why_not(prep, config) is None
+    return select.policy().off["megakernel"] is None and why_not(prep, config) is None
 
 
 def why_not(prep, config=None) -> Optional[str]:
@@ -106,14 +107,6 @@ def why_not(prep, config=None) -> Optional[str]:
             return "some valid nodes carry no hostname label"
         if len(np.unique(nd[nv])) != int(nv.sum()):
             return "hostname domains are not node-identity (duplicate hostname labels)"
-    # pallas compiled path only on TPU; elsewhere the interpreter would be
-    # slower than the XLA scan (tests force it via OPENSIM_FASTPATH=interpret)
-    if envknobs.raw("OPENSIM_DISABLE_FASTPATH"):
-        return "disabled by --backend xla (OPENSIM_DISABLE_FASTPATH)"
-    if envknobs.raw("OPENSIM_NATIVE") == "1":
-        return "disabled by --backend native (OPENSIM_NATIVE=1)"
-    if jax.default_backend() != "tpu" and envknobs.raw("OPENSIM_FASTPATH") != "interpret":
-        return f"no TPU backend (jax.default_backend()={jax.default_backend()!r})"
     vmem = vmem_estimate(prep)
     if vmem > _VMEM_BUDGET:
         return f"VMEM estimate {vmem / 1e6:.1f} MB exceeds the {_VMEM_BUDGET / 1e6:.0f} MB budget"
@@ -213,13 +206,12 @@ def _resolve_interpret(explicit: Optional[bool]) -> bool:
     explicit argument, else ``OPENSIM_FASTPATH=interpret``. A compiled run
     off a TPU backend is an error — silently interpreting there is how a
     CPU run gets read as a kernel result."""
-    interpret = (
-        envknobs.raw("OPENSIM_FASTPATH") == "interpret" if explicit is None else explicit
-    )
-    if not interpret and jax.default_backend() != "tpu":
+    pol = select.policy()
+    interpret = pol.interpret if explicit is None else explicit
+    if not interpret and pol.platform != "tpu":
         raise RuntimeError(
             "the Pallas megakernel compiles only for a TPU backend "
-            f"(jax.default_backend()={jax.default_backend()!r}); pass "
+            f"(jax.default_backend()={pol.platform!r}); pass "
             "interpret=True or set OPENSIM_FASTPATH=interpret to run the "
             "Pallas interpreter instead"
         )

@@ -1,19 +1,12 @@
-"""Native-engine selection and marshalling.
+"""The C++ scan engine's envelope and marshalling.
 
-``applicable()`` decides whether a prepared simulation should run on the
-C++ scan engine (``opensim_tpu/native``); ``schedule()`` marshals the
-encoded cluster into its flat-buffer ABI and returns a full
+``why_not()`` says what the C++ loop lacks for a run and whether its ``.so``
+is built; whether the engine is tried at all is ``engine/select.py``'s (the
+megakernel owns the TPU, this engine owns hosts without an accelerator, and
+``OPENSIM_NATIVE=1`` asks for it by name). ``schedule()`` marshals the encoded
+cluster into the flat-buffer ABI of ``opensim_tpu/native`` and returns a full
 ``ScheduleOutput`` — including a completely populated final ``ScanState``
 and exact per-pod failure attribution, so no XLA re-scan is ever needed.
-
-Selection policy: the Pallas megakernel owns the TPU; the native engine
-owns hosts without an accelerator (the reference itself is a CPU program —
-its engine is the vendored Go scheduler, SURVEY.md §2.2). On a TPU backend
-the native engine only runs when OPENSIM_NATIVE=1 explicitly asks for it.
-Unlike the megakernel it has no feature envelope: every workload the XLA
-scan handles (including --default-scheduler-config weight/disable merges)
-runs natively; only out-of-tree ``extra_plugins`` (arbitrary jittable
-callables) force the XLA path.
 """
 
 from __future__ import annotations
@@ -25,7 +18,7 @@ import numpy as np
 from ..encoding import vocab as V
 from ..encoding.state import ScanState
 from ..ops import kernels
-from ..utils import envknobs
+from . import select
 from .schedconfig import DEFAULT_CONFIG
 
 
@@ -43,33 +36,24 @@ def _warn_native_unavailable() -> None:
 
 
 def applicable(prep, config=None, extra_plugins: tuple = ()) -> bool:
-    return why_not(prep, config, extra_plugins) is None
+    return select.policy().off["native"] is None and why_not(prep, config, extra_plugins) is None
 
 
 def why_not(prep, config=None, extra_plugins: tuple = (), tie_seed=None):
-    """Selection check for the C++ engine: returns None when it should run,
-    else a one-line reason (engine attribution — VERDICT r4 #3). tie_seed
-    is accepted: the engine implements the seeded sampled tie-break."""
+    """What the C++ engine lacks for this run, None when it can take it, else
+    a one-line reason (engine attribution). tie_seed is accepted: the engine
+    implements the seeded sampled tie-break."""
     if extra_plugins:
         return "out-of-tree extra_plugins are jittable callables (XLA scan only)"
     if config is not None and getattr(config, "fit_ignored_cols", ()):
         # NodeResourcesFitArgs ignored columns are an XLA-scan feature; the
         # C++ fit loop has no per-column skip (rare config — not worth ABI)
         return "NodeResourcesFitArgs ignoredResources need the XLA scan's per-column skip"
-    if envknobs.raw("OPENSIM_DISABLE_NATIVE"):
-        return "disabled by --backend xla (OPENSIM_DISABLE_NATIVE)"
     from .. import native
 
-    if envknobs.raw("OPENSIM_NATIVE") == "1":
-        if not native.available():
-            _warn_native_unavailable()
-            return f"engine not built: {native.load_error() or 'unknown'}"
-        return None
-    import jax
-
-    if jax.default_backend() == "tpu":
-        return "TPU backend present (the megakernel/XLA scan own the accelerator)"
     if not native.available():
+        if select.policy().forced_native:
+            _warn_native_unavailable()
         return f"engine not built: {native.load_error() or 'unknown'}"
     return None
 

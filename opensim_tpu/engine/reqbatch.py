@@ -14,12 +14,9 @@ each demultiplexed result is bit-identical to running that request alone —
 the same mask-flip argument ``drop_pods`` and the scenario sweeps rest on,
 and gated end-to-end by ``tests/test_admission.py``.
 
-Engine routing mirrors ``scenarios.sweep_auto``: the default is the
-vmapped XLA scan (one compiled dispatch for the whole batch, request axis
-prepended by ``jax.vmap``); ``OPENSIM_BATCH_ENGINE=native`` routes through
-sequential C++ scans instead (accelerator-less hosts that want zero XLA
-compiles), and ``auto`` picks native only when the vmapped scan cannot run
-the stream. Either way the decode demultiplexes through the same
+Engine routing is ``select.batch``'s: the vmapped XLA scan (one compiled
+dispatch for the whole batch, request axis prepended by ``jax.vmap``) or
+sequential C++ scans. Either way the decode demultiplexes through the same
 ``simulator.finish_decode`` tail the solo path uses, restoring bind state
 between requests so shared pod objects never leak one request's binds into
 another's report.
@@ -38,7 +35,7 @@ from ..encoding.state import EncodedCluster, ScanState
 from ..models.objects import ResourceTypes
 from ..obs import trace as obs
 from ..resilience.deadline import Deadline, DeadlineExceeded
-from ..utils import envknobs
+from . import select
 from .scheduler import (
     ScheduleOutput,
     _schedule_pods_jit as _schedule_pods_traced,
@@ -61,7 +58,6 @@ __all__ = [
     "run_request_batch",
     "dispatch_request_batch",
     "decode_request_batch",
-    "batch_engine_mode",
 ]
 
 # request-axis pad buckets: the batch size participates in the jit
@@ -89,19 +85,6 @@ class BatchItem:
     # running them to completion (the vmapped XLA path is one atomic
     # dispatch and keeps queue-boundary-only enforcement)
     deadline: Optional[Deadline] = None
-
-
-def batch_engine_mode() -> str:
-    """``OPENSIM_BATCH_ENGINE``: ``auto`` (default) = the vmapped XLA scan,
-    falling back to sequential C++ scans when the stream cannot take the
-    XLA path; ``xla`` / ``native`` force a rung (native still requires the
-    C++ engine to be applicable)."""
-    raw = envknobs.raw("OPENSIM_BATCH_ENGINE", "auto").strip().lower() or "auto"
-    if raw not in ("auto", "xla", "native"):
-        raise ValueError(
-            f"OPENSIM_BATCH_ENGINE must be auto|xla|native, got {raw!r}"
-        )
-    return raw
 
 
 @functools.partial(jax.jit, static_argnames=("features", "unroll", "explain"))
@@ -249,33 +232,7 @@ def dispatch_request_batch(prep: Prepared, items: List[BatchItem]) -> BatchDispa
 
     P = len(prep.ordered)
     pod_valid = _request_masks(prep, items)
-    mode = batch_engine_mode()
-    native_miss = nativepath.why_not(prep, None, ())
-    # auto routing mirrors scenarios.sweep_auto: on an accelerator-less
-    # single-device host — or under --backend native (OPENSIM_NATIVE=1) —
-    # the sequential C++ scans win (ms-scale per request, zero XLA
-    # compiles; the batch's saving is the ONE shared derive + assemble +
-    # upload); with an accelerator the whole batch is one vmapped dispatch
-    use_native = mode == "native" or (
-        mode == "auto"
-        and native_miss is None
-        and (
-            envknobs.raw("OPENSIM_NATIVE") == "1"
-            or (len(jax.devices()) == 1 and jax.default_backend() != "tpu")
-        )
-    )
-    if use_native and native_miss is not None:
-        if mode == "native":
-            raise RuntimeError(
-                f"OPENSIM_BATCH_ENGINE=native but the C++ engine cannot run "
-                f"this stream: {native_miss}"
-            )
-        use_native = False
-
-    skips: Dict[str, str] = {
-        "megakernel": "request-axis batches run on the vmapped XLA scan "
-        "(or sequential C++ scans)",
-    }
+    engine_name, skips = select.batch(prep)
 
     def _shed_rider(s: int, dl: Deadline) -> DeadlineExceeded:
         obs.event(
@@ -291,9 +248,7 @@ def dispatch_request_batch(prep: Prepared, items: List[BatchItem]) -> BatchDispa
 
     outs: List[Optional[ScheduleOutput]] = []
     shed: Dict[int, BaseException] = {}
-    if use_native:
-        engine_name = "native"
-        skips["xla"] = "OPENSIM_BATCH_ENGINE routed the batch to the C++ engine"
+    if engine_name == "native":
         with obs.span("engine.native", requests=len(items), pods=P):
             for s in range(len(items)):
                 dl = items[s].deadline
@@ -308,9 +263,6 @@ def dispatch_request_batch(prep: Prepared, items: List[BatchItem]) -> BatchDispa
                     nativepath.schedule(prep, pod_valid[s], explain=items[s].explain)
                 )
     else:
-        engine_name = "xla"
-        if native_miss is None:
-            skips["native"] = "request-axis batching dispatches ONE vmapped scan"
         # pre-dispatch deadline shedding (ISSUE 15 satellite): an already-
         # expired rider never enters the compiled dispatch — its lane's
         # mask is all-invalid (it schedules nothing and cannot perturb the

@@ -46,6 +46,7 @@ import numpy as np
 
 from ..encoding.state import ScanState
 from ..ops import kernels
+from . import select
 from .scheduler import pad_pod_stream, scan_unroll, schedule_pods
 
 # float32 holds every integer below 2**24: the bound under which the count
@@ -248,29 +249,7 @@ def _widen(carry: ResidentCarry, base_prep, prep) -> ScanState:
     return _widen_carry(carry.state, base, cols=tuple(cols.values()), n_dom=n_dom)
 
 
-def _why_not(prep, nv_mask, sched_config, extra_plugins, tie_seed, explain, segments) -> Optional[str]:
-    """What the input shows that the carry cannot serve, None when it can."""
-    if segments:
-        return "segments"
-    if prep.resident_base is None:
-        return "no_base"  # a plain prepare, or a base extended with new nodes
-    if nv_mask is not None:
-        return "node_mask"
-    if tie_seed is not None:
-        return "tie_seed"  # a key rides the carry and is split every step
-    if explain:
-        return "explain"  # every step emits its rows
-    if sched_config is not None:
-        return "sched_config"
-    if extra_plugins:
-        return "extra_plugins"
-    return None
-
-
-def fetch(
-    prep, pod_valid, *, nv_mask=None, sched_config=None, extra_plugins=(),
-    tie_seed=None, explain=False, segments=False,
-) -> Optional[Head]:
+def fetch(prep, pod_valid, ask: select.Ask = select.Ask()) -> Optional[Head]:
     """The resident carry for this run of ``prep``, built if its base entry
     has none yet, widened to ``prep``'s encoding; None when the run has to
     replay in full. Either way the ``xla.resident`` span and the counter say
@@ -280,7 +259,7 @@ def fetch(
 
     with obs.span("xla.resident") as sp:
         head, outcome, n_res = None, "declined", 0
-        reason = _why_not(prep, nv_mask, sched_config, extra_plugins, tie_seed, explain, segments)
+        reason = select.carry(prep, ask)
         if reason is None:
             entry = prep.resident_base
             with entry.lock:

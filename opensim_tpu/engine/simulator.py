@@ -36,11 +36,10 @@ from ..models.objects import (
     ResourceTypes,
 )
 from ..ops import kernels
-from ..utils import envknobs
 from ..resilience import breaker as breakers
 from ..resilience import faults
 from ..resilience.deadline import Deadline, check_deadline, deadline_scope
-from . import queues, reasons
+from . import queues, reasons, select
 from .scheduler import pad_pod_stream, scan_unroll, schedule_pods, to_device
 
 
@@ -485,10 +484,7 @@ def _xla_scan(ec, st, tmpl_ids, pod_valid, forced, nv_mask, head=None, **kwargs)
     return out if head is None else head.in_front_of(out)
 
 
-def _run_segments(
-    prep, segments, pod_valid, forced, tmpl_ids, extra_plugins, tie_seed,
-    nv_mask, skips, log, explain=False,
-):
+def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, skips, log):
     """Consecutive scans over contiguous same-profile segments, sharing the
     scheduling carry — the segmented multi-profile path
     (``utils.go:304-381``). Each segment runs the full padded stream with
@@ -512,16 +508,12 @@ def _run_segments(
     gpu_take = np.zeros((P, Gd), np.float32)
     sf_pod = np.zeros((P, n_static), np.int32)
 
-    use_native = all(
-        nativepath.why_not(prep, cfg, extra_plugins, tie_seed=tie_seed) is None
-        for cfg, _, _ in segments
-    )
-    if not use_native:
-        reasons = {
-            nativepath.why_not(prep, cfg, extra_plugins, tie_seed=tie_seed)
-            for cfg, _, _ in segments
-        } - {None}
-        skips["native"] = "; ".join(sorted(reasons)) or "segment config unsupported"
+    misses = {
+        select.ladder(prep, ask._replace(sched_config=cfg))["native"] for cfg, _, _ in segments
+    } - {None}
+    use_native = not misses
+    if misses:
+        skips["native"] = "; ".join(sorted(misses))
         log.info("segmented run on the XLA scan: %s", skips["native"])
 
     st = prep.st0
@@ -536,16 +528,16 @@ def _run_segments(
             if use_native:
                 out = nativepath.schedule(
                     prep, seg_valid, config=cfg, node_valid=nv_mask,
-                    tie_seed=tie_seed, st0=st, explain=explain,
+                    tie_seed=ask.tie_seed, st0=st, explain=ask.explain,
                 )
                 if out.native_stats is not None:
                     seg_stats.append(out.native_stats)
             else:
-                resident.fetch(prep, seg_valid, segments=True)  # declines, and says so
+                resident.fetch(prep, seg_valid, ask)  # declines, and says so
                 out = _xla_scan(
                     prep.ec, st, tmpl_ids, seg_valid, forced, nv_mask,
-                    features=prep.features, config=cfg, extra_plugins=extra_plugins,
-                    tie_seed=tie_seed, explain=explain,
+                    features=prep.features, config=cfg, extra_plugins=ask.extra_plugins,
+                    tie_seed=ask.tie_seed, explain=ask.explain,
                 )
         chosen[lo:hi] = np.asarray(out.chosen)[lo:hi]
         fail_counts[lo:hi] = np.asarray(out.fail_counts)[lo:hi]
@@ -585,11 +577,11 @@ def _run_engine_ladder(
     tie_seed, nv_mask, ec, st0, log, explain=False,
 ):
     """The engine fallback ladder (megakernel → C++ native → XLA scan) for
-    one prepared stream: selection pre-checks, breaker gating, runtime
-    demotion. Returns ``(out, engine_name, skips, sf_rows)``. Split out of
-    ``simulate`` so the whole ladder sits under one traced ``schedule``
-    span with a child span per engine actually *attempted* (ISSUE 5) — a
-    skipped rung gets a demotion event, not a span."""
+    one prepared stream: ``select`` says which rungs may run; here are the
+    breaker gating and the runtime demotion. Returns ``(out, engine_name,
+    skips, sf_rows)``. Split out of ``simulate`` so the whole ladder sits
+    under one traced ``schedule`` span with a child span per engine actually
+    *attempted* (ISSUE 5) — a skipped rung gets a demotion event, not a span."""
     from ..obs import trace as obs
 
     out = None
@@ -604,129 +596,78 @@ def _run_engine_ladder(
         "features": features, "interpod_terms": prep.meta.interpod_terms,
         "masked": nv_mask is not None,
     }
-    require_tpu = envknobs.raw("OPENSIM_REQUIRE_TPU") == "1"
-    interpret = envknobs.raw("OPENSIM_FASTPATH") == "interpret"
+    pol = select.policy()
+    ask = select.Ask(
+        segments=None if segments is None else len(segments), explain=explain,
+        sched_config=sched_config, extra_plugins=extra_plugins, tie_seed=tie_seed,
+        node_mask=nv_mask is not None,
+    )
+    rungs = select.ladder(prep, ask, pol)
     sf_rows = tmpl_ids  # decode: static_fail row per pod
     if segments is not None:
-        skips["megakernel"] = (
-            f"segmented multi-profile stream ({len(segments)} segments)"
-        )
+        skips["megakernel"] = rungs["megakernel"]
         out, engine_name = _run_segments(
-            prep, segments, pod_valid, forced, tmpl_ids, extra_plugins,
-            tie_seed, nv_mask, skips, log, explain=explain,
+            prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, skips, log
         )
         sf_rows = np.arange(len(tmpl_ids), dtype=np.int32)
-    # decision audit (ISSUE 7): explain mode needs every step's per-filter
-    # verdicts — only the C++ generic path and the XLA count_all scan
-    # produce them; the megakernel never materializes per-filter masks
-    elif explain:
-        skips["megakernel"] = "explain mode audits per-filter verdicts (C++/XLA engines)"
-    # importing the megakernel module costs ~1 s of pallas Python-module
-    # compile — only pay it where it can actually run (TPU backend, or
-    # the tests' interpret mode); CPU hosts go straight to the C++ path.
-    # These pre-import gates mirror the first checks of fastpath.why_not
-    # (which stays authoritative once the module is imported) — they
-    # exist only so the import itself can be skipped. A node mask (the
-    # planner's prep reuse) is no gate: the kernel takes validity as a
-    # runtime row, so a masked stream is a schedule of one scenario, the
-    # same one the capacity sweeps run under that mask.
-    elif sched_config is not None:
-        skips["megakernel"] = "non-default scheduler config"
-    elif extra_plugins:
-        skips["megakernel"] = "out-of-tree extra_plugins run on the XLA scan"
-    elif tie_seed is not None:
-        skips["megakernel"] = "sampled tie-break runs on the C++ engine or XLA scan"
-    elif jax.default_backend() != "tpu" and not interpret:
-        skips["megakernel"] = (
-            f"no TPU backend (jax.default_backend()={jax.default_backend()!r})"
-        )
+    elif rungs["megakernel"] is not None:
+        skips["megakernel"] = rungs["megakernel"]
+    elif not (pol.strict or pol.interpret) and not breakers.engine_breaker("megakernel").allow():
+        # circuit breaker (resilience/breaker.py): after repeated failures
+        # the doomed attempt is skipped until the cooldown's half-open probe.
+        # Asked only once select has passed the rung: allow() takes the probe
+        # slot and only an attempt releases it. Strict and interpret demand
+        # the real attempt (and its hard failure) over a silent demotion.
+        skips["megakernel"] = breakers.engine_breaker("megakernel").describe_block()
+        log.warning("megakernel skipped: %s", skips["megakernel"])
     else:
         from . import fastpath
 
-        miss = fastpath.why_not(prep)
-        if miss is not None:
-            skips["megakernel"] = miss
-            log.info("megakernel envelope miss: %s", miss)
-        elif (
-            not require_tpu
-            and not interpret
-            and not breakers.engine_breaker("megakernel").allow()
-        ):
-            # runtime-failure circuit breaker (resilience/breaker.py):
-            # after repeated compile/run failures the doomed attempt is
-            # skipped outright until the cooldown's half-open probe.
-            # Checked AFTER why_not so an envelope miss never consumes
-            # the probe slot (allow() marks it; only an actual attempt
-            # can release it). REQUIRE_TPU and the tests' interpret mode
-            # bypass gating — both demand the real attempt (and its hard
-            # failure) over a silent demotion.
-            skips["megakernel"] = breakers.engine_breaker("megakernel").describe_block()
-            log.warning("megakernel skipped: %s", skips["megakernel"])
-        else:
-            # Pallas megakernel fast path: identical placements, ~4×
-            # the XLA scan's step rate. A Mosaic COMPILE failure (a
-            # construct that passes interpret mode but not the real
-            # compiler) must degrade to the slower engines — unless
-            # --backend tpu demanded the TPU engine, where silently
-            # benchmarking a fallback would be a lie (VERDICT r4 #3).
-            try:
-                with obs.span("engine.megakernel", **shape):
-                    f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev = fastpath.schedule(
-                        prep, tmpl_ids, pod_valid, forced, node_valid=nv_mask
-                    )
-                # a clean kernel RUN is a breaker success even if the
-                # result is later discarded for mid-stream attribution —
-                # and recording here releases a half-open probe slot no
-                # matter which path the result takes
-                breakers.engine_breaker("megakernel").record_success()
-            except Exception as e:
-                if interpret:
-                    # test/CI mode: a broken megakernel contract must
-                    # FAIL, not silently validate the fallback engine
-                    raise
-                if require_tpu:
-                    raise RuntimeError(
-                        "--backend tpu: the Pallas megakernel failed to "
-                        f"compile/run ({type(e).__name__}: {e}); refusing "
-                        "to silently fall back to a slower engine"
-                    ) from e
-                breakers.engine_breaker("megakernel").record_failure(e)
-                log.warning(
-                    "megakernel failed (%s: %s); falling back to a "
-                    "slower engine", type(e).__name__, e,
+        try:  # identical placements at ~4× the XLA scan's step rate
+            with obs.span("engine.megakernel", **shape):
+                f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev = fastpath.schedule(
+                    prep, tmpl_ids, pod_valid, forced, node_valid=nv_mask
                 )
-                skips["megakernel"] = f"{type(e).__name__}: {e}"
-                f_chosen = None
-            if f_chosen is not None:
-                failed = (f_chosen < 0) & pod_valid & ~forced
-                if not failed.any():
-                    out = _fast_output(f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev, prep)
+            # a clean kernel RUN is a breaker success even if the result
+            # is later discarded for mid-stream attribution — and recording
+            # here releases a half-open probe slot no matter which path the
+            # result takes
+            breakers.engine_breaker("megakernel").record_success()
+        except Exception as e:  # opensim-lint: disable=exception-swallow (kernel_failed raises or logs)
+            skips["megakernel"] = select.kernel_failed(
+                e, "stream", breakers.engine_breaker("megakernel")
+            )
+            f_chosen = None
+        if f_chosen is not None:
+            failed = (f_chosen < 0) & pod_valid & ~forced
+            if not failed.any():
+                out = _fast_output(f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev, prep)
+                engine_name = "megakernel"
+            else:
+                # Failure reasons without a second full scan: exact
+                # whenever nothing bound after the first failure (the
+                # state a failed pod saw is then the final carry —
+                # failed pods mutate nothing). Otherwise fall through
+                # to the XLA scan for exact mid-stream attribution.
+                first_fail = int(np.argmax(failed))
+                if not (f_chosen[first_fail + 1 :] >= 0).any():
+                    out = _fast_output(
+                        f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev, prep
+                    )
+                    out = _fast_failure_details(
+                        out, prep, np.nonzero(failed)[0], nv_mask=nv_mask
+                    )
                     engine_name = "megakernel"
                 else:
-                    # Failure reasons without a second full scan: exact
-                    # whenever nothing bound after the first failure (the
-                    # state a failed pod saw is then the final carry —
-                    # failed pods mutate nothing). Otherwise fall through
-                    # to the XLA scan for exact mid-stream attribution.
-                    first_fail = int(np.argmax(failed))
-                    if not (f_chosen[first_fail + 1 :] >= 0).any():
-                        out = _fast_output(
-                            f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev, prep
-                        )
-                        out = _fast_failure_details(
-                            out, prep, np.nonzero(failed)[0], nv_mask=nv_mask
-                        )
-                        engine_name = "megakernel"
-                    else:
-                        skips["megakernel"] = (
-                            "mid-stream scheduling failures need exact "
-                            "in-stream attribution (full re-scan engine)"
-                        )
-                        log.info("megakernel result discarded: %s", skips["megakernel"])
+                    skips["megakernel"] = (
+                        "mid-stream scheduling failures need exact "
+                        "in-stream attribution (full re-scan engine)"
+                    )
+                    log.info("megakernel result discarded: %s", skips["megakernel"])
     if out is None:
         from . import nativepath
 
-        miss = nativepath.why_not(prep, sched_config, extra_plugins, tie_seed=tie_seed)
+        miss = rungs["native"]
         native_breaker = breakers.engine_breaker("native")
         if miss is None and not native_breaker.allow():
             miss = native_breaker.describe_block()
@@ -761,10 +702,7 @@ def _run_engine_ladder(
         from . import resident
 
         with obs.span("engine.xla", pods=len(tmpl_ids), **shape) as rung:
-            head = resident.fetch(
-                prep, pod_valid, nv_mask=nv_mask, sched_config=sched_config,
-                extra_plugins=extra_plugins, tie_seed=tie_seed, explain=explain,
-            )
+            head = resident.fetch(prep, pod_valid, ask)
             rung.set(scanned=len(tmpl_ids) - (head.n_res if head is not None else 0))
             out = _xla_scan(
                 ec, st0, tmpl_ids, pod_valid, forced, nv_mask, head=head,

@@ -21,7 +21,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..encoding.state import EncodedCluster, ScanState
-from ..utils import envknobs
+from ..engine import select
 # the sweep bodies run UNDER tracing (vmapped inside the jitted sweeps):
 # they call the raw jit entry, never the observed schedule_pods wrapper —
 # the compile watch's host bookkeeping must stay outside the trace (OSL1601)
@@ -96,10 +96,9 @@ def sweep_auto(
     forced_masks: Optional[np.ndarray] = None,
     config=None,
 ) -> SweepResult:
-    """Route a scenario sweep: on a single device, run ALL scenarios in one
-    batched Pallas dispatch (vmap prepends a scenario axis to the kernel
-    grid — no per-scenario dispatch overhead); on a multi-device mesh,
-    shard the vmapped XLA scan across devices instead."""
+    """A scenario sweep on the rung ``select`` gives it: sequential C++ scans,
+    ALL scenarios in one batched Pallas dispatch (the scenario axis rides the
+    kernel grid), or the vmapped XLA scan sharded across the devices."""
     S = node_valid_masks.shape[0]
     if forced_masks is None:
         forced_masks = np.broadcast_to(prep.forced, (S, len(prep.forced)))
@@ -128,11 +127,12 @@ def sweep_auto(
                 np.asarray(forced_masks, dtype=bool),
             )
         config = distinct.pop() if distinct else None
-    from ..engine import nativepath
     from ..obs import trace as obs
 
-    if len(jax.devices()) == 1 and nativepath.applicable(prep, config):
-        # accelerator-less (or --backend native): sequential C++ scans —
+    rungs = select.ladder(prep, select.Ask(shape="sweep", sched_config=config))
+    if rungs["native"] is None:
+        from ..engine import nativepath
+
         # no XLA scan compile; the incremental template cache makes each
         # scenario ms-scale on small configs
         with obs.span("sweep.native", scenarios=S):
@@ -143,50 +143,19 @@ def sweep_auto(
             unscheduled=jnp.asarray(unscheduled), used=jnp.asarray(used),
             chosen=jnp.asarray(chosen), vg_used=jnp.asarray(vg_used),
         )
-    if (
-        len(jax.devices()) == 1
-        and config is None
-        and (
-            jax.default_backend() == "tpu"
-            or envknobs.raw("OPENSIM_FASTPATH") == "interpret"
-        )
-    ):
+    if rungs["megakernel"] is None:
         from ..engine import fastpath
 
-        miss = fastpath.why_not(prep)
-        if miss is None:
-            try:
-                with obs.span("sweep.megakernel", scenarios=S):
-                    unscheduled, used, chosen, vg_used = fastpath.sweep(
-                        prep, node_valid_masks, pod_valid_masks, forced_masks
-                    )
-                return SweepResult(
-                    unscheduled=unscheduled, used=used, chosen=chosen, vg_used=vg_used
+        try:
+            with obs.span("sweep.megakernel", scenarios=S):
+                unscheduled, used, chosen, vg_used = fastpath.sweep(
+                    prep, node_valid_masks, pod_valid_masks, forced_masks
                 )
-            except Exception as e:
-                # a Mosaic compile failure on the batched kernel must not
-                # kill the sweep — the XLA path below computes the same —
-                # unless --backend tpu explicitly demanded the TPU engine
-                import logging
-
-                if envknobs.raw("OPENSIM_FASTPATH") == "interpret":
-                    raise  # test/CI mode: fail loudly, don't validate the fallback
-                if envknobs.raw("OPENSIM_REQUIRE_TPU") == "1":
-                    raise RuntimeError(
-                        "--backend tpu: the batched megakernel sweep failed "
-                        f"({type(e).__name__}: {e}); refusing to silently "
-                        "fall back to the XLA sweep"
-                    ) from e
-                logging.getLogger("opensim_tpu").warning(
-                    "megakernel sweep failed (%s: %s); falling back to the "
-                    "XLA sweep", type(e).__name__, e,
-                )
-        else:
-            import logging
-
-            logging.getLogger("opensim_tpu").info(
-                "megakernel sweep envelope miss: %s", miss
+            return SweepResult(
+                unscheduled=unscheduled, used=used, chosen=chosen, vg_used=vg_used
             )
+        except Exception as e:  # opensim-lint: disable=exception-swallow (kernel_failed raises or logs)
+            select.kernel_failed(e, "sweep")  # demoted: the XLA sweep below computes the same
     from ..obs.profile import launch_span
 
     with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices())):
@@ -240,9 +209,9 @@ def sweep_segmented(
     to the scenario axis. Out-of-segment pods are mask-invalid per scan, so
     binds happen in exact stream order and placements per scenario equal a
     solo segmented simulate of that scenario (gated by
-    tests/test_parallel.py). Routing matches ``sweep_auto``: sequential C++
-    scans on accelerator-less hosts (chaining ``st0`` between segments),
-    the vmapped XLA scan with a batched carry otherwise."""
+    tests/test_parallel.py). Sequential C++ scans where ``select`` gives
+    every segment that rung (chaining ``st0`` between segments), the
+    vmapped XLA scan with a batched carry otherwise."""
     from ..engine import nativepath
     from ..engine.schedconfig import DEFAULT_CONFIG
 
@@ -252,8 +221,9 @@ def sweep_segmented(
         (None if c == DEFAULT_CONFIG else c, lo, hi) for c, lo, hi in segments
     ]
     chosen = np.full((S, P), -1, dtype=np.int32)
-    use_native = len(jax.devices()) == 1 and all(
-        nativepath.applicable(prep, cfg) for cfg, _, _ in segments
+    ask = select.Ask(shape="sweep", segments=len(segments))
+    use_native = all(
+        select.ladder(prep, ask._replace(sched_config=cfg))["native"] is None for cfg, _, _ in segments
     )
     vg0 = np.asarray(prep.st0.vg_free)
     nv_np = np.asarray(node_valid_masks, dtype=bool)

@@ -959,34 +959,18 @@ class _Executor:
         return replay_state(prep, chosen, gpu, upto=P)
 
     def _run_engine(self, prep, pod_valid, forced_vec, nv, st0):
-        """The same engine routing as ``simulate``'s segmented path: C++
-        scan where applicable, the XLA scan otherwise."""
-        from ..engine import nativepath
+        """The C++ scan where ``select`` gives that rung, else the XLA scan: a
+        step starts from its own carry and forced vector, which only they take."""
+        from ..engine import select, simulator
 
-        if nativepath.why_not(prep, None, ()) is None:
+        if select.ladder(prep, select.Ask(node_mask=True, start_state=True))["native"] is None:
+            from ..engine import nativepath
+
             return nativepath.schedule(
                 prep, pod_valid, node_valid=nv, forced=forced_vec, st0=st0
             )
-        import jax
-        import jax.numpy as jnp
-
-        from ..encoding.state import ScanState
-        from ..engine.scheduler import pad_pod_stream, scan_unroll, schedule_pods
-
-        tmpl_p, valid_p, forced_p = pad_pod_stream(prep.tmpl_ids, pod_valid, forced_vec)
-        ec_run = prep.ec._replace(node_valid=jnp.asarray(nv))
-        st_dev = ScanState(*[jnp.asarray(a) for a in st0])
-        out = schedule_pods(
-            ec_run, st_dev, tmpl_p, valid_p, forced_p,
-            features=prep.features, unroll=scan_unroll(),
-        )
-        jax.block_until_ready(out.chosen)
-        P = len(prep.ordered)
-        return out._replace(
-            chosen=np.asarray(out.chosen)[:P],
-            fail_counts=np.asarray(out.fail_counts)[:P],
-            insufficient=np.asarray(out.insufficient)[:P],
-            gpu_take=np.asarray(out.gpu_take)[:P],
+        return simulator._xla_scan(
+            prep.ec, st0, prep.tmpl_ids, pod_valid, forced_vec, nv, features=prep.features
         )
 
     def _report_pending(self, rep: StepReport, scan_set: List[int], out=None, pos_of=None, nv=None) -> None:

@@ -1,8 +1,8 @@
 """Per-engine circuit breakers for the scheduling-engine fallback ladder.
 
-The ladder (megakernel → C++ native → XLA scan) already had *selection*
-pre-checks (``fastpath.why_not`` / ``nativepath.why_not``); this module adds
-the *runtime*-failure half: when an engine that passed its pre-checks fails
+Which rungs of the ladder (megakernel → C++ native → XLA scan) may run is
+``engine/select.py``'s, before anything runs; this module is the
+*runtime*-failure half: when an engine that ``select`` passed fails
 while running (Mosaic compile error, ``ScanArgs`` ABI drift, device loss),
 ``engine/simulator.simulate()`` records the failure here and demotes the
 request one rung. After ``threshold`` consecutive failures the breaker opens

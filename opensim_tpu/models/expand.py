@@ -19,6 +19,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import yaml
 
+from ..obs.metrics import RECORDER
+from ..obs.trace import NOOP_SPAN, current_span
 from . import selectors
 from .quantity import parse_quantity
 from .objects import (
@@ -676,6 +678,33 @@ def yaml_files_in_dir(path: str) -> List[str]:
     return sorted(out)
 
 
+def _pick_yaml_loader() -> Tuple[type, str]:
+    """The safe loader of the PyYAML build at hand, and its `loader` label:
+    libyaml's reader, scanner, parser and composer where the library was
+    built with them (`CSafeLoader`, some nine times faster), the pure-Python
+    ones otherwise. Resolver and constructor are the same Python classes in
+    both, so a document both accept gives the same objects."""
+    if getattr(yaml, "__with_libyaml__", False):
+        return yaml.CSafeLoader, "c"
+    return yaml.SafeLoader, "python"
+
+
+_YAML_LOADER, _YAML_LOADER_NAME = _pick_yaml_loader()
+
+
+def _yaml_documents(stream) -> List[object]:
+    """Every document of one file or string, through the build's safe loader;
+    counted on ``/metrics`` and, as attributes and not as a span, on the span
+    that is current (a span here would end `bench.load`, PERF.md section 7)."""
+    docs = list(yaml.load_all(stream, Loader=_YAML_LOADER))
+    RECORDER.count_yaml_documents(_YAML_LOADER_NAME, len(docs))
+    sp = current_span()
+    if sp is not NOOP_SPAN:
+        sp.set(yaml_loader=_YAML_LOADER_NAME,
+               yaml_documents=sp.attrs.get("yaml_documents", 0) + len(docs))
+    return docs
+
+
 def load_yaml_objects(path: str) -> List[dict]:
     """All YAML documents in a file or directory (ignores non-YAML)."""
     docs: List[dict] = []
@@ -683,18 +712,14 @@ def load_yaml_objects(path: str) -> List[dict]:
         if not fp.endswith((".yaml", ".yml")):
             continue
         with open(fp) as f:
-            for doc in yaml.safe_load_all(f):
-                if isinstance(doc, dict):
-                    docs.append(doc)
+            docs.extend(d for d in _yaml_documents(f) if isinstance(d, dict))
     return docs
 
 
 def decode_yaml_strings(contents: List[str]) -> List[dict]:
     docs: List[dict] = []
     for s in contents:
-        for doc in yaml.safe_load_all(s):
-            if isinstance(doc, dict):
-                docs.append(doc)
+        docs.extend(d for d in _yaml_documents(s) if isinstance(d, dict))
     return docs
 
 

@@ -182,6 +182,10 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_masked_pass_total": (
         "Simulations over a masked node set (the planner's prep reuse) by the engine that answered", "counter",
     ),
+    # loader: c | python (models/expand.py: libyaml where PyYAML has it)
+    "simon_yaml_documents_total": (
+        "YAML documents read from files and rendered charts by the parser that read them", "counter",
+    ),
     # capacity observatory (obs/capacity.py, docs/observability.md) —
     # cardinality contract: every family below is label-free or bounded
     # (resource ∈ {cpu, memory, pods}; profile = registered headroom
@@ -570,6 +574,9 @@ class MetricsRecorder:
         # masked simulations by answering engine; megakernel over all is
         # how often the planner's final pass engages the kernel
         self.masked_pass = make_counter("simon_masked_pass_total", ("engine",))
+        # documents by the YAML parser that read them (models/expand.py);
+        # "python" on a host whose PyYAML has libyaml means the fast parser is not engaged
+        self.yaml_documents = make_counter("simon_yaml_documents_total", ("loader",))
         # watch-pipeline latency (ISSUE 9 satellite): event receipt → twin
         # applied, fed from the supervisor's dispatch (server/watch.py)
         self.watch_apply = make_histogram(
@@ -643,6 +650,10 @@ class MetricsRecorder:
         with self.lock:
             self.masked_pass.inc((engine,))
 
+    def count_yaml_documents(self, loader: str, n: int) -> None:
+        with self.lock:
+            self.yaml_documents.inc((loader,), n)
+
     def render_lines(self) -> List[str]:
         with self.lock:
             return (
@@ -651,6 +662,7 @@ class MetricsRecorder:
                 + self.resident_carry.render_lines()
                 + self.engine_features.render_lines()
                 + self.masked_pass.render_lines()
+                + self.yaml_documents.render_lines()
                 + self.phase_seconds.render_lines()
                 + self.request_seconds.render_lines()
                 + self.watch_apply.render_lines()
@@ -665,6 +677,7 @@ class MetricsRecorder:
             self.resident_carry.reset()
             self.engine_features.reset()
             self.masked_pass.reset()
+            self.yaml_documents.reset()
             self.watch_apply.reset()
 
 

@@ -21,6 +21,7 @@ import numpy as np
 
 from ..models.objects import ANNO_NODE_LOCAL_STORAGE, Node
 from ..models.quantity import parse_quantity
+from ..obs import trace as obs
 from .templates import SchedTemplate
 
 MEDIA_SSD = 0
@@ -29,20 +30,32 @@ MEDIA_HDD = 1
 
 def encode_gpu_nodes(nodes: List[Node], n_pad: int) -> Tuple[np.ndarray, np.ndarray]:
     """Per-device total memory [N, Gd] and device count [N]."""
-    counts = []
-    mems = []
-    for n in nodes:
-        total = n.capacity.get("alibabacloud.com/gpu-mem", n.allocatable.get("alibabacloud.com/gpu-mem", 0.0))
-        cnt = int(n.capacity.get("alibabacloud.com/gpu-count", n.allocatable.get("alibabacloud.com/gpu-count", 0)))
-        counts.append(cnt if total > 0 else 0)
-        mems.append(total / cnt if cnt > 0 and total > 0 else 0.0)
-    Gd = max(counts + [1])
-    node_gpu_mem = np.zeros((n_pad, Gd), dtype=np.float32)
-    node_gpu_count = np.zeros((n_pad,), dtype=np.int32)
-    for i, (cnt, mem) in enumerate(zip(counts, mems)):
-        node_gpu_count[i] = cnt
-        node_gpu_mem[i, :cnt] = mem
+    with obs.span("encode.gpushare", nodes=len(nodes)) as sp:
+        counts = []
+        mems = []
+        for n in nodes:
+            total = n.capacity.get("alibabacloud.com/gpu-mem", n.allocatable.get("alibabacloud.com/gpu-mem", 0.0))
+            cnt = int(n.capacity.get("alibabacloud.com/gpu-count", n.allocatable.get("alibabacloud.com/gpu-count", 0)))
+            counts.append(cnt if total > 0 else 0)
+            mems.append(total / cnt if cnt > 0 and total > 0 else 0.0)
+        Gd = max(counts + [1])
+        node_gpu_mem = np.zeros((n_pad, Gd), dtype=np.float32)
+        node_gpu_count = np.zeros((n_pad,), dtype=np.int32)
+        for i, (cnt, mem) in enumerate(zip(counts, mems)):
+            node_gpu_count[i] = cnt
+            node_gpu_mem[i, :cnt] = mem
+        sp.set(gpu_nodes=sum(1 for c in counts if c), devices=sum(counts))
     return node_gpu_mem, node_gpu_count
+
+
+def encode_gpu_requests(templates: List[SchedTemplate]) -> Tuple[np.ndarray, np.ndarray]:
+    """Per-template gpu-share columns: per-GPU memory [U] and GPU count [U]
+    (the pod annotations, ``utils/pod.go:83-100``)."""
+    with obs.span("encode.gpushare", templates=len(templates)) as sp:
+        gpu_mem = np.array([t.gpu_mem for t in templates], dtype=np.float32)
+        gpu_count = np.array([t.gpu_count for t in templates], dtype=np.int32)
+        sp.set(gpu_templates=int((gpu_mem > 0).sum()))
+    return gpu_mem, gpu_count
 
 
 def parse_node_storage(node: Node):

@@ -764,8 +764,6 @@ class ClusterEncoder:
         spr_skew = np.zeros((U, Cs), dtype=np.int32)
         spr_hard = np.zeros((U, Cs), dtype=bool)
         pin = np.full((U,), -1, dtype=np.int32)
-        gpu_mem = np.zeros((U,), dtype=np.float32)
-        gpu_count = np.zeros((U,), dtype=np.int32)
 
         for u, t in enumerate(templates):
             for rid, v in vb.encode_resources(t.requests).items():
@@ -799,8 +797,6 @@ class ClusterEncoder:
                 spr_sel[u, j] = c.sel_id
                 spr_skew[u, j] = c.max_skew
                 spr_hard[u, j] = c.hard
-            gpu_mem[u] = t.gpu_mem
-            gpu_count[u] = t.gpu_count
 
         matches_sel = np.zeros((U, A), dtype=bool)
         mm = self.ts.match_matrix()
@@ -818,8 +814,9 @@ class ClusterEncoder:
 
         # ---- extensions: node side cached in the arenas, template side
         # encoded by its dedicated module (task: gpu/local)
-        from .extensions import encode_local_requests
+        from .extensions import encode_gpu_requests, encode_local_requests
 
+        gpu_mem, gpu_count = encode_gpu_requests(templates)
         node_gpu_mem, node_gpu_count = ar.node_gpu_mem, ar.node_gpu_count
         from ..models.objects import RES_GPU_COUNT
 

@@ -491,6 +491,12 @@ def _kernel_flags(prep) -> dict:
     )
 
 
+def _gpu_rows(prep, fi: FastInputs) -> int:
+    """Rows of the kernel's per-device block ([Gd, N], sublane-padded); 0
+    where the variant without gpu-share runs and the block does not exist."""
+    return int(fi.gpu0_DN.shape[0]) if prep.features.gpu else 0
+
+
 class _SweepContext:
     """Host-side tables hoisted out of the per-scenario loop."""
 
@@ -569,7 +575,7 @@ def sweep(
 
     with launch_span(  # as in schedule(): no helper frame round the kernel
         "mk.launch", scenarios=S, pods=P + pad, nodes=fi.alloc_T.shape[1],
-        templates=fi.static_pass.shape[0], big_u=big_u,
+        templates=fi.static_pass.shape[0], big_u=big_u, gpu_devices=_gpu_rows(prep, fi),
     ):
         chosen_b, used_b, _gt, _gf, vg_b, _dev = outs = run_fast_scan(
             fi, tmpl, pv_all, fm_all,
@@ -641,7 +647,7 @@ def schedule(
     # kernel made its lowering 0.3 s longer on the chip's host (PERF.md §6).
     with launch_span(
         "mk.launch", scenarios=1, pods=len(tmpl_ids), nodes=fi.alloc_T.shape[1],
-        templates=fi.static_pass.shape[0], big_u=big_u,
+        templates=fi.static_pass.shape[0], big_u=big_u, gpu_devices=_gpu_rows(prep, fi),
     ):
         outs = run_fast_scan(
             fi, tmpl_ids, pod_valid[None], forced[None],

@@ -1059,11 +1059,11 @@ def finish_decode(
     # masked runs: candidate nodes beyond the valid prefix have no report
     # bucket (chosen never points at an invalid node)
     pod_lists = [node_pods.get(n) for n in node_names]
-    gpu_any = gpu_take.sum(axis=1) > 0  # one vectorized pass, not per-pod sums
+    gpu_ids = _gpu_device_ids(prep, chosen, gpu_take, decode_drops, engine_name)
 
     with gc_paused():
         statuses = _decode(
-            ordered, chosen, forced, custom_reasons, victims_of, gpu_any, gpu_take,
+            ordered, chosen, forced, custom_reasons, victims_of, gpu_ids,
             sf_rows, static_fail, fail_counts, insufficient, meta, n_nodes,
             node_names, pod_lists, node_pods, unscheduled, cluster, out,
             decode_drops,
@@ -1195,8 +1195,46 @@ def _drop_mask(drop_pods, n: int) -> Optional[np.ndarray]:
     return None
 
 
+def _gpu_device_ids(prep, chosen, gpu_take, drop_pods, engine_name: str) -> Dict[int, str]:
+    """The gpu-index annotation of every placed pod that holds GPU slots,
+    by stream index (GetUpdatedPodAnnotationSpec, gpushare utils/pod.go:116-127):
+    device ids joined by ``-``, one per packed slot, so a pod that took two
+    slots of device 3 reads ``3-3``. Counts the pods on ``/metrics`` by the
+    rung that answered and by kind: ``multi`` (several slots), ``whole`` (one
+    slot of a device's whole memory), ``fraction``."""
+    from ..obs import trace as obs
+
+    if not prep.features.gpu:
+        return {}
+    with obs.span("decode.gpu") as sp:
+        slots = gpu_take.sum(axis=1)
+        held = (np.asarray(chosen) >= 0) & (slots > 0)
+        dropm = _drop_mask(drop_pods, len(held))
+        if dropm is not None:
+            held &= ~dropm
+        idx = np.nonzero(held)[0]
+        sp.set(pods=int(idx.size))
+        if not idx.size:
+            return {}
+        takes = np.rint(gpu_take[idx]).astype(np.int64)
+        ids = {
+            i: "-".join(str(d) for d, cnt in enumerate(row) for _ in range(cnt))
+            for i, row in zip(idx.tolist(), takes.tolist())
+        }
+        asked = np.asarray(prep.ec_np.gpu_mem)[np.asarray(prep.tmpl_ids)[idx]]
+        device_total = np.asarray(prep.ec_np.node_gpu_mem)[np.asarray(chosen)[idx], takes.argmax(axis=1)]
+        multi = slots[idx] > 1
+        whole = ~multi & (asked >= device_total)
+        from ..obs.metrics import RECORDER
+
+        RECORDER.count_gpushare_pods(engine_name, {
+            "fraction": int((~multi & ~whole).sum()), "whole": int(whole.sum()), "multi": int(multi.sum()),
+        })
+    return ids
+
+
 def _decode(
-    ordered, chosen, forced, custom_reasons, victims_of, gpu_any, gpu_take,
+    ordered, chosen, forced, custom_reasons, victims_of, gpu_ids,
     sf_rows, static_fail, fail_counts, insufficient, meta, n_nodes,
     node_names, pod_lists, node_pods, unscheduled, cluster, out, drop_pods=(),
 ):
@@ -1226,13 +1264,9 @@ def _decode(
         pod = ordered[i]
         pod.spec.node_name = node_names[c]
         pod.phase = "Running"
-        # gpu-index annotation parity (GetUpdatedPodAnnotationSpec,
-        # gpushare utils/pod.go:116-127): device ids, one per packed slot
-        if gpu_any[i]:
-            ids: List[str] = []
-            for d, cnt in enumerate(gpu_take[i]):
-                ids.extend([str(d)] * int(round(float(cnt))))
-            pod.metadata.annotations[ANNO_GPU_INDEX] = "-".join(ids)
+        ids = gpu_ids.get(i)
+        if ids is not None:
+            pod.metadata.annotations[ANNO_GPU_INDEX] = ids
             # assume-time annotation (gpushare utils/pod.go:125): bind
             # timestamp in nanoseconds
             pod.metadata.annotations[ANNO_GPU_ASSUME_TIME] = str(time.time_ns())

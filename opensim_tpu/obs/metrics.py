@@ -182,6 +182,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_masked_pass_total": (
         "Simulations over a masked node set (the planner's prep reuse) by the engine that answered", "counter",
     ),
+    # engine: megakernel | native | xla; kind: fraction | whole | multi
+    "simon_gpushare_pods_total": (
+        "Pods placed with a gpu-share request by the engine that answered and by kind: a fraction of a device, one whole device, several slots",
+        "counter",
+    ),
     # loader: c | python (models/expand.py: libyaml where PyYAML has it)
     "simon_yaml_documents_total": (
         "YAML documents read from files and rendered charts by the parser that read them", "counter",
@@ -574,6 +579,9 @@ class MetricsRecorder:
         # masked simulations by answering engine; megakernel over all is
         # how often the planner's final pass engages the kernel
         self.masked_pass = make_counter("simon_masked_pass_total", ("engine",))
+        # pods placed with a gpu-share request, by answering engine and kind
+        # (fraction of a device, one whole device, several slots)
+        self.gpushare_pods = make_counter("simon_gpushare_pods_total", ("engine", "kind"))
         # documents by the YAML parser that read them (models/expand.py);
         # "python" on a host whose PyYAML has libyaml means the fast parser is not engaged
         self.yaml_documents = make_counter("simon_yaml_documents_total", ("loader",))
@@ -650,6 +658,12 @@ class MetricsRecorder:
         with self.lock:
             self.masked_pass.inc((engine,))
 
+    def count_gpushare_pods(self, engine: str, by_kind: Dict[str, int]) -> None:
+        with self.lock:
+            for kind, n in by_kind.items():
+                if n:
+                    self.gpushare_pods.inc((engine, kind), int(n))
+
     def count_yaml_documents(self, loader: str, n: int) -> None:
         with self.lock:
             self.yaml_documents.inc((loader,), n)
@@ -662,6 +676,7 @@ class MetricsRecorder:
                 + self.resident_carry.render_lines()
                 + self.engine_features.render_lines()
                 + self.masked_pass.render_lines()
+                + self.gpushare_pods.render_lines()
                 + self.yaml_documents.render_lines()
                 + self.phase_seconds.render_lines()
                 + self.request_seconds.render_lines()
@@ -677,6 +692,7 @@ class MetricsRecorder:
             self.resident_carry.reset()
             self.engine_features.reset()
             self.masked_pass.reset()
+            self.gpushare_pods.reset()
             self.yaml_documents.reset()
             self.watch_apply.reset()
 

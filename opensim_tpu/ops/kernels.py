@@ -118,6 +118,25 @@ def _corrected(a, b, q):
     return q + ((a - p) - e) / b
 
 
+def floor_div32(a, b):
+    """``floor(a / b)`` exactly, on any backend, for the device arithmetic of
+    gpu-share: how many requests of ``b`` fit what a device has free. A
+    TPU's float32 quotient is up to 2 ulp off (see ``div32``), so a device
+    with exactly two halves free can read 1.9999999 and floor to one. Shared
+    by the XLA scan and the megakernel so the two stay op for op."""
+    return _floor_corrected(a, b, a / b)
+
+
+def _floor_corrected(a, b, q):
+    """The floor of ``q``, a quotient of ``a / b`` within a few ulp, moved to
+    the floor of the true quotient by the residual it leaves. Operands are
+    whole numbers of Mi under 2**24 Mi, so ``floor(q) * b`` and the residual
+    are exact in float32."""
+    f = jnp.floor(q)
+    r = a - f * b
+    return jnp.where(r < 0, f - 1.0, jnp.where(r >= b, f + 1.0, f))
+
+
 def _minmax_normalize(scores, feasible):
     """SimonPlugin.NormalizeScore (plugin/simon.go:76-101): min-max over the
     feasible set to [0, 100]; degenerate range → 0."""
@@ -316,7 +335,7 @@ def gpu_filter(ec, st, u):
     sum_d floor(free_d / mem) >= count."""
     mem = ec.gpu_mem[u]
     cnt = ec.gpu_count[u].astype(jnp.float32)
-    chunks = jnp.sum(jnp.floor_divide(st.gpu_free, jnp.maximum(mem, 1.0)), axis=-1)  # [N]
+    chunks = jnp.sum(floor_div32(st.gpu_free, jnp.maximum(mem, 1.0)), axis=-1)  # [N]
     ok = (chunks >= cnt) & (cnt > 0)
     return jnp.where(mem > 0, ok, True)
 
@@ -1178,7 +1197,7 @@ def bind_update(ec: EncodedCluster, st: ScanState, u, node, apply,
         mem = ec.gpu_mem[u]
         cnt = ec.gpu_count[u].astype(jnp.float32)
         free = st.gpu_free[node]  # [Gd]
-        chunks = jnp.floor_divide(free, jnp.maximum(mem, 1.0))
+        chunks = floor_div32(free, jnp.maximum(mem, 1.0))
         cum = jnp.cumsum(chunks)
         take_greedy = jnp.clip(cnt - (cum - chunks), 0.0, chunks)
         big = jnp.float32(1e30)

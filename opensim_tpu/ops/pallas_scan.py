@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..encoding import vocab as V
-from .kernels import div32
+from .kernels import div32, floor_div32
 
 NEG = -1e30
 MAX_SCORE = 100.0
@@ -487,11 +487,13 @@ def _make_kernel(
                 # Open-Gpu-Share filter: sum_d floor(free_d / mem) >= count
                 gmem = gmem_ref[u]
                 gcnt = gcnt_ref[u]
+                # slots of this pod's size on every device, one [Gd, N] block:
+                # the bind below packs from the same rows (nothing writes
+                # gpu_free between the filter and the bind of a step)
+                gpu_chunks = floor_div32(gpu_free_ref[:], jnp.maximum(gmem, 1.0))
                 chunks_sum = jnp.zeros((1, N), jnp.float32)
                 for d in range(n_gpu):
-                    chunks_sum = chunks_sum + jnp.floor(
-                        gpu_free_ref[pl.ds(d, 1), :] / jnp.maximum(gmem, 1.0)
-                    )
+                    chunks_sum = chunks_sum + gpu_chunks[d:d + 1, :]
                 gpu_ok = ((chunks_sum >= gcnt) & (gcnt > 0)).astype(jnp.float32)
                 feasible = jnp.where(gmem > 0, feasible * gpu_ok, feasible)
 
@@ -823,7 +825,7 @@ def _make_kernel(
                         fits_d = (free_d >= gmem).astype(jnp.float32)
                         take_tight = fits_d * (free_d == best_free).astype(jnp.float32) * (1.0 - jnp.minimum(assigned, 1.0))
                         assigned = assigned + take_tight
-                        chunks_d = jnp.floor(free_d / jnp.maximum(gmem, 1.0))
+                        chunks_d = gpu_chunks[d:d + 1, :]
                         take_greedy = jnp.clip(gcnt - cum, 0.0, chunks_d)
                         cum = cum + chunks_d
                         take_d = jnp.where(gcnt == 1, take_tight, take_greedy)

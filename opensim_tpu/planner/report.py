@@ -393,11 +393,12 @@ def report_cluster_info(result: SimulateResult, extended: List[str], out: TextIO
         print("", file=out)
 
     if contains_gpu(extended):
-        print("GPU Node Resource", file=out)
-        _table(gpu_node_rows(result), out)
-        print("\nPod -> Node Map", file=out)
-        _table(gpu_pod_map_rows(result), out)
-        print("", file=out)
+        with obs.span("report.gpu"):  # per device and per pod: grows with both
+            print("GPU Node Resource", file=out)
+            _table(gpu_node_rows(result), out)
+            print("\nPod -> Node Map", file=out)
+            _table(gpu_pod_map_rows(result), out)
+            print("", file=out)
 
 
 def report_app_info(result: SimulateResult, app_names: List[str], out: TextIO) -> None:

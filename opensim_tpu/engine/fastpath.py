@@ -19,7 +19,7 @@ import numpy as np
 
 from ..encoding import vocab as V
 from ..obs import trace as obs
-from ..obs.profile import launch_span
+from ..obs.profile import launch_span, observed_jit_call
 from ..ops import kernels
 from ..ops.pallas_scan import CHUNK, FastInputs, run_fast_scan
 from . import select
@@ -497,6 +497,31 @@ def _gpu_rows(prep, fi: FastInputs) -> int:
     return int(fi.gpu0_DN.shape[0]) if prep.features.gpu else 0
 
 
+def _launch(prep, fi: FastInputs, tmpl_ids, pod_valid, forced, interpret: bool, big_u: bool):
+    """`mk.launch`: everything the host does to get the kernel onto the
+    device, for `fi` with its per-scenario rows set, `tmpl_ids` [P] and
+    `pod_valid`/`forced` [S, P]. run_fast_scan is one jitted function, entered
+    through the compile watch as `megakernel`: a signature's first call in a
+    process traces and lowers the kernel and looks the executable up in the
+    persistent cache (the span says `entry="traced"`,
+    simon_compile_total{fn="megakernel"} counts it), every later call is the
+    jit's cache lookup, the transfer of the three pod streams and the enqueue
+    (`entry="cached"`). The device's own time is mk.wait. A frame more or
+    less between the caller and the kernel moves the kernel's MLIR locations,
+    so the persistent cache's key and the seconds of that first lowering
+    (0.3 s for one frame on the chip's host, PERF.md §6); the calls after it
+    are not touched."""
+    with launch_span(
+        "mk.launch", watch="megakernel", scenarios=pod_valid.shape[0], pods=len(tmpl_ids),
+        nodes=fi.alloc_T.shape[1], templates=fi.static_pass.shape[0], big_u=big_u,
+        gpu_devices=_gpu_rows(prep, fi),
+    ):
+        return observed_jit_call(
+            "megakernel", run_fast_scan, (fi, tmpl_ids, pod_valid, forced),
+            dict(interpret=interpret, big_u=big_u, **_kernel_flags(prep)),
+        )
+
+
 class _SweepContext:
     """Host-side tables hoisted out of the per-scenario loop."""
 
@@ -573,14 +598,7 @@ def sweep(
         fm_all[:, :P] = np.asarray(forced_masks, dtype=bool)
         fi, nv_all = _scenario_rows(prep, fi, node_valid_masks)
 
-    with launch_span(  # as in schedule(): no helper frame round the kernel
-        "mk.launch", scenarios=S, pods=P + pad, nodes=fi.alloc_T.shape[1],
-        templates=fi.static_pass.shape[0], big_u=big_u, gpu_devices=_gpu_rows(prep, fi),
-    ):
-        chosen_b, used_b, _gt, _gf, vg_b, _dev = outs = run_fast_scan(
-            fi, tmpl, pv_all, fm_all,
-            interpret=interpret, big_u=big_u, **_kernel_flags(prep),
-        )
+    chosen_b, used_b, _gt, _gf, vg_b, _dev = outs = _launch(prep, fi, tmpl, pv_all, fm_all, interpret, big_u)
     with obs.span("mk.wait"):
         jax.block_until_ready(outs)
     with obs.span("mk.fetch"):
@@ -639,34 +657,21 @@ def schedule(
             static_fail = np.asarray(
                 _precompute_jit(prep.ec._replace(node_valid=jnp.asarray(mask))).static_fail
             )
-    # mk.launch: everything the host does to get the kernel onto the device
-    # (the eager pallas_call's trace, lowering and cache lookup, argument
-    # transfer, enqueue, the lazy reshapes of its outputs); the device's own
-    # time is mk.wait. The span is opened here and not in a helper round
-    # run_fast_scan: one more Python frame between the caller and the
-    # kernel made its lowering 0.3 s longer on the chip's host (PERF.md §6).
-    with launch_span(
-        "mk.launch", scenarios=1, pods=len(tmpl_ids), nodes=fi.alloc_T.shape[1],
-        templates=fi.static_pass.shape[0], big_u=big_u, gpu_devices=_gpu_rows(prep, fi),
-    ):
-        outs = run_fast_scan(
-            fi, tmpl_ids, pod_valid[None], forced[None],
-            interpret=interpret, big_u=big_u, **_kernel_flags(prep),
-        )
+    outs = _launch(prep, fi, tmpl_ids, pod_valid[None], forced[None], interpret, big_u)
     with obs.span("mk.wait"):
         jax.block_until_ready(outs)
     with obs.span("mk.fetch"):
-        chosen, used_T, gpu_take, gpu_T, vg_T, dev_T = (out[0] for out in outs)
+        chosen, used_T, gpu_take, gpu_T, vg_T, dev_T = (np.asarray(out)[0] for out in outs)
         Gd = int(prep.st0.gpu_free.shape[1])
         Vg = int(prep.st0.vg_free.shape[1])
         Dv = int(prep.st0.dev_free.shape[1])
         No = meta["n_orig"]  # lane padding added in build_inputs is trimmed here
         return (
-            np.asarray(chosen)[:P],
-            np.asarray(used_T).T[:No],
+            chosen[:P],
+            used_T.T[:No],
             static_fail,
-            np.asarray(gpu_take)[:P, :Gd],
-            np.asarray(gpu_T)[:Gd].T[:No],
-            np.asarray(vg_T)[:Vg].T[:No],
-            np.asarray(dev_T)[:Dv].T[:No],
+            gpu_take[:P, :Gd],
+            gpu_T[:Gd].T[:No],
+            vg_T[:Vg].T[:No],
+            dev_T[:Dv].T[:No],
         )

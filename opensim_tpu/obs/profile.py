@@ -17,8 +17,8 @@ monitoring events come from ``jax.monitoring`` listeners, and the
 persistent cache directory's file/byte footprint from
 ``utils/jitcache.cache_stats``. The same listeners count what the compile
 PATH costs when nothing compiles: calls and seconds per stage (``trace``,
-``lower``, ``backend``, ``cache_retrieval``), the stages an eager
-``pallas_call`` re-enters on every plan (ISSUE 27).
+``lower``, ``backend``, ``cache_retrieval``), the stages a signature's
+first call walks even where the persistent cache answers (ISSUE 27).
 
 **Cumulative phase profiles.** The flight recorder answers "why was THAT
 request slow"; capacity questions need "where do requests spend time in
@@ -229,6 +229,13 @@ class CompileWatch:
         with self._lock:
             return self._stages["backend"][0], self._cache_events.get("cache_hits", 0)
 
+    def traced(self, name: str) -> int:
+        """Calls at boundary ``name`` that traced and compiled so far (what
+        ``simon_compile_total{fn=}`` renders)."""
+        with self._lock:
+            fn = self._fns.get(name)
+            return fn["compiles"] if fn else 0
+
     def snapshot(self) -> dict:
         from ..utils import jitcache
 
@@ -353,29 +360,42 @@ COMPILES = CompileWatch()
 
 class _LaunchScope(_SpanScope):
     """A span that also counts, as attributes, the entries into the backend
-    compiler and the persistent-cache hits inside it."""
+    compiler and the persistent-cache hits inside it, and for a call through
+    ``observed_jit_call`` at boundary ``watch`` says as ``entry`` whether the
+    call ``traced`` or was served from the jit's cache (``cached``)."""
 
-    __slots__ = ("_before",)
+    __slots__ = ("_before", "_watch")
+
+    def __init__(self, tr, name: str, attrs: dict, watch: Optional[str]) -> None:
+        super().__init__(tr, name, attrs)
+        self._watch = watch
+
+    def _counts(self) -> Tuple[int, int, int]:
+        return (*COMPILES.counts(), COMPILES.traced(self._watch) if self._watch else 0)
 
     def __enter__(self):
-        self._before = COMPILES.counts()
+        self._before = self._counts()
         return super().__enter__()
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        compiles, hits = COMPILES.counts()
+        compiles, hits, traced = self._counts()
         self.span.attrs.update(
             backend_compiles=compiles - self._before[0], cache_hits=hits - self._before[1]
         )
+        if self._watch and exc_type is None:
+            self.span.attrs["entry"] = "traced" if traced > self._before[2] else "cached"
         return super().__exit__(exc_type, exc, tb)
 
 
-def launch_span(name: str, **attrs: Any):
+def launch_span(name: str, watch: Optional[str] = None, **attrs: Any):
     """``obs.span`` for a call that may enter the compile path (the
     megakernel's ``mk.launch``, the scans' ``xla.launch``): the span says
-    whether its seconds held a compile, a cache hit or neither. The shared
-    no-op without an ambient trace, like every instrumentation point."""
+    whether its seconds held a compile, a cache hit or neither, and with
+    ``watch`` (an ``observed_jit_call`` boundary) whether the jitted entry
+    traced. The shared no-op without an ambient trace, like every
+    instrumentation point."""
     tr = current_trace()
-    return NOOP_SPAN if tr is None else _LaunchScope(tr, name, attrs)
+    return NOOP_SPAN if tr is None else _LaunchScope(tr, name, attrs, watch)
 
 
 def observed_jit_call(name: str, fn, args: tuple, static: Optional[dict] = None):

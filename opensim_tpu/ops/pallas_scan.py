@@ -22,6 +22,9 @@ generated per feature-flag combination so absent features cost nothing, and
 node validity is a runtime row: a scenario sweep is the same kernel with a
 leading scenario grid axis, each scenario reading its own mask, spread-weight
 table and pod streams (`run_fast_scan`; a plain schedule is one scenario).
+`run_fast_scan` is the one entry and a module-level `jax.jit`: a process
+traces and lowers the kernel once per signature (argument shapes × feature
+flags) and enters it from the jit's cache after that.
 
 Layouts (N = padded node axis, lanes; rows padded to sublane multiples):
   alloc_T     [R, N]    f32  allocatable per resource row
@@ -914,6 +917,13 @@ def _make_kernel(
     return kernel
 
 
+# what selects the generated kernel; everything else run_fast_scan reads
+# comes from the shapes of its traced arguments
+_STATIC = ("has_interpod", "has_gpu", "has_local", "has_ports", "has_na", "has_tt",
+           "has_avoid", "interpret", "big_u", "gc_row")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def run_fast_scan(
     fi: FastInputs,
     tmpl_ids,
@@ -940,7 +950,16 @@ def run_fast_scan(
 
     `big_u` keeps the [U, N] / [X, U] template tables in HBM and DMAs one
     row/column per pod step into VMEM scratch — VMEM use then no longer
-    scales with U, lifting the template cap (fastpath.applicable)."""
+    scales with U, lifting the template cap (fastpath.applicable).
+
+    This is the one entry to the kernel and it is jitted (the XLA module is
+    `jit_run_fast_scan`): `fi` and the three pod streams are traced, the
+    flags of `_STATIC` are static, so a process traces and lowers the kernel
+    once per signature (argument shapes × flags) and every later call of
+    that signature is a cache lookup, a transfer of the streams and an
+    enqueue. The casts and layout changes below and the normalisation of the
+    outputs are part of the same program. `run_fast_scan.__wrapped__` is the
+    plain function (the tests compare the two)."""
     P = tmpl_ids.shape[0]
     assert P % CHUNK == 0, P
     S = pod_valid.shape[0]

@@ -63,6 +63,12 @@ def _find(tr, name):
     return [sp for sp in tr.walk() if sp.name == name]
 
 
+def _walk_depth(sp, depth=0):
+    yield depth, sp
+    for c in sp.children:
+        yield from _walk_depth(c, depth + 1)
+
+
 def assert_spans_nest(sp):
     """Children lie inside their parent and no two siblings overlap: two
     spans of one tree share time only where one is the other's ancestor."""
@@ -170,8 +176,31 @@ def test_the_launch_says_what_it_launched_and_whether_it_compiled(megakernel_tra
     (launch,) = _find(megakernel_trace, "mk.launch")
     assert launch.attrs["scenarios"] == 1 and launch.attrs["nodes"] == 128
     assert launch.attrs["pods"] % 256 == 0 and launch.attrs["templates"] >= 1
-    assert launch.attrs["big_u"] is False
+    assert launch.attrs["big_u"] is False and launch.attrs["gpu_devices"] == 0
     assert launch.attrs["backend_compiles"] >= 0 and launch.attrs["cache_hits"] >= 0
+    # ISSUE 34: the attributes it had, and whether the jitted entry traced
+    assert set(launch.attrs) == {
+        "scenarios", "pods", "nodes", "templates", "big_u", "gpu_devices", "backend_compiles", "cache_hits", "entry",
+    }
+    assert launch.attrs["entry"] in ("traced", "cached")
+
+
+def test_a_second_plan_of_the_same_shapes_enters_the_kernel_from_the_cache(monkeypatch, megakernel_trace):
+    """ISSUE 34: the tree is the first plan's line for line; only `entry` says the jit's cache answered."""
+    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    tr, res = _traced(lambda: simulate(_cluster(), _apps()))
+    assert res.engine.name == "megakernel"
+    names = lambda t: [(depth, sp.name) for depth, sp in _walk_depth(t.root)]
+    assert names(tr) == names(megakernel_trace)
+    (launch,) = _find(tr, "mk.launch")
+    assert launch.attrs["entry"] == "cached" and launch.attrs["backend_compiles"] == 0
+
+
+def test_only_the_kernels_launch_says_entry(monkeypatch):
+    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
+    tr, _ = _traced(lambda: simulate(_cluster(), _apps()))
+    (launch,) = _find(tr, "xla.launch")
+    assert set(launch.attrs) == {"pods", "backend_compiles", "cache_hits"}
 
 
 def test_a_megakernel_sweep_has_the_same_four_parts():

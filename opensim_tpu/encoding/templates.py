@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..models.objects import Pod
 from ..models.selectors import match_label_selector
+from ..obs import trace as obs
 
 ZONE_LABEL = "topology.kubernetes.io/zone"
 HOSTNAME_LABEL = "kubernetes.io/hostname"
@@ -314,9 +315,12 @@ class TemplateSet:
     def match_matrix(self):
         """[U, A] bool: does a pod of template u match selector a?
 
-        Incremental: the previous matrix (if any) fills the known block, so
-        a delta build evaluates only new-template rows and new-selector
-        columns — O(ΔU·A + U·ΔA) python selector matches, not O(U·A)."""
+        A template is tested only against selectors that can match it
+        (`_selector_index`): those of its namespace filed under one of its
+        (key, value) pairs, those of its namespace with expressions alone,
+        and the conjunctions. Incremental: the previous matrix (if any) fills
+        the known block, so a delta build tests new templates against those
+        selectors and known templates against the new selectors alone."""
         import numpy as np
 
         U, A = len(self.templates), len(self.selectors)
@@ -326,10 +330,53 @@ class TemplateSet:
         if prev is not None and prev.shape[0] <= U and prev.shape[1] <= A:
             u0, a0 = prev.shape
             m[:u0, :a0] = prev
-        for u, t in enumerate(self.templates):
-            for a, canon in enumerate(self.selectors):
-                if u < u0 and a < a0:
+        with obs.span("encode.match", templates=U, selectors=A) as sp:
+            evaluated = 0
+            for lo, hi, first in ((0, u0, a0), (u0, U, 0)):
+                if lo == hi or first == A:
                     continue
-                m[u, a] = selector_matches(canon, t.namespace, t.labels)
+                by_pair, by_ns, conjunctions = self._selector_index(first)
+                for u in range(lo, hi):
+                    t = self.templates[u]
+                    ns, labels = t.namespace, t.labels
+                    candidates = conjunctions + by_ns.get(ns, [])
+                    for pair in labels.items():
+                        candidates += by_pair.get((ns, *pair), ())
+                    for a in candidates:
+                        m[u, a] = selector_matches(self.selectors[a], ns, labels)
+                    evaluated += len(candidates)
+            sp.set(evaluated=evaluated)
         self._mm = m
         return m
+
+    def _selector_index(self, first: int):
+        """The selectors from id `first` on, by what a pod must carry for one
+        to match it: `(namespace, key, value)` of one `matchLabels` pair ->
+        ids, the pair being the one the fewest of these selectors name (a
+        label every workload carries tells nothing apart); namespace -> ids of
+        the selectors with no `matchLabels` (empty, or expressions alone);
+        and the ids of the conjunctions, which are tested against every
+        template. A nil selector matches nothing."""
+        by_pair: Dict[tuple, List[int]] = {}
+        by_ns: Dict[str, List[int]] = {}
+        conjunctions: List[int] = []
+        named: Dict[tuple, int] = {}  # (key, value) -> selectors that name it
+        plain = []
+        for a in range(first, len(self.selectors)):
+            canon = self.selectors[a]
+            if canon is None:
+                continue
+            if canon[0] == "AND":
+                conjunctions.append(a)
+                continue
+            plain.append((a, set(canon[0]), canon[1]))
+            for pair in canon[1]:
+                named[pair] = named.get(pair, 0) + 1
+        for a, namespaces, match_labels in plain:
+            pair = min(match_labels, key=named.__getitem__, default=None)
+            for ns in namespaces:
+                if pair is None:
+                    by_ns.setdefault(ns, []).append(a)
+                else:
+                    by_pair.setdefault((ns, *pair), []).append(a)
+        return by_pair, by_ns, conjunctions

@@ -599,7 +599,7 @@ def extend_with_nodes(
 
         # per-DaemonSet pods for the new nodes, in cluster.daemon_sets order —
         # the same expansion order _cluster_pods uses
-        groups_new = [expand.pods_from_daemon_set(ds, new_nodes) for ds in cluster.daemon_sets]
+        groups_new = expand.pods_from_daemon_sets(cluster.daemon_sets, new_nodes)
         if len(groups_new) != len(base_prep.ds_group_sizes):
             timed.declined()
             return None  # cluster's DS set changed vs the base prep: not a pure node delta

@@ -14,6 +14,7 @@ run the kernel cannot take never imports Pallas (~1 s).
 from __future__ import annotations
 
 import logging
+import re
 from typing import Dict, NamedTuple, Optional, Set, Tuple
 
 import jax
@@ -141,6 +142,58 @@ def ladder(prep, ask: Ask = Ask(), pol: Optional[Policy] = None) -> Dict[str, Op
 
         native = nativepath.why_not(prep, ask.sched_config, ask.extra_plugins, ask.tie_seed)
     return {"megakernel": megakernel, "native": native, "xla": None}
+
+
+_OVER = re.compile(r"\b([RUA])=\d+ > \d+ supported")
+
+
+def _envelope_token(engine: str, reason: str) -> str:
+    """An envelope's reason as a short token. The kernel's: the table axes
+    over their caps joined by ``+`` (``U``, ``A``, ``R``), ``vmem``,
+    ``topo_keys``, or ``features`` for a feature's table it has no rows for.
+    The C++ scan's: ``extra_plugins``, ``fit_ignored_cols``, ``not_built``."""
+    if engine == "megakernel":
+        over = _OVER.findall(reason)
+        if over:
+            return "+".join(over)
+        if reason.startswith("VMEM estimate"):
+            return "vmem"
+        return "topo_keys" if "topology keys" in reason else "features"
+    if reason.startswith("engine not built"):
+        return "not_built"
+    return "extra_plugins" if "extra_plugins" in reason else "fit_ignored_cols"
+
+
+def turned_away(prep, ask: Ask, pol: Policy, rungs: Dict[str, Optional[str]]) -> Optional[Tuple[str, str]]:
+    """The first rung the policy left on that declined the run, with its
+    reason as a short token: the row's name in :data:`DECLINES`, else the
+    token of the engine's envelope. None when that rung serves the run; a
+    rung the policy switched off turned nothing away."""
+    asked = _asked(prep, ask, pol.devices)
+    for engine in ("megakernel", "native"):
+        if pol.off[engine] is not None:
+            continue
+        if rungs[engine] is None:
+            return None
+        row = next((name for name, _why in DECLINES[engine] if name in asked), None)
+        return engine, row or _envelope_token(engine, rungs[engine])
+    return None
+
+
+def decline_attrs(prep, away: Optional[Tuple[str, str]]) -> Dict[str, object]:
+    """What the span of the rung that runs instead says of a run that
+    :func:`turned_away` names: ``declined`` (``<rung>:<token>``) and the sizes
+    the envelopes look at, the templates, the selectors and the pods pinned to
+    a node (each DaemonSet pod is a template of its own). Empty for None."""
+    if away is None:
+        return {}
+    ec = prep.ec_np if prep.ec_np is not None else prep.ec
+    return {
+        "declined": ":".join(away),
+        "templates": int(ec.req.shape[0]),
+        "selectors": int(ec.matches_sel.shape[1]),
+        "pinned_pods": sum(1 for t in prep.ds_target if t >= 0),
+    }
 
 
 def carry(prep, ask: Ask = Ask()) -> Optional[str]:

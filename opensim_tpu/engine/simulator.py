@@ -235,12 +235,18 @@ def _tmpl_hint(pod: Pod) -> Optional[tuple]:
     return (pod.metadata.namespace, kind, name, owner_uid, pod.spec.node_name, pin)
 
 
+#: the workload kinds kube's ``DefaultSelector`` finds a selector for (a
+#: Deployment's pods are its ReplicaSet's); a Job's or a DaemonSet's pods have
+#: none, so ``buildDefaultConstraints`` gives them no constraint
+_SPREAD_BY_DEFAULT = ("ReplicaSet", "ReplicationController", "StatefulSet")
+
+
 def _owner_selector(pod: Pod) -> Optional[dict]:
     """Selector used for system-default topology spreading: the owning
     workload's pods share identical labels, so matching on the pod's own
     labels reproduces the RS/STS selector grouping that k8s
     buildDefaultConstraints derives from the owning objects."""
-    if pod.metadata.annotations.get(ANNO_WORKLOAD_KIND) and pod.metadata.labels:
+    if pod.metadata.annotations.get(ANNO_WORKLOAD_KIND) in _SPREAD_BY_DEFAULT and pod.metadata.labels:
         return {"matchLabels": dict(pod.metadata.labels)}
     return None
 
@@ -274,8 +280,7 @@ def _cluster_pods(cluster: ResourceTypes) -> Tuple[List[Pod], int, List[int]]:
     )
     pods = expand.generate_pods_from_resources(rt, cluster.nodes, include_daemon_sets=False)
     ds_group_sizes: List[int] = []
-    for ds in cluster.daemon_sets:
-        group = expand.pods_from_daemon_set(ds, cluster.nodes)
+    for group in expand.pods_from_daemon_sets(cluster.daemon_sets, cluster.nodes):
         ds_group_sizes.append(len(group))
         pods.extend(group)
     return pods, len(bare), ds_group_sizes
@@ -583,6 +588,7 @@ def _run_engine_ladder(
     under one traced ``schedule`` span with a child span per engine actually
     *attempted* (ISSUE 5) — a skipped rung gets a demotion event, not a span."""
     from ..obs import trace as obs
+    from ..obs.metrics import RECORDER
 
     out = None
     engine_name = "xla"
@@ -701,7 +707,10 @@ def _run_engine_ladder(
     if out is None:
         from . import resident
 
-        with obs.span("engine.xla", pods=len(tmpl_ids), **shape) as rung:
+        away = select.turned_away(prep, ask, pol, rungs)
+        if away is not None:
+            RECORDER.count_engine_declined(*away)
+        with obs.span("engine.xla", pods=len(tmpl_ids), **shape, **select.decline_attrs(prep, away)) as rung:
             head = resident.fetch(prep, pod_valid, ask)
             rung.set(scanned=len(tmpl_ids) - (head.n_res if head is not None else 0))
             out = _xla_scan(
@@ -709,8 +718,6 @@ def _run_engine_ladder(
                 features=prep.features, config=sched_config, extra_plugins=extra_plugins,
                 tie_seed=tie_seed, explain=explain,
             )
-    from ..obs.metrics import RECORDER
-
     RECORDER.count_engine_features(engine_name, features)
     if nv_mask is not None:
         RECORDER.count_masked_pass(engine_name)

@@ -20,7 +20,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import yaml
 
 from ..obs.metrics import RECORDER
-from ..obs.trace import NOOP_SPAN, current_span
+from ..obs.trace import NOOP_SPAN, current_span, span
 from . import selectors
 from .quantity import parse_quantity
 from .objects import (
@@ -622,6 +622,18 @@ def pods_from_daemon_set(ds: Workload, nodes: List[Node]) -> List[Pod]:
     return pods
 
 
+def pods_from_daemon_sets(daemon_sets: List[Workload], nodes: List[Node]) -> List[List[Pod]]:
+    """The pods of each DaemonSet, in the order given. Every pod pins its
+    node, so each is a scheduling template of its own: the span
+    `expand.daemonsets` says how many that makes."""
+    if not daemon_sets:
+        return []
+    with span("expand.daemonsets", daemonsets=len(daemon_sets), nodes=len(nodes)) as sp:
+        groups = [pods_from_daemon_set(ds, nodes) for ds in daemon_sets]
+        sp.set(pods=sum(len(g) for g in groups))
+    return groups
+
+
 def generate_pods_from_resources(
     resources: ResourceTypes, nodes: Optional[List[Node]] = None, include_daemon_sets: bool = True
 ) -> List[Pod]:
@@ -657,8 +669,8 @@ def generate_pods_from_resources(
     for cj in resources.cron_jobs:
         pods.extend(pods_from_cron_job(cj))
     if include_daemon_sets:
-        for ds in resources.daemon_sets:
-            pods.extend(pods_from_daemon_set(ds, nodes if nodes is not None else resources.nodes))
+        for group in pods_from_daemon_sets(resources.daemon_sets, nodes if nodes is not None else resources.nodes):
+            pods.extend(group)
     return pods
 
 

@@ -206,9 +206,9 @@ def _put_template(b: _Buf, pod: Pod):
         b.u8(1 if c.get("whenUnsatisfiable", "DoNotSchedule") == "DoNotSchedule" else 0)
         _put_selector(b, sel)
 
-    owner = None
-    if pod.metadata.annotations.get("simon/workload-kind") and pod.metadata.labels:
-        owner = {"matchLabels": dict(pod.metadata.labels)}
+    from ..engine.simulator import _owner_selector
+
+    owner = _owner_selector(pod)
     if owner is None:
         b.u8(0)
     else:

@@ -182,6 +182,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_masked_pass_total": (
         "Simulations over a masked node set (the planner's prep reuse) by the engine that answered", "counter",
     ),
+    # engine: megakernel | native, the rung that turned the run away; reason:
+    # a row of select.DECLINES, or the envelope's token (U, A, R, vmem, topo_keys, ...)
+    "simon_engine_declined_total": (
+        "Streams and sweeps a faster engine turned away, by that engine and the short token of its reason", "counter",
+    ),
     # engine: megakernel | native | xla; kind: fraction | whole | multi
     "simon_gpushare_pods_total": (
         "Pods placed with a gpu-share request by the engine that answered and by kind: a fraction of a device, one whole device, several slots",
@@ -579,6 +584,9 @@ class MetricsRecorder:
         # masked simulations by answering engine; megakernel over all is
         # how often the planner's final pass engages the kernel
         self.masked_pass = make_counter("simon_masked_pass_total", ("engine",))
+        # runs that reached a slower rung because a faster one declined them
+        # (engine/select.py turned_away): the rung and its reason's token
+        self.engine_declined = make_counter("simon_engine_declined_total", ("engine", "reason"))
         # pods placed with a gpu-share request, by answering engine and kind
         # (fraction of a device, one whole device, several slots)
         self.gpushare_pods = make_counter("simon_gpushare_pods_total", ("engine", "kind"))
@@ -658,6 +666,10 @@ class MetricsRecorder:
         with self.lock:
             self.masked_pass.inc((engine,))
 
+    def count_engine_declined(self, engine: str, reason: str) -> None:
+        with self.lock:
+            self.engine_declined.inc((engine, reason))
+
     def count_gpushare_pods(self, engine: str, by_kind: Dict[str, int]) -> None:
         with self.lock:
             for kind, n in by_kind.items():
@@ -676,6 +688,7 @@ class MetricsRecorder:
                 + self.resident_carry.render_lines()
                 + self.engine_features.render_lines()
                 + self.masked_pass.render_lines()
+                + self.engine_declined.render_lines()
                 + self.gpushare_pods.render_lines()
                 + self.yaml_documents.render_lines()
                 + self.phase_seconds.render_lines()
@@ -692,6 +705,7 @@ class MetricsRecorder:
             self.resident_carry.reset()
             self.engine_features.reset()
             self.masked_pass.reset()
+            self.engine_declined.reset()
             self.gpushare_pods.reset()
             self.yaml_documents.reset()
             self.watch_apply.reset()

@@ -129,7 +129,8 @@ def sweep_auto(
         config = distinct.pop() if distinct else None
     from ..obs import trace as obs
 
-    rungs = select.ladder(prep, select.Ask(shape="sweep", sched_config=config))
+    pol, ask = select.policy(), select.Ask(shape="sweep", sched_config=config)
+    rungs = select.ladder(prep, ask, pol)
     if rungs["native"] is None:
         from ..engine import nativepath
 
@@ -156,9 +157,13 @@ def sweep_auto(
             )
         except Exception as e:  # opensim-lint: disable=exception-swallow (kernel_failed raises or logs)
             select.kernel_failed(e, "sweep")  # demoted: the XLA sweep below computes the same
+    from ..obs.metrics import RECORDER
     from ..obs.profile import launch_span
 
-    with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices())):
+    away = select.turned_away(prep, ask, pol, rungs)
+    if away is not None:
+        RECORDER.count_engine_declined(*away)
+    with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices()), **select.decline_attrs(prep, away)):
         with launch_span("xla.launch", scenarios=S, pods=len(prep.tmpl_ids)):
             res = sweep(
                 prep.ec,

@@ -323,3 +323,68 @@ def test_a_plain_simulate_on_the_cpu_does_not_import_pallas():
         [sys.executable, "-c", code], cwd=ROOT, env=env, capture_output=True, text=True, timeout=300
     )
     assert done.returncode == 0, done.stderr[-2000:]
+
+
+# id -> (policy, ask, the rung that turned the run away and the token of its reason)
+TURNED_AWAY = {
+    "the_kernel_serves_it": ({"env": INTERPRET}, {}, None),
+    # a rung the policy switched off turned nothing away; the next one is asked
+    "no_tpu_and_the_cpp_scan_serves_it": ({}, {}, None),
+    "backend_xla": ({"env": BACKEND_XLA}, {"explain": True}, None),
+    # the row's name in DECLINES
+    "batch": ({"env": INTERPRET}, {"shape": "batch"}, ("megakernel", "batch")),
+    "many_devices": ({"platform": "tpu", "devices": 4}, {"shape": "sweep"}, ("megakernel", "many_devices")),
+    "segments": ({"env": INTERPRET}, {"segments": 2}, ("megakernel", "segments")),
+    "explain": ({"env": INTERPRET}, {"explain": True}, ("megakernel", "explain")),
+    "sched_config": ({"env": INTERPRET}, {"sched_config": WEIGHTED}, ("megakernel", "sched_config")),
+    "extra_plugins": ({"env": INTERPRET}, {"extra_plugins": PLUGIN}, ("megakernel", "extra_plugins")),
+    "tie_seed": ({"env": INTERPRET}, {"tie_seed": 7}, ("megakernel", "tie_seed")),
+    "start_state": ({"env": INTERPRET}, {"start_state": True}, ("megakernel", "start_state")),
+    "the_first_row_that_applies": ({"env": INTERPRET}, {"explain": True, "tie_seed": 7}, ("megakernel", "explain")),
+    # with the kernel switched off, the C++ scan is the rung that can turn a run away
+    "native_many_devices": ({}, {"shape": "sweep"}, ("native", "many_devices")),
+    "native_extra_plugins": ({}, {"extra_plugins": PLUGIN}, ("native", "extra_plugins")),
+    "native_fit_ignored_cols": (
+        {}, {"sched_config": DEFAULT_CONFIG._replace(fit_ignored_cols=(2,))}, ("native", "fit_ignored_cols"),
+    ),
+    "native_not_built": ({"built": False}, {}, ("native", "not_built")),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TURNED_AWAY))
+def test_turned_away(case, prep, monkeypatch):
+    pol, ask, want = TURNED_AWAY[case]
+    _policy(monkeypatch, **pol)
+    ask, pol = select.Ask(**ask), select.policy()
+    rungs = select.ladder(prep, ask, pol)
+    assert select.turned_away(prep, ask, pol, rungs) == want
+    attrs = select.decline_attrs(prep, want)
+    if want is None:
+        assert attrs == {}
+    else:
+        assert attrs == {"declined": ":".join(want), "templates": 1, "selectors": 1, "pinned_pods": 0}
+
+
+# the words of fastpath.why_not -> the token
+ENVELOPE = {
+    "table sizes outside envelope: U=10450 > 2048 supported, A=5300 > 64 supported": "U+A",
+    "table sizes outside envelope: U=3001 > 2048 supported": "U",
+    "table sizes outside envelope: A=80 > 64 supported": "A",
+    "table sizes outside envelope: R=9 > 8 supported, U=2120 > 2048 supported": "R+U",
+    "VMEM estimate 12.3 MB exceeds the 10 MB budget": "vmem",
+    "5 non-hostname topology keys > 4 supported": "topo_keys",
+    "port-vocab ids >=64 exceed the 64 padded port rows": "features",
+    "some valid nodes carry no hostname label": "features",
+}
+
+
+@pytest.mark.parametrize("reason", sorted(ENVELOPE))
+def test_the_kernels_envelope_as_a_token(reason, prep, monkeypatch):
+    from opensim_tpu.engine import fastpath
+
+    _policy(monkeypatch, env=INTERPRET)
+    monkeypatch.setattr(fastpath, "why_not", lambda prep, config=None: reason)
+    pol = select.policy()
+    rungs = select.ladder(prep, select.Ask(), pol)
+    assert rungs["megakernel"] == reason
+    assert select.turned_away(prep, select.Ask(), pol, rungs) == ("megakernel", ENVELOPE[reason])

@@ -643,7 +643,10 @@ class SerialScheduler:
 
     @staticmethod
     def _owner_selector(pod: Pod):
-        if pod.metadata.annotations.get("simon/workload-kind") and pod.metadata.labels:
+        # kube's DefaultSelector finds a selector for the pods of a ReplicaSet
+        # (a Deployment's), an RC or a StatefulSet; a Job's or a DaemonSet's have none
+        kind = pod.metadata.annotations.get("simon/workload-kind")
+        if kind in ("ReplicaSet", "ReplicationController", "StatefulSet") and pod.metadata.labels:
             return {"matchLabels": dict(pod.metadata.labels)}
         return None
 

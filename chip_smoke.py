@@ -12,8 +12,9 @@ repo's headline size, and checks what comes out by the repo's own means:
                 Pass: pods per (node, workload) identical to plan-fit — the
                 compiled kernel against the reference engine at full size.
   plan-short    the same apps on a cluster that is too small, with a newNode
-                template: discarded megakernel pass, XLA scan, count sweep,
-                masked final pass. Pass: ``(added K new node(s))``.
+                template: a megakernel pass kept without failure reasons
+                (none are asked), count sweep, masked final pass. Pass:
+                ``(added K new node(s))``.
   plan-short-xla  the same input with --backend xla. Pass: the same K.
   server        simon server --backend tpu against a stub apiserver holding
                 3,000 nodes / 30,000 bound pods; a handful of deploy-apps
@@ -160,8 +161,9 @@ def write_plan_inputs(out: str, size: dict, seed: int) -> dict:
     rng.shuffle(order)
     fit_nodes = [node_doc(f"node-{order[i]:05d}", i, "256") for i in range(size["nodes"])]
     # the short cluster (sizing: see FULL): the ssd-only workloads run out of
-    # room mid-stream while later workloads still bind, which is what makes
-    # simulate() discard the megakernel pass and re-scan for exact attribution
+    # room mid-stream while later workloads still bind: a caller that reads
+    # failure reasons would have the megakernel pass discarded and re-scanned
+    # for exact attribution; the plan has a newNode template and asks for none
     short_nodes = [
         node_doc(f"node-{order[i]:05d}", i, str(size["ssd_cap"]) if i % 3 else "256")
         for i in range(size["short_nodes"])

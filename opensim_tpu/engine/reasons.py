@@ -110,6 +110,16 @@ def pending_observed() -> str:
     return PENDING_OBSERVED
 
 
+# a pod the megakernel left unplaced in a run whose caller asked for no
+# reasons (``simulate(reasons=False)``: the planner's first pass with a
+# newNode template): nothing counted its filters, so nothing is rendered
+NOT_ATTRIBUTED = "unschedulable (failure reasons were not asked of this simulation)"
+
+
+def not_attributed() -> str:
+    return NOT_ATTRIBUTED
+
+
 @dataclass
 class ReasonCount:
     """One line of a FitError breakdown: ``count`` nodes rejected for

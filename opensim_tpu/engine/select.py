@@ -68,6 +68,9 @@ class Ask(NamedTuple):
     tie_seed: Optional[int] = None
     node_mask: bool = False
     start_state: bool = False  # the stream starts from the caller's ScanState, not prep.st0
+    # the caller reads why a pod failed. No rung is chosen by it: the ladder's one
+    # question is whether a kernel result with a mid-stream failure is re-scanned
+    reasons: bool = True
 
 
 _SHARDED = "{devices} devices: the sweep is sharded across them on the XLA scan"

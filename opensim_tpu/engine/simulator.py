@@ -96,6 +96,12 @@ class EngineDecision:
     explanations: Optional[list] = None
     filter_rejects: Optional[Dict[str, int]] = None
     explain_ctx: Optional[object] = None
+    # what became of the failure reasons of a clean megakernel run: ``none``
+    # (no pod failed), ``tail`` (exact, from the final carry), ``not_asked``
+    # (the result kept without reasons: ``simulate(reasons=False)``) or
+    # ``rescan`` (discarded for a scan that attributes); None where the
+    # kernel did not run
+    attribution: Optional[str] = None
 
     def describe(self) -> str:
         base = self.name if self.native_path is None else f"{self.name}/{self.native_path}"
@@ -579,18 +585,19 @@ def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, ski
 
 def _run_engine_ladder(
     prep, segments, sched_config, pod_valid, forced, tmpl_ids, extra_plugins,
-    tie_seed, nv_mask, ec, st0, log, explain=False,
+    tie_seed, nv_mask, ec, st0, log, explain=False, reasons=True,
 ):
     """The engine fallback ladder (megakernel → C++ native → XLA scan) for
     one prepared stream: ``select`` says which rungs may run; here are the
     breaker gating and the runtime demotion. Returns ``(out, engine_name,
-    skips, sf_rows)``. Split out of ``simulate`` so the whole ladder sits
-    under one traced ``schedule`` span with a child span per engine actually
+    skips, sf_rows, attribution)``, the last as ``EngineDecision`` has it.
+    Split out of ``simulate`` so the whole ladder sits under one traced
+    ``schedule`` span with a child span per engine actually
     *attempted* (ISSUE 5) — a skipped rung gets a demotion event, not a span."""
     from ..obs import trace as obs
     from ..obs.metrics import RECORDER
 
-    out = None
+    out = attribution = None
     engine_name = "xla"
     skips: Dict[str, str] = {}
     # what chose the kernels' signature, on the span of the rung that ran
@@ -606,7 +613,7 @@ def _run_engine_ladder(
     ask = select.Ask(
         segments=None if segments is None else len(segments), explain=explain,
         sched_config=sched_config, extra_plugins=extra_plugins, tie_seed=tie_seed,
-        node_mask=nv_mask is not None,
+        node_mask=nv_mask is not None, reasons=reasons,
     )
     rungs = select.ladder(prep, ask, pol)
     sf_rows = tmpl_ids  # decode: static_fail row per pod
@@ -630,7 +637,7 @@ def _run_engine_ladder(
         from . import fastpath
 
         try:  # identical placements at ~4× the XLA scan's step rate
-            with obs.span("engine.megakernel", **shape):
+            with obs.span("engine.megakernel", **shape) as rung:
                 f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev = fastpath.schedule(
                     prep, tmpl_ids, pod_valid, forced, node_valid=nv_mask
                 )
@@ -646,9 +653,14 @@ def _run_engine_ladder(
             f_chosen = None
         if f_chosen is not None:
             failed = (f_chosen < 0) & pod_valid & ~forced
-            if not failed.any():
+            any_failed = bool(failed.any())
+            if not any_failed or not ask.reasons:
+                # nothing to attribute, or a caller that reads no reasons (the
+                # planner's first pass with a newNode template): the result is
+                # kept, and decode gives its failed pods reasons.not_attributed()
                 out = _fast_output(f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev, prep)
                 engine_name = "megakernel"
+                attribution = "not_asked" if any_failed else "none"
             else:
                 # Failure reasons without a second full scan: exact
                 # whenever nothing bound after the first failure (the
@@ -664,12 +676,16 @@ def _run_engine_ladder(
                         out, prep, np.nonzero(failed)[0], nv_mask=nv_mask
                     )
                     engine_name = "megakernel"
+                    attribution = "tail"
                 else:
                     skips["megakernel"] = (
                         "mid-stream scheduling failures need exact "
                         "in-stream attribution (full re-scan engine)"
                     )
                     log.info("megakernel result discarded: %s", skips["megakernel"])
+                    attribution = "rescan"
+            rung.set(attribution=attribution)
+            RECORDER.count_megakernel_attribution(attribution)
     if out is None:
         from . import nativepath
 
@@ -721,7 +737,7 @@ def _run_engine_ladder(
     RECORDER.count_engine_features(engine_name, features)
     if nv_mask is not None:
         RECORDER.count_masked_pass(engine_name)
-    return out, engine_name, skips, sf_rows
+    return out, engine_name, skips, sf_rows, attribution
 
 
 _REASON_EVENT_CAP = 8  # per-pod unschedulable events per schedule span
@@ -729,7 +745,7 @@ _REASON_EVENT_CAP = 8  # per-pod unschedulable events per schedule span
 
 def _schedule_reason_events(
     obs, out, ordered, tmpl_ids, pod_valid, forced, sf_rows, meta, nv_mask,
-    chosen=None, exclude=frozenset(),
+    chosen=None, exclude=frozenset(), attributed=True,
 ):
     """Decision telemetry on the span tree (ISSUE 7): one instant event per
     unschedulable pod (capped at :data:`_REASON_EVENT_CAP`) plus a
@@ -738,7 +754,9 @@ def _schedule_reason_events(
     the schedule span; preemption runs pass the post-preemption ``chosen``
     (and the victim set to ``exclude`` — victims fail by eviction, not by a
     filter) so the events never contradict the response. A no-op without an
-    ambient trace or without failures."""
+    ambient trace or without failures. ``attributed=False`` (a kernel result
+    kept under ``reasons=False``): the count alone, ``attribution="not_asked"``,
+    and nothing rendered from rows that no engine filled."""
     if obs.current_trace() is None:
         return
     P = len(ordered)
@@ -755,6 +773,9 @@ def _schedule_reason_events(
     n_nodes = int(nv_mask.sum()) if nv_mask is not None else meta.n_real_nodes
     idx = np.array([i for i in np.nonzero(failed)[0] if int(i) not in exclude])
     if not len(idx):
+        return
+    if not attributed:
+        obs.event("placement.reasons", unschedulable=int(len(idx)), attribution="not_asked")
         return
     hist = explain_mod.primary_reason_histogram(static_fail, sf_rows, fail_counts, idx)
     obs.event(
@@ -804,6 +825,7 @@ def simulate(
     drop_pods: Optional[np.ndarray] = None,
     deadline: Optional[Deadline] = None,
     explain: bool = False,
+    reasons: bool = True,
 ) -> SimulateResult:
     """One full simulation: cluster pods then apps in order. `sched_config`
     is an optional SchedulerConfig (the --default-scheduler-config merge);
@@ -840,7 +862,15 @@ def simulate(
     ``explain_ctx`` for the deep evaluator). Runs on the C++ generic path
     or the XLA count_all scan — engine-consistent by the reason-parity
     gate — and costs nothing when False (the default compiled scan and the
-    incremental C++ path are untouched)."""
+    incremental C++ path are untouched).
+
+    `reasons=False`: the caller reads which pods failed and never why (the
+    planner's first pass when a newNode template exists). A megakernel
+    result with a mid-stream failure is then kept, not re-scanned for
+    attribution, and its failed pods carry ``reasons.not_attributed()``;
+    engines that attribute as a by-product answer as ever. Placements and
+    the unscheduled set are the same either way. Preemption reads the carry
+    a failed pod left, so with `enable_preemption` reasons are always asked."""
     from ..obs import trace as obs
 
     if deadline is not None:
@@ -854,7 +884,7 @@ def simulate(
                 sched_config=sched_config, patch_pods_fn=patch_pods_fn,
                 extra_plugins=extra_plugins, enable_preemption=enable_preemption,
                 tie_seed=tie_seed, prep=prep, node_valid=node_valid,
-                drop_pods=drop_pods, explain=explain,
+                drop_pods=drop_pods, explain=explain, reasons=reasons,
             )
 
     _validate_extra_plugins(extra_plugins)
@@ -937,9 +967,10 @@ def simulate(
     log = logging.getLogger("opensim_tpu")
     check_deadline("schedule")
     with obs.span("schedule", pods=len(ordered)) as _sched_span:
-        out, engine_name, skips, sf_rows = _run_engine_ladder(
+        out, engine_name, skips, sf_rows, attribution = _run_engine_ladder(
             prep, segments, sched_config, pod_valid, forced, tmpl_ids,
             extra_plugins, tie_seed, nv_mask, ec, st0, log, explain=explain,
+            reasons=reasons or enable_preemption,
         )
         nstats = getattr(out, "native_stats", None)
         engine = EngineDecision(
@@ -947,6 +978,7 @@ def simulate(
             skipped=skips,
             native_path=nstats["path"] if nstats else None,
             native_steps=dict(nstats["steps"]) if nstats else None,
+            attribution=attribution,
         )
         # every rung that did NOT run is an instant demotion span, so
         # the flight-recorder tree carries exactly the attribution
@@ -960,7 +992,7 @@ def simulate(
             # would report pods the preempt pass later schedules
             _schedule_reason_events(
                 obs, out, ordered, tmpl_ids, pod_valid, forced, sf_rows,
-                meta, nv_mask,
+                meta, nv_mask, attributed=attribution != "not_asked",
             )
     check_deadline("decode")
     with obs.span("decode", pods=len(ordered)):
@@ -1067,17 +1099,19 @@ def finish_decode(
     # bucket (chosen never points at an invalid node)
     pod_lists = [node_pods.get(n) for n in node_names]
     gpu_ids = _gpu_device_ids(prep, chosen, gpu_take, decode_drops, engine_name)
+    # a kernel result kept under reasons=False: its count rows are zeros no engine filled
+    attributed = engine.attribution != "not_asked"
 
     with gc_paused():
         statuses = _decode(
             ordered, chosen, forced, custom_reasons, victims_of, gpu_ids,
             sf_rows, static_fail, fail_counts, insufficient, meta, n_nodes,
             node_names, pod_lists, node_pods, unscheduled, cluster, out,
-            decode_drops,
+            decode_drops, attributed,
         )
     _record_decision_metrics(
         chosen, pod_valid, forced, custom_reasons, victims_of, drops,
-        static_fail, sf_rows, fail_counts,
+        static_fail, sf_rows, fail_counts, attributed,
     )
     if explain:
         from . import explain as explain_mod
@@ -1110,26 +1144,29 @@ def finish_decode(
 
 def _record_decision_metrics(
     chosen, pod_valid, forced, custom_reasons, victims_of, drops,
-    static_fail, sf_rows, fail_counts,
+    static_fail, sf_rows, fail_counts, attributed=True,
 ):
     """Always-on decision counters (ISSUE 7, /metrics):
     ``simon_unschedulable_total{reason=}`` — pods by primary reason — and
     ``simon_filter_reject_total{filter=}`` — node-level rejects from the
     failure attribution every engine computes for unschedulable pods.
     Independent of explain mode so dashboards see identical series either
-    way."""
+    way. Pods of a result kept without reasons (``attributed=False``) count
+    under ``not_attributed`` and reject no node."""
     from ..obs.metrics import RECORDER
     from . import explain as explain_mod
 
     failed = pod_valid & ~forced & (np.asarray(chosen) < 0)
-    attributed = [
+    by_filter = [
         int(i)
         for i in np.nonzero(failed)[0]
         if int(i) not in victims_of and int(i) not in custom_reasons
     ]
-    hist = explain_mod.primary_reason_histogram(
-        static_fail, sf_rows, fail_counts, attributed
-    )
+    if attributed:
+        hist = explain_mod.primary_reason_histogram(static_fail, sf_rows, fail_counts, by_filter)
+    else:
+        hist = {"not_attributed": len(by_filter)} if by_filter else {}
+        by_filter = []
     nnf = int((forced & (np.asarray(chosen) < 0) & pod_valid).sum())
     if nnf:
         hist["node_not_found"] = hist.get("node_not_found", 0) + nnf
@@ -1140,9 +1177,9 @@ def _record_decision_metrics(
         hist["preempted"] = hist.get("preempted", 0) + len(victims_of)
     if hist:
         RECORDER.count_unschedulable(hist)
-    if attributed:
+    if by_filter:
         mask = np.zeros(len(pod_valid), dtype=bool)
-        mask[attributed] = True
+        mask[by_filter] = True
         rejects = explain_mod.audit_rejects(static_fail, sf_rows, fail_counts, mask)
         RECORDER.count_filter_rejects(reasons.rejects_dict(rejects))
 
@@ -1243,7 +1280,7 @@ def _gpu_device_ids(prep, chosen, gpu_take, drop_pods, engine_name: str) -> Dict
 def _decode(
     ordered, chosen, forced, custom_reasons, victims_of, gpu_ids,
     sf_rows, static_fail, fail_counts, insufficient, meta, n_nodes,
-    node_names, pod_lists, node_pods, unscheduled, cluster, out, drop_pods=(),
+    node_names, pod_lists, node_pods, unscheduled, cluster, out, drop_pods=(), attributed=True,
 ):
     # Vectorized decode (ISSUE 16): one numpy pass classifies the whole
     # stream — dropped / placed / failed — and Python only touches the
@@ -1295,6 +1332,8 @@ def _decode(
                     ),
                 )
             )
+        elif not attributed:
+            unscheduled.append(UnscheduledPod(pod, reasons.not_attributed()))
         else:
             unscheduled.append(
                 UnscheduledPod(

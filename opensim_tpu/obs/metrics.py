@@ -182,6 +182,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_masked_pass_total": (
         "Simulations over a masked node set (the planner's prep reuse) by the engine that answered", "counter",
     ),
+    # outcome: none | tail | not_asked | rescan
+    "simon_megakernel_attribution_total": (
+        "Clean megakernel runs of a stream by what became of their failure reasons: no pod failed, exact from the final "
+        "carry, kept without reasons (the caller asked for none), or discarded for a scan that attributes", "counter",
+    ),
     # engine: megakernel | native, the rung that turned the run away; reason:
     # a row of select.DECLINES, or the envelope's token (U, A, R, vmem, topo_keys, ...)
     "simon_engine_declined_total": (
@@ -584,6 +589,9 @@ class MetricsRecorder:
         # masked simulations by answering engine; megakernel over all is
         # how often the planner's final pass engages the kernel
         self.masked_pass = make_counter("simon_masked_pass_total", ("engine",))
+        # clean kernel runs by what became of their failure reasons; rescan over
+        # all is how often a stream pays the kernel and a whole scan after it
+        self.megakernel_attribution = make_counter("simon_megakernel_attribution_total", ("outcome",))
         # runs that reached a slower rung because a faster one declined them
         # (engine/select.py turned_away): the rung and its reason's token
         self.engine_declined = make_counter("simon_engine_declined_total", ("engine", "reason"))
@@ -666,6 +674,10 @@ class MetricsRecorder:
         with self.lock:
             self.masked_pass.inc((engine,))
 
+    def count_megakernel_attribution(self, outcome: str) -> None:
+        with self.lock:
+            self.megakernel_attribution.inc((outcome,))
+
     def count_engine_declined(self, engine: str, reason: str) -> None:
         with self.lock:
             self.engine_declined.inc((engine, reason))
@@ -688,6 +700,7 @@ class MetricsRecorder:
                 + self.resident_carry.render_lines()
                 + self.engine_features.render_lines()
                 + self.masked_pass.render_lines()
+                + self.megakernel_attribution.render_lines()
                 + self.engine_declined.render_lines()
                 + self.gpushare_pods.render_lines()
                 + self.yaml_documents.render_lines()
@@ -705,6 +718,7 @@ class MetricsRecorder:
             self.resident_carry.reset()
             self.engine_features.reset()
             self.masked_pass.reset()
+            self.megakernel_attribution.reset()
             self.engine_declined.reset()
             self.gpushare_pods.reset()
             self.yaml_documents.reset()

@@ -329,17 +329,19 @@ class Applier:
             prep0 = prepare(cluster, apps, use_greed=self.opts.use_greed)
             snap0 = snapshot_bind_state(prep0) if prep0 is not None else None
         with Spinner("schedule pods"):
+            # with a newNode template this pass says which pods failed and is never asked why: its
+            # reasons are printed below only where there is none, and the report is the final pass's
             if prep0 is not None:
                 result = simulate(
                     cluster, apps, sched_config=self.sched_config,
                     tie_seed=self.tie_seed, prep=prep0,
-                    explain=self.opts.explain,
+                    explain=self.opts.explain, reasons=template is None,
                 )
             else:
                 result = simulate(
                     cluster, apps, use_greed=self.opts.use_greed, sched_config=self.sched_config,
                     enable_preemption=self.opts.enable_preemption, tie_seed=self.tie_seed,
-                    explain=self.opts.explain,
+                    explain=self.opts.explain, reasons=template is None,
                 )
         n_new = 0
         if result.unscheduled_pods or not satisfy_resource_setting(result)[0]:

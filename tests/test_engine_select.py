@@ -108,6 +108,15 @@ LADDER = {
         {"env": INTERPRET}, {"node_mask": True, "start_state": True},
         "a stream from the caller's scan state runs on the C++ engine or XLA scan", None,
     ),
+    # a caller that reads no failure reasons (ISSUE 36): every rung serves it as before; what it changes
+    # is whether the ladder re-scans a kernel result with a mid-stream failure, not which rung runs
+    "no_reasons_cpu": ({}, {"reasons": False}, NO_TPU, None),
+    "no_reasons_interpret": ({"env": INTERPRET}, {"reasons": False, "node_mask": True}, None, None),
+    "no_reasons_tpu": ({"platform": "tpu"}, {"reasons": False}, None, TPU_OWNS),
+    "no_reasons_explain": (
+        {"env": INTERPRET}, {"reasons": False, "explain": True},
+        "explain mode audits per-filter verdicts (C++/XLA engines)", None,
+    ),
     # --backend xla / native / tpu
     "backend_xla_cpu": ({"env": BACKEND_XLA}, {}, NO_TPU, XLA_OFF_NATIVE),
     "backend_xla_tpu": ({"env": BACKEND_XLA, "platform": "tpu"}, {}, XLA_OFF_MK, XLA_OFF_NATIVE),
@@ -167,6 +176,9 @@ def test_carry(case, prep):
     ask, has_base, want = CARRY[case]
     prep = dataclasses.replace(prep, resident_base=object() if has_base else None)
     assert select.carry(prep, select.Ask(**ask)) == want
+    # failure reasons are asked by default, and no answer of this module depends on them
+    assert select.Ask(**ask).reasons is True
+    assert select.carry(prep, select.Ask(**ask, reasons=False)) == want
 
 
 # id -> (policy, engine, skips) or (policy, the error)

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 import yaml
 
-from opensim_tpu.engine import fastpath
+from opensim_tpu.engine import fastpath, reasons, simulator
 from opensim_tpu.engine.simulator import AppResource, prepare, simulate
 from opensim_tpu.models import ResourceTypes, expand, fixtures as fx
 from opensim_tpu.obs import trace as tracing
@@ -85,15 +85,21 @@ class Plan:
         sub.nodes = self.cluster.nodes + self.candidates[:k]
         return sub
 
-    def masked(self, k):
+    def masked(self, k, **asked):
         """A masked simulate over a fresh full Prepared (decode binds its
         pods); returns the result and each stream pod's node, in stream
         order, which two Prepareds of the same inputs share."""
-        prep = prepare(self.full, self.apps)
+        prep = self.prep = prepare(self.full, self.apps)
         mask = np.zeros(np.asarray(prep.ec_np.node_valid).shape[0], bool)
         mask[: 6 + k] = True
-        res = simulate(self.sub(k), self.apps, prep=prep, node_valid=mask)
+        res = simulate(self.sub(k), self.apps, prep=prep, node_valid=mask, **asked)
         return res, mask, [p.spec.node_name or None for p in prep.ordered]
+
+    def plain(self, **asked):
+        """The same over the cluster alone, unmasked: the planner's first pass."""
+        prep = self.prep = prepare(self.cluster, self.apps)
+        res = simulate(self.cluster, self.apps, prep=prep, **asked)
+        return res, [p.spec.node_name or None for p in prep.ordered]
 
 
 def _per_node_counts(res):
@@ -197,7 +203,7 @@ def test_a_masked_schedule_is_its_row_of_a_sweep_under_the_same_masks():
         assert static_fail[prep.tmpl_ids[0]].sum() == 6 + ks[s] - 1
 
 
-def _write_plan(tmp_path, plan):
+def _write_plan(tmp_path, plan, new_node=True):
     dirs = {name: tmp_path / name for name in ("cluster", "app", "newnode")}
     for d in dirs.values():
         d.mkdir()
@@ -215,7 +221,7 @@ def _write_plan(tmp_path, plan):
         "spec": {
             "cluster": {"customConfig": str(dirs["cluster"])},
             "appList": [{"name": app.name, "path": str(dirs["app"] / app.name)} for app in plan.apps],
-            "newNode": str(dirs["newnode"]),
+            **({"newNode": str(dirs["newnode"])} if new_node else {}),
         },
     }))
     return str(cfg)
@@ -230,9 +236,10 @@ def _pod_table(text):
 
 
 def test_a_short_plan_ends_in_one_masked_megakernel_pass_and_reports_the_same(monkeypatch, tmp_path):
-    """`plan-short` in small: pods fail mid-stream, so the first pass's kernel
-    result is discarded for the scan's; the count is searched; the final pass
-    over the masked Prepared is the kernel's, and no second scan runs."""
+    """`plan-short` in small: pods fail mid-stream, and with a `newNode`
+    template the first pass is asked for no reasons, so the kernel's result is
+    kept (ISSUE 36); the count is searched; the final pass over the masked
+    Prepared is the kernel's, and no scan runs at all."""
     import re
 
     from opensim_tpu.planner.apply import Applier, Options
@@ -249,15 +256,18 @@ def test_a_short_plan_ends_in_one_masked_megakernel_pass_and_reports_the_same(mo
         assert rc == 0
         rungs = [(sp.name, sp.attrs["masked"]) for sp in tr.walk() if sp.name in ("engine.megakernel", "engine.xla")]
         text = out.read_text()
-        return rungs, int(re.search(r"\(added (\d+) new node\(s\)\)", text).group(1)), _pod_table(text)
+        assert reasons.not_attributed() not in text  # the first pass's failed pods are nobody's to print
+        attributions = [sp.attrs["attribution"] for sp in tr.walk() if sp.name == "engine.megakernel"]
+        return rungs, int(re.search(r"\(added (\d+) new node\(s\)\)", text).group(1)), _pod_table(text), attributions
 
-    rungs, added, table = run("on.txt")
-    assert rungs == [("engine.megakernel", False), ("engine.xla", False), ("engine.megakernel", True)]
+    rungs, added, table, attributions = run("on.txt")
+    assert rungs == [("engine.megakernel", False), ("engine.megakernel", True)]
+    assert attributions == ["not_asked", "none"]
     assert 0 < added < N_CANDIDATES
     assert "Scheduling engine: megakernel" in table
 
     _kernel_off(monkeypatch)
-    rungs, added_off, table_off = run("off.txt")
+    rungs, added_off, table_off, _ = run("off.txt")
     assert rungs == [("engine.xla", False), ("engine.xla", True)]
     assert added_off == added
     assert [l for l in table if not l.startswith("Scheduling engine:")] == [
@@ -278,5 +288,175 @@ def test_masked_passes_are_counted_by_the_engine_that_answered(monkeypatch):
         assert "# TYPE simon_masked_pass_total counter" in lines
         assert 'simon_masked_pass_total{engine="megakernel"} 1' in lines
         assert 'simon_masked_pass_total{engine="xla"} 2' in lines
+    finally:
+        RECORDER.reset()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 36: failure reasons are part of what a caller asks
+# ---------------------------------------------------------------------------
+
+MIDSTREAM = dict(web=120, spread=4, db=2)  # web overfills every node, spread still binds, db fails again
+TAIL = dict(db=20, template_ssd=False)  # the ssd pool fills last: nothing binds after the first failure
+
+
+def _unscheduled(res, plan):
+    """The unscheduled pods in the result's order, as positions in the stream
+    of the plan's last Prepared (names are numbered anew in every expansion)."""
+    at = {id(p): i for i, p in enumerate(plan.prep.ordered)}
+    return [(at[id(u.pod)], u.pod.metadata.labels.get("app")) for u in res.unscheduled_pods]
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["first_pass", "masked"])
+def test_a_stream_asked_for_no_reasons_keeps_the_kernels_result_and_places_the_same(masked):
+    plan = Plan(**MIDSTREAM)
+    run = (lambda **kw: plan.masked(1, **kw)[::2]) if masked else plan.plain
+    asked, asked_nodes = run()
+    asked_unscheduled = _unscheduled(asked, plan)
+    quiet, quiet_nodes = run(reasons=False)
+    # asked: today's path, the kernel's result thrown away for the scan's exact strings
+    assert asked.engine.name == "xla" and asked.engine.attribution == "rescan"
+    assert "mid-stream scheduling failures" in asked.engine.skipped["megakernel"]
+    n = 7 if masked else 6
+    assert asked.unscheduled_pods and all(r.startswith(f"0/{n} nodes are available: ") for r in _reasons(asked))
+    # not asked: the kernel's own result, nothing skipped, pod for pod the same
+    assert quiet.engine.name == "megakernel" and quiet.engine.skipped == {}, quiet.engine.describe()
+    assert quiet.engine.attribution == "not_asked"
+    assert quiet_nodes == asked_nodes
+    assert _unscheduled(quiet, plan) == asked_unscheduled
+    assert [ns.node.metadata.name for ns in quiet.node_status] == [ns.node.metadata.name for ns in asked.node_status]
+    assert _per_node_counts(quiet) == _per_node_counts(asked)
+    assert [ns.node.metadata.annotations for ns in quiet.node_status] == [ns.node.metadata.annotations for ns in asked.node_status]
+    # and no reason that looks like one: zeros are never decoded into a string
+    assert {u.reason for u in quiet.unscheduled_pods} == {reasons.not_attributed()}
+    assert "nodes are available" not in reasons.not_attributed()
+
+
+def test_reasons_are_asked_unless_the_caller_says_otherwise(monkeypatch):
+    """The default, at every layer: `Ask`, `simulate` and the `Ask` the ladder
+    builds from it; and a run with preemption asks whatever it is told."""
+    from opensim_tpu.engine import select
+
+    assert select.Ask().reasons is True
+    asks = []
+    ladder = select.ladder
+    monkeypatch.setattr(select, "ladder", lambda prep, ask, pol=None: asks.append(ask) or ladder(prep, ask, pol))
+    plan = Plan(**MIDSTREAM)
+    res, _ = plan.plain()
+    assert [a.reasons for a in asks] == [True] and res.engine.name == "xla"
+    res, _ = plan.plain(reasons=False)
+    assert [a.reasons for a in asks] == [True, False] and res.engine.name == "megakernel"
+    # preemption reads the carry a failed pod left: it always asks
+    res = simulate(plan.cluster, plan.apps, enable_preemption=True, reasons=False)
+    assert asks[-1].reasons is True and res.engine.attribution == "rescan"
+    assert reasons.not_attributed() not in _reasons(res)
+
+
+def _apply(config, out):
+    from opensim_tpu.planner.apply import Applier, Options
+
+    tr = tracing.start_trace("apply", force=True)
+    with tracing.trace_scope(tr):
+        rc = Applier(Options(simon_config=config, output_file=str(out), max_new_nodes=N_CANDIDATES)).run()
+    tr.finish()
+    return tr, rc
+
+
+def test_a_plan_without_a_template_prints_the_scans_reasons(monkeypatch, tmp_path):
+    import re
+
+    config = _write_plan(tmp_path, Plan(**MIDSTREAM), new_node=False)
+    tr, rc = _apply(config, tmp_path / "on.txt")
+    assert rc == 1
+    rungs = [sp.name for sp in tr.walk() if sp.name in ("engine.megakernel", "engine.xla")]
+    assert rungs == ["engine.megakernel", "engine.xla"]
+    assert [sp.attrs["attribution"] for sp in tr.walk() if sp.name == "engine.megakernel"] == ["rescan"]
+    lines = (tmp_path / "on.txt").read_text().splitlines()
+    assert lines[0] == "Simulation failed: pods are unschedulable and no newNode is configured:"
+    assert len(lines) > 1 and all(": 0/6 nodes are available: " in l for l in lines[1:]), lines[:3]
+    _kernel_off(monkeypatch)
+    _, rc = _apply(config, tmp_path / "off.txt")
+    assert rc == 1
+    numbered = lambda text: [re.sub(r"(-[0-9a-f]{10})+", "*", line) for line in text.splitlines()]
+    assert numbered((tmp_path / "off.txt").read_text()) == numbered("\n".join(lines))
+
+
+def test_the_prompt_loop_asks_for_the_reasons_it_shows(tmp_path):
+    import io
+
+    from opensim_tpu.planner.apply import Applier, Options
+
+    config = _write_plan(tmp_path, Plan(**MIDSTREAM))  # a template is there, and the prompt still prints why
+    applier = Applier(Options(simon_config=config, interactive=True))
+    applier.out = io.StringIO()
+    script = iter(["show", "exit"])
+    applier.input_fn = lambda: next(script)
+    tr = tracing.start_trace("apply", force=True)
+    with tracing.trace_scope(tr):
+        applier.run()
+    tr.finish()
+    assert [sp.attrs["attribution"] for sp in tr.walk() if sp.name == "engine.megakernel"] == ["rescan"]
+    text = applier.out.getvalue()
+    assert ": 0/6 nodes are available: " in text and reasons.not_attributed() not in text
+
+
+def test_a_tail_failure_not_asked_about_evaluates_no_reasons(monkeypatch):
+    plan = Plan(**TAIL)
+    asked, _, asked_nodes = plan.masked(1)
+    asked_unscheduled = _unscheduled(asked, plan)
+    assert asked.engine.name == "megakernel" and asked.engine.attribution == "tail"
+
+    def never(*a, **kw):
+        raise AssertionError("reasons were evaluated for a caller that reads none")
+
+    monkeypatch.setattr(simulator, "_fast_failure_details", never)
+    quiet, _, quiet_nodes = plan.masked(1, reasons=False)
+    assert quiet.engine.name == "megakernel" and quiet.engine.attribution == "not_asked"
+    assert quiet_nodes == asked_nodes and _unscheduled(quiet, plan) == asked_unscheduled
+    assert {u.reason for u in quiet.unscheduled_pods} == {reasons.not_attributed()}
+
+
+ATTRIBUTIONS = {
+    "none": (dict(), dict()),
+    "tail": (TAIL, dict()),
+    "not_asked": (MIDSTREAM, dict(reasons=False)),
+    "rescan": (MIDSTREAM, dict()),
+}
+
+
+@pytest.mark.parametrize("outcome", sorted(ATTRIBUTIONS))
+def test_the_kernels_span_and_metrics_say_what_became_of_the_reasons(outcome):
+    sizes, asked = ATTRIBUTIONS[outcome]
+    plan = Plan(**sizes)
+    RECORDER.reset()
+    tr = tracing.start_trace("simulate", force=True)
+    try:
+        with tracing.trace_scope(tr):
+            res, _, _ = plan.masked(1, **asked)
+        tr.finish()
+        assert res.engine.attribution == outcome
+        assert [sp.attrs["attribution"] for sp in tr.walk() if sp.name == "engine.megakernel"] == [outcome]
+        lines = RECORDER.render_lines()
+        assert "# TYPE simon_megakernel_attribution_total counter" in lines
+        assert [l for l in lines if l.startswith("simon_megakernel_attribution_total{")] == [
+            f'simon_megakernel_attribution_total{{outcome="{outcome}"}} 1'
+        ]
+        events = [sp for sp in tr.walk() if sp.name in ("placement.reasons", "placement.unschedulable")]
+        unschedulable = [l for l in lines if l.startswith("simon_unschedulable_total{")]
+        rejects = [l for l in lines if l.startswith("simon_filter_reject_total{")]
+        if outcome == "none":
+            assert not events and not unschedulable and not rejects
+        elif outcome == "not_asked":
+            # the count, and nothing built from rows no engine filled
+            assert [(sp.name, sp.attrs) for sp in events] == [
+                ("placement.reasons", {"unschedulable": len(res.unscheduled_pods), "attribution": "not_asked"})
+            ]
+            assert unschedulable == [f'simon_unschedulable_total{{reason="not_attributed"}} {len(res.unscheduled_pods)}']
+            assert not rejects
+        else:
+            assert events[0].name == "placement.reasons" and "attribution" not in events[0].attrs
+            assert any(k.startswith("reason_") for k in events[0].attrs)
+            assert len(events) == 1 + min(len(res.unscheduled_pods), 8)
+            assert unschedulable and rejects and not any("not_attributed" in l for l in unschedulable)
     finally:
         RECORDER.reset()

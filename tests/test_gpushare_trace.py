@@ -150,8 +150,12 @@ def test_the_plan_replays_through_the_reference_pod_for_pod_and_device_for_devic
     assert 0 < max(devices.values()) <= 1000 * MI and min(devices.values()) >= 0
     if named == "megakernel":
         launches = spans_of(window, "mk.launch")
-        # the discarded pass, the two sweeps (one where the bracket is closed) and the masked final pass
+        # the first pass, the two sweeps (one where the bracket is closed) and the masked final pass
         assert 3 <= len(launches) <= 4 and all(sp["attrs"]["big_u"] is big_u for sp in launches)
+        # a newNode template is there, so the first pass is asked for no reasons: its result is kept as it
+        # is, tasks that fit nowhere and all, and no XLA scan is left in the plan (ISSUE 36)
+        assert not spans_of(window, "engine.xla")
+        assert [sp["attrs"]["attribution"] for sp in spans_of(window, "engine.megakernel")] == ["not_asked", "none"]
 
 
 @pytest.mark.parametrize("name", ["xla", "native", "megakernel"])
@@ -342,8 +346,8 @@ def test_the_device_a_task_sits_on_worked_by_hand(monkeypatch, name, case):
     own, ref = reference_ids(cluster)
     assert own == want
     result = simulate_docs(nodes, pods)
-    # a task that fits nowhere with one after it that does: the kernel's pass is discarded for the
-    # XLA scan's attribution of the failure, as in the first pass of a short plan
+    # a task that fits nowhere with one after it that does: a caller that asks for reasons (the default)
+    # has the kernel's pass discarded for the XLA scan's attribution of the failure
     mid_stream = None in want[:-1] and want[-1] is not None
     assert result.engine.name == ("xla" if named == "megakernel" and mid_stream else named)
     got = placements(result)

@@ -174,7 +174,7 @@ def _ladder(prep, valid, **kw):
     """The XLA rung as simulate() reaches it, with the raw ScheduleOutput."""
     args = dict(segments=None, sched_config=None, extra_plugins=(), tie_seed=None, nv_mask=None, explain=False)
     args.update(kw)
-    out, engine, _skips, _rows = simulator._run_engine_ladder(
+    out, engine, _skips, _rows, _attribution = simulator._run_engine_ladder(
         prep, args["segments"], args["sched_config"], valid, prep.forced, prep.tmpl_ids,
         args["extra_plugins"], args["tie_seed"], args["nv_mask"], prep.ec, prep.st0,
         logging.getLogger("test"), explain=args["explain"],

@@ -19,7 +19,7 @@ import copy
 import os
 import sys
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, TextIO
+from typing import Callable, List, Optional, TextIO, Tuple
 
 import numpy as np
 
@@ -186,24 +186,43 @@ class Applier:
     def load_apps(self) -> List[AppResource]:
         apps = []
         for app in self.config.app_list:
+            name = app.get("name", "")
+            label = f"app:{name}"
             path = _resolve(self.base, app.get("path", ""))
             if app.get("chart"):
                 from ..chart.render import process_chart
 
-                contents = process_chart(app.get("name", ""), path)
-                docs = expand.decode_yaml_strings(contents)
+                docs = expand.decode_yaml_strings(process_chart(name, path), label)
             else:
-                docs = expand.load_yaml_objects(path)
-            rt, _ = expand.resources_from_dicts(docs)
-            apps.append(AppResource(name=app.get("name", ""), resources=rt))
+                docs = expand.load_yaml_objects(path, label)
+            rt, _ = expand.resources_from_dicts(docs, label)
+            apps.append(AppResource(name=name, resources=rt))
         return apps
 
     def load_new_node(self) -> Optional[Node]:
         if not self.config.new_node:
             return None
         path = _resolve(self.base, self.config.new_node)
-        rt = expand.load_cluster_from_dir(path)
+        rt = expand.load_cluster_from_dir(path, "new_node")
         return rt.nodes[0] if rt.nodes else None
+
+    def load(self) -> Tuple[ResourceTypes, List[AppResource], Optional[Node]]:
+        """The cluster, the apps and the newNode template, inside one `load`
+        span that sums its `load.parse` children: the inputs read, their
+        documents and their bytes."""
+        from ..utils.progress import Spinner
+
+        with obs.span("load") as sp:
+            with Spinner("load cluster"):
+                cluster = self.load_cluster()
+            with Spinner(f"render {len(self.config.app_list)} app(s)"):
+                apps = self.load_apps()
+            template = self.load_new_node()
+            if sp is not obs.NOOP_SPAN:
+                parsed = [c.attrs for c in sp.children if c.name == "load.parse"]
+                sp.set(inputs=len(parsed), documents=sum(a["documents"] for a in parsed),
+                       bytes=sum(a["bytes"] for a in parsed))
+        return cluster, apps, template
 
     # -- capacity search ----------------------------------------------------
 
@@ -311,11 +330,7 @@ class Applier:
         from ..utils.progress import Spinner
 
         initialize()  # no-op unless JAX_COORDINATOR is set (DCN scale-out)
-        with Spinner("load cluster"):
-            cluster = self.load_cluster()
-        with Spinner(f"render {len(self.config.app_list)} app(s)"):
-            apps = self.load_apps()
-        template = self.load_new_node()
+        cluster, apps, template = self.load()
 
         if self.opts.interactive:
             return self._run_interactive(cluster, apps, template)

@@ -1299,17 +1299,18 @@ class DeployStep(Step):
         return cls(index, name, app_name, path, bool(app.get("chart")), resources)
 
     def _load(self) -> ResourceTypes:
+        label = f"app:{self.app_name}"
         if self.resources is not None:
-            rt, _ = expand.resources_from_dicts(list(self.resources))
+            rt, _ = expand.resources_from_dicts(list(self.resources), label)
             return rt
         path = _resolve_path(self.path)
         if self.chart:
             from ..chart.render import process_chart
 
-            docs = expand.decode_yaml_strings(process_chart(self.app_name, path))
+            docs = expand.decode_yaml_strings(process_chart(self.app_name, path), label)
         else:
-            docs = expand.load_yaml_objects(path)
-        rt, _ = expand.resources_from_dicts(docs)
+            docs = expand.load_yaml_objects(path, label)
+        rt, _ = expand.resources_from_dicts(docs, label)
         return rt
 
     def run(self, ex, rep):
@@ -1445,7 +1446,7 @@ class AddNodesStep(Step):
 
     def run(self, ex, rep):
         if self.path:
-            rt = expand.load_cluster_from_dir(_resolve_path(self.path))
+            rt = expand.load_cluster_from_dir(_resolve_path(self.path), "new_node")
             if not rt.nodes:
                 raise CampaignError(
                     f"no Node manifest under {self.path!r}", step=self.where, field="template.path"

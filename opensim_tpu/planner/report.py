@@ -53,11 +53,13 @@ def report(
     out: TextIO = sys.stdout,
     pod_nodes: List[str] = None,
 ) -> None:
-    report_cluster_info(result, extended_resources, out)
+    with obs.span("report.nodes"):
+        report_cluster_info(result, extended_resources, out)
     if pod_nodes is not None:
         with obs.span("report.pods"):  # the one table that grows with the pods
             report_node_info(result, extended_resources, pod_nodes, out)
-    report_app_info(result, app_names, out)
+    with obs.span("report.apps", apps=len(app_names)):  # walks every pod once per app
+        report_app_info(result, app_names, out)
 
 
 # ---------------------------------------------------------------------------

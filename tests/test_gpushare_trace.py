@@ -457,8 +457,9 @@ def test_the_path_names_its_devices_its_templates_and_the_pods_it_placed(tmp_pat
     with_gpu = sum(1 for w in cluster.workloads if w.gpu_count)
     assert decodes[-1].attrs["pods"] == with_gpu
     (report,) = find(tr, "report")
+    (nodes,) = find(tr, "report.nodes")
     (table,) = find(tr, "report.gpu")
-    assert table in report.children
+    assert nodes in report.children and table in nodes.children
     if named == "megakernel":
         launches = find(tr, "mk.launch")
         assert launches and all(sp.attrs["gpu_devices"] == 8 and sp.attrs["templates"] == len(shapes) for sp in launches)

@@ -86,27 +86,19 @@ def assert_spans_nest(sp):
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def profiled(tmp_path_factory):
-    """One tiny simulate() on the XLA scan under an ambient trace, inside a
-    profiler capture: the request's spans and the host plane's events."""
+def _captured(fn, endpoint, out):
+    """`fn()` under an ambient trace inside a profiler capture written to
+    `out`: the trace, the host plane's events by name, and what `fn` gave."""
     import jax
     from jax.profiler import ProfileData
 
-    os.environ["OPENSIM_DISABLE_NATIVE"] = "1"
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=options)
     try:
-        simulate(_cluster(), _apps())  # compile outside the capture
-        out = str(tmp_path_factory.mktemp("profile"))
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        jax.profiler.start_trace(out, profiler_options=options)
-        try:
-            tr, res = _traced(lambda: simulate(_cluster(), _apps()), endpoint="lib-call")
-        finally:
-            jax.profiler.stop_trace()
+        tr, res = _traced(fn, endpoint=endpoint)
     finally:
-        del os.environ["OPENSIM_DISABLE_NATIVE"]
-    assert res.engine.name == "xla"
+        jax.profiler.stop_trace()
     path = sorted(glob.glob(os.path.join(out, "plugins", "profile", "*", "*.xplane.pb")))[-1]
     events = {}
     for plane in ProfileData.from_file(path).planes:
@@ -114,19 +106,60 @@ def profiled(tmp_path_factory):
             for line in plane.lines:
                 for ev in line.events:
                     events.setdefault(ev.name, []).append(ev)
+    return tr, events, res
+
+
+def _at_own_interval(tr, events, name):
+    """Every span of the name has a host-plane event of the name at its own
+    interval, one offset (the root's) tying the two clocks."""
+    (root,) = events[tr.root.name]
+    offset = root.start_ns * 1e-9 - tr.root.start
+    spans = _find(tr, name)
+    evs = sorted(events[name], key=lambda ev: ev.start_ns)
+    assert spans and len(evs) == len(spans)
+    for sp, ev in zip(spans, evs):
+        assert abs(ev.start_ns * 1e-9 - offset - sp.start) < 1e-3
+        assert abs((ev.start_ns + ev.duration_ns) * 1e-9 - offset - sp.end) < 1e-3
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One tiny simulate() on the XLA scan under an ambient trace, inside a
+    profiler capture: the request's spans and the host plane's events."""
+    os.environ["OPENSIM_DISABLE_NATIVE"] = "1"
+    try:
+        simulate(_cluster(), _apps())  # compile outside the capture
+        tr, events, res = _captured(lambda: simulate(_cluster(), _apps()), "lib-call",
+                                    str(tmp_path_factory.mktemp("profile")))
+    finally:
+        del os.environ["OPENSIM_DISABLE_NATIVE"]
+    assert res.engine.name == "xla"
     return tr, events
 
 
 @pytest.mark.parametrize("name", ["prepare", "schedule", "engine.xla", "xla.launch", "xla.wait", "decode"])
 def test_a_span_stands_in_the_profilers_host_plane_at_its_own_interval(profiled, name):
-    tr, events = profiled
-    (sp,) = _find(tr, name)
-    (ev,) = events[name]
-    # one offset ties the trace's clock to the monotonic one: the root's
-    (root,) = events["lib-call"]
-    offset = root.start_ns * 1e-9 - tr.root.start
-    assert abs(ev.start_ns * 1e-9 - offset - sp.start) < 1e-3
-    assert abs((ev.start_ns + ev.duration_ns) * 1e-9 - offset - sp.end) < 1e-3
+    _at_own_interval(*profiled, name)
+
+
+@pytest.fixture(scope="module")
+def profiled_plan(tmp_path_factory):
+    """The example plan (`simon apply`) under an ambient trace, inside a
+    profiler capture (ISSUE 37): its load and its report tables."""
+    from opensim_tpu.planner.apply import Applier, Options
+
+    out = tmp_path_factory.mktemp("plan")
+    opts = Options(simon_config=os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                                             "example", "simon-config.yaml"),
+                   output_file=str(out / "report.txt"), report_pods=True)
+    tr, events, rc = _captured(lambda: Applier(opts).run(), "apply", str(out / "profile"))
+    assert rc == 0
+    return tr, events
+
+
+@pytest.mark.parametrize("name", ["load", "load.parse", "load.objects", "report.nodes", "report.apps"])
+def test_a_plans_load_and_report_spans_stand_in_the_host_plane_at_their_own_intervals(profiled_plan, name):
+    _at_own_interval(*profiled_plan, name)
 
 
 def test_the_roots_annotation_carries_the_request_id(profiled):

@@ -3,8 +3,8 @@ takes PyYAML's libyaml loader where the library was built with it and the
 pure-Python one otherwise. Both feed the same Python resolver and constructor,
 so what they return has to be equal document for document; these cases pin
 that on every YAML file the repo ships and on the scalars and structures
-Kubernetes manifests trip on, and pin the counter and span attributes that
-say which parser was engaged. CPU only, no chip."""
+Kubernetes manifests trip on, and pin the counter and the `load.parse` span
+attributes that say which parser was engaged (ISSUE 37). CPU only, no chip."""
 
 import glob
 import json
@@ -228,28 +228,39 @@ def test_files_are_read_in_sorted_order_and_only_mappings_kept(tmp_path):
     assert expand.load_yaml_objects(str(tmp_path / "b.yaml")) == [{"name": "b1"}, {"name": "b2"}]
 
 
-def test_a_traced_call_marks_the_current_span_and_adds_no_child(counted):
+NEWNODE = os.path.join(REPO, "example", "newnode", "demo")
+
+
+def test_a_traced_load_opens_a_parse_span_per_input_and_marks_no_other_span(counted):
     tr = tracing.start_trace("apply", force=True)
     with tracing.trace_scope(tr):
-        expand.load_yaml_objects(CLUSTER)
-        assert tr.root.attrs["yaml_documents"] == 10
+        expand.load_yaml_objects(CLUSTER, "cluster")
         with tracing.span("inner") as sp:
-            expand.decode_yaml_strings(RENDERED)
-        expand.load_yaml_objects(os.path.join(REPO, "example", "newnode", "demo"))
+            expand.decode_yaml_strings(RENDERED, "app:rendered")
+        expand.load_cluster_from_dir(NEWNODE, "new_node")
     tr.finish()
-    assert tr.root.attrs == {"yaml_loader": expand._YAML_LOADER_NAME, "yaml_documents": 11}
-    assert sp.attrs == {"yaml_loader": expand._YAML_LOADER_NAME, "yaml_documents": 5}
-    assert [s.name for s in tr.walk()] == ["apply", "inner"]
-    # with no trace ambient there is nothing to mark, and the counter still counts
+    assert tr.root.attrs == {} and sp.attrs == {}
+    assert [(s.name, s.attrs.get("input")) for s in tr.walk()] == [
+        ("apply", None), ("load.parse", "cluster"), ("inner", None), ("load.parse", "app:rendered"),
+        ("load.parse", "new_node"), ("load.objects", "new_node")]
+    name = expand._YAML_LOADER_NAME
+    parses = [s.attrs for s in tr.walk() if s.name == "load.parse"]
+    cluster_bytes = sum(os.path.getsize(p) for p in expand.yaml_files_in_dir(CLUSTER) if p.endswith(".yaml"))
+    assert parses[0] == {"input": "cluster", "files": 10, "bytes": cluster_bytes, "documents": 10, "yaml_loader": name}
+    # what the parser yielded, the list it skips and the empty document too
+    assert parses[1] == {"input": "app:rendered", "files": 3, "bytes": sum(len(s) for s in RENDERED),
+                         "documents": 5, "yaml_loader": name}
+    assert parses[2]["documents"] == 1
+    assert tr.root.children[-1].attrs == {"input": "new_node", "objects": 1, "skipped": 0}
+    # with no trace ambient there is no span, and the counter still counts
     expand.load_yaml_objects(CLUSTER)
-    assert counted() == [f'simon_yaml_documents_total{{loader="{expand._YAML_LOADER_NAME}"}} 26']
+    assert counted() == [f'simon_yaml_documents_total{{loader="{name}"}} 26']
 
 
 @pytest.mark.parametrize("hidden", [False, True], ids=["build_loader", "python_loader"])
-def test_a_traced_plan_says_which_parser_read_it_and_still_opens_with_prepare(tmp_path, counted, request, hidden):
-    """The harness's `bench.load` bracket runs from the call to the root's
-    first child (benchmarks/drivers/plan_loop.py): loading must not put a
-    span of its own before `prepare`."""
+def test_a_traced_plan_opens_with_load_and_its_parses_count_every_document(tmp_path, counted, request, hidden):
+    """The root's first child is `load`, one `load.parse` and one
+    `load.objects` per input inside it; the report's tables are its children."""
     from opensim_tpu.chart.render import process_chart
     from opensim_tpu.planner.apply import Applier, Options
 
@@ -258,10 +269,14 @@ def test_a_traced_plan_says_which_parser_read_it_and_still_opens_with_prepare(tm
     tr = tracing.start_trace("apply", force=True)
     with tracing.trace_scope(tr):
         rc = Applier(Options(simon_config=os.path.join(REPO, "example", "simon-config.yaml"),
-                             output_file=str(tmp_path / "report.txt"))).run()
+                             output_file=str(tmp_path / "report.txt"), report_pods=True)).run()
     tr.finish()
     assert rc == 0
-    assert tr.root.children[0].name == "prepare"
+    load = tr.root.children[0]
+    inputs = ["cluster", "app:obs", "app:simple", "new_node"]
+    assert load.name == "load"
+    assert [(c.name, c.attrs["input"]) for c in load.children] == [
+        (kind, i) for i in inputs for kind in ("load.parse", "load.objects")]
 
     def held(directory):
         total = 0
@@ -275,6 +290,12 @@ def test_a_traced_plan_says_which_parser_read_it_and_still_opens_with_prepare(tm
                 for s in process_chart("obs", os.path.join(REPO, "example", "application", "charts", "obs-stack")))
     expected = held("cluster/demo") + held("application/simple") + held("newnode/demo") + chart
     name = "python" if hidden else expand._pick_yaml_loader()[1]
-    assert tr.root.attrs["yaml_loader"] == name
-    assert tr.root.attrs["yaml_documents"] == expected
+    parses = [c.attrs for c in load.children if c.name == "load.parse"]
+    assert {a["yaml_loader"] for a in parses} == {name}
+    assert sum(a["documents"] for a in parses) == load.attrs["documents"] == expected
+    assert load.attrs["inputs"] == 4 and load.attrs["bytes"] == sum(a["bytes"] for a in parses)
+    assert not any(k.startswith("yaml_") for k in tr.root.attrs)
     assert counted() == [f'simon_yaml_documents_total{{loader="{name}"}} {expected}']
+    (report,) = [c for c in tr.root.children if c.name == "report"]
+    assert [c.name for c in report.children] == ["report.nodes", "report.pods", "report.apps"]
+    assert report.children[-1].attrs == {"apps": 2}

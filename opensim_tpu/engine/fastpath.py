@@ -19,9 +19,10 @@ import numpy as np
 
 from ..encoding import vocab as V
 from ..obs import trace as obs
+from ..obs.metrics import RECORDER
 from ..obs.profile import launch_span, observed_jit_call
 from ..ops import kernels
-from ..ops.pallas_scan import CHUNK, FastInputs, run_fast_scan
+from ..ops.pallas_scan import CHUNK, VMEM_LIMIT_BYTES, FastInputs, run_fast_scan
 from . import select
 from .schedconfig import DEFAULT_CONFIG
 
@@ -33,6 +34,9 @@ HOSTNAME = "kubernetes.io/hostname"
 # assumed: shapes at the budget's edge (plan/affinity/gpu/local-pv at
 # 6.5k-7k nodes) compile for v5e on the installed JAX/libtpu.
 _VMEM_BUDGET = 10 * 1024 * 1024
+# scenarios a kernel step holds in a packed sweep: one a sublane of the
+# vector registers' eight
+SWEEP_SUBLANES = 8
 
 
 def _pad8_static(n: int) -> int:
@@ -113,9 +117,11 @@ def why_not(prep, config=None) -> Optional[str]:
     return None
 
 
-def vmem_estimate(prep) -> int:
+def vmem_estimate(prep, sublanes: int = 1) -> int:
     """Bytes of the kernel's resident VMEM rows, as `why_not` reckons them
-    and `mk.inputs` reports them."""
+    and `mk.inputs` reports them, for `sublanes` scenarios a step: the
+    per-scenario rows (state scratch, its outputs, validity) are held once a
+    sublane, the shared tables once."""
     f = prep.features
     ec = prep.ec_np if prep.ec_np is not None else prep.ec
     N = 128 * math.ceil(int(ec.node_valid.shape[0]) / 128)
@@ -126,17 +132,19 @@ def vmem_estimate(prep) -> int:
     non_host = [k for k in topo_keys if k != HOSTNAME]
     # VMEM budget. The pallas_call signature is generated per feature-flag
     # combination (_input_layout): a feature that is off contributes ZERO
-    # rows — its buffers don't exist in the program. Resident rows ([x, N]):
-    #   always: alloc/used0/used/used_out (4R), template tables (3U unless
-    #   big-U), node_cnt (A), has_zone (K), node_valid (1)
-    #   +interpod: anti_node + prefw_node (2G)
-    #   +gpu: gpu0/gpu_free/gpu_out (3Gd)
-    #   +local: vg cap/init/free/out (4Vg) + dev cap/init/free/out + media
-    #   one-hots (6Dv)
-    #   +ports: port_used (Hp)
-    #   +na/tt: one [U, N] table each
-    # plus the zone blocks: zone_NZ + zone_ZN (2·K·N·Z) and the [*, Z]
-    # scratch counts.
+    # rows — its buffers don't exist in the program. Resident rows ([x, N]),
+    # shared / per scenario:
+    #   always: alloc/used0 (2R) / used/used_out (2R), template tables (3U
+    #   unless big-U), has_zone (K) / node_cnt (A), node_valid (1)
+    #   +interpod: / anti_node + prefw_node (2G)
+    #   +gpu: gpu0 (Gd) / gpu_free/gpu_out (2Gd)
+    #   +local: vg cap/init (2Vg) / free/out (2Vg); dev cap/init + media
+    #   one-hots (4Dv) / free/out (2Dv)
+    #   +ports: / port_used (Hp)
+    #   +na/tt/avoid: one [U, N] table each
+    #   packed (sublanes > 1): zone_id (K) and the [SB, CHUNK] chosen window
+    # plus the zone blocks: zone_NZ + zone_ZN (2·K·N·Z) and the per-scenario
+    # [*, Z] scratch counts.
     if non_host:
         counts = []
         for key in non_host:
@@ -155,22 +163,25 @@ def vmem_estimate(prep) -> int:
         int(ports_np.max()) + 1 if ports_np.size and ports_np.max() >= 0 else 1
     )
     U_resident = 0 if use_big_u(U, N) else U
-    rows = 4 * R + 3 * U_resident + A + K + 1
+    rows = 2 * R + 3 * U_resident + K
+    scen_rows = 2 * R + A + 1
     zone_z_rows = K * A
     # [X, U] tables resident in non-big-U mode ([X, U_pad128] in big-U they
     # move to HBM): matches + ports + interpod term tables
     u_cols = 0 if use_big_u(U, N) else max(U, 128)
     u_rows = A  # matches_AU
     if f.interpod or f.prefg:
-        rows += 2 * G
+        scen_rows += 2 * G
         zone_z_rows += 2 * G
         u_rows += 4 * G  # antig/gmatch/prefg/pmatch
     if f.gpu:
-        rows += 3 * Gd_pad
+        rows += Gd_pad
+        scen_rows += 2 * Gd_pad
     if f.local:
-        rows += 4 * Vg_pad + 6 * Dv_pad
+        rows += 2 * Vg_pad + 4 * Dv_pad
+        scen_rows += 2 * Vg_pad + 2 * Dv_pad
     if f.ports:
-        rows += Hp_pad
+        scen_rows += Hp_pad
         u_rows += 2 * Hp_pad  # port_HU + port_conf_HU
     if f.pref_node_affinity:
         rows += U_resident
@@ -178,7 +189,41 @@ def vmem_estimate(prep) -> int:
         rows += U_resident
     if f.prefer_avoid:
         rows += U_resident
-    return (rows * N + (2 * K * N + zone_z_rows) * Z + u_rows * u_cols) * 4
+    window = 0
+    if sublanes > 1:
+        rows += K
+        window = 2 * sublanes * CHUNK  # double-buffered int32 chosen window
+    rows += sublanes * scen_rows
+    return (rows * N + (2 * K * N + sublanes * zone_z_rows) * Z + u_rows * u_cols + window) * 4
+
+
+def sweep_sublanes(prep, S: int) -> int:
+    """Scenarios a step of a sweep of S: eight, one a sublane, where there
+    are two or more and the packed kernel's resident rows leave the compiler
+    the room it needs under the limit it is compiled with (about twice the
+    estimate: double-buffered blocks and the operand splits of the exact
+    matmuls, see pallas_scan.VMEM_LIMIT_BYTES); else one."""
+    if S >= 2 and 2 * vmem_estimate(prep, SWEEP_SUBLANES) <= VMEM_LIMIT_BYTES:
+        return SWEEP_SUBLANES
+    return 1
+
+
+def _key_weights(weights, key_tks, K: int) -> np.ndarray:
+    """[K+1] weight of each kernel topology key (0 = hostname, 1..K zone
+    keys) from the [Tk] weights of the vocabulary's keys; `key_tks` lists the
+    vocabulary key of each kernel key, -1 where there is none."""
+    out = np.zeros((K + 1,), np.float32)
+    for ki, tk in enumerate(key_tks):
+        if tk >= 0:
+            out[ki] = weights[tk]
+    return out
+
+
+def _key_tks(prep):
+    """The vocabulary topology key of each kernel key (build_inputs' order)."""
+    topo_keys = prep.meta.vocab.topo_keys.items()
+    host_tk = topo_keys.index(HOSTNAME) if HOSTNAME in topo_keys else -1
+    return [host_tk] + [i for i, k in enumerate(topo_keys) if k != HOSTNAME]
 
 
 def _gc_row(prep) -> int:
@@ -253,10 +298,8 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
     U = int(ec.req.shape[0])
     A = int(ec.matches_sel.shape[1])
     R = int(ec.alloc.shape[1])
-    vocab = prep.meta.vocab
-    topo_keys = vocab.topo_keys.items()
-    host_tk = topo_keys.index(HOSTNAME) if HOSTNAME in topo_keys else -1
-    zone_tks = [i for i, k in enumerate(topo_keys) if k != HOSTNAME]
+    topo_keys = prep.meta.vocab.topo_keys.items()
+    host_tk, *zone_tks = _key_tks(prep)
 
     trash = np.asarray(ec.domain_topo).shape[0] - 1
     node_domain = _padN(np.asarray(ec.node_domain), axis=0, fill=trash)
@@ -308,13 +351,10 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
     spr_hard = np.asarray(ec.spr_hard).astype(np.int32)
     matches_sel = np.asarray(ec.matches_sel)
     spr_self = np.zeros((U, Cs), np.float32)
-    spread_weight = np.asarray(stat.spread_weight)
-    spr_weight = np.zeros((U, Cs), np.float32)
     for u in range(U):
         for c in range(Cs):
             if spr_topo[u, c] >= 0:
                 spr_self[u, c] = float(matches_sel[u, spr_sel[u, c]])
-                spr_weight[u, c] = float(spread_weight[spr_topo[u, c]])
 
     # extension state, fetched in ONE batched device_get (one blocking
     # device→host round trip, not three), then transposed with sublane padding
@@ -429,7 +469,7 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
         spr_skew=spr_skew,
         spr_hard=spr_hard,
         spr_self=spr_self,
-        spr_weight=spr_weight,
+        key_weight=_key_weights(np.asarray(stat.spread_weight), [host_tk, *zone_tks], K),
         at_active=at_active,
         at_key=at_key,
         at_sel=at_sel,
@@ -497,10 +537,16 @@ def _gpu_rows(prep, fi: FastInputs) -> int:
     return int(fi.gpu0_DN.shape[0]) if prep.features.gpu else 0
 
 
-def _launch(prep, fi: FastInputs, tmpl_ids, pod_valid, forced, interpret: bool, big_u: bool):
+def _launch(
+    prep, fi: FastInputs, tmpl_ids, pod_valid, forced, interpret: bool, big_u: bool,
+    sublanes: int = 1, pad: int = 0,
+):
     """`mk.launch`: everything the host does to get the kernel onto the
     device, for `fi` with its per-scenario rows set, `tmpl_ids` [P] and
-    `pod_valid`/`forced` [S, P]. run_fast_scan is one jitted function, entered
+    `pod_valid`/`forced` [S, P], `sublanes` scenarios a step, the last `pad`
+    of the S padding. The span says `scenarios` (those asked for),
+    `sublanes`, `blocks` (the grid's scenario blocks, S / sublanes) and
+    `pad_scenarios`. run_fast_scan is one jitted function, entered
     through the compile watch as `megakernel`: a signature's first call in a
     process traces and lowers the kernel and looks the executable up in the
     persistent cache (the span says `entry="traced"`,
@@ -511,14 +557,15 @@ def _launch(prep, fi: FastInputs, tmpl_ids, pod_valid, forced, interpret: bool, 
     so the persistent cache's key and the seconds of that first lowering
     (0.3 s for one frame on the chip's host, PERF.md §6); the calls after it
     are not touched."""
+    S = pod_valid.shape[0]
     with launch_span(
-        "mk.launch", watch="megakernel", scenarios=pod_valid.shape[0], pods=len(tmpl_ids),
+        "mk.launch", watch="megakernel", scenarios=S - pad, pods=len(tmpl_ids),
         nodes=fi.alloc_T.shape[1], templates=fi.static_pass.shape[0], big_u=big_u,
-        gpu_devices=_gpu_rows(prep, fi),
+        gpu_devices=_gpu_rows(prep, fi), sublanes=sublanes, blocks=S // sublanes, pad_scenarios=pad,
     ):
         return observed_jit_call(
             "megakernel", run_fast_scan, (fi, tmpl_ids, pod_valid, forced),
-            dict(interpret=interpret, big_u=big_u, **_kernel_flags(prep)),
+            dict(interpret=interpret, big_u=big_u, sublanes=sublanes, **_kernel_flags(prep)),
         )
 
 
@@ -529,39 +576,39 @@ class _SweepContext:
         ec = prep.ec_np if prep.ec_np is not None else jax.device_get(prep.ec)
         self.node_domain = np.asarray(ec.node_domain)
         self.trash = np.asarray(ec.domain_topo).shape[0] - 1
-        self.spr_topo = np.asarray(ec.spr_topo)
         self.log_sizes = np.asarray(ec.log_sizes)
+        self.key_tks = _key_tks(prep)
 
-    def spread_weights(self, node_valid: np.ndarray) -> np.ndarray:
-        """[U, Cs] log(size+2) table for a scenario's valid-node subset
-        (domain counts are valid-set dependent). Weights come from the
-        shared ec.log_sizes lookup so they are bitwise-identical to every
-        other engine's."""
+    def key_weights(self, node_valid: np.ndarray, K: int) -> np.ndarray:
+        """[K+1] log(size+2) of each kernel topology key for a scenario's
+        valid-node subset (domain counts are valid-set dependent). Weights
+        come from the shared ec.log_sizes lookup so they are bitwise-identical
+        to every other engine's."""
         Tk = self.node_domain.shape[1]
         sizes = np.zeros((Tk,), np.int64)
         for tk in range(Tk):
             doms = self.node_domain[node_valid, tk]
             sizes[tk] = len(np.unique(doms[doms != self.trash]))
         weights = self.log_sizes[np.clip(sizes, 0, self.log_sizes.shape[0] - 1)]
-        return np.where(
-            self.spr_topo >= 0, weights[np.maximum(self.spr_topo, 0)], 0.0
-        ).astype(np.float32)
+        return _key_weights(weights, self.key_tks, K)
 
 
 def _scenario_rows(prep, fi: FastInputs, masks):
     """`fi` with the kernel's two per-scenario rows set for [S, N_orig] node
     masks: validity padded to the 128-lane node axis as [S, 1, N] and the
-    spread weights of each valid set as [S, U, Cs]. Also returns the padded
-    masks as [S, N] bool. Everything else in `fi` was built with all nodes
-    valid (build_inputs) and is shared by every scenario."""
+    topology keys' spread weights of each valid set as [S, K+1]. Also
+    returns the padded masks as [S, N] bool. Everything else in `fi` was
+    built with all nodes valid (build_inputs) and is shared by every
+    scenario."""
     masks = np.asarray(masks, dtype=bool)
     nv = np.zeros((masks.shape[0], int(fi.node_valid.shape[1])), bool)
     nv[:, : masks.shape[1]] = masks
     ctx = _SweepContext(prep)
-    sw = np.stack([ctx.spread_weights(m) for m in masks])
+    K = int(fi.key_weight.shape[0]) - 1
+    kw = np.stack([ctx.key_weights(m, K) for m in masks])
     fi = fi._replace(
         node_valid=jnp.asarray(nv.astype(np.float32)[:, None, :]),
-        spr_weight=jnp.asarray(sw),
+        key_weight=jnp.asarray(kw),
     )
     return fi, nv
 
@@ -571,18 +618,26 @@ def sweep(
     interpret: Optional[bool] = None, big_u: Optional[bool] = None,
 ):
     """Scenario sweep on the megakernel: ALL scenarios in ONE batched
-    dispatch — the per-scenario inputs (node validity, spread weights, pod
-    masks) ride the kernel grid's leading scenario axis, so S scans run
-    back-to-back in a single Pallas program with no per-scenario dispatch
-    overhead (the shared template/state tables are not duplicated). Returns
-    (unscheduled [S], used [S, N, R], chosen [S, P], vg_used [S]) matching
-    parallel.scenarios.SweepResult. `big_u=None` defers to the use_big_u
-    heuristic (tests override it to exercise the HBM-DMA path on small
-    shapes)."""
+    dispatch. A step of the kernel holds `sweep_sublanes` scenarios, eight
+    where they fit, one a sublane: the scenarios are padded to blocks of
+    eight (a padding scenario has no valid node and no valid pod) and the
+    grid walks the pod stream once a block, so a sweep of S scenarios makes
+    ceil(S / 8) passes over the stream, not S; the per-scenario inputs (node
+    validity, spread weights, pod masks) ride the block, the shared
+    template/state tables are not duplicated. Returns (unscheduled [S], used
+    [S, N, R], chosen [S, P], vg_used [S]) matching
+    parallel.scenarios.SweepResult, the padding dropped. `big_u=None` defers
+    to the use_big_u heuristic (tests override it to exercise the HBM-DMA
+    path on small shapes)."""
     interpret = _resolve_interpret(interpret)
     S = node_valid_masks.shape[0]
     P = pod_valid_masks.shape[1]
-    with obs.span("mk.inputs", scenarios=S, vmem_estimate_bytes=vmem_estimate(prep)):
+    sublanes = sweep_sublanes(prep, S)
+    S_pad = -(-S // sublanes) * sublanes
+    with obs.span(
+        "mk.inputs", scenarios=S, sublanes=sublanes, blocks=S_pad // sublanes, pad_scenarios=S_pad - S,
+        vmem_estimate_bytes=vmem_estimate(prep, sublanes),
+    ):
         fi, meta = build_inputs(prep)
         if big_u is None:
             big_u = use_big_u(*fi.static_pass.shape)
@@ -592,21 +647,26 @@ def sweep(
             tmpl = np.concatenate([tmpl, np.zeros(pad, tmpl.dtype)])
         vg0 = np.asarray(fi.vg0_VN)
         N_orig = meta["n_orig"]
-        pv_all = np.zeros((S, P + pad), bool)
-        pv_all[:, :P] = np.asarray(pod_valid_masks, dtype=bool)
-        fm_all = np.zeros((S, P + pad), bool)
-        fm_all[:, :P] = np.asarray(forced_masks, dtype=bool)
-        fi, nv_all = _scenario_rows(prep, fi, node_valid_masks)
+        pv_all = np.zeros((S_pad, P + pad), bool)
+        pv_all[:S, :P] = np.asarray(pod_valid_masks, dtype=bool)
+        fm_all = np.zeros((S_pad, P + pad), bool)
+        fm_all[:S, :P] = np.asarray(forced_masks, dtype=bool)
+        masks = np.zeros((S_pad, node_valid_masks.shape[1]), bool)
+        masks[:S] = np.asarray(node_valid_masks, dtype=bool)
+        fi, nv_all = _scenario_rows(prep, fi, masks)
 
-    chosen_b, used_b, _gt, _gf, vg_b, _dev = outs = _launch(prep, fi, tmpl, pv_all, fm_all, interpret, big_u)
+    chosen_b, used_b, _gt, _gf, vg_b, _dev = outs = _launch(
+        prep, fi, tmpl, pv_all, fm_all, interpret, big_u, sublanes, S_pad - S
+    )
+    RECORDER.count_megakernel_sweep_blocks(sublanes, S_pad // sublanes)
     with obs.span("mk.wait"):
         jax.block_until_ready(outs)
     with obs.span("mk.fetch"):
-        chosen_all = np.asarray(chosen_b)[:, :P]
-        unscheduled = ((chosen_all < 0) & pv_all[:, :P]).sum(axis=1).astype(np.int32)
-        used = np.asarray(used_b).transpose(0, 2, 1)[:, :N_orig]
+        chosen_all = np.asarray(chosen_b)[:S, :P]
+        unscheduled = ((chosen_all < 0) & pv_all[:S, :P]).sum(axis=1).astype(np.int32)
+        used = np.asarray(used_b)[:S].transpose(0, 2, 1)[:, :N_orig]
         # per the XLA sweep, VG usage counts only scenario-valid nodes
-        vg_used = ((vg0[None] - np.asarray(vg_b)) * nv_all[:, None, :]).sum(
+        vg_used = ((vg0[None] - np.asarray(vg_b)[:S]) * nv_all[:S, None, :]).sum(
             axis=(1, 2)
         ).astype(np.float32)
     return unscheduled, used, chosen_all, vg_used
@@ -633,7 +693,7 @@ def schedule(
     # fails hard under OPENSIM_REQUIRE_TPU=1 (chaos suite)
     faults.fault_point("engine.compile")
     interpret = _resolve_interpret(interpret)
-    with obs.span("mk.inputs", vmem_estimate_bytes=vmem_estimate(prep)):
+    with obs.span("mk.inputs", sublanes=1, blocks=1, pad_scenarios=0, vmem_estimate_bytes=vmem_estimate(prep)):
         fi, meta = build_inputs(prep)
         if big_u is None:
             big_u = use_big_u(*fi.static_pass.shape)
@@ -648,7 +708,7 @@ def schedule(
             forced = np.concatenate([forced, np.zeros(pad, bool)])
         if node_valid is None:
             static_fail = meta["static_fail"]
-            fi = fi._replace(node_valid=fi.node_valid[None], spr_weight=fi.spr_weight[None])
+            fi = fi._replace(node_valid=fi.node_valid[None], key_weight=fi.key_weight[None])
         else:
             mask = np.asarray(node_valid, dtype=bool)
             fi, _ = _scenario_rows(prep, fi, mask[None])

@@ -187,6 +187,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Clean megakernel runs of a stream by what became of their failure reasons: no pod failed, exact from the final "
         "carry, kept without reasons (the caller asked for none), or discarded for a scan that attributes", "counter",
     ),
+    # sublanes: 1 | 8, the scenarios a kernel step holds
+    "simon_megakernel_sweep_blocks_total": (
+        "Scenario blocks the megakernel's sweeps walked the pod stream for, by the scenarios a step holds: a packed sweep "
+        "of S scenarios is ceil(S/8) blocks of 8 sublanes, an unpacked one S blocks of 1", "counter",
+    ),
     # engine: megakernel | native, the rung that turned the run away; reason:
     # a row of select.DECLINES, or the envelope's token (U, A, R, vmem, topo_keys, ...)
     "simon_engine_declined_total": (
@@ -592,6 +597,9 @@ class MetricsRecorder:
         # clean kernel runs by what became of their failure reasons; rescan over
         # all is how often a stream pays the kernel and a whole scan after it
         self.megakernel_attribution = make_counter("simon_megakernel_attribution_total", ("outcome",))
+        # scenario blocks of megakernel sweeps by sublanes a step; blocks of
+        # 1 mean a sweep that did not pack (fastpath.sweep_sublanes)
+        self.megakernel_sweep_blocks = make_counter("simon_megakernel_sweep_blocks_total", ("sublanes",))
         # runs that reached a slower rung because a faster one declined them
         # (engine/select.py turned_away): the rung and its reason's token
         self.engine_declined = make_counter("simon_engine_declined_total", ("engine", "reason"))
@@ -678,6 +686,10 @@ class MetricsRecorder:
         with self.lock:
             self.megakernel_attribution.inc((outcome,))
 
+    def count_megakernel_sweep_blocks(self, sublanes: int, blocks: int) -> None:
+        with self.lock:
+            self.megakernel_sweep_blocks.inc((str(sublanes),), blocks)
+
     def count_engine_declined(self, engine: str, reason: str) -> None:
         with self.lock:
             self.engine_declined.inc((engine, reason))
@@ -701,6 +713,7 @@ class MetricsRecorder:
                 + self.engine_features.render_lines()
                 + self.masked_pass.render_lines()
                 + self.megakernel_attribution.render_lines()
+                + self.megakernel_sweep_blocks.render_lines()
                 + self.engine_declined.render_lines()
                 + self.gpushare_pods.render_lines()
                 + self.yaml_documents.render_lines()
@@ -719,6 +732,7 @@ class MetricsRecorder:
             self.engine_features.reset()
             self.masked_pass.reset()
             self.megakernel_attribution.reset()
+            self.megakernel_sweep_blocks.reset()
             self.engine_declined.reset()
             self.gpushare_pods.reset()
             self.yaml_documents.reset()

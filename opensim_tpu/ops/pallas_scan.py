@@ -19,22 +19,33 @@ templates the kernel switches to big-U mode: the [U, N]/[X, U] template
 tables stay in HBM and each pod step DMAs its row/column into VMEM scratch,
 so VMEM no longer scales with U (cap 2048, bounded by SMEM scalars). The kernel is
 generated per feature-flag combination so absent features cost nothing, and
-node validity is a runtime row: a scenario sweep is the same kernel with a
-leading scenario grid axis, each scenario reading its own mask, spread-weight
-table and pod streams (`run_fast_scan`; a plain schedule is one scenario).
-`run_fast_scan` is the one entry and a module-level `jax.jit`: a process
-traces and lowers the kernel once per signature (argument shapes × feature
-flags) and enters it from the jit's cache after that.
+node validity is a runtime row: a scenario sweep is the same kernel over
+scenarios, each reading its own mask, spread weights and pod streams
+(`run_fast_scan`; a plain schedule is one scenario).
 
-Layouts (N = padded node axis, lanes; rows padded to sublane multiples):
-  alloc_T     [R, N]    f32  allocatable per resource row
-  used        [R, N]    f32  scratch, persistent across the grid
-  static_pass [U, N]    f32  0/1 from kernels.precompute_static
-  node_cnt    [A, N]    f32  scratch — per-hostname-domain selector counts
-  zone_cnt    [K*A, Z]  f32  scratch — per-(zone-key, selector) counts
-  anti_node   [G, N]    f32  scratch — existing-pod anti-affinity terms
-  prefw_node  [Gp, N]   f32  scratch — symmetric preferred-term weights
-  matches_AU  [A, U]    f32  selector-match matrix (column = template)
+Scenarios sit on the sublane axis. A step works on [SB, N] rows, one
+scenario a sublane: SB = 1 for a schedule, where a [1, N] row fills one of
+the eight sublanes of each vector register it lies in, and SB = 8 for a
+packed sweep, where eight scenarios fill them all. The grid is
+(S / SB scenario blocks, pod chunks); a block's scenarios walk the shared
+pod stream together, reading the same template row, static row and request
+once a step. `run_fast_scan` is the one entry and a module-level `jax.jit`:
+a process traces and lowers the kernel once per signature (argument shapes ×
+feature flags × SB) and enters it from the jit's cache after that.
+
+Layouts (N = padded node axis, lanes; rows padded to sublane multiples). A
+per-scenario table of X rows is [X*SB, N]: row x of scenario s is row
+x*SB + s, so row x of a block is the aligned [SB, N] slice at x*SB (with
+SB = 1 the table is the plain [X, N]):
+  alloc_T     [R, N]       f32  allocatable per resource row (shared)
+  used        [R*SB, N]    f32  scratch, persistent across a block's chunks
+  static_pass [U, N]       f32  0/1 from kernels.precompute_static (shared)
+  node_valid  [SB, N]      f32  the block's validity rows
+  node_cnt    [A*SB, N]    f32  scratch — per-hostname-domain selector counts
+  zone_cnt    [K*A*SB, Z]  f32  scratch — per-(zone-key, selector) counts
+  anti_node   [G*SB, N]    f32  scratch — existing-pod anti-affinity terms
+  prefw_node  [Gp*SB, N]   f32  scratch — symmetric preferred-term weights
+  matches_AU  [A, U]       f32  selector-match matrix (column = template)
 """
 
 from __future__ import annotations
@@ -77,18 +88,23 @@ def _dot(a, b):
     )
 
 
-def _div_rows(pairs):
-    """Exactly rounded quotients (kernels.div32) of [1, N] rows, eight at a
-    time: a [1, N] row uses one of the eight sublanes of each vector register
-    it lies in, so the quotients of a step are stacked into [8, N] blocks and
-    eight of them cost one division. A denominator may be a scalar. Returned
-    in the order given."""
+def _div_rows(pairs, shape):
+    """Exactly rounded quotients (kernels.div32) of `shape` = [SB, N] rows,
+    a whole vector register's eight sublanes at a time: with SB = 1 a row
+    uses one sublane of each register it lies in, so the quotients of a step
+    are stacked into [8, N] blocks and eight of them cost one division; with
+    SB = 8 each quotient fills its block alone. Either operand may broadcast
+    to `shape`. Returned in the order given."""
+    per = 8 // shape[0]
     out = []
-    for at in range(0, len(pairs), 8):
-        group = pairs[at:at + 8]
-        nums = [a for a, _ in group]
-        dens = [jnp.broadcast_to(b, a.shape) for a, b in group]
-        fill = 8 - len(group)
+    for at in range(0, len(pairs), per):
+        group = pairs[at:at + per]
+        nums = [jnp.broadcast_to(a, shape) for a, _ in group]
+        dens = [jnp.broadcast_to(b, shape) for _, b in group]
+        if per == 1:
+            out.append(div32(nums[0], dens[0]))
+            continue
+        fill = per - len(group)
         q = div32(
             jnp.concatenate(nums + nums[:1] * fill, axis=0),
             jnp.concatenate(dens + dens[:1] * fill, axis=0),
@@ -122,7 +138,10 @@ class FastInputs(NamedTuple):
     spr_skew: np.ndarray  # f32
     spr_hard: np.ndarray  # i32 0/1
     spr_self: np.ndarray  # f32 0/1 template matches own selector
-    spr_weight: np.ndarray  # f32 log(size+2) (run_fast_scan takes [S, U, Cs])
+    # [K+1] f32 log(domain count + 2) of each topology key index (0 =
+    # hostname, 1..K zone keys): a spread constraint's weight is its key's
+    # (run_fast_scan takes [S, K+1])
+    key_weight: np.ndarray
     # inter-pod affinity (all zero-shaped semantics when has_interpod=False)
     at_active: np.ndarray  # [U, Ti] i32 — incoming required affinity terms
     at_key: np.ndarray  # [U, Ti] i32 key index (0 = hostname, 1..K = zone)
@@ -173,18 +192,20 @@ def _input_layout(
     has_tt: bool,
     has_avoid: bool,
     big_u: bool,
+    packed: bool = False,
 ):
     """Ordered (name, kind) list of kernel inputs for one feature-flag
     combination; kind ∈ {stream, smem, vmem, any}. The pallas_call signature
     is generated from this, so a workload with a feature off pays ZERO
-    VMEM/SMEM for that feature's tables — the buffers don't exist."""
+    VMEM/SMEM for that feature's tables — the buffers don't exist. A packed
+    kernel (SB > 1) also reads `zone_id`, each node's zone under each key."""
     ut = "any" if big_u else "vmem"  # U-scaled tables move to HBM in big-U mode
     L = [
         ("tmpl", "stream"), ("valid", "stream"), ("forced", "stream"),
         ("req", "smem"), ("cpu_nz", "smem"), ("mem_nz", "smem"), ("pin", "smem"),
         ("spr_active", "smem"), ("spr_key", "smem"), ("spr_sel", "smem"),
         ("spr_skew", "smem"), ("spr_hard", "smem"), ("spr_self", "smem"),
-        ("spr_weight", "smem"),
+        ("key_weight", "smem"),
     ]
     if has_interpod:
         L += [
@@ -206,6 +227,8 @@ def _input_layout(
         ("zone_NZ", "vmem"), ("zone_ZN", "vmem"), ("has_zone", "vmem"),
         ("matches_AU", ut), ("node_valid", "vmem"),
     ]
+    if packed:
+        L += [("zone_id", "vmem")]
     if has_interpod:
         L += [("antig_GU", ut), ("gmatch_GU", ut), ("prefg_GU", ut), ("pmatch_GU", ut)]
     if has_gpu:
@@ -254,12 +277,15 @@ def _make_kernel(
     big_u: bool = False,
     n_zkeys: int = 1,
     gc_row: int = -1,
+    sublanes: int = 1,
 ):
-    layout = _input_layout(has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u)
+    SB = sublanes
+    layout = _input_layout(has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u, SB > 1)
     in_names = [n for n, _ in layout]
     out_names = ["chosen", "used_out"]
     if has_gpu:
-        out_names += ["gpu_take", "gpu_out"]
+        # a packed sweep reads no device takes: only a schedule writes them
+        out_names += ["gpu_take", "gpu_out"] if SB == 1 else ["gpu_out"]
     if has_local:
         out_names += ["vg_out", "dev_out"]
     scratch_names = _scratch_names(has_interpod, has_gpu, has_local, has_ports)
@@ -271,9 +297,9 @@ def _make_kernel(
         tmpl_ref, valid_ref, forced_ref = Rd["tmpl"], Rd["valid"], Rd["forced"]
         req_ref, cpu_nz_ref, mem_nz_ref, pin_ref = (
             Rd["req"], Rd["cpu_nz"], Rd["mem_nz"], Rd["pin"])
-        sa_ref, sh_ref, ss_ref, sk_ref, shard_ref, sself_ref, sw_ref = (
+        sa_ref, sh_ref, ss_ref, sk_ref, shard_ref, sself_ref, kw_ref = (
             Rd["spr_active"], Rd["spr_key"], Rd["spr_sel"], Rd["spr_skew"],
-            Rd["spr_hard"], Rd["spr_self"], Rd["spr_weight"])
+            Rd["spr_hard"], Rd["spr_self"], Rd["key_weight"])
         if has_interpod:
             ata_ref, ath_ref, ats_ref, atf_ref = (
                 Rd["at_active"], Rd["at_key"], Rd["at_sel"], Rd["at_self"])
@@ -288,7 +314,7 @@ def _make_kernel(
         if has_gpu:
             gmem_ref, gcnt_ref = Rd["gpu_mem"], Rd["gpu_cnt"]
             gpu0_ref, gpu_free_ref = Rd["gpu0_DN"], Rd["gpu_free"]
-            gpu_take_ref, gpu_out_ref = Rd["gpu_take"], Rd["gpu_out"]
+            gpu_take_ref, gpu_out_ref = Rd.get("gpu_take"), Rd["gpu_out"]
         if has_local:
             lvm_ref, dreq_ref, dneed_ref, dsz_ref = (
                 Rd["lvm_req"], Rd["dev_req"], Rd["dev_need"], Rd["dev_sizes"])
@@ -312,6 +338,7 @@ def _make_kernel(
         zone_nz_ref, zone_zn_ref, has_zone_ref = (
             Rd["zone_NZ"], Rd["zone_ZN"], Rd["has_zone"])
         matches_ref, nodevalid_ref = Rd["matches_AU"], Rd["node_valid"]
+        zone_id_ref = Rd.get("zone_id")  # [K, N] f32, a packed kernel's alone
         chosen_ref, used_out_ref = Rd["chosen"], Rd["used_out"]
         used_ref, node_cnt_ref, zone_cnt_ref = (
             Rd["used"], Rd["node_cnt"], Rd["zone_cnt"])
@@ -323,12 +350,82 @@ def _make_kernel(
             Tn = ana_ref.shape[0]
             Tp = pta_ref.shape[0]
 
-        # grid = (scenario, chunk): the carried state lives in scratch that
-        # persists across the whole grid, so every scenario re-initializes it
-        # at its first chunk
+        # --- the sublane axis. A per-scenario table of X rows is [X*SB, N]
+        # (row x of scenario s at x*SB + s); with SB = 1 every helper below
+        # is the plain [X, N] form, so a schedule compiles the scalar kernel
+        def srows(ref, x):
+            """Row x of a per-scenario table, for every scenario: [SB, W]."""
+            if SB == 1:
+                return ref[pl.ds(x, 1), :]
+            start = x * SB if isinstance(x, int) else pl.multiple_of(x * SB, SB)
+            return ref[pl.ds(start, SB), :]
+
+        def set_srows(ref, x, value):
+            ref[pl.ds(x * SB, SB), :] = value
+
+        def add_outer(ref, col, rows, base=None):
+            """Rows base..base+X (the whole table without a base) of a
+            per-scenario table += col [X, 1] ⊗ rows [SB, W]: row x of
+            scenario s gains col[x] * rows[s]."""
+            X = col.shape[0]
+            if SB == 1:
+                at = slice(None) if base is None else pl.ds(base, X)
+                ref[at, :] = ref[at, :] + col * rows
+                return
+            for x in range(X):
+                at = pl.ds(((base or 0) + x) * SB, SB)
+                # over the sublanes first, then the lanes: Mosaic broadcasts
+                # a [1, 1] slice along one of the two at a time
+                cx = jnp.zeros((SB, 1), jnp.float32) + col[x:x + 1, :]
+                ref[at, :] = ref[at, :] + cx * rows
+
+        def fill(dst, src):
+            """Every scenario of the block starts from the shared table src."""
+            if SB == 1:
+                dst[:] = src[:]
+                return
+            for x in range(src.shape[0]):
+                set_srows(dst, x, jnp.broadcast_to(src[pl.ds(x, 1), :], (SB, src.shape[1])))
+
+        # reductions over the node axis: one per scenario, [SB, 1] (a scalar
+        # when SB = 1). max/min/sum of counts are exact in any order
+        if SB == 1:
+            vmin, vmax, vsum = jnp.min, jnp.max, jnp.sum
+        else:
+            vmin = functools.partial(jnp.min, axis=-1, keepdims=True)
+            vmax = functools.partial(jnp.max, axis=-1, keepdims=True)
+            vsum = functools.partial(jnp.sum, axis=-1, keepdims=True)
+            s_iota = jax.lax.broadcasted_iota(jnp.int32, (SB, 1), 0)
+            lane_chunk = jax.lax.broadcasted_iota(jnp.int32, (SB, CHUNK), 1)
+
+            def column(get, dtype):
+                """[SB, 1] column of the block's SMEM scalars get(s)."""
+                col = jnp.zeros((SB, 1), dtype)
+                for s in range(SB):
+                    col = jnp.where(s_iota == s, get(s).astype(dtype), col)
+                return col
+
+        def kron_row(m):
+            """[1, X] -> [SB, X*SB], m[x] at (s, x*SB + s): a shared row of
+            term weights against a per-scenario [X*SB, W] table in one
+            exact dot. The identity when SB = 1."""
+            if SB == 1:
+                return m
+            X = m.shape[1]
+            lane = jax.lax.broadcasted_iota(jnp.int32, (X, X * SB), 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, (X, X * SB), 0)
+            spread = ((lane >= row * SB) & (lane < row * SB + SB)).astype(jnp.float32)
+            rep = _dot(m, spread)  # [1, X*SB]: m[x] on lanes x*SB .. x*SB+SB-1
+            lane = jax.lax.broadcasted_iota(jnp.int32, (SB, X * SB), 1)
+            sub = jax.lax.broadcasted_iota(jnp.int32, (SB, X * SB), 0)
+            return jnp.where((lane & (SB - 1)) == sub, rep, 0.0)
+
+        # grid = (scenario block, chunk): the carried state lives in scratch
+        # that persists across the whole grid, so every block re-initializes
+        # it at its first chunk
         @pl.when(pl.program_id(1) == 0)
         def _init():
-            used_ref[:] = used0_ref[:]
+            fill(used_ref, used0_ref)
             node_cnt_ref[:] = jnp.zeros_like(node_cnt_ref)
             zone_cnt_ref[:] = jnp.zeros_like(zone_cnt_ref)
             if has_interpod:
@@ -337,20 +434,40 @@ def _make_kernel(
                 prefw_node_ref[:] = jnp.zeros_like(prefw_node_ref)
                 prefw_zone_ref[:] = jnp.zeros_like(prefw_zone_ref)
             if has_gpu:
-                gpu_free_ref[:] = gpu0_ref[:]
+                fill(gpu_free_ref, gpu0_ref)
             if has_local:
-                vg_free_ref[:] = vg0_ref[:]
-                dev_free_ref[:] = dev0_ref[:]
+                fill(vg_free_ref, vg0_ref)
+                fill(dev_free_ref, dev0_ref)
             if has_ports:
                 port_used_ref[:] = jnp.zeros_like(port_used_ref)
 
         iota_n = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
         iota_u = jax.lax.broadcasted_iota(jnp.int32, (U, 1), 0)
-        valid_row = nodevalid_ref[:]  # [1, N]
+        valid_row = nodevalid_ref[:]  # [SB, N]
         ones_1n = jnp.ones((1, N), jnp.float32)
 
-        A_rows = node_cnt_ref.shape[0]
+        A_rows = node_cnt_ref.shape[0] // SB
         Zk = zone_zn_ref.shape[0] // n_zkeys
+
+        # a spread constraint's weight is its topology key's, per scenario
+        if SB == 1:
+            def key_weight(key):
+                return kw_ref[0, key]
+        else:
+            kw_cols = [column(lambda s, k=k: kw_ref[s, k], jnp.float32) for k in range(n_zkeys + 1)]
+
+            def key_weight(key):
+                w = kw_cols[0]
+                for k in range(1, n_zkeys + 1):
+                    w = jnp.where(key == k, kw_cols[k], w)
+                return w
+
+        def pod_flag(ref, i):
+            """This step's entry of a per-scenario pod stream (the block's
+            SB streams lie CHUNK apart in its window)."""
+            if SB == 1:
+                return ref[i]
+            return column(lambda s: ref[s * CHUNK + i], jnp.int32)
 
         def _flag_row(flag_ref, n_rows):
             """Expand an SMEM int-flag table into a [1, n_rows] f32 vector
@@ -378,9 +495,9 @@ def _make_kernel(
             """Count of bound pods matching selector `sel` in the candidate
             node's domain under topology key index `key` (0 = hostname,
             1..K = zone keys; zone counts live in per-key row blocks)."""
-            host_cnt = node_cnt_ref[pl.ds(sel, 1), :]  # [1, N]
+            host_cnt = srows(node_cnt_ref, sel)  # [SB, N]
             k = jnp.maximum(key - 1, 0)
-            zrow = zone_cnt_ref[pl.ds(k * A_rows + sel, 1), :]  # [1, Zk]
+            zrow = srows(zone_cnt_ref, k * A_rows + sel)  # [SB, Zk]
             zone_gather = _dot(zrow, zone_zn_ref[pl.ds(k * Zk, Zk), :])
             has = has_zone_ref[pl.ds(k, 1), :]
             return jnp.where(key == 0, host_cnt, zone_gather), jnp.where(
@@ -443,7 +560,7 @@ def _make_kernel(
                 static_row = s_static[:]
             else:
                 static_row = static_ref[pl.ds(u, 1), :]  # [1, N] (validity applied separately)
-            if has_gpu:
+            if has_gpu and SB == 1:
                 for d in range(n_gpu):  # SMEM outputs have no default value
                     gpu_take_ref[d, i] = jnp.float32(0.0)
 
@@ -457,7 +574,7 @@ def _make_kernel(
                 gc_has_dev = jnp.zeros((1, N), jnp.float32)
                 for d in range(n_gpu):
                     valid_d = (gpu0_ref[pl.ds(d, 1), :] > 0).astype(jnp.float32)
-                    free_d = (gpu_free_ref[pl.ds(d, 1), :] > 0).astype(jnp.float32)
+                    free_d = (srows(gpu_free_ref, d) > 0).astype(jnp.float32)
                     gc_dyn_row = gc_dyn_row + valid_d * free_d
                     gc_has_dev = jnp.maximum(gc_has_dev, valid_d)
             fit = ones_1n
@@ -466,7 +583,7 @@ def _make_kernel(
                 alloc_r = alloc_ref[pl.ds(r, 1), :]
                 if use_gc and r == gc_row:
                     alloc_r = jnp.where(gc_has_dev > 0, gc_dyn_row, alloc_r)
-                over = (used_ref[pl.ds(r, 1), :] + req_r > alloc_r).astype(jnp.float32)
+                over = (srows(used_ref, r) + req_r > alloc_r).astype(jnp.float32)
                 fit = fit * jnp.where(req_r > 0, 1.0 - over, 1.0)
             # node validity is a runtime row (NOT folded into static_pass) so
             # scenario sweeps can vary it without re-marshalling the tables
@@ -482,8 +599,8 @@ def _make_kernel(
                     onehot_u_p = (iota_u == u).astype(jnp.float32)
                     my_ports = _dot(port_conf_hu_ref[:], onehot_u_p)  # [Hp, 1]
                 conflicts = _dot(
-                    my_ports.reshape(1, -1), (port_used_ref[:] > 0).astype(jnp.float32)
-                )  # [1, N]
+                    kron_row(my_ports.reshape(1, -1)), (port_used_ref[:] > 0).astype(jnp.float32)
+                )  # [SB, N]
                 feasible = feasible * (conflicts == 0).astype(jnp.float32)
 
             if has_gpu:
@@ -496,7 +613,7 @@ def _make_kernel(
                 gpu_chunks = floor_div32(gpu_free_ref[:], jnp.maximum(gmem, 1.0))
                 chunks_sum = jnp.zeros((1, N), jnp.float32)
                 for d in range(n_gpu):
-                    chunks_sum = chunks_sum + gpu_chunks[d:d + 1, :]
+                    chunks_sum = chunks_sum + gpu_chunks[d * SB:(d + 1) * SB, :]
                 gpu_ok = ((chunks_sum >= gcnt) & (gcnt > 0)).astype(jnp.float32)
                 feasible = jnp.where(gmem > 0, feasible * gpu_ok, feasible)
 
@@ -506,7 +623,7 @@ def _make_kernel(
                 lvm = lvm_ref[u]
                 best_vg_free = jnp.full((1, N), -1e30, jnp.float32)
                 for v in range(n_vg):
-                    best_vg_free = jnp.maximum(best_vg_free, vg_free_ref[pl.ds(v, 1), :])
+                    best_vg_free = jnp.maximum(best_vg_free, srows(vg_free_ref, v))
                 feasible = jnp.where(
                     lvm > 0, feasible * (best_vg_free >= lvm).astype(jnp.float32), feasible
                 )
@@ -517,7 +634,7 @@ def _make_kernel(
                         size = dsz_ref[m * n_dvol + vi, u]
                         cnt_fit = jnp.zeros((1, N), jnp.float32)
                         for d in range(n_dev):
-                            free_d = dev_free_ref[pl.ds(d, 1), :]
+                            free_d = srows(dev_free_ref, d)
                             media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
                             cnt_fit = cnt_fit + media_d * ((free_d >= size) & (free_d > 0)).astype(jnp.float32)
                         feasible = jnp.where(
@@ -539,11 +656,11 @@ def _make_kernel(
 
                 elig = aff_row * has_label
                 masked = jnp.where(elig > 0, cnt, jnp.float32(1e30))
-                min_cnt = jnp.min(masked)
+                min_cnt = vmin(masked)
                 ok = (cnt + sself_ref[c, u] - min_cnt <= skew) & (has_label > 0)
                 feasible = jnp.where(hardf, feasible * ok.astype(jnp.float32), feasible)
 
-                contrib = jnp.where(has_label > 0, cnt * sw_ref[c, u] + (skew - 1.0), 0.0)
+                contrib = jnp.where(has_label > 0, cnt * key_weight(sh_ref[c, u]) + (skew - 1.0), 0.0)
                 soft_raw = soft_raw + jnp.where(softf, contrib, 0.0)
                 ignored = jnp.maximum(ignored, jnp.where(softf, 1.0 - has_label, 0.0))
                 any_soft = jnp.maximum(any_soft, jnp.where(softf, 1.0, 0.0))
@@ -571,11 +688,9 @@ def _make_kernel(
                 at_self_all = jnp.float32(1.0)
                 for t in range(Ti):
                     cnt, has_label = sel_cnt(ats_ref[t, u], ath_ref[t, u])
-                    total_host = jnp.sum(node_cnt_ref[pl.ds(ats_ref[t, u], 1), :])
+                    total_host = vsum(srows(node_cnt_ref, ats_ref[t, u]))
                     at_k = jnp.maximum(ath_ref[t, u] - 1, 0)
-                    total_zone = jnp.sum(
-                        zone_cnt_ref[pl.ds(at_k * A_rows + ats_ref[t, u], 1), :]
-                    )
+                    total_zone = vsum(srows(zone_cnt_ref, at_k * A_rows + ats_ref[t, u]))
                     total = jnp.where(ath_ref[t, u] == 0, total_host, total_zone)
                     activef = ata_ref[t, u] == 1
                     term_ok = ((cnt > 0) & (has_label > 0)).astype(jnp.float32)
@@ -601,11 +716,11 @@ def _make_kernel(
                     my_gmatch = _dot(gmatch_ref[:], onehot_u_col)
                 m_row = my_gmatch.reshape(1, n_anti)
                 m_host = m_row * (g_key_row == 0).astype(jnp.float32)
-                sym_cnt = _dot(m_host, anti_node_ref[:])
+                sym_cnt = _dot(kron_row(m_host), anti_node_ref[:])
                 for zk in range(n_zkeys):
                     m_k = m_row * (g_key_row == zk + 1).astype(jnp.float32)
                     sym_cnt = sym_cnt + _dot(
-                        _dot(m_k, anti_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
+                        _dot(kron_row(m_k), anti_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
                     )
                 feasible = feasible * (1.0 - (sym_cnt > 0).astype(jnp.float32))
                 # score: incoming preferred terms
@@ -622,11 +737,11 @@ def _make_kernel(
                     my_pmatch = _dot(pmatch_ref[:], onehot_u_col)
                 pm_row = my_pmatch.reshape(1, n_pref)
                 pm_host = pm_row * (p_key_row == 0).astype(jnp.float32)
-                ip_raw = ip_raw + _dot(pm_host, prefw_node_ref[:])
+                ip_raw = ip_raw + _dot(kron_row(pm_host), prefw_node_ref[:])
                 for zk in range(n_zkeys):
                     pm_k = pm_row * (p_key_row == zk + 1).astype(jnp.float32)
                     ip_raw = ip_raw + _dot(
-                        _dot(pm_k, prefw_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
+                        _dot(kron_row(pm_k), prefw_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
                     )
 
             # --- scores
@@ -634,8 +749,8 @@ def _make_kernel(
             mem_req = mem_nz_ref[u]
             alloc_cpu = alloc_ref[pl.ds(V.RES_CPU, 1), :]
             alloc_mem = alloc_ref[pl.ds(V.RES_MEMORY, 1), :]
-            used_cpu = used_ref[pl.ds(V.RES_CPU, 1), :] + cpu_req
-            used_mem = used_ref[pl.ds(V.RES_MEMORY, 1), :] + mem_req
+            used_cpu = srows(used_ref, V.RES_CPU) + cpu_req
+            used_mem = srows(used_ref, V.RES_MEMORY) + mem_req
             # every quotient of the step is taken in one stacked block
             # below (_div_rows): its operands are gathered here first
             cap_cpu = jnp.maximum(alloc_cpu, 1.0)
@@ -665,35 +780,35 @@ def _make_kernel(
                 ) * MAX_SCORE
                 share_row = jnp.maximum(share_row, jnp.where(gc_req > 0, sh, 0.0))
             feas_b = feasible > 0
-            lo = jnp.min(jnp.where(feas_b, share_row, jnp.float32(1e30)))
-            hi = jnp.max(jnp.where(feas_b, share_row, jnp.float32(-1e30)))
+            lo = vmin(jnp.where(feas_b, share_row, jnp.float32(1e30)))
+            hi = vmax(jnp.where(feas_b, share_row, jnp.float32(-1e30)))
             rng = hi - lo
             quotients.append(((share_row - lo) * MAX_SCORE, rng))
 
             scored = feas_b & (ignored == 0)
-            smn = jnp.min(jnp.where(scored, soft_raw, jnp.float32(1e30)))
-            smx = jnp.max(jnp.where(scored, soft_raw, jnp.float32(-1e30)))
+            smn = vmin(jnp.where(scored, soft_raw, jnp.float32(1e30)))
+            smx = vmax(jnp.where(scored, soft_raw, jnp.float32(-1e30)))
             quotients.append((MAX_SCORE * (smx + smn - soft_raw), jnp.maximum(smx, 1.0)))
             if has_interpod:
                 # interpod_score normalization: min/max seeded with 0
                 ip_masked = jnp.where(feas_b, ip_raw, 0.0)
-                ip_hi = jnp.maximum(jnp.max(ip_masked), 0.0)
-                ip_lo = jnp.minimum(jnp.min(ip_masked), 0.0)
+                ip_hi = jnp.maximum(vmax(ip_masked), 0.0)
+                ip_lo = jnp.minimum(vmin(ip_masked), 0.0)
                 ip_rng = ip_hi - ip_lo
                 quotients.append((MAX_SCORE * (ip_raw - ip_lo), jnp.maximum(ip_rng, 1.0)))
             if has_na:
                 # NodeAffinity preferred-term weights, max-normalized over
                 # the feasible set (DefaultNormalizeScore)
                 na_row = s_na[:] if big_u else na_ref[pl.ds(u, 1), :]
-                na_max = jnp.max(jnp.where(feas_b, na_row, 0.0))
+                na_max = vmax(jnp.where(feas_b, na_row, 0.0))
                 quotients.append((na_row * MAX_SCORE, jnp.maximum(na_max, 1.0)))
             if has_tt:
                 # TaintToleration: intolerable PreferNoSchedule counts,
                 # reverse-normalized
                 tt_row = s_tt[:] if big_u else tt_ref[pl.ds(u, 1), :]
-                tt_max = jnp.max(jnp.where(feas_b, tt_row, 0.0))
+                tt_max = vmax(jnp.where(feas_b, tt_row, 0.0))
                 quotients.append((tt_row * MAX_SCORE, jnp.maximum(tt_max, 1.0)))
-            quotients = iter(_div_rows(quotients))
+            quotients = iter(_div_rows(quotients, (SB, N)))
 
             l_cpu = jnp.where((alloc_cpu == 0) | (used_cpu > alloc_cpu), 0.0, next(quotients))
             l_mem = jnp.where((alloc_mem == 0) | (used_mem > alloc_mem), 0.0, next(quotients))
@@ -734,7 +849,7 @@ def _make_kernel(
                 best_free = jnp.full((1, N), big_f, jnp.float32)
                 best_cap = jnp.zeros((1, N), jnp.float32)
                 for v in range(n_vg):
-                    free_v = vg_free_ref[pl.ds(v, 1), :]
+                    free_v = srows(vg_free_ref, v)
                     fits_v = free_v >= lvm
                     better = fits_v & (free_v < best_free)
                     best_free = jnp.where(better, free_v, best_free)
@@ -748,7 +863,7 @@ def _make_kernel(
                     need = dneed_ref[m, u]
                     first_cap = jnp.full((1, N), big_f, jnp.float32)
                     for d in range(n_dev):
-                        free_d = dev_free_ref[pl.ds(d, 1), :]
+                        free_d = srows(dev_free_ref, d)
                         media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
                         fitting = (media_d > 0) & (free_d >= size) & (free_d > 0)
                         first_cap = jnp.where(
@@ -757,8 +872,8 @@ def _make_kernel(
                     parts = parts + jnp.where(size > 0, div32(need * size, jnp.maximum(first_cap, 1.0)), 0.0)
                     count = count + jnp.where(size > 0, need, 0.0)
                 local_raw = jnp.where(count > 0, div32(parts, jnp.maximum(count, 1.0)) * 10.0, 0.0)
-                l_lo = jnp.min(jnp.where(feas_b, local_raw, big_f))
-                l_hi = jnp.max(jnp.where(feas_b, local_raw, -big_f))
+                l_lo = vmin(jnp.where(feas_b, local_raw, big_f))
+                l_hi = vmax(jnp.where(feas_b, local_raw, -big_f))
                 l_rng = l_hi - l_lo
                 score = score + jnp.where(l_rng > 0, div32((local_raw - l_lo) * MAX_SCORE, l_rng), 0.0)
             if has_avoid:
@@ -770,47 +885,46 @@ def _make_kernel(
             # --- selectHost: lowest index among maxima — Mosaic's argmax
             # breaks ties by HIGHEST index, diverging from the XLA scan
             masked_score = jnp.where(feas_b, score, jnp.float32(NEG))
-            mx_score = jnp.max(masked_score)
-            best = jnp.min(jnp.where(masked_score == mx_score, iota_n, N)).astype(jnp.int32)
-            any_feasible = jnp.max(feasible) > 0
+            mx_score = vmax(masked_score)
+            best = vmin(jnp.where(masked_score == mx_score, iota_n, N)).astype(jnp.int32)
+            any_feasible = vmax(feasible) > 0
             sel_choice = jnp.where(any_feasible, best, jnp.int32(-1))
-            is_forced = forced_ref[i] == 1
+            is_forced = pod_flag(forced_ref, i) == 1
             pin_u = pin_ref[u]
             choice = jnp.where(is_forced, jnp.where(pin_u >= 0, pin_u, -1), sel_choice)
-            do_bind = (valid_ref[i] == 1) & (choice >= 0)
-            chosen_ref[i] = jnp.where(do_bind, choice, -1)
+            do_bind = (pod_flag(valid_ref, i) == 1) & (choice >= 0)
+            if SB == 1:
+                chosen_ref[i] = jnp.where(do_bind, choice, -1)
+            else:  # the block's [SB, CHUNK] window in VMEM: column i
+                chosen_ref[:] = jnp.where(lane_chunk == i, jnp.where(do_bind, choice, -1), chosen_ref[:])
 
             # --- bind update
-            @pl.when(do_bind)
-            def _bind():
-                c = jnp.maximum(choice, 0)
-                onehot = (iota_n == c).astype(jnp.float32)  # [1, N]
-                iota_r = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
-                req_col = jnp.zeros((R, 1), jnp.float32)
-                for r in range(R):  # static unroll; .at[] would lower to scatter
-                    req_col = jnp.where(iota_r == r, req_ref[r, u], req_col)
-                used_ref[:] = used_ref[:] + req_col * onehot
+            def _bind(onehot, zone_rows):
+                """The chosen node's one-hot [SB, N] (a zero row where a
+                scenario binds nothing) and zone_rows(k), the [SB, Z]
+                one-hot of its zone under key k."""
+                if SB == 1:
+                    iota_r = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+                    req_col = jnp.zeros((R, 1), jnp.float32)
+                    for r in range(R):  # static unroll; .at[] would lower to scatter
+                        req_col = jnp.where(iota_r == r, req_ref[r, u], req_col)
+                    used_ref[:] = used_ref[:] + req_col * onehot
+                else:
+                    for r in range(R):
+                        set_srows(used_ref, r, srows(used_ref, r) + req_ref[r, u] * onehot)
 
                 if big_u:
                     m_col = col_of(s_match)  # [A, 1]
                 else:
                     onehot_u = (iota_u == u).astype(jnp.float32)  # [U, 1]
                     m_col = _dot(matches_ref[:], onehot_u)
-                # per-key [1, Zk] one-hot rows of the chosen node's zones —
-                # read from the 3-D [K, N, Z] table so every key's row sits
-                # at lane offset 0 (a lane-offset slice can't broadcast)
-                zrow_k = [
-                    zone_nz_ref[zk, pl.ds(c, 1), :] for zk in range(n_zkeys)
-                ]
-                node_cnt_ref[:] = node_cnt_ref[:] + m_col * onehot
+                zrow_k = [zone_rows(zk) for zk in range(n_zkeys)]
+                add_outer(node_cnt_ref, m_col, onehot)
                 for zk in range(n_zkeys):
-                    zone_cnt_ref[pl.ds(zk * A_rows, A_rows), :] = (
-                        zone_cnt_ref[pl.ds(zk * A_rows, A_rows), :]
-                        + m_col * zrow_k[zk]
-                    )
+                    add_outer(zone_cnt_ref, m_col, zrow_k[zk], base=zk * A_rows)
                 if has_ports:
                     p_col = col_of(s_port) if big_u else _dot(port_hu_ref[:], onehot_u)
-                    port_used_ref[:] = port_used_ref[:] + p_col * onehot
+                    add_outer(port_used_ref, p_col, onehot)
                 if has_gpu:
                     # device packing on the chosen node (computed for all
                     # nodes, applied via the one-hot): single-GPU tightest
@@ -819,38 +933,39 @@ def _make_kernel(
                     gcnt = gcnt_ref[u]
                     best_free = jnp.full((1, N), 1e30, jnp.float32)
                     for d in range(n_gpu):
-                        free_d = gpu_free_ref[pl.ds(d, 1), :]
+                        free_d = srows(gpu_free_ref, d)
                         best_free = jnp.where(free_d >= gmem, jnp.minimum(best_free, free_d), best_free)
                     assigned = jnp.zeros((1, N), jnp.float32)
                     cum = jnp.zeros((1, N), jnp.float32)
                     for d in range(n_gpu):
-                        free_d = gpu_free_ref[pl.ds(d, 1), :]
+                        free_d = srows(gpu_free_ref, d)
                         fits_d = (free_d >= gmem).astype(jnp.float32)
                         take_tight = fits_d * (free_d == best_free).astype(jnp.float32) * (1.0 - jnp.minimum(assigned, 1.0))
                         assigned = assigned + take_tight
-                        chunks_d = gpu_chunks[d:d + 1, :]
+                        chunks_d = gpu_chunks[d * SB:(d + 1) * SB, :]
                         take_greedy = jnp.clip(gcnt - cum, 0.0, chunks_d)
                         cum = cum + chunks_d
                         take_d = jnp.where(gcnt == 1, take_tight, take_greedy)
                         take_d = jnp.where(gmem > 0, take_d, 0.0)
-                        gpu_free_ref[pl.ds(d, 1), :] = free_d - take_d * gmem * onehot
-                        gpu_take_ref[d, i] = jnp.sum(take_d * onehot)
+                        set_srows(gpu_free_ref, d, free_d - take_d * gmem * onehot)
+                        if SB == 1:
+                            gpu_take_ref[d, i] = jnp.sum(take_d * onehot)
                 if has_local:
                     # LVM: tightest-fitting VG (first among equals)
                     lvm = lvm_ref[u]
                     big_f = jnp.float32(1e30)
                     best_free = jnp.full((1, N), big_f, jnp.float32)
                     for v in range(n_vg):
-                        free_v = vg_free_ref[pl.ds(v, 1), :]
+                        free_v = srows(vg_free_ref, v)
                         best_free = jnp.where(free_v >= lvm, jnp.minimum(best_free, free_v), best_free)
                     taken_vg = jnp.zeros((1, N), jnp.float32)
                     for v in range(n_vg):
-                        free_v = vg_free_ref[pl.ds(v, 1), :]
+                        free_v = srows(vg_free_ref, v)
                         take_v = (
                             (free_v >= lvm) & (free_v == best_free)
                         ).astype(jnp.float32) * (1.0 - jnp.minimum(taken_vg, 1.0))
                         taken_vg = taken_vg + take_v
-                        vg_free_ref[pl.ds(v, 1), :] = free_v - jnp.maximum(lvm, 0.0) * take_v * onehot
+                        set_srows(vg_free_ref, v, free_v - jnp.maximum(lvm, 0.0) * take_v * onehot)
                     # exclusive devices: one device per volume, smallest
                     # volume onto the smallest-capacity fitting free device
                     # (common.go:290-349; ties by lowest device index) —
@@ -862,7 +977,7 @@ def _make_kernel(
                             size = dsz_ref[m * n_dvol + vi, u]
                             best_cap = jnp.full((1, N), big_cap, jnp.float32)
                             for d in range(n_dev):
-                                free_d = dev_free_ref[pl.ds(d, 1), :]
+                                free_d = srows(dev_free_ref, d)
                                 media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
                                 cand_d = (
                                     (media_d > 0) & (free_d >= size) & (free_d > 0)
@@ -875,7 +990,7 @@ def _make_kernel(
                                 )
                             assigned = jnp.zeros((1, N), jnp.float32)
                             for d in range(n_dev):
-                                free_d = dev_free_ref[pl.ds(d, 1), :]
+                                free_d = srows(dev_free_ref, d)
                                 media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
                                 cand_d = (
                                     (media_d > 0) & (free_d >= size) & (free_d > 0)
@@ -887,23 +1002,42 @@ def _make_kernel(
                                 take_d = take_d * jnp.where(size > 0, 1.0, 0.0)
                                 assigned = assigned + take_d
                                 taken_rows[d] = jnp.maximum(taken_rows[d], take_d)
-                                dev_free_ref[pl.ds(d, 1), :] = free_d * (1.0 - take_d * onehot)
+                                set_srows(dev_free_ref, d, free_d * (1.0 - take_d * onehot))
                 if has_interpod:
                     a_col = col_of(s_antig) if big_u else _dot(antig_ref[:], onehot_u)
-                    anti_node_ref[:] = anti_node_ref[:] + a_col * onehot
+                    add_outer(anti_node_ref, a_col, onehot)
                     for zk in range(n_zkeys):
                         key_mask = (g_key_col == zk + 1).astype(jnp.float32)
-                        anti_zone_ref[:] = (
-                            anti_zone_ref[:] + a_col * key_mask * zrow_k[zk]
-                        )
+                        add_outer(anti_zone_ref, a_col * key_mask, zrow_k[zk])
                     p_col = col_of(s_prefg) if big_u else _dot(prefg_ref[:], onehot_u)
-                    prefw_node_ref[:] = prefw_node_ref[:] + p_col * onehot
+                    add_outer(prefw_node_ref, p_col, onehot)
                     for zk in range(n_zkeys):
                         key_mask = (p_key_col == zk + 1).astype(jnp.float32)
-                        prefw_zone_ref[:] = (
-                            prefw_zone_ref[:] + p_col * key_mask * zrow_k[zk]
-                        )
+                        add_outer(prefw_zone_ref, p_col * key_mask, zrow_k[zk])
 
+            if SB == 1:
+                @pl.when(do_bind)
+                def _():
+                    c = jnp.maximum(choice, 0)
+                    # the chosen node's [1, Zk] zone one-hot under each key —
+                    # read from the 3-D [K, N, Z] table so every key's row
+                    # sits at lane offset 0 (a lane-offset slice can't
+                    # broadcast)
+                    _bind((iota_n == c).astype(jnp.float32), lambda zk: zone_nz_ref[zk, pl.ds(c, 1), :])
+            else:
+                # every scenario's bind at once: a scenario that binds
+                # nothing has a zero one-hot row, and every update adds its
+                # product (or, for a device, scales by 1 - it). A zone row is
+                # the one-hot of the chosen node's zone id (-1: none, or no
+                # bind), read by an exact reduction over the node lanes
+                onehot = ((iota_n == choice) & do_bind).astype(jnp.float32)  # [SB, N]
+                iota_z = jax.lax.broadcasted_iota(jnp.int32, (1, zone_nz_ref.shape[2]), 1)
+
+                def zone_rows(zk):
+                    zid = vsum(onehot * (zone_id_ref[pl.ds(zk, 1), :] + 1.0)) - 1.0  # [SB, 1]
+                    return (iota_z.astype(jnp.float32) == zid).astype(jnp.float32)
+
+                _bind(onehot, zone_rows)
             return 0
 
         jax.lax.fori_loop(0, tmpl_ref.shape[0], body, 0)
@@ -920,7 +1054,7 @@ def _make_kernel(
 # what selects the generated kernel; everything else run_fast_scan reads
 # comes from the shapes of its traced arguments
 _STATIC = ("has_interpod", "has_gpu", "has_local", "has_ports", "has_na", "has_tt",
-           "has_avoid", "interpret", "big_u", "gc_row")
+           "has_avoid", "interpret", "big_u", "gc_row", "sublanes")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -939,18 +1073,24 @@ def run_fast_scan(
     interpret: bool = False,
     big_u: bool = False,
     gc_row: int = -1,
+    sublanes: int = 1,
 ):
-    """Execute the megakernel over S scenarios in ONE dispatch. The scenario
-    axis is the leading grid dimension: tmpl_ids is [P] (P a multiple of
-    CHUNK, shared), pod_valid/forced are [S, P], ``fi.node_valid`` is
-    [S, 1, N] and ``fi.spr_weight`` [S, U, Cs]; every other table is shared.
-    A plain schedule is S = 1. Returns (chosen [S, P] i32, used_final
-    [S, R, N], gpu_take [S, P, Gd], gpu_final [S, Gd, N], vg_final
-    [S, Vg, N], dev_final [S, Dv, N]).
+    """Execute the megakernel over S scenarios in ONE dispatch: tmpl_ids is
+    [P] (P a multiple of CHUNK, shared), pod_valid/forced are [S, P],
+    ``fi.node_valid`` is [S, 1, N] and ``fi.key_weight`` [S, K+1]; every
+    other table is shared. A plain schedule is S = 1. `sublanes` (SB, 1 or
+    8) scenarios share each kernel step, one a sublane: the grid is
+    (S / SB, P / CHUNK), and S must be a multiple of SB (fastpath.sweep pads
+    a packed sweep with scenarios that have no valid node and no valid pod).
+    Returns (chosen [S, P] i32, used_final [S, R, N], gpu_take [S, P, Gd],
+    gpu_final [S, Gd, N], vg_final [S, Vg, N], dev_final [S, Dv, N]); a
+    packed run (SB > 1) writes no device takes and returns None for
+    gpu_take.
 
     `big_u` keeps the [U, N] / [X, U] template tables in HBM and DMAs one
     row/column per pod step into VMEM scratch — VMEM use then no longer
-    scales with U, lifting the template cap (fastpath.applicable).
+    scales with U, lifting the template cap (fastpath.applicable). One DMA
+    a step serves every scenario of a block.
 
     This is the one entry to the kernel and it is jitted (the XLA module is
     `jit_run_fast_scan`): `fi` and the three pod streams are traced, the
@@ -960,14 +1100,19 @@ def run_fast_scan(
     enqueue. The casts and layout changes below and the normalisation of the
     outputs are part of the same program. `run_fast_scan.__wrapped__` is the
     plain function (the tests compare the two)."""
+    SB = sublanes
+    assert SB in (1, 8), SB
     P = tmpl_ids.shape[0]
     assert P % CHUNK == 0, P
     S = pod_valid.shape[0]
     assert pod_valid.shape == forced.shape == (S, P), (pod_valid.shape, forced.shape)
+    assert S % SB == 0, (S, SB)
+    B = S // SB
     R, N = fi.alloc_T.shape
     assert fi.node_valid.shape == (S, 1, N), fi.node_valid.shape
     A = fi.matches_AU.shape[0]
     K = fi.has_zone.shape[0]  # number of non-hostname topology keys (>= 1)
+    assert fi.key_weight.shape == (S, K + 1), fi.key_weight.shape
     Z = fi.zone_NZ.shape[2]
     G = fi.antig_GU.shape[0]
     Gp = fi.prefg_GU.shape[0]
@@ -976,26 +1121,37 @@ def run_fast_scan(
     Dv = fi.dev0_DN.shape[0]
     Hp = fi.port_HU.shape[0]
     n_chunks = P // CHUNK
-    grid = (S, n_chunks)
+    grid = (B, n_chunks)
 
     smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     # big-U tables are pinned to HBM (not ANY): the point of the mode is
     # that they stay out of VMEM even when one happens to fit
     anyspace = lambda: pl.BlockSpec(memory_space=pltpu.HBM)
-    # per-scenario pod streams are FLAT [S·P] with 1-D CHUNK blocks: a
-    # [S, P] array blocked (1, CHUNK) breaks the TPU rule that a block's
-    # second-minor dim is a multiple of 8 or the whole axis (which is why
-    # jax.vmap over the pallas_call never lowered)
-    shared_stream = lambda: pl.BlockSpec((CHUNK,), lambda s, i: (i,), memory_space=pltpu.SMEM)
+    # per-scenario pod streams are FLAT with 1-D blocks of SB·CHUNK, a
+    # block's SB streams CHUNK apart: a [S, P] array blocked (1, CHUNK)
+    # breaks the TPU rule that a block's second-minor dim is a multiple of 8
+    # or the whole axis (which is why jax.vmap over the pallas_call never
+    # lowered)
+    shared_stream = lambda: pl.BlockSpec((CHUNK,), lambda b, i: (i,), memory_space=pltpu.SMEM)
     scen_stream = lambda: pl.BlockSpec(
-        (CHUNK,), lambda s, i: (s * n_chunks + i,), memory_space=pltpu.SMEM
+        (SB * CHUNK,), lambda b, i: (b * n_chunks + i,), memory_space=pltpu.SMEM
     )
 
-    def per_scenario(space, *tail):  # [S, *tail] → one scenario's [*tail] block
+    def flat_streams(arr):  # [S, P] -> [B, chunk, SB, CHUNK], flat
+        if SB == 1:
+            return arr.reshape(S * P)
+        return arr.reshape(B, SB, n_chunks, CHUNK).transpose(0, 2, 1, 3).reshape(S * P)
+
+    def per_block(space, *tail):  # [B, *tail] → one block's [*tail]
         return pl.BlockSpec(
-            (pl.Squeezed(), *tail), lambda s, i: (s,) + (0,) * len(tail), memory_space=space
+            (pl.Squeezed(), *tail), lambda b, i: (b,) + (0,) * len(tail), memory_space=space
         )
+
+    def unpack(out, X):  # [B, X*SB, N] (row x*SB + s) → [S, X, N]
+        if SB == 1:
+            return out
+        return out.reshape(B, X, SB, N).transpose(0, 2, 1, 3).reshape(S, X, N)
 
     _I32 = {"tmpl", "valid", "forced", "pin", "spr_active", "spr_key", "spr_sel",
             "spr_hard", "at_active", "at_key", "at_sel", "an_active", "an_key",
@@ -1009,16 +1165,20 @@ def run_fast_scan(
     # with X ≤ 8 would cost 128/X× the memory — fatal at big U (a [2048, 2]
     # table would pad to 1 MB, the whole SMEM)
     _SMEM_T = {"req", "spr_active", "spr_key", "spr_sel", "spr_skew",
-               "spr_hard", "spr_self", "spr_weight",
+               "spr_hard", "spr_self",
                "at_active", "at_key", "at_sel", "at_self",
                "an_active", "an_key", "an_sel",
                "pt_active", "pt_key", "pt_sel", "pt_w",
                "dev_req", "dev_need", "dev_sizes"}
-    layout = _input_layout(has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u)
+    layout = _input_layout(has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u, SB > 1)
     in_specs, args = [], []
     for name, kind in layout:
         if kind == "stream":
             src = {"tmpl": tmpl_ids, "valid": pod_valid, "forced": forced}[name]
+        elif name == "zone_id":
+            # each node's zone index under each key, -1 where it has no label
+            zone_NZ = jnp.asarray(fi.zone_NZ, jnp.float32)
+            src = jnp.sum(zone_NZ * jnp.arange(1, Z + 1, dtype=jnp.float32), axis=-1) - 1.0
         else:
             src = getattr(fi, name)
         arr = jnp.asarray(src, jnp.int32 if name in _I32 else jnp.float32)
@@ -1033,9 +1193,10 @@ def run_fast_scan(
         if name == "tmpl":
             spec = shared_stream()
         elif kind == "stream":
-            arr, spec = arr.reshape(S * P), scen_stream()
-        elif name in ("node_valid", "spr_weight"):  # the per-scenario tables
-            spec = per_scenario({"smem": pltpu.SMEM, "vmem": pltpu.VMEM}[kind], *arr.shape[1:])
+            arr, spec = flat_streams(arr), scen_stream()
+        elif name in ("node_valid", "key_weight"):  # the per-scenario tables
+            arr = arr.reshape(B, SB, arr.shape[-1])
+            spec = per_block({"smem": pltpu.SMEM, "vmem": pltpu.VMEM}[kind], SB, arr.shape[-1])
         else:
             spec = {"smem": smem, "vmem": vmem, "any": anyspace}[kind]()
         in_specs.append(spec)
@@ -1044,36 +1205,44 @@ def run_fast_scan(
     # outputs: feature-gated, like the inputs. gpu_take is [Gd, S·P] (device
     # rows × pod lanes): an SMEM window's minor dim pads to 128 lanes, so the
     # natural [P, Gd] layout would burn 1 MB of the chip's 1 MB SMEM on
-    # 8-lane rows — transposed, the window is [Gd, CHUNK] = 32 KB.
-    out_shape = [jax.ShapeDtypeStruct((S * P,), jnp.int32),
-                 jax.ShapeDtypeStruct((S, R, N), jnp.float32)]
-    out_specs = [scen_stream(), per_scenario(pltpu.VMEM, R, N)]
+    # 8-lane rows — transposed, the window is [Gd, CHUNK] = 32 KB. A packed
+    # block writes its chosen nodes as [SB, CHUNK] VMEM windows, a column a
+    # step, and no gpu_take (fastpath.sweep reads neither takes nor devices)
+    if SB == 1:
+        out_shape = [jax.ShapeDtypeStruct((S * P,), jnp.int32)]
+        out_specs = [scen_stream()]
+    else:
+        out_shape = [jax.ShapeDtypeStruct((S, P), jnp.int32)]
+        out_specs = [pl.BlockSpec((SB, CHUNK), lambda b, i: (b, i), memory_space=pltpu.VMEM)]
+    out_shape += [jax.ShapeDtypeStruct((B, R * SB, N), jnp.float32)]
+    out_specs += [per_block(pltpu.VMEM, R * SB, N)]
     if has_gpu:
-        out_shape += [jax.ShapeDtypeStruct((Gd, S * P), jnp.float32),
-                      jax.ShapeDtypeStruct((S, Gd, N), jnp.float32)]
-        out_specs += [pl.BlockSpec((Gd, CHUNK), lambda s, i: (0, s * n_chunks + i),
-                                   memory_space=pltpu.SMEM),
-                      per_scenario(pltpu.VMEM, Gd, N)]
+        if SB == 1:
+            out_shape += [jax.ShapeDtypeStruct((Gd, S * P), jnp.float32)]
+            out_specs += [pl.BlockSpec((Gd, CHUNK), lambda b, i: (0, b * n_chunks + i),
+                                       memory_space=pltpu.SMEM)]
+        out_shape += [jax.ShapeDtypeStruct((B, Gd * SB, N), jnp.float32)]
+        out_specs += [per_block(pltpu.VMEM, Gd * SB, N)]
     if has_local:
-        out_shape += [jax.ShapeDtypeStruct((S, Vg, N), jnp.float32),
-                      jax.ShapeDtypeStruct((S, Dv, N), jnp.float32)]
-        out_specs += [per_scenario(pltpu.VMEM, Vg, N), per_scenario(pltpu.VMEM, Dv, N)]
+        out_shape += [jax.ShapeDtypeStruct((B, Vg * SB, N), jnp.float32),
+                      jax.ShapeDtypeStruct((B, Dv * SB, N), jnp.float32)]
+        out_specs += [per_block(pltpu.VMEM, Vg * SB, N), per_block(pltpu.VMEM, Dv * SB, N)]
 
-    scratch = [pltpu.VMEM((R, N), jnp.float32),
-               pltpu.VMEM((A, N), jnp.float32),
-               pltpu.VMEM((K * A, Z), jnp.float32)]
+    scratch = [pltpu.VMEM((R * SB, N), jnp.float32),
+               pltpu.VMEM((A * SB, N), jnp.float32),
+               pltpu.VMEM((K * A * SB, Z), jnp.float32)]
     if has_interpod:
-        scratch += [pltpu.VMEM((G, N), jnp.float32),
-                    pltpu.VMEM((G, Z), jnp.float32),
-                    pltpu.VMEM((Gp, N), jnp.float32),
-                    pltpu.VMEM((Gp, Z), jnp.float32)]
+        scratch += [pltpu.VMEM((G * SB, N), jnp.float32),
+                    pltpu.VMEM((G * SB, Z), jnp.float32),
+                    pltpu.VMEM((Gp * SB, N), jnp.float32),
+                    pltpu.VMEM((Gp * SB, Z), jnp.float32)]
     if has_gpu:
-        scratch += [pltpu.VMEM((Gd, N), jnp.float32)]
+        scratch += [pltpu.VMEM((Gd * SB, N), jnp.float32)]
     if has_local:
-        scratch += [pltpu.VMEM((Vg, N), jnp.float32),
-                    pltpu.VMEM((Dv, N), jnp.float32)]
+        scratch += [pltpu.VMEM((Vg * SB, N), jnp.float32),
+                    pltpu.VMEM((Dv * SB, N), jnp.float32)]
     if has_ports:
-        scratch += [pltpu.VMEM((Hp, N), jnp.float32)]
+        scratch += [pltpu.VMEM((Hp * SB, N), jnp.float32)]
 
     if big_u:
         # per-step scratch: rows [1, N] for the [U, N] tables, 128-lane
@@ -1102,7 +1271,7 @@ def run_fast_scan(
     out = pl.pallas_call(
         _make_kernel(
             has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid,
-            G, Gp, Gd, Vg, Dv, fi.dev_sizes.shape[1] // 2, big_u, K, gc_row,
+            G, Gp, Gd, Vg, Dv, fi.dev_sizes.shape[1] // 2, big_u, K, gc_row, SB,
         ),
         grid=grid,
         out_shape=tuple(out_shape),
@@ -1120,18 +1289,21 @@ def run_fast_scan(
     # normalize to the fixed 6-tuple callers expect — absent features report
     # their initial state / zero takes
     res = list(out)
-    chosen, used_T = res[0].reshape(S, P), res[1]
+    chosen, used_T = res[0].reshape(S, P), unpack(res[1], R)
     idx = 2
     if has_gpu:
-        gpu_take = res[idx].reshape(Gd, S, P).transpose(1, 2, 0)
-        gpu_T = res[idx + 1]
-        idx += 2
+        gpu_take = None
+        if SB == 1:
+            gpu_take = res[idx].reshape(Gd, S, P).transpose(1, 2, 0)
+            idx += 1
+        gpu_T = unpack(res[idx], Gd)
+        idx += 1
     else:
-        gpu_take = jnp.zeros((S, P, Gd), jnp.float32)
+        gpu_take = jnp.zeros((S, P, Gd), jnp.float32) if SB == 1 else None
         gpu_T = jnp.broadcast_to(jnp.asarray(fi.gpu0_DN, jnp.float32), (S, Gd, N))
     if has_local:
-        vg_T = res[idx]
-        dev_T = res[idx + 1]
+        vg_T = unpack(res[idx], Vg)
+        dev_T = unpack(res[idx + 1], Dv)
     else:
         vg_T = jnp.broadcast_to(jnp.asarray(fi.vg0_VN, jnp.float32), (S, Vg, N))
         dev_T = jnp.broadcast_to(jnp.asarray(fi.dev0_DN, jnp.float32), (S, Dv, N))

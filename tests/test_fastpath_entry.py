@@ -104,7 +104,8 @@ def test_two_schedules_of_equal_shapes_trace_once():
 
 CHANGES = {
     # what changes between the warm call and the next: (warm, changed, the cause the compile watch names)
-    "scenarios": (lambda: _sweep(_prep(), 2), lambda: _sweep(_prep(), 3), "shape"),
+    # a sweep packs eight scenarios a block: 2 and 8 are one block, 9 two
+    "scenarios": (lambda: _sweep(_prep(), 2), lambda: _sweep(_prep(), 9), "shape"),
     "nodes": (lambda: _schedule(_prep(8)), lambda: _schedule(_prep(130)), "shape"),
     "feature_flag": (lambda: _schedule(_prep()), lambda: _schedule(_prep(kind="ports")), "static"),
     "big_u": (lambda: _schedule(_prep()), lambda: _schedule(_prep(), big_u=True), "static"),
@@ -126,7 +127,7 @@ def test_a_new_signature_traces_once_more_and_the_watch_names_the_cause(what):
 def test_the_jitted_entry_returns_what_the_function_it_wraps_returns(kind, big_u):
     prep = _prep(kind=kind)
     fi, _meta = fastpath.build_inputs(prep)
-    fi = fi._replace(node_valid=fi.node_valid[None], spr_weight=fi.spr_weight[None])
+    fi = fi._replace(node_valid=fi.node_valid[None], key_weight=fi.key_weight[None])
     P = len(prep.ordered)
     tmpl = np.zeros(CHUNK, np.int32)
     tmpl[:P] = np.asarray(prep.tmpl_ids)

@@ -1,0 +1,76 @@
+"""The megakernel compiles for a v5e chip at the benchmark cells' own widths,
+one scenario a step and eight (ISSUE 38): the TPU's compiler is installed
+here and compiles for a chip that is described, not attached, so a kernel
+that Mosaic refuses (a layout it cannot broadcast, a slice off the tiling,
+too much VMEM) fails here and not on the chip. The interpreter runs none of
+these checks. Nothing runs: results are the other tests'. Tier-1, a second
+or two a case."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from opensim_tpu.ops.pallas_scan import CHUNK, FastInputs, run_fast_scan
+
+_I32 = {"pin", "spr_active", "spr_key", "spr_sel", "spr_hard", "at_active", "at_key", "at_sel",
+        "an_active", "an_key", "an_sel", "pt_active", "pt_key", "pt_sel", "anti_g_key", "prefg_key"}
+_OFF = dict(has_interpod=False, has_gpu=False, has_local=False, has_ports=False, has_na=False,
+            has_tt=False, has_avoid=False, gc_row=-1)
+# (widths, flags, pod chunks): plan-short's k8s-5k-50k sweep (4,736 nodes, 4
+# resources, 20 templates, 24 selectors, two spread constraints a template)
+# and plan-gpushare's openb-gpushare-1523 (1,664 nodes, 866 templates in
+# big-U mode, eight devices a node)
+SHAPES = {
+    "plan-short": (dict(N=4736, R=4, U=20, A=24, Cs=2), dict(_OFF), 50),
+    "plan-gpushare": (dict(N=1664, R=6, U=866, A=8, Cs=1), dict(_OFF, has_gpu=True, big_u=True), 9),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: the tests skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache and
+    # cannot be read back without one: keep it out
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _inputs(dev, S, N, R, U, A, Cs, K=1, Z=128, X=8):
+    rows = dict(
+        alloc_T=(R, N), used0_T=(R, N), static_pass=(U, N), aff_mask=(U, N), share_raw=(U, N),
+        zone_NZ=(K, N, Z), zone_ZN=(K * Z, N), has_zone=(K, N), matches_AU=(A, U), node_valid=(S, 1, N),
+        req=(U, R), cpu_nz=(U,), mem_nz=(U,), pin=(U,), key_weight=(S, K + 1),
+        **{n: (U, Cs) for n in ("spr_active", "spr_key", "spr_sel", "spr_skew", "spr_hard", "spr_self")},
+        **{n: (U, 1) for n in ("at_active", "at_key", "at_sel", "at_self", "an_active", "an_key", "an_sel",
+                               "pt_active", "pt_key", "pt_sel", "pt_w")},
+        anti_g_key=(X,), prefg_key=(X,), antig_GU=(X, U), gmatch_GU=(X, U), prefg_GU=(X, U), pmatch_GU=(X, U),
+        gpu_mem=(U,), gpu_cnt=(U,), gpu0_DN=(X, N), lvm_req=(U,), dev_req=(U, 2), dev_need=(U, 2), dev_sizes=(U, 2),
+        vg_cap_VN=(X, N), vg0_VN=(X, N), dev_cap_DN=(X, N), dev0_DN=(X, N), dev_media_DN=(2 * X, N),
+        port_HU=(X, U), port_conf_HU=(X, U), na_raw=(U, N), tt_raw=(U, N), avoid_raw=(U, N),
+    )
+    return FastInputs(**{k: jax.ShapeDtypeStruct(v, jnp.int32 if k in _I32 else jnp.float32, sharding=dev)
+                         for k, v in rows.items()})
+
+
+@pytest.mark.parametrize("sublanes", [1, 8])
+@pytest.mark.parametrize("cell", sorted(SHAPES))
+def test_the_kernel_compiles_for_the_chip_at_a_cells_widths(one_chip, cell, sublanes):
+    widths, flags, chunks = SHAPES[cell]
+    S, P = 2 * sublanes, chunks * CHUNK  # two scenario blocks
+    stream = lambda *shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    lowered = run_fast_scan.lower(
+        _inputs(one_chip, S, **widths), stream(P, dt=jnp.int32), stream(S, P, dt=jnp.bool_),
+        stream(S, P, dt=jnp.bool_), sublanes=sublanes, **flags,
+    )
+    assert "tpu_custom_call" in lowered.compile().as_text()

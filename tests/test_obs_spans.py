@@ -214,7 +214,9 @@ def test_the_launch_says_what_it_launched_and_whether_it_compiled(megakernel_tra
     # ISSUE 34: the attributes it had, and whether the jitted entry traced
     assert set(launch.attrs) == {
         "scenarios", "pods", "nodes", "templates", "big_u", "gpu_devices", "backend_compiles", "cache_hits", "entry",
+        "sublanes", "blocks", "pad_scenarios",  # ISSUE 38: a schedule is one block of one scenario
     }
+    assert (launch.attrs["sublanes"], launch.attrs["blocks"], launch.attrs["pad_scenarios"]) == (1, 1, 0)
     assert launch.attrs["entry"] in ("traced", "cached")
 
 

@@ -482,10 +482,16 @@ def test_div32_inside_the_interpreted_kernel_block_is_the_ieee_quotient():
 
     a, b = (x.astype(np.float32).reshape(4, 5000)[:, :640] for x in _operands()["cpu"])
     rows = [(a[j:j + 1], b[j:j + 1]) for j in range(3)] + [(a[3:4], np.float32(37.0))]
-    got = pallas_scan._div_rows([(np.asarray(n), d) for n, d in rows])
+    got = pallas_scan._div_rows([(np.asarray(n), d) for n, d in rows], (1, 640))
     for (n, d), q in zip(rows, got):
         assert np.array_equal(np.asarray(q), n / d)
     # nine quotients take two blocks and come back in order
     nine = [(a[j % 4:j % 4 + 1] + np.float32(j), b[(j + 1) % 4:(j + 1) % 4 + 1]) for j in range(9)]
-    for (n, d), q in zip(nine, pallas_scan._div_rows(nine)):
+    for (n, d), q in zip(nine, pallas_scan._div_rows(nine, (1, 640))):
         assert np.array_equal(np.asarray(q), n / d)
+    # ISSUE 38: in a packed step each quotient is [8, N], a block of its own;
+    # a denominator may be a column, one a scenario
+    a8, b8 = np.concatenate([a, a + 1]), np.concatenate([b, b[::-1]])
+    col = b8[:, :1] + np.float32(3)
+    for (n, d), q in zip([(a8, b8), (a8, col), (a[:1], col)], pallas_scan._div_rows([(a8, b8), (a8, col), (a[:1], col)], (8, 640))):
+        assert np.array_equal(np.asarray(q), np.broadcast_to(n / d, (8, 640)))

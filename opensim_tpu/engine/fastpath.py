@@ -24,7 +24,7 @@ from ..obs.profile import launch_span, observed_jit_call
 from ..ops import kernels
 from ..ops.pallas_scan import CHUNK, VMEM_LIMIT_BYTES, FastInputs, run_fast_scan
 from . import select
-from .schedconfig import DEFAULT_CONFIG
+from .schedconfig import kernel_gap
 
 HOSTNAME = "kubernetes.io/hostname"
 
@@ -52,9 +52,11 @@ def why_not(prep, config=None) -> Optional[str]:
     simulation can run on it, else a one-line reason (surfaced as engine
     attribution — VERDICT r4 #3). The kernel covers: static filters + fit +
     least/balanced/share + topology spread + inter-pod terms, hostname plus
-    at most four other topology keys (stacked per-key count blocks)."""
-    if config is not None and config != DEFAULT_CONFIG:
-        return "non-default scheduler config (weight/disable merges run on the XLA or C++ engine)"
+    at most four other topology keys (stacked per-key count blocks), and a
+    config's score weights and RequestedToCapacityRatio term."""
+    gap = kernel_gap(config)
+    if gap is not None:
+        return f"a scheduler config the kernel cannot compute ({gap})"
     f = prep.features
     ec = prep.ec_np if prep.ec_np is not None else prep.ec
     if f.ports and int(ec.ports.max() if ec.ports.size else -1) >= 64:
@@ -539,14 +541,15 @@ def _gpu_rows(prep, fi: FastInputs) -> int:
 
 def _launch(
     prep, fi: FastInputs, tmpl_ids, pod_valid, forced, interpret: bool, big_u: bool,
-    sublanes: int = 1, pad: int = 0,
+    sublanes: int = 1, pad: int = 0, config=None,
 ):
     """`mk.launch`: everything the host does to get the kernel onto the
     device, for `fi` with its per-scenario rows set, `tmpl_ids` [P] and
     `pod_valid`/`forced` [S, P], `sublanes` scenarios a step, the last `pad`
     of the S padding. The span says `scenarios` (those asked for),
     `sublanes`, `blocks` (the grid's scenario blocks, S / sublanes) and
-    `pad_scenarios`. run_fast_scan is one jitted function, entered
+    `pad_scenarios`. `config` is the score profile, a static argument (None
+    for the default one). run_fast_scan is one jitted function, entered
     through the compile watch as `megakernel`: a signature's first call in a
     process traces and lowers the kernel and looks the executable up in the
     persistent cache (the span says `entry="traced"`,
@@ -565,7 +568,7 @@ def _launch(
     ):
         return observed_jit_call(
             "megakernel", run_fast_scan, (fi, tmpl_ids, pod_valid, forced),
-            dict(interpret=interpret, big_u=big_u, sublanes=sublanes, **_kernel_flags(prep)),
+            dict(interpret=interpret, big_u=big_u, sublanes=sublanes, config=config, **_kernel_flags(prep)),
         )
 
 
@@ -615,7 +618,7 @@ def _scenario_rows(prep, fi: FastInputs, masks):
 
 def sweep(
     prep, node_valid_masks, pod_valid_masks, forced_masks,
-    interpret: Optional[bool] = None, big_u: Optional[bool] = None,
+    interpret: Optional[bool] = None, big_u: Optional[bool] = None, config=None,
 ):
     """Scenario sweep on the megakernel: ALL scenarios in ONE batched
     dispatch. A step of the kernel holds `sweep_sublanes` scenarios, eight
@@ -628,7 +631,8 @@ def sweep(
     [S, N, R], chosen [S, P], vg_used [S]) matching
     parallel.scenarios.SweepResult, the padding dropped. `big_u=None` defers
     to the use_big_u heuristic (tests override it to exercise the HBM-DMA
-    path on small shapes)."""
+    path on small shapes). `config` is the scheduler config whose score
+    profile the kernel computes (``why_not`` has admitted it)."""
     interpret = _resolve_interpret(interpret)
     S = node_valid_masks.shape[0]
     P = pod_valid_masks.shape[1]
@@ -656,7 +660,7 @@ def sweep(
         fi, nv_all = _scenario_rows(prep, fi, masks)
 
     chosen_b, used_b, _gt, _gf, vg_b, _dev = outs = _launch(
-        prep, fi, tmpl, pv_all, fm_all, interpret, big_u, sublanes, S_pad - S
+        prep, fi, tmpl, pv_all, fm_all, interpret, big_u, sublanes, S_pad - S, config
     )
     RECORDER.count_megakernel_sweep_blocks(sublanes, S_pad // sublanes)
     with obs.span("mk.wait"):
@@ -674,7 +678,7 @@ def sweep(
 
 def schedule(
     prep, tmpl_ids, pod_valid, forced, node_valid=None,
-    interpret: Optional[bool] = None, big_u: Optional[bool] = None,
+    interpret: Optional[bool] = None, big_u: Optional[bool] = None, config=None,
 ):
     """Run the megakernel on a pod stream (padded here to P % CHUNK == 0).
     Returns (chosen [P] i32, used_final [N, R], static_fail [U, 4],
@@ -685,7 +689,7 @@ def schedule(
     spread weights are those of the masked set's domains, `static_fail`
     counts over the masked set, and the marshalled tables (`build_inputs`,
     all nodes valid) are reused as they are. `big_u=None` defers to the
-    use_big_u heuristic."""
+    use_big_u heuristic; `config` is as in `sweep`."""
     from ..resilience import faults
 
     # stands in for a Mosaic compile failure (a construct passing interpret
@@ -717,7 +721,7 @@ def schedule(
             static_fail = np.asarray(
                 _precompute_jit(prep.ec._replace(node_valid=jnp.asarray(mask))).static_fail
             )
-    outs = _launch(prep, fi, tmpl_ids, pod_valid[None], forced[None], interpret, big_u)
+    outs = _launch(prep, fi, tmpl_ids, pod_valid[None], forced[None], interpret, big_u, config=config)
     with obs.span("mk.wait"):
         jax.block_until_ready(outs)
     with obs.span("mk.fetch"):

@@ -45,6 +45,8 @@ def why_not(prep, config=None, extra_plugins: tuple = (), tie_seed=None):
     implements the seeded sampled tie-break."""
     if extra_plugins:
         return "out-of-tree extra_plugins are jittable callables (XLA scan only)"
+    if config is not None and getattr(config, "w_rtcr", 0.0):
+        return "RequestedToCapacityRatio runs on the megakernel or the XLA scan"
     if config is not None and getattr(config, "fit_ignored_cols", ()):
         # NodeResourcesFitArgs ignored columns are an XLA-scan feature; the
         # C++ fit loop has no per-column skip (rare config — not worth ABI)

@@ -20,6 +20,9 @@ LOUDLY naming the field (the policy VERDICT r3 #7 asks for):
   the fit filter skips those resource columns;
 - ``InterPodAffinityArgs.hardPodAffinityWeight`` — accepted at the default
   (1), rejected otherwise (the weight is encoded at template-build time);
+- ``RequestedToCapacityRatioArgs`` (``shape``, ``resources``) — validated as
+  kube 1.21 validates them, the shape's scores scaled by 10 as kube converts
+  them; the score is ``kernels.rtcr_score``;
 - args that cannot change a simulation's outcome in either implementation
   (``DefaultPreemption``, volume plugins — vacuous, see PARITY.md) are
   accepted;
@@ -46,6 +49,9 @@ SCORE_PLUGINS = {
     "Open-Gpu-Share": "gpu_share",
     "Open-Local": "local",
     "NodePreferAvoidPods": "prefer_avoid",
+    # in kube 1.21's in-tree registry, off in the default profile: the
+    # bin-packing score (RequestedToCapacityRatioArgs in pluginConfig)
+    "RequestedToCapacityRatio": "rtcr",
     # present in the default profile but structurally zero in a simulation
     # (nodes carry no images)
     "ImageLocality": None,
@@ -82,6 +88,7 @@ _EXTENSION_POINTS = {
     "reserve", "permit", "preBind", "bind", "postBind",
 }
 
+from ..encoding.vocab import BASE_RESOURCES  # noqa: E402
 from ..models.objects import DEFAULT_SCHEDULER_NAME  # noqa: E402 (single source)
 
 
@@ -101,6 +108,7 @@ class SchedulerConfig(NamedTuple):
     w_simon: float = 1.0
     w_gpu_share: float = 1.0
     w_local: float = 1.0
+    w_rtcr: float = 0.0
     f_taints: bool = True
     f_node_affinity: bool = True
     f_ports: bool = True
@@ -114,9 +122,39 @@ class SchedulerConfig(NamedTuple):
     # ignoredResources/ignoredResourceGroups, resolved against the vocab by
     # resolve_profiles)
     fit_ignored_cols: tuple = ()
+    # RequestedToCapacityRatio, empty while w_rtcr is 0: the shape as
+    # ((utilization, score x 10), ...) and ((resource column, weight), ...),
+    # the columns resolved against the vocabulary like fit_ignored_cols
+    rtcr_shape: tuple = ()
+    rtcr_resources: tuple = ()
 
 
 DEFAULT_CONFIG = SchedulerConfig()
+
+
+def profile_of(config) -> str:
+    """What a run's spans and ``simon_engine_profile_total`` call its score
+    profile: ``default`` (no config, or the default one), ``rtcr`` (the
+    RequestedToCapacityRatio score on) or ``weights`` (anything else: the
+    default plugins at other weights or disables)."""
+    if config is None or config == DEFAULT_CONFIG:
+        return "default"
+    return "rtcr" if config.w_rtcr else "weights"
+
+
+def kernel_gap(config) -> Optional[str]:
+    """What of a scheduler config the megakernel cannot compute, as a token,
+    or None: it takes every score weight and the RequestedToCapacityRatio
+    term as trace-time constants, but neither a disabled filter
+    (``disabled_filter``) nor NodeResourcesFit's ignored columns
+    (``fit_ignored_cols``)."""
+    if config is None:
+        return None
+    if not all(getattr(config, f) for f in config._fields if f.startswith("f_")):
+        return "disabled_filter"
+    if config.fit_ignored_cols:
+        return "fit_ignored_cols"
+    return None
 
 
 class Profile(NamedTuple):
@@ -124,6 +162,9 @@ class Profile(NamedTuple):
     config: SchedulerConfig
     fit_ignored_names: Tuple[str, ...] = ()
     fit_ignored_groups: Tuple[str, ...] = ()
+    # RequestedToCapacityRatioArgs.resources as ((name, weight), ...): resolved
+    # to config.rtcr_resources against the cluster's resource vocabulary
+    rtcr_names: Tuple[Tuple[str, float], ...] = ()
 
 
 class SchedulerProfiles(NamedTuple):
@@ -142,11 +183,60 @@ def _err(path: str, msg: str):
     raise ValueError(f"{path}: {msg}")
 
 
+#: kube 1.21 converts a shape point's score (0..MaxCustomPriorityScore = 10)
+#: to node-score points (0..MaxNodeScore = 100)
+RTCR_SCORE_SCALE = 10.0
+#: RequestedToCapacityRatioArgs.resources when none are given (v1beta1 defaults)
+RTCR_DEFAULT_RESOURCES = (("cpu", 1.0), ("memory", 1.0))
+
+
+def _whole(path: str, where: str, value) -> int:
+    if isinstance(value, bool) or not isinstance(value, (int, float)) or int(value) != value:
+        _err(path, f"{where}={value!r} is not a whole number")
+    return int(value)
+
+
+def _parse_rtcr_args(path: str, profile_name: str, args: dict) -> tuple:
+    """RequestedToCapacityRatioArgs as kube 1.21 validates and converts them
+    (``ValidateRequestedToCapacityRatioArgs``): at least one shape point,
+    each utilization in 0..100 and greater than the one before, each score in
+    0..10 (scaled to 0..100), each resource weight at least 1; a weight of 0
+    or none is 1, and no resources are cpu and memory at weight 1 (the
+    v1beta1 defaults). Returns (shape, ((name, weight), ...))."""
+    where = f"profile {profile_name!r}: RequestedToCapacityRatioArgs"
+    for field in args:
+        if field not in ("shape", "resources", "apiVersion", "kind"):
+            _err(path, f"{where}.{field} is not supported")
+    points = []
+    for i, point in enumerate(args.get("shape") or []):
+        util = _whole(path, f"{where}.shape[{i}].utilization", point.get("utilization", 0))
+        score = _whole(path, f"{where}.shape[{i}].score", point.get("score", 0))
+        if not 0 <= util <= 100:
+            _err(path, f"{where}.shape[{i}].utilization {util} is not in the range 0..100")
+        if points and util <= points[-1][0]:
+            _err(path, f"{where}.shape[{i}].utilization {util}: utilization values must be "
+                       "sorted in increasing order")
+        if not 0 <= score <= 10:
+            _err(path, f"{where}.shape[{i}].score {score} is not in the range 0..10")
+        points.append((float(util), score * RTCR_SCORE_SCALE))
+    if not points:
+        _err(path, f"{where}.shape: at least one point must be specified")
+    resources = []
+    for i, res in enumerate(args.get("resources") or []):
+        weight = _whole(path, f"{where}.resources[{i}].weight", res.get("weight", 0) or 0) or 1
+        if weight < 1:
+            _err(path, f"{where}.resources[{i}].weight {weight} is under 1")
+        resources.append((str(res.get("name", "")), float(weight)))
+    return tuple(points), tuple(resources) or RTCR_DEFAULT_RESOURCES
+
+
 def _parse_plugin_args(path: str, profile_name: str, entries) -> tuple:
-    """pluginConfig → (fit_ignored_names, fit_ignored_groups); everything
-    that would change outcomes and does not map fails loudly."""
+    """pluginConfig → (fit_ignored_names, fit_ignored_groups, rtcr args or
+    None); everything that would change outcomes and does not map fails
+    loudly."""
     names: list = []
     groups: list = []
+    rtcr = None
     for pc in entries or []:
         pname = str(pc.get("name", ""))
         args = pc.get("args") or {}
@@ -162,6 +252,8 @@ def _parse_plugin_args(path: str, profile_name: str, entries) -> tuple:
                     _err(path, f"profile {profile_name!r}: NodeResourcesFitArgs."
                                f"{field} is not supported (only ignoredResources/"
                                "ignoredResourceGroups map onto the fit kernel)")
+        elif pname == "RequestedToCapacityRatio":
+            rtcr = _parse_rtcr_args(path, profile_name, args)
         elif pname == "InterPodAffinity":
             w = args.get("hardPodAffinityWeight", 1)
             if int(w) != 1:
@@ -185,7 +277,15 @@ def _parse_plugin_args(path: str, profile_name: str, entries) -> tuple:
         else:
             _err(path, f"profile {profile_name!r}: pluginConfig names unknown "
                        f"plugin {pname!r}")
-    return tuple(names), tuple(groups)
+    return tuple(names), tuple(groups), rtcr
+
+
+def rtcr_columns(names, resource_names) -> tuple:
+    """((name, weight), ...) -> ((column, weight), ...) on a resource axis
+    named by `resource_names`; -1 for a resource no node or pod declares
+    (its capacity is 0 everywhere)."""
+    cols = {n: i for i, n in enumerate(resource_names)}
+    return tuple((cols.get(n, -1), w) for n, w in names)
 
 
 def _parse_profile(path: str, profile: dict, index: int) -> Profile:
@@ -248,12 +348,19 @@ def _parse_profile(path: str, profile: dict, index: int) -> Profile:
         check_known(ps.get("disabled"), f"plugins.{point}.disabled")
         check_known(ps.get("enabled"), f"plugins.{point}.enabled")
 
-    names, groups = _parse_plugin_args(path, name, profile.get("pluginConfig"))
+    names, groups, rtcr = _parse_plugin_args(path, name, profile.get("pluginConfig"))
+    rtcr_names = ()
+    if cfg["w_rtcr"]:
+        if rtcr is None:
+            _err(path, f"profile {name!r}: RequestedToCapacityRatio is enabled without "
+                       "pluginConfig args: at least one shape point must be specified")
+        cfg["rtcr_shape"], rtcr_names = rtcr
     return Profile(
         scheduler_name=name,
         config=SchedulerConfig(**cfg),
         fit_ignored_names=names,
         fit_ignored_groups=groups,
+        rtcr_names=rtcr_names,
     )
 
 
@@ -290,8 +397,11 @@ def load_scheduler_config(path: str):
         and profiles[0].scheduler_name == DEFAULT_SCHEDULER_NAME
         and not profiles[0].fit_ignored_names
         and not profiles[0].fit_ignored_groups
+        and all(n in BASE_RESOURCES for n, _w in profiles[0].rtcr_names)
     ):
-        return profiles[0].config
+        # the base resources' columns are the same in every vocabulary
+        p = profiles[0]
+        return p.config._replace(rtcr_resources=rtcr_columns(p.rtcr_names, BASE_RESOURCES))
     return SchedulerProfiles(profiles=profiles)
 
 
@@ -314,7 +424,10 @@ def _route_stream(sched_config, ordered, resource_names, forced=None):
                 rname.startswith(g + "/") for g in profile.fit_ignored_groups
             ):
                 cols.append(i)
-        return profile.config._replace(fit_ignored_cols=tuple(cols))
+        return profile.config._replace(
+            fit_ignored_cols=tuple(cols),
+            rtcr_resources=rtcr_columns(profile.rtcr_names, resource_names),
+        )
 
     invalid = {}
     used = {}

@@ -20,6 +20,7 @@ from typing import Dict, NamedTuple, Optional, Set, Tuple
 import jax
 
 from ..utils import envknobs
+from .schedconfig import DEFAULT_CONFIG, kernel_gap
 
 log = logging.getLogger("opensim_tpu")
 
@@ -85,7 +86,8 @@ DECLINES = {
         ("segments", "segmented multi-profile stream ({segments} segments)"),
         # only the C++ generic path and the XLA count_all scan emit per-filter verdicts
         ("explain", "explain mode audits per-filter verdicts (C++/XLA engines)"),
-        ("sched_config", "non-default scheduler config"),
+        # the profile's weights and its RequestedToCapacityRatio are served
+        ("sched_config", "a scheduler config the kernel cannot compute ({gap})"),
         ("extra_plugins", "out-of-tree extra_plugins run on the XLA scan"),
         ("tie_seed", "sampled tie-break runs on the C++ engine or XLA scan"),
         ("start_state", "a stream from the caller's scan state runs on the C++ engine or XLA scan"),
@@ -99,7 +101,7 @@ DECLINES = {
         ("node_mask", "node_mask"),
         ("tie_seed", "tie_seed"),  # a key rides the carry and is split every step
         ("explain", "explain"),  # every step emits its rows
-        ("sched_config", "sched_config"),
+        ("config", "sched_config"),  # any config but the default one
         ("extra_plugins", "extra_plugins"),
     ),
 }
@@ -111,7 +113,8 @@ def _asked(prep, ask: Ask, devices: int = 1) -> Set[str]:
         "many_devices": ask.shape == "sweep" and devices != 1,
         "segments": ask.segments is not None,
         "explain": ask.explain,
-        "sched_config": ask.sched_config is not None,
+        "sched_config": kernel_gap(ask.sched_config) is not None,
+        "config": ask.sched_config is not None and ask.sched_config != DEFAULT_CONFIG,
         "extra_plugins": bool(ask.extra_plugins),
         "tie_seed": ask.tie_seed is not None,
         "node_mask": ask.node_mask,
@@ -131,7 +134,7 @@ def ladder(prep, ask: Ask = Ask(), pol: Optional[Policy] = None) -> Dict[str, Op
     engine's own envelope."""
     pol = pol or policy()
     asked = _asked(prep, ask, pol.devices)
-    words = {"segments": ask.segments, "devices": pol.devices}
+    words = {"segments": ask.segments, "devices": pol.devices, "gap": kernel_gap(ask.sched_config)}
     megakernel = _declined("megakernel", asked, **words) or pol.off["megakernel"]
     if megakernel is None:
         from . import fastpath
@@ -154,7 +157,8 @@ def _envelope_token(engine: str, reason: str) -> str:
     """An envelope's reason as a short token. The kernel's: the table axes
     over their caps joined by ``+`` (``U``, ``A``, ``R``), ``vmem``,
     ``topo_keys``, or ``features`` for a feature's table it has no rows for.
-    The C++ scan's: ``extra_plugins``, ``fit_ignored_cols``, ``not_built``."""
+    The C++ scan's: ``extra_plugins``, ``fit_ignored_cols``, ``rtcr``,
+    ``not_built``."""
     if engine == "megakernel":
         over = _OVER.findall(reason)
         if over:
@@ -164,13 +168,16 @@ def _envelope_token(engine: str, reason: str) -> str:
         return "topo_keys" if "topology keys" in reason else "features"
     if reason.startswith("engine not built"):
         return "not_built"
+    if "RequestedToCapacityRatio" in reason:
+        return "rtcr"
     return "extra_plugins" if "extra_plugins" in reason else "fit_ignored_cols"
 
 
 def turned_away(prep, ask: Ask, pol: Policy, rungs: Dict[str, Optional[str]]) -> Optional[Tuple[str, str]]:
     """The first rung the policy left on that declined the run, with its
-    reason as a short token: the row's name in :data:`DECLINES`, else the
-    token of the engine's envelope. None when that rung serves the run; a
+    reason as a short token: the row's name in :data:`DECLINES` (the
+    ``sched_config`` row's with what the kernel cannot compute,
+    ``sched_config:disabled_filter``), else the token of the engine's envelope. None when that rung serves the run; a
     rung the policy switched off turned nothing away."""
     asked = _asked(prep, ask, pol.devices)
     for engine in ("megakernel", "native"):
@@ -179,6 +186,8 @@ def turned_away(prep, ask: Ask, pol: Policy, rungs: Dict[str, Optional[str]]) ->
         if rungs[engine] is None:
             return None
         row = next((name for name, _why in DECLINES[engine] if name in asked), None)
+        if row == "sched_config":  # which part of the config: a token, not the prose of the reason
+            row = f"sched_config:{kernel_gap(ask.sched_config)}"
         return engine, row or _envelope_token(engine, rungs[engine])
     return None
 

@@ -40,6 +40,7 @@ from ..resilience import breaker as breakers
 from ..resilience import faults
 from ..resilience.deadline import Deadline, check_deadline, deadline_scope
 from . import queues, reasons, select
+from .schedconfig import profile_of
 from .scheduler import pad_pod_stream, scan_unroll, schedule_pods, to_device
 
 
@@ -506,6 +507,7 @@ def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, ski
     with sf_rows=arange) because static filter tables are config-dependent
     and failure attribution resolves per segment."""
     from ..obs import trace as obs
+    from ..obs.metrics import RECORDER
     from . import nativepath, resident
 
     P = len(tmpl_ids)
@@ -533,9 +535,9 @@ def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, ski
     for cfg, lo, hi in segments:
         seg_valid = np.zeros((P,), dtype=bool)
         seg_valid[lo:hi] = pod_valid[lo:hi]
-        with obs.span(
-            "engine.native" if use_native else "engine.xla", segment=f"{lo}:{hi}"
-        ):
+        engine = "native" if use_native else "xla"
+        RECORDER.count_engine_profile(engine, profile_of(cfg))
+        with obs.span(f"engine.{engine}", segment=f"{lo}:{hi}", profile=profile_of(cfg)):
             if use_native:
                 out = nativepath.schedule(
                     prep, seg_valid, config=cfg, node_valid=nv_mask,
@@ -607,7 +609,7 @@ def _run_engine_ladder(
     features = "+".join(n for n, on in zip(prep.features._fields, prep.features) if on) or "none"
     shape = {
         "features": features, "interpod_terms": prep.meta.interpod_terms,
-        "masked": nv_mask is not None,
+        "masked": nv_mask is not None, "profile": profile_of(sched_config),
     }
     pol = select.policy()
     ask = select.Ask(
@@ -639,7 +641,7 @@ def _run_engine_ladder(
         try:  # identical placements at ~4× the XLA scan's step rate
             with obs.span("engine.megakernel", **shape) as rung:
                 f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev = fastpath.schedule(
-                    prep, tmpl_ids, pod_valid, forced, node_valid=nv_mask
+                    prep, tmpl_ids, pod_valid, forced, node_valid=nv_mask, config=sched_config
                 )
             # a clean kernel RUN is a breaker success even if the result
             # is later discarded for mid-stream attribution — and recording
@@ -735,6 +737,8 @@ def _run_engine_ladder(
                 tie_seed=tie_seed, explain=explain,
             )
     RECORDER.count_engine_features(engine_name, features)
+    if segments is None:  # a segmented run counts each of its scans
+        RECORDER.count_engine_profile(engine_name, shape["profile"])
     if nv_mask is not None:
         RECORDER.count_masked_pass(engine_name)
     return out, engine_name, skips, sf_rows, attribution

@@ -192,6 +192,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Scenario blocks the megakernel's sweeps walked the pod stream for, by the scenarios a step holds: a packed sweep "
         "of S scenarios is ceil(S/8) blocks of 8 sublanes, an unpacked one S blocks of 1", "counter",
     ),
+    # engine: megakernel | native | xla; profile: default | weights | rtcr
+    # (engine/schedconfig.py profile_of)
+    "simon_engine_profile_total": (
+        "Scans of a stream or a sweep by the engine that ran them and the score profile of their scheduler config", "counter",
+    ),
     # engine: megakernel | native, the rung that turned the run away; reason:
     # a row of select.DECLINES, or the envelope's token (U, A, R, vmem, topo_keys, ...)
     "simon_engine_declined_total": (
@@ -603,6 +608,9 @@ class MetricsRecorder:
         # runs that reached a slower rung because a faster one declined them
         # (engine/select.py turned_away): the rung and its reason's token
         self.engine_declined = make_counter("simon_engine_declined_total", ("engine", "reason"))
+        # every scan by the engine that ran it and its config's score profile:
+        # a profile other than default on the XLA scan is one the kernel lost
+        self.engine_profile = make_counter("simon_engine_profile_total", ("engine", "profile"))
         # pods placed with a gpu-share request, by answering engine and kind
         # (fraction of a device, one whole device, several slots)
         self.gpushare_pods = make_counter("simon_gpushare_pods_total", ("engine", "kind"))
@@ -694,6 +702,10 @@ class MetricsRecorder:
         with self.lock:
             self.engine_declined.inc((engine, reason))
 
+    def count_engine_profile(self, engine: str, profile: str) -> None:
+        with self.lock:
+            self.engine_profile.inc((engine, profile))
+
     def count_gpushare_pods(self, engine: str, by_kind: Dict[str, int]) -> None:
         with self.lock:
             for kind, n in by_kind.items():
@@ -715,6 +727,7 @@ class MetricsRecorder:
                 + self.megakernel_attribution.render_lines()
                 + self.megakernel_sweep_blocks.render_lines()
                 + self.engine_declined.render_lines()
+                + self.engine_profile.render_lines()
                 + self.gpushare_pods.render_lines()
                 + self.yaml_documents.render_lines()
                 + self.phase_seconds.render_lines()
@@ -734,6 +747,7 @@ class MetricsRecorder:
             self.megakernel_attribution.reset()
             self.megakernel_sweep_blocks.reset()
             self.engine_declined.reset()
+            self.engine_profile.reset()
             self.gpushare_pods.reset()
             self.yaml_documents.reset()
             self.watch_apply.reset()

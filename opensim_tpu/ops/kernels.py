@@ -17,7 +17,8 @@ Kernel ↔ reference-plugin parity map (score weights from
           InterPodAffinity (w1), LeastAllocated (w1), NodeAffinity (w1),
           NodePreferAvoidPods (w10000, annotation table), PodTopologySpread (w2),
           TaintToleration (w1), Simon share (w1, plugin/simon.go:45-101),
-          GpuShare share (w1), OpenLocal (w1)
+          GpuShare share (w1), OpenLocal (w1); RequestedToCapacityRatio
+          (off unless a profile enables it: the bin-packing score)
 
 All functions take the EncodedCluster (`ec`), the scan carry (`st`) and a
 traced template index `u`; shapes are static.
@@ -25,6 +26,7 @@ traced template index `u`; shapes are static.
 
 from __future__ import annotations
 
+import itertools
 from typing import NamedTuple
 
 import jax
@@ -387,6 +389,85 @@ def least_allocated_score(ec, st, u):
 def _least_requested(requested, capacity):
     score = div32((capacity - requested) * MAX_NODE_SCORE, jnp.maximum(capacity, 1.0))
     return jnp.where((capacity == 0) | (requested > capacity), 0.0, score)
+
+
+def rtcr_utilization(q, requested, capacity):
+    """RequestedToCapacityRatio's utilization of one resource from ``q``,
+    LeastAllocated's quotient ``(capacity - requested) * 100 / capacity``
+    (``div32``): ``100 - q``, or 100 where the capacity is 0 or exceeded
+    (requested_to_capacity_ratio.go reads the shape at maxUtilization there)."""
+    return jnp.where((capacity == 0) | (requested > capacity), MAX_NODE_SCORE, MAX_NODE_SCORE - q)
+
+
+def rtcr_shape_score(util, shape):
+    """kube's broken-linear function of a shape (helper/shape_score.go) in
+    float32 and unrounded: ``shape[0]``'s score up to its utilization, the
+    last score beyond the last, else on the segment i that holds ``util``
+    ``s[i-1] + (s[i] - s[i-1]) * (util - u[i-1]) / (u[i] - u[i-1])``, the
+    quotient by ``div32``. A segment whose slope is exactly 1 is folded at
+    trace time to ``s[i-1] + (util - u[i-1])``, as the plain reference folds
+    it. The XLA scan and the megakernel both call this on their own rows."""
+    out = jnp.full_like(util, shape[-1][1])
+    for i in range(len(shape) - 1, -1, -1):
+        u_i, s_i = shape[i]
+        if i == 0:
+            val = s_i
+        else:
+            u_p, s_p = shape[i - 1]
+            if s_i - s_p == u_i - u_p:
+                val = s_p + (util - u_p)
+            else:
+                val = s_p + div32((s_i - s_p) * (util - u_p), jnp.full_like(util, u_i - u_p))
+        out = jnp.where(util <= u_i, val, out)
+    return out
+
+
+def _power_of_two(x: float) -> bool:
+    return x >= 1 and float(x).is_integer() and (int(x) & (int(x) - 1)) == 0
+
+
+def rtcr_mean(weighted):
+    """The node's RequestedToCapacityRatio score from ((weight, f), ...) in
+    the profile's resource order: ``sum w*f / sum w`` over the resources whose
+    ``f`` is above 0, 0 where none is (the kube 1.21 rule). The sums run in
+    the given order. Where every sum of weights the rule can leave is a power
+    of two (cpu and memory at weight 1: 1 or 2) the quotient is exact as a
+    product and is taken as one, by a reciprocal chosen per node; else by
+    ``div32``."""
+    num = den = None
+    for w, f in weighted:
+        term = f if w == 1.0 else w * f
+        num = term if num is None else num + term
+        d = jnp.where(f > 0, w, 0.0)
+        den = d if den is None else den + d
+    weights = [w for w, _f in weighted]
+    sums = {sum(c) for k in range(1, len(weights) + 1) for c in itertools.combinations(weights, k)}
+    if all(_power_of_two(x) for x in sums):
+        recip = None
+        for x in sorted(sums - {1.0}):
+            recip = jnp.where(den == x, 1.0 / x, 1.0 if recip is None else recip)
+        return num if recip is None else num * recip
+    return div32(num, jnp.maximum(den, 1.0))
+
+
+def rtcr_score(ec, st, u, cfg):
+    """RequestedToCapacityRatio (requested_to_capacity_ratio.go, kube 1.21)
+    over ``cfg.rtcr_resources``: for cpu and memory the requested amount is
+    LeastAllocated's (the node's total plus the pod's non-zero request), for
+    another resource the plain request; a column of -1 is a resource the
+    cluster does not have (capacity 0, read at utilization 100)."""
+    cpu_req, mem_req = _nonzero_req(ec, u)
+    weighted = []
+    for col, w in cfg.rtcr_resources:
+        if col < 0:
+            util = jnp.full(ec.alloc.shape[:1], MAX_NODE_SCORE, jnp.float32)
+        else:
+            req = cpu_req if col == V.RES_CPU else mem_req if col == V.RES_MEMORY else ec.req[u, col]
+            requested, capacity = st.used[:, col] + req, ec.alloc[:, col]
+            q = div32((capacity - requested) * MAX_NODE_SCORE, jnp.maximum(capacity, 1.0))
+            util = rtcr_utilization(q, requested, capacity)
+        weighted.append((w, rtcr_shape_score(util, cfg.rtcr_shape)))
+    return rtcr_mean(weighted)
 
 
 def balanced_allocation_score(ec, st, u):
@@ -986,6 +1067,8 @@ def score_parts(
         parts["NodeResourcesLeastAllocated"] = (
             cfg.w_least * least_allocated_score(ec, st, u)
         )
+    if cfg.w_rtcr:
+        parts["RequestedToCapacityRatio"] = cfg.w_rtcr * rtcr_score(ec, st, u, cfg)
     if feat.pref_node_affinity and cfg.w_node_affinity:
         na_raw = stat.na_raw[u]
         na_max = jnp.max(jnp.where(feasible, na_raw, 0.0))

@@ -21,7 +21,10 @@ so VMEM no longer scales with U (cap 2048, bounded by SMEM scalars). The kernel 
 generated per feature-flag combination so absent features cost nothing, and
 node validity is a runtime row: a scenario sweep is the same kernel over
 scenarios, each reading its own mask, spread weights and pod streams
-(`run_fast_scan`; a plain schedule is one scenario).
+(`run_fast_scan`; a plain schedule is one scenario). A scheduler config's
+score profile (its weights and RequestedToCapacityRatio) is a trace-time
+constant of the generated kernel too; the default profile is the kernel
+without one.
 
 Scenarios sit on the sublane axis. A step works on [SB, N] rows, one
 scenario a sublane: SB = 1 for a schedule, where a [1, N] row fills one of
@@ -60,7 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..encoding import vocab as V
-from .kernels import div32, floor_div32
+from .kernels import div32, floor_div32, rtcr_mean, rtcr_shape_score, rtcr_utilization
 
 NEG = -1e30
 MAX_SCORE = 100.0
@@ -278,8 +281,23 @@ def _make_kernel(
     n_zkeys: int = 1,
     gc_row: int = -1,
     sublanes: int = 1,
+    config=None,
 ):
+    from ..engine.schedconfig import DEFAULT_CONFIG
+
     SB = sublanes
+    # the score profile (a SchedulerConfig whose filters are all on; select
+    # declines the others): its weights are trace-time constants, a weight of
+    # one multiplies nothing and the RequestedToCapacityRatio term exists only
+    # where its weight is not 0, so the default profile is the kernel it was
+    cfg = config or DEFAULT_CONFIG
+    rtcr_cols = [col for col, _w in cfg.rtcr_resources] if cfg.w_rtcr else []
+    # the RequestedToCapacityRatio columns with a quotient of their own (cpu
+    # and memory reuse LeastAllocated's)
+    rtcr_extra = [col for col in rtcr_cols if col >= 0 and col not in (V.RES_CPU, V.RES_MEMORY)]
+
+    def weighted(w, term):
+        return term if w == 1.0 else w * term
     layout = _input_layout(has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u, SB > 1)
     in_names = [n for n, _ in layout]
     out_names = ["chosen", "used_out"]
@@ -808,10 +826,19 @@ def _make_kernel(
                 tt_row = s_tt[:] if big_u else tt_ref[pl.ds(u, 1), :]
                 tt_max = vmax(jnp.where(feas_b, tt_row, 0.0))
                 quotients.append((tt_row * MAX_SCORE, jnp.maximum(tt_max, 1.0)))
-            quotients = iter(_div_rows(quotients, (SB, N)))
+            rtcr_req = {}
+            for col in rtcr_extra:
+                requested = srows(used_ref, col) + req_ref[col, u]
+                alloc_c = alloc_ref[pl.ds(col, 1), :]
+                rtcr_req[col] = (requested, alloc_c)
+                quotients.append(((alloc_c - requested) * MAX_SCORE, jnp.maximum(alloc_c, 1.0)))
+            quotients = _div_rows(quotients, (SB, N))
+            rtcr_q = dict(zip(rtcr_extra, quotients[len(quotients) - len(rtcr_extra):]))
+            quotients = iter(quotients[:len(quotients) - len(rtcr_extra)])
 
-            l_cpu = jnp.where((alloc_cpu == 0) | (used_cpu > alloc_cpu), 0.0, next(quotients))
-            l_mem = jnp.where((alloc_mem == 0) | (used_mem > alloc_mem), 0.0, next(quotients))
+            q_cpu, q_mem = next(quotients), next(quotients)
+            l_cpu = jnp.where((alloc_cpu == 0) | (used_cpu > alloc_cpu), 0.0, q_cpu)
+            l_mem = jnp.where((alloc_mem == 0) | (used_mem > alloc_mem), 0.0, q_mem)
             least = (l_cpu + l_mem) / 2.0
             cpu_frac = next(quotients)
             mem_frac = next(quotients)
@@ -828,20 +855,48 @@ def _make_kernel(
                 ip_norm = jnp.where(ip_rng > 0, next(quotients), 0.0)
 
             # the weighted sum is taken in kernels.score_parts' order
-            # (balanced, least, node affinity, taints, inter-pod, spread,
-            # share, open-local, prefer-avoid): float32 addition does not
-            # associate, and a sum taken in another order leaves the XLA
-            # scan's by an ulp wherever three terms differ over the nodes
-            score = least + balanced
+            # (balanced, least, requested-to-capacity, node affinity, taints,
+            # inter-pod, spread, share, open-local, prefer-avoid): float32
+            # addition does not associate, and a sum taken in another order
+            # leaves the XLA scan's by an ulp wherever three terms differ over
+            # the nodes. It commutes: least + balanced is the scan's
+            # 0 + balanced + least
+            if cfg.w_least and cfg.w_balanced:
+                score = weighted(cfg.w_least, least) + weighted(cfg.w_balanced, balanced)
+            elif cfg.w_least or cfg.w_balanced:
+                score = weighted(cfg.w_least, least) if cfg.w_least else weighted(cfg.w_balanced, balanced)
+            else:
+                score = jnp.zeros((SB, N), jnp.float32)
+            if rtcr_cols:
+                per_resource = []
+                for col, w in cfg.rtcr_resources:
+                    if col == V.RES_CPU:
+                        util = rtcr_utilization(q_cpu, used_cpu, alloc_cpu)
+                    elif col == V.RES_MEMORY:
+                        util = rtcr_utilization(q_mem, used_mem, alloc_mem)
+                    elif col >= 0:
+                        util = rtcr_utilization(rtcr_q[col], *rtcr_req[col])
+                    else:
+                        util = jnp.full((SB, N), MAX_SCORE, jnp.float32)
+                    per_resource.append((w, rtcr_shape_score(util, cfg.rtcr_shape)))
+                score = score + weighted(cfg.w_rtcr, rtcr_mean(per_resource))
             if has_na:
-                score = score + jnp.where(na_max > 0, next(quotients), na_row)
+                na_q = next(quotients)
+                if cfg.w_node_affinity:
+                    score = score + weighted(cfg.w_node_affinity, jnp.where(na_max > 0, na_q, na_row))
             if has_tt:
-                score = score + jnp.where(tt_max > 0, MAX_SCORE - next(quotients), MAX_SCORE)
-            if has_interpod:
-                score = score + ip_norm
-            score = score + 2.0 * spread_norm
-            score = score + 2.0 * share_norm
-            if has_local:
+                tt_q = next(quotients)
+                if cfg.w_taint_toleration:
+                    score = score + weighted(
+                        cfg.w_taint_toleration, jnp.where(tt_max > 0, MAX_SCORE - tt_q, MAX_SCORE)
+                    )
+            if has_interpod and cfg.w_interpod:
+                score = score + weighted(cfg.w_interpod, ip_norm)
+            if cfg.w_spread:
+                score = score + weighted(cfg.w_spread, spread_norm)
+            if cfg.w_simon + cfg.w_gpu_share:
+                score = score + weighted(cfg.w_simon + cfg.w_gpu_share, share_norm)
+            if has_local and cfg.w_local:
                 # Open-Local binpack score (local_score in kernels.py):
                 # mean over units of used/capacity × 10, min-max normalized
                 lvm = lvm_ref[u]
@@ -875,12 +930,14 @@ def _make_kernel(
                 l_lo = vmin(jnp.where(feas_b, local_raw, big_f))
                 l_hi = vmax(jnp.where(feas_b, local_raw, -big_f))
                 l_rng = l_hi - l_lo
-                score = score + jnp.where(l_rng > 0, div32((local_raw - l_lo) * MAX_SCORE, l_rng), 0.0)
-            if has_avoid:
+                score = score + weighted(
+                    cfg.w_local, jnp.where(l_rng > 0, div32((local_raw - l_lo) * MAX_SCORE, l_rng), 0.0)
+                )
+            if has_avoid and cfg.w_prefer_avoid:
                 # NodePreferAvoidPods (w=10000, no NormalizeScore): raw
                 # 0/100 static table, same shape class as na_raw
                 av_row = s_av[:] if big_u else av_ref[pl.ds(u, 1), :]
-                score = score + 10000.0 * av_row
+                score = score + weighted(cfg.w_prefer_avoid, av_row)
 
             # --- selectHost: lowest index among maxima — Mosaic's argmax
             # breaks ties by HIGHEST index, diverging from the XLA scan
@@ -1054,7 +1111,7 @@ def _make_kernel(
 # what selects the generated kernel; everything else run_fast_scan reads
 # comes from the shapes of its traced arguments
 _STATIC = ("has_interpod", "has_gpu", "has_local", "has_ports", "has_na", "has_tt",
-           "has_avoid", "interpret", "big_u", "gc_row", "sublanes")
+           "has_avoid", "interpret", "big_u", "gc_row", "sublanes", "config")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -1074,6 +1131,7 @@ def run_fast_scan(
     big_u: bool = False,
     gc_row: int = -1,
     sublanes: int = 1,
+    config=None,
 ):
     """Execute the megakernel over S scenarios in ONE dispatch: tmpl_ids is
     [P] (P a multiple of CHUNK, shared), pod_valid/forced are [S, P],
@@ -1271,7 +1329,7 @@ def run_fast_scan(
     out = pl.pallas_call(
         _make_kernel(
             has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid,
-            G, Gp, Gd, Vg, Dv, fi.dev_sizes.shape[1] // 2, big_u, K, gc_row, SB,
+            G, Gp, Gd, Vg, Dv, fi.dev_sizes.shape[1] // 2, big_u, K, gc_row, SB, config,
         ),
         grid=grid,
         out_shape=tuple(out_shape),

@@ -127,15 +127,19 @@ def sweep_auto(
                 np.asarray(forced_masks, dtype=bool),
             )
         config = distinct.pop() if distinct else None
+    from ..engine.schedconfig import profile_of
     from ..obs import trace as obs
+    from ..obs.metrics import RECORDER
 
     pol, ask = select.policy(), select.Ask(shape="sweep", sched_config=config)
     rungs = select.ladder(prep, ask, pol)
+    profile = profile_of(config)
     if rungs["native"] is None:
         from ..engine import nativepath
 
         # no XLA scan compile; the incremental template cache makes each
         # scenario ms-scale on small configs
+        RECORDER.count_engine_profile("native", profile)
         with obs.span("sweep.native", scenarios=S):
             unscheduled, used, chosen, vg_used = nativepath.sweep(
                 prep, node_valid_masks, pod_valid_masks, forced_masks, config=config
@@ -148,22 +152,24 @@ def sweep_auto(
         from ..engine import fastpath
 
         try:
-            with obs.span("sweep.megakernel", scenarios=S):
+            with obs.span("sweep.megakernel", scenarios=S, profile=profile):
                 unscheduled, used, chosen, vg_used = fastpath.sweep(
-                    prep, node_valid_masks, pod_valid_masks, forced_masks
+                    prep, node_valid_masks, pod_valid_masks, forced_masks, config=config
                 )
+            RECORDER.count_engine_profile("megakernel", profile)
             return SweepResult(
                 unscheduled=unscheduled, used=used, chosen=chosen, vg_used=vg_used
             )
         except Exception as e:  # opensim-lint: disable=exception-swallow (kernel_failed raises or logs)
             select.kernel_failed(e, "sweep")  # demoted: the XLA sweep below computes the same
-    from ..obs.metrics import RECORDER
     from ..obs.profile import launch_span
 
     away = select.turned_away(prep, ask, pol, rungs)
     if away is not None:
         RECORDER.count_engine_declined(*away)
-    with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices()), **select.decline_attrs(prep, away)):
+    RECORDER.count_engine_profile("xla", profile)
+    with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices()), profile=profile,
+                  **select.decline_attrs(prep, away)):
         with launch_span("xla.launch", scenarios=S, pods=len(prep.tmpl_ids)):
             res = sweep(
                 prep.ec,
@@ -232,6 +238,11 @@ def sweep_segmented(
     )
     vg0 = np.asarray(prep.st0.vg_free)
     nv_np = np.asarray(node_valid_masks, dtype=bool)
+    from ..engine.schedconfig import profile_of
+    from ..obs.metrics import RECORDER
+
+    for cfg, _lo, _hi in segments:
+        RECORDER.count_engine_profile("native" if use_native else "xla", profile_of(cfg))
     if use_native:
         used = np.zeros((S,) + np.asarray(prep.st0.used).shape, np.float32)
         vg_used = np.zeros((S,), np.float32)

@@ -38,6 +38,11 @@ BATCH_XLA = "OPENSIM_BATCH_ENGINE routed the batch to the C++ engine"
 PLUGINS_MK = "out-of-tree extra_plugins run on the XLA scan"
 PLUGINS_NATIVE = "out-of-tree extra_plugins are jittable callables (XLA scan only)"
 WEIGHTED = DEFAULT_CONFIG._replace(w_least=3.0)
+BINPACK = DEFAULT_CONFIG._replace(w_least=0.0, w_rtcr=1.0, rtcr_shape=((0.0, 0.0), (100.0, 100.0)),
+                                  rtcr_resources=((0, 1.0), (1, 1.0)))
+NO_TAINTS = DEFAULT_CONFIG._replace(f_taints=False)
+GAP_FILTER = "a scheduler config the kernel cannot compute (disabled_filter)"
+GAP_COLS = "a scheduler config the kernel cannot compute (fit_ignored_cols)"
 PLUGIN = (("filter", lambda ec, st, u: None),)
 
 
@@ -80,13 +85,19 @@ LADDER = {
     # each ask alone: the asks come before the platform
     "segments": ({}, {"segments": 2}, "segmented multi-profile stream (2 segments)", None),
     "explain": ({}, {"explain": True}, "explain mode audits per-filter verdicts (C++/XLA engines)", None),
-    "sched_config": ({}, {"sched_config": WEIGHTED}, "non-default scheduler config", None),
-    "sched_config_default_is_still_a_config": (
-        {"env": INTERPRET}, {"sched_config": DEFAULT_CONFIG}, "non-default scheduler config", None,
+    # a config declines the kernel only for what the kernel cannot compute: its score
+    # weights and RequestedToCapacityRatio are served, a disabled filter and ignored fit columns are not
+    "sched_config": ({}, {"sched_config": NO_TAINTS}, GAP_FILTER, None),
+    "sched_config_weights": ({"env": INTERPRET}, {"sched_config": WEIGHTED}, None, None),
+    "sched_config_rtcr": (
+        {"env": INTERPRET}, {"sched_config": BINPACK}, None,
+        "RequestedToCapacityRatio runs on the megakernel or the XLA scan",
     ),
+    # the default config is no config
+    "sched_config_default_is_still_a_config": ({"env": INTERPRET}, {"sched_config": DEFAULT_CONFIG}, None, None),
     "fit_ignored_cols": (
         {}, {"sched_config": DEFAULT_CONFIG._replace(fit_ignored_cols=(2,))},
-        "non-default scheduler config",
+        GAP_COLS,
         "NodeResourcesFitArgs ignoredResources need the XLA scan's per-column skip",
     ),
     "extra_plugins": ({}, {"extra_plugins": PLUGIN}, PLUGINS_MK, PLUGINS_NATIVE),
@@ -134,9 +145,9 @@ LADDER = {
     "sweep_1_tpu": ({"platform": "tpu", "devices": 1}, {"shape": "sweep"}, None, TPU_OWNS),
     "sweep_4_tpu": ({"platform": "tpu", "devices": 4}, {"shape": "sweep"}, "4 devices", "4 devices"),
     "sweep_8_cpu": ({}, {"shape": "sweep"}, "8 devices", "8 devices"),
-    "sweep_1_config": (
-        {"env": INTERPRET, "devices": 1}, {"shape": "sweep", "sched_config": WEIGHTED},
-        "non-default scheduler config", None,
+    "sweep_1_config": ({"env": INTERPRET, "devices": 1}, {"shape": "sweep", "sched_config": WEIGHTED}, None, None),
+    "sweep_1_config_the_kernel_cannot_compute": (
+        {"env": INTERPRET, "devices": 1}, {"shape": "sweep", "sched_config": NO_TAINTS}, GAP_FILTER, None,
     ),
 }
 
@@ -167,6 +178,7 @@ CARRY = {
     "explain": ({"explain": True}, True, "explain"),
     "tie_seed_before_explain": ({"explain": True, "tie_seed": 0}, True, "tie_seed"),
     "sched_config": ({"sched_config": WEIGHTED}, True, "sched_config"),
+    "sched_config_default": ({"sched_config": DEFAULT_CONFIG}, True, None),
     "extra_plugins": ({"extra_plugins": PLUGIN}, True, "extra_plugins"),
 }
 
@@ -348,7 +360,13 @@ TURNED_AWAY = {
     "many_devices": ({"platform": "tpu", "devices": 4}, {"shape": "sweep"}, ("megakernel", "many_devices")),
     "segments": ({"env": INTERPRET}, {"segments": 2}, ("megakernel", "segments")),
     "explain": ({"env": INTERPRET}, {"explain": True}, ("megakernel", "explain")),
-    "sched_config": ({"env": INTERPRET}, {"sched_config": WEIGHTED}, ("megakernel", "sched_config")),
+    # the token names what the kernel cannot compute
+    "sched_config": ({"env": INTERPRET}, {"sched_config": NO_TAINTS}, ("megakernel", "sched_config:disabled_filter")),
+    "sched_config_fit_ignored_cols": (
+        {"env": INTERPRET}, {"sched_config": DEFAULT_CONFIG._replace(fit_ignored_cols=(2,))},
+        ("megakernel", "sched_config:fit_ignored_cols"),
+    ),
+    "sched_config_weights_are_served": ({"env": INTERPRET}, {"sched_config": WEIGHTED}, None),
     "extra_plugins": ({"env": INTERPRET}, {"extra_plugins": PLUGIN}, ("megakernel", "extra_plugins")),
     "tie_seed": ({"env": INTERPRET}, {"tie_seed": 7}, ("megakernel", "tie_seed")),
     "start_state": ({"env": INTERPRET}, {"start_state": True}, ("megakernel", "start_state")),
@@ -359,6 +377,7 @@ TURNED_AWAY = {
     "native_fit_ignored_cols": (
         {}, {"sched_config": DEFAULT_CONFIG._replace(fit_ignored_cols=(2,))}, ("native", "fit_ignored_cols"),
     ),
+    "native_rtcr": ({}, {"sched_config": BINPACK}, ("native", "rtcr")),
     "native_not_built": ({"built": False}, {}, ("native", "not_built")),
 }
 

@@ -89,10 +89,12 @@ def test_fastpath_rejects_unsupported():
     app = ResourceTypes()
     app.pods.append(fx.make_fake_pod("p", "1", "1Gi"))
 
-    # non-default scheduler config stays on the XLA path
+    # a scheduler config the kernel cannot compute (a disabled filter) stays on the XLA path;
+    # its score weights are served
     prep = prepare(cluster, [AppResource("a", app)], node_pad=128)
     assert fastpath.applicable(prep)
-    assert not fastpath.applicable(prep, DEFAULT_CONFIG._replace(w_least=3.0))
+    assert fastpath.applicable(prep, DEFAULT_CONFIG._replace(w_least=3.0))
+    assert not fastpath.applicable(prep, DEFAULT_CONFIG._replace(f_taints=False))
 
     # two non-hostname topology keys are in scope; a third is not
     def spread_app(keys):

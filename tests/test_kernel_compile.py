@@ -74,3 +74,30 @@ def test_the_kernel_compiles_for_the_chip_at_a_cells_widths(one_chip, cell, subl
         stream(S, P, dt=jnp.bool_), sublanes=sublanes, **flags,
     )
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+#: plan-binpack's profile: RequestedToCapacityRatio on cpu and
+#: memory with the folded shape 0 -> 0, 100 -> 100 and LeastAllocated off; and
+#: a three-point shape over an extended resource column, whose segments and
+#: mean divide
+PROFILES = {
+    "binpack": dict(w_least=0.0, w_rtcr=1.0, rtcr_shape=((0.0, 0.0), (100.0, 100.0)),
+                    rtcr_resources=((0, 1.0), (1, 1.0))),
+    "three_points": dict(w_rtcr=3.0, rtcr_shape=((0.0, 0.0), (40.0, 70.0), (100.0, 30.0)),
+                         rtcr_resources=((0, 2.0), (1, 1.0), (3, 1.0))),
+}
+
+
+@pytest.mark.parametrize("S,sublanes", [(1, 1), (16, 8)], ids=["schedule", "packed-sweep"])
+@pytest.mark.parametrize("profile", sorted(PROFILES))
+def test_a_profiles_kernel_compiles_for_the_chip_at_plan_shorts_widths(one_chip, profile, S, sublanes):
+    from opensim_tpu.engine.schedconfig import DEFAULT_CONFIG
+
+    widths, flags, chunks = SHAPES["plan-short"]
+    P = chunks * CHUNK
+    stream = lambda *shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    lowered = run_fast_scan.lower(
+        _inputs(one_chip, S, **widths), stream(P, dt=jnp.int32), stream(S, P, dt=jnp.bool_),
+        stream(S, P, dt=jnp.bool_), sublanes=sublanes, config=DEFAULT_CONFIG._replace(**PROFILES[profile]), **flags,
+    )
+    assert "tpu_custom_call" in lowered.compile().as_text()

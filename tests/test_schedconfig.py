@@ -753,3 +753,168 @@ def test_sweep_auto_single_profile_still_routes_one_config(tmp_path):
     node_valid[:, :4] = True
     res = scenarios.sweep_auto(prep, node_valid, np.ones((2, P), bool), config=cfg)
     assert list(np.asarray(res.unscheduled)) == [0, 0]
+
+
+# ---------------------------------------------------------------------------
+# RequestedToCapacityRatio (kube 1.21's in-tree bin-packing score)
+# ---------------------------------------------------------------------------
+
+#: the Resource Bin Packing page's profile, LeastAllocated off as the
+#: >= 1.23 scoring strategy replaces it
+BINPACK_PROFILE = """kind: KubeSchedulerConfiguration
+apiVersion: kubescheduler.config.k8s.io/v1beta1
+profiles:
+  - schedulerName: default-scheduler
+    plugins:
+      score:
+        disabled:
+          - name: NodeResourcesLeastAllocated
+        enabled:
+          - name: RequestedToCapacityRatio
+            weight: 1
+    pluginConfig:
+      - name: RequestedToCapacityRatio
+        args:
+          shape:
+            - {utilization: 0, score: 0}
+            - {utilization: 100, score: 10}
+          resources:
+            - {name: cpu, weight: 1}
+            - {name: memory, weight: 1}
+"""
+
+
+def test_the_bin_packing_profile_parses_to_its_config(tmp_path):
+    from opensim_tpu.engine.schedconfig import kernel_gap, profile_of
+
+    cfg = load_scheduler_config(_write(tmp_path, BINPACK_PROFILE))
+    # cpu and memory are columns of every vocabulary: no routing needed
+    assert cfg == DEFAULT_CONFIG._replace(
+        w_least=0.0, w_rtcr=1.0, rtcr_shape=((0.0, 0.0), (100.0, 100.0)), rtcr_resources=((0, 1.0), (1, 1.0)),
+    )
+    assert profile_of(cfg) == "rtcr" and kernel_gap(cfg) is None
+
+
+def test_rtcr_defaults_and_an_extended_resource(tmp_path):
+    # no resources: cpu and memory at weight 1; a weight of 0 is 1 (the v1beta1 defaults)
+    cfg = load_scheduler_config(_write(tmp_path, """kind: KubeSchedulerConfiguration
+profiles:
+  - plugins:
+      score:
+        enabled: [{name: RequestedToCapacityRatio, weight: 4}]
+    pluginConfig:
+      - name: RequestedToCapacityRatio
+        args:
+          shape: [{utilization: 20, score: 3}]
+"""))
+    assert (cfg.w_rtcr, cfg.w_least, cfg.rtcr_shape, cfg.rtcr_resources) == (4.0, 1.0, ((20.0, 30.0),), ((0, 1.0), (1, 1.0)))
+    # an extended resource resolves against the cluster's vocabulary
+    got = load_scheduler_config(_write(tmp_path, """kind: KubeSchedulerConfiguration
+profiles:
+  - plugins:
+      score:
+        enabled: [{name: RequestedToCapacityRatio}]
+    pluginConfig:
+      - name: RequestedToCapacityRatio
+        args:
+          shape: [{utilization: 0, score: 10}, {utilization: 100, score: 0}]
+          resources: [{name: example.com/foo, weight: 0}, {name: memory, weight: 3}, {name: example.com/none, weight: 2}]
+"""))
+    assert isinstance(got, SchedulerProfiles)
+    from opensim_tpu.engine.schedconfig import resolve_profiles
+
+    cluster = ResourceTypes()
+    cluster.nodes.append(fx.make_fake_node("n0", "8", "16Gi", "110", fx.with_allocatable({"example.com/foo": "4"})))
+    app = ResourceTypes()
+    app.pods.append(fx.make_fake_pod("p", "1", "1Gi"))
+    from opensim_tpu.engine.simulator import prepare
+
+    prep = prepare(cluster, [AppResource("a", app)])
+    cfg, _invalid = resolve_profiles(got, prep.ordered, prep.meta.resource_names)
+    foo = prep.meta.resource_names.index("example.com/foo")
+    assert cfg.rtcr_resources == ((foo, 1.0), (1, 3.0), (-1, 2.0))
+    assert cfg.rtcr_shape == ((0.0, 100.0), (100.0, 0.0))
+    # a profile that does not enable the plugin scores nothing with its args: no config
+    off = load_scheduler_config(_write(tmp_path, """kind: KubeSchedulerConfiguration
+profiles:
+  - pluginConfig:
+      - name: RequestedToCapacityRatio
+        args:
+          shape: [{utilization: 0, score: 0}]
+"""))
+    assert off == DEFAULT_CONFIG
+
+
+RTCR_INVALID = {
+    "utilization_not_increasing": ("shape: [{utilization: 50, score: 1}, {utilization: 50, score: 2}]",
+                                   "utilization values must be sorted in increasing order"),
+    "utilization_decreasing": ("shape: [{utilization: 60, score: 1}, {utilization: 20, score: 2}]",
+                               "utilization values must be sorted in increasing order"),
+    "utilization_over_100": ("shape: [{utilization: 101, score: 1}]", "utilization 101 is not in the range 0..100"),
+    "score_over_10": ("shape: [{utilization: 0, score: 11}]", "score 11 is not in the range 0..10"),
+    "score_negative": ("shape: [{utilization: 0, score: -1}]", "score -1 is not in the range 0..10"),
+    "weight_under_1": ("shape: [{utilization: 0, score: 1}]\n          resources: [{name: cpu, weight: -1}]",
+                       "weight -1 is under 1"),
+    "empty_shape": ("shape: []", "at least one point must be specified"),
+    "no_shape": ("resources: [{name: cpu, weight: 1}]", "at least one point must be specified"),
+    "not_whole": ("shape: [{utilization: 12.5, score: 1}]", "is not a whole number"),
+    "unknown_field": ("shape: [{utilization: 0, score: 1}]\n          scoringStrategy: x", "scoringStrategy is not supported"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RTCR_INVALID))
+def test_rtcr_args_are_validated_as_kube_validates_them(tmp_path, case):
+    args, message = RTCR_INVALID[case]
+    with pytest.raises(ValueError, match=message):
+        load_scheduler_config(_write(tmp_path, f"""kind: KubeSchedulerConfiguration
+profiles:
+  - plugins:
+      score:
+        enabled: [{{name: RequestedToCapacityRatio}}]
+    pluginConfig:
+      - name: RequestedToCapacityRatio
+        args:
+          {args}
+"""))
+
+
+def test_rtcr_enabled_without_args_fails_loudly(tmp_path):
+    with pytest.raises(ValueError, match="RequestedToCapacityRatio is enabled without pluginConfig args"):
+        load_scheduler_config(_write(tmp_path, """kind: KubeSchedulerConfiguration
+profiles:
+  - plugins:
+      score:
+        enabled: [{name: RequestedToCapacityRatio}]
+"""))
+
+
+NO_CONFIG = {
+    "no_profiles": "kind: KubeSchedulerConfiguration\n",
+    "one_default_profile": "kind: KubeSchedulerConfiguration\nprofiles:\n  - schedulerName: default-scheduler\n",
+    "one_profile_at_the_default_weights": """kind: KubeSchedulerConfiguration
+profiles:
+  - plugins:
+      score:
+        enabled: [{name: NodeResourcesLeastAllocated, weight: 1}, {name: PodTopologySpread, weight: 2}]
+""",
+}
+
+
+@pytest.mark.parametrize("case", sorted(NO_CONFIG))
+def test_a_file_that_changes_nothing_is_no_config_and_stays_on_the_kernel(tmp_path, monkeypatch, case):
+    from opensim_tpu.engine import select
+
+    cfg = load_scheduler_config(_write(tmp_path, NO_CONFIG[case]))
+    assert cfg == DEFAULT_CONFIG
+    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
+    monkeypatch.delenv("OPENSIM_DISABLE_FASTPATH", raising=False)
+    cluster = ResourceTypes()
+    cluster.nodes.append(fx.make_fake_node("n0", "8", "16Gi"))
+    app = ResourceTypes()
+    app.deployments.append(fx.make_fake_deployment("web", 3, "1", "1Gi"))
+    from opensim_tpu.engine.simulator import prepare
+
+    prep = prepare(cluster, [AppResource("a", app)], node_pad=128)
+    assert select.ladder(prep, select.Ask(sched_config=cfg))["megakernel"] is None
+    res = simulate(cluster, [AppResource("a", app)], sched_config=cfg)
+    assert res.engine.name == "megakernel" and not res.unscheduled_pods

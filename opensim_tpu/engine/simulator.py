@@ -724,11 +724,11 @@ def _run_engine_ladder(
             log.info("native engine skipped: %s", miss)
     if out is None:
         from . import resident
-
         away = select.turned_away(prep, ask, pol, rungs)
         if away is not None:
             RECORDER.count_engine_declined(*away)
-        with obs.span("engine.xla", pods=len(tmpl_ids), **shape, **select.decline_attrs(prep, away)) as rung:
+        with obs.span("engine.xla", pods=len(tmpl_ids), **shape, **kernels.count_reads(ec, prep.features),
+                      **select.decline_attrs(prep, away)) as rung:
             head = resident.fetch(prep, pod_valid, ask)
             rung.set(scanned=len(tmpl_ids) - (head.n_res if head is not None else 0))
             out = _xla_scan(

@@ -262,7 +262,7 @@ def spread_filter(ec, st, u, node_aff_mask):
 
     dom = ec.node_domain[:, jnp.maximum(topo, 0)]  # [N, Cs]
     has_label = dom < ec.domain_topo.shape[0] - 1  # trash row = missing label
-    cnt = st.dom_sel[dom, sel[None, :]]  # [N, Cs]
+    cnt = domain_counts(st.dom_sel, dom, sel)  # [N, Cs]
     self_match = ec.matches_sel[u, sel]  # [Cs]
 
     # min matchNum over eligible domains: nodes passing node affinity with the
@@ -289,7 +289,7 @@ def interpod_filter(ec, st, u):
     an_topo = ec.an_topo[u]
     an_active = an_sel >= 0
     dom = ec.node_domain[:, an_topo]  # [N, Tn]
-    anti_cnt = st.dom_sel[dom, jnp.maximum(an_sel, 0)[None, :]]  # [N, Tn]
+    anti_cnt = domain_counts(st.dom_sel, dom, jnp.maximum(an_sel, 0))  # [N, Tn]
     # k8s: a node missing the topology label forms no topology pair, so the
     # anti-affinity term is vacuously satisfied there.
     has_label = dom < D_trash
@@ -316,10 +316,10 @@ def interpod_filter(ec, st, u):
     at_topo = ec.at_topo[u]
     at_active = at_sel >= 0
     dom_a = ec.node_domain[:, at_topo]  # [N, Ti]
-    aff_cnt = st.dom_sel[dom_a, jnp.maximum(at_sel, 0)[None, :]]  # [N, Ti]
+    aff_cnt = domain_counts(st.dom_sel, dom_a, jnp.maximum(at_sel, 0))  # [N, Ti]
     has_label_a = dom_a < D_trash
     dom_is_key = ec.domain_topo[None, :] == at_topo[:, None]  # [Ti, D+1]
-    total = jnp.sum(jnp.where(dom_is_key, st.dom_sel[:, jnp.maximum(at_sel, 0)].T, 0.0), axis=-1)  # [Ti]
+    total = jnp.sum(jnp.where(dom_is_key, selector_columns(st.dom_sel, jnp.maximum(at_sel, 0)).T, 0.0), axis=-1)
     map_empty = jnp.sum(jnp.where(at_active, total, 0.0)) == 0
     self_match = ec.matches_sel[u, jnp.maximum(at_sel, 0)]  # [Ti]
     bootstrap = map_empty & jnp.all(~at_active | self_match) & jnp.any(at_active)
@@ -518,7 +518,7 @@ def interpod_score(ec, st, u, feasible):
     pt_w = ec.pt_w[u]
     dom = ec.node_domain[:, pt_topo]  # [N, Tpp]
     has_label = dom < D_trash
-    cnt = st.dom_sel[dom, jnp.maximum(pt_sel, 0)[None, :]]
+    cnt = domain_counts(st.dom_sel, dom, jnp.maximum(pt_sel, 0))
     incoming = jnp.sum(
         jnp.where((pt_sel[None, :] >= 0) & has_label, cnt * pt_w[None, :], 0.0), axis=-1
     )
@@ -554,7 +554,7 @@ def spread_score(ec, stat: StaticTables, st, u, feasible):
     D_trash = ec.domain_topo.shape[0] - 1
     dom = ec.node_domain[:, jnp.maximum(topo, 0)]  # [N, Cs]
     has_label = dom < D_trash
-    cnt = st.dom_sel[dom, sel[None, :]]  # [N, Cs]
+    cnt = domain_counts(st.dom_sel, dom, sel)  # [N, Cs]
 
     ignored = feasible & ~jnp.all(has_label | ~soft[None, :], axis=-1)  # [N]
     scored = feasible & ~ignored
@@ -1345,3 +1345,63 @@ def bind_update(ec: EncodedCluster, st: ScanState, u, node, apply,
         ),
         take * applyf,
     )
+
+
+# The readers of the selector-count carry below sit after every function the
+# megakernel traces, so that adding them moved none of those lines (a Mosaic
+# compile key holds the call stack).
+
+#: columns of the selector-count carry in one window: one lane tile
+COUNT_WINDOW = 128
+
+
+def selector_columns(counts, cols):
+    """``counts[:, cols]``: the [D+1, C] slab of the per-domain selector
+    counts ``counts`` [D+1, A] under the terms' selectors ``cols`` [C] (each
+    at least 0). Past one window, each column is taken from the window of
+    ``COUNT_WINDOW`` columns that holds it: a dynamic slice of whole rows,
+    then a select over the window's lanes and a sum of one count and zeros,
+    so the same float32 counts. A gather of the columns makes XLA lay the
+    carry out by columns, and the bind's row update then walks every tile of
+    a row (35 us more a step at [5,002, 5,300] on a v5e); a window keeps it
+    by rows. A carry of one window is read as it is."""
+    A, lanes = counts.shape[1], COUNT_WINDOW
+    if A <= lanes:
+        return counts[:, cols]
+    start = jnp.clip(cols - cols % lanes, 0, A - lanes)  # [C]
+    hit = jnp.arange(lanes)[None, :] == (cols - start)[:, None]  # [C, lanes]
+    return jnp.stack([
+        jnp.sum(jnp.where(hit[c], jax.lax.dynamic_slice_in_dim(counts, start[c], lanes, axis=1), 0.0), axis=1)
+        for c in range(cols.shape[0])
+    ], axis=1)
+
+
+def domain_counts(counts, dom, cols):
+    """``counts[dom, cols[None, :]]``: each node's count in its own domain
+    under each term's topology key (``dom`` [N, C] the nodes' domains).
+    Past one window, gathered node by node within :func:`selector_columns`'
+    [D+1, C] slab: a point gather of ``counts`` itself reads N·C cells
+    scattered over the whole carry at every step, [5,002, 5,300] (106 MB)
+    for 5,300 spread selectors, 188 us of a 239 us step on a v5e. A carry of
+    one window is gathered from directly: a slab would hold all of it, and
+    on a v5e the served what-if's scan (A = 1) took 45 % more device time
+    through one."""
+    if counts.shape[1] <= COUNT_WINDOW:
+        return counts[dom, cols[None, :]]
+    return jnp.take_along_axis(selector_columns(counts, cols), dom, axis=0, mode="promise_in_bounds")
+
+
+def count_reads(ec, feat: Features) -> dict:
+    """How wide an XLA step's reads of the selector-count carry are, for the
+    spans of the rungs that run the XLA scan: ``count_columns``, the columns
+    of ``dom_sel`` one step reads through :func:`selector_columns` for the
+    features that are on (the spread constraints' Cs, one slab for filter and
+    score; the inter-pod anti and affinity terms' Tn + Ti; the incoming
+    preferred terms' Tpp), and ``count_table_bytes``, the carry's (D+1)·A·4."""
+    spread = ec.spr_topo.shape[1] if feat.spread_hard or feat.spread_soft else 0
+    required = ec.an_sel.shape[1] + ec.at_sel.shape[1] if feat.interpod else 0
+    preferred = ec.pt_sel.shape[1] if feat.interpod or feat.prefg else 0
+    return {
+        "count_columns": int(spread + required + preferred),
+        "count_table_bytes": int(ec.domain_topo.shape[0] * ec.matches_sel.shape[1] * 4),
+    }

@@ -25,8 +25,8 @@ from ..engine import select
 # the sweep bodies run UNDER tracing (vmapped inside the jitted sweeps):
 # they call the raw jit entry, never the observed schedule_pods wrapper —
 # the compile watch's host bookkeeping must stay outside the trace (OSL1601)
-from ..engine.scheduler import _schedule_pods_jit as _schedule_pods_traced
-from ..engine.scheduler import scan_unroll
+from ..engine.scheduler import _schedule_pods_jit as _schedule_pods_traced, scan_unroll
+from ..ops import kernels
 
 
 class SweepResult(NamedTuple):
@@ -169,7 +169,7 @@ def sweep_auto(
         RECORDER.count_engine_declined(*away)
     RECORDER.count_engine_profile("xla", profile)
     with obs.span("sweep.xla", scenarios=S, devices=len(jax.devices()), profile=profile,
-                  **select.decline_attrs(prep, away)):
+                  **kernels.count_reads(prep.ec, prep.features), **select.decline_attrs(prep, away)):
         with launch_span("xla.launch", scenarios=S, pods=len(prep.tmpl_ids)):
             res = sweep(
                 prep.ec,

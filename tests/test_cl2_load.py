@@ -238,7 +238,7 @@ def test_a_jobs_and_a_daemonsets_pods_carry_no_default_spread(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# what the path reports: expand.daemonsets, encode.match, declined, the counter
+# what the path reports: expand.daemonsets, encode.match, the count reads, declined, the counter
 # ---------------------------------------------------------------------------
 
 
@@ -270,6 +270,10 @@ def test_the_spans_of_the_expansion_and_of_the_match_matrix(tmp_path, monkeypatc
     assert match in encode.children and encode.start <= match.start and match.end <= encode.end
     # 24 pinned pods and 76 workloads are 100 templates; 64 of them have a selector, and meet no other
     assert match.attrs == {"templates": 100, "selectors": 64, "evaluated": 64}
+    # the XLA scan reads the columns of a pod's two default spread constraints (hostname, zone) of a
+    # count table of 24 hostname domains, one zone and the trash row by 64 selectors
+    (rung,) = find(tr, "engine.xla")
+    assert (rung.attrs["count_columns"], rung.attrs["count_table_bytes"]) == (2, (24 + 1 + 1) * 64 * 4)
 
 
 def test_a_run_the_kernel_declines_says_so_on_the_rung_that_runs(tmp_path, monkeypatch):

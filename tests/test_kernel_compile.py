@@ -4,9 +4,11 @@ here and compiles for a chip that is described, not attached, so a kernel
 that Mosaic refuses (a layout it cannot broadcast, a slice off the tiling,
 too much VMEM) fails here and not on the chip. The interpreter runs none of
 these checks. Nothing runs: results are the other tests'. Tier-1, a second
-or two a case."""
+or two a case. And the XLA scan at plan-cl2's widths: what the chip's
+compiler makes of its reads of the selector-count carry."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,3 +103,63 @@ def test_a_profiles_kernel_compiles_for_the_chip_at_plan_shorts_widths(one_chip,
         stream(S, P, dt=jnp.bool_), sublanes=sublanes, config=DEFAULT_CONFIG._replace(**PROFILES[profile]), **flags,
     )
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def gathers(hlo: str):
+    """(operand type, slice_sizes) of every gather of an optimized HLO module."""
+    out = []
+    for comp in hlo.split("\n\n"):
+        types = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", comp))
+        for operand, sizes in re.findall(r" gather\(%([\w.\-]+), .*?slice_sizes=\{([\d,]+)\}", comp):
+            out.append((types.get(operand), tuple(int(x) for x in sizes.split(","))))
+    return out
+
+
+def test_the_xla_scan_reads_plan_cl2s_count_carry_by_row_windows_on_the_chip(one_chip, tmp_path, monkeypatch):
+    """`_schedule_pods_jit` at plan-cl2's widths, from the tiny size's
+    encoding with its node, template, selector and domain axes widened:
+    5,000 nodes (5,120 padded), 10,450 templates, 5,300 selectors, 5,000
+    hostname domains, one zone and the trash row, 54,528 steps. After XLA's
+    passes no gather reads the [D+1, A] count carry (the point gather of
+    10,240 cells a step that `kernels.domain_counts` replaced, or a column
+    gather, for which XLA lays the carry out by columns): it is read by
+    windows of 128 whole rows, keeps its row-major layout everywhere, and the
+    per-node gather reads the [D+1, C] slab."""
+    import functools
+    import importlib
+    import json
+
+    import numpy as np
+
+    from benchmarks.drivers import Context
+    from opensim_tpu.engine.scheduler import _schedule_pods_jit
+    from opensim_tpu.engine.simulator import prepare
+    from opensim_tpu.planner.apply import Applier, Options
+
+    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs", "cl2-load-5k.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "traffic", "fit-cl2.json")) as f:
+        traffic = json.load(f)
+    ctx = Context(config=config, traffic=traffic, seed=5, scratch=str(tmp_path), rehearse=True, sizes=config["tiny"])
+    driver = importlib.import_module("benchmarks.drivers.plan_loop_kinds").Driver(ctx)
+    driver.prepare()
+    applier = Applier(Options(simon_config=driver.simon_config))
+    prep = prepare(applier.load_cluster(), applier.load_apps())
+    ec, st0 = prep.ec_np, prep.st0
+    N, U, A, Dp1 = ec.node_valid.shape[0], ec.req.shape[0], ec.matches_sel.shape[1], ec.domain_topo.shape[0]
+    wide = {N: 5120, N + 1: 5121, U: 10450, A: 5300, Dp1: 5002}
+    # the four axes are told apart by their widths: every other axis of the tiny size is narrower
+    assert len(wide) == 5 and all(d < 8 for a in (*ec, *st0) for d in np.shape(a) if d not in wide)
+    spec = lambda a: jax.ShapeDtypeStruct(tuple(wide.get(d, d) for d in np.shape(a)), np.asarray(a).dtype,
+                                          sharding=one_chip)
+    stream = lambda dt: jax.ShapeDtypeStruct((54528,), dt, sharding=one_chip)
+    hlo = jax.jit(functools.partial(_schedule_pods_jit, features=prep.features)).lower(
+        jax.tree.map(spec, ec), jax.tree.map(spec, st0), stream(jnp.int32), stream(jnp.bool_), stream(jnp.bool_),
+    ).compile().as_text()
+    assert not [g for g in gathers(hlo) if g[0] == "f32[5002,5300]"]
+    assert re.search(r"dynamic-slice\(.*dynamic_slice_sizes=\{5002,128\}", hlo)
+    assert set(re.findall(r"f32\[5002,5300\]\{([\d,]+)", hlo)) == {"1,0"}
+    slab = f"f32[5002,{ec.spr_topo.shape[1]}]"
+    assert any(operand == slab and sizes == (1, 1) for operand, sizes in gathers(hlo))

@@ -86,22 +86,25 @@ def parse_node_storage(node: Node):
 
 def encode_local_storage(nodes: List[Node], n_pad: int):
     """VG capacity [N, Vg], device capacity [N, Dv], device media [N, Dv]."""
-    parsed = [parse_node_storage(n) for n in nodes]
-    Vg = max([len(v) for v, _ in parsed] + [1])
-    Dv = max([len(d) for _, d in parsed] + [1])
-    vg_cap = np.zeros((n_pad, Vg), dtype=np.float32)
-    dev_cap = np.zeros((n_pad, Dv), dtype=np.float32)
-    dev_media = np.full((n_pad, Dv), -1, dtype=np.int32)
-    vg_names: List[List[str]] = []
-    dev_names: List[List[str]] = []
-    for i, (vgs, devs) in enumerate(parsed):
-        vg_names.append([name for name, _ in vgs])
-        dev_names.append([name for name, _, _ in devs])
-        for j, (_, cap) in enumerate(vgs):
-            vg_cap[i, j] = cap
-        for j, (_, cap, media) in enumerate(devs):
-            dev_cap[i, j] = cap
-            dev_media[i, j] = media
+    with obs.span("encode.local", nodes=len(nodes)) as sp:
+        parsed = [parse_node_storage(n) for n in nodes]
+        Vg = max([len(v) for v, _ in parsed] + [1])
+        Dv = max([len(d) for _, d in parsed] + [1])
+        vg_cap = np.zeros((n_pad, Vg), dtype=np.float32)
+        dev_cap = np.zeros((n_pad, Dv), dtype=np.float32)
+        dev_media = np.full((n_pad, Dv), -1, dtype=np.int32)
+        vg_names: List[List[str]] = []
+        dev_names: List[List[str]] = []
+        for i, (vgs, devs) in enumerate(parsed):
+            vg_names.append([name for name, _ in vgs])
+            dev_names.append([name for name, _, _ in devs])
+            for j, (_, cap) in enumerate(vgs):
+                vg_cap[i, j] = cap
+            for j, (_, cap, media) in enumerate(devs):
+                dev_cap[i, j] = cap
+                dev_media[i, j] = media
+        sp.set(vg_nodes=sum(1 for v in vg_names if v), vgs=sum(len(v) for v in vg_names),
+               devices=sum(len(d) for d in dev_names))
     return vg_cap, dev_cap, dev_media, vg_names, dev_names
 
 
@@ -112,23 +115,25 @@ def encode_local_requests(templates: List[SchedTemplate]):
     smallest-volume → smallest fitting device, common.go:290-349); the
     max-size `dev_req` and `dev_req_count` remain for the score proxy."""
     U = len(templates)
-    lvm_req = np.zeros((U,), dtype=np.float32)
-    dev_req = np.zeros((U, 2), dtype=np.float32)
-    dev_req_count = np.zeros((U, 2), dtype=np.int32)
-    per_media: List[List[List[float]]] = [[[], []] for _ in range(U)]
-    for u, t in enumerate(templates):
-        for kind, size, _sc in t.local_volumes:
-            if kind == "LVM":
-                lvm_req[u] += size
-            elif kind in ("SSD", "HDD"):
-                media = MEDIA_SSD if kind == "SSD" else MEDIA_HDD
-                dev_req[u, media] = max(dev_req[u, media], size)
-                dev_req_count[u, media] += 1
-                per_media[u][media].append(float(size))
-    Mv = max([len(v) for row in per_media for v in row] + [1])
-    dev_req_sizes = np.zeros((U, 2, Mv), dtype=np.float32)
-    for u in range(U):
-        for media in (0, 1):
-            for i, size in enumerate(sorted(per_media[u][media], reverse=True)):
-                dev_req_sizes[u, media, i] = size
+    with obs.span("encode.local", templates=U) as sp:
+        lvm_req = np.zeros((U,), dtype=np.float32)
+        dev_req = np.zeros((U, 2), dtype=np.float32)
+        dev_req_count = np.zeros((U, 2), dtype=np.int32)
+        per_media: List[List[List[float]]] = [[[], []] for _ in range(U)]
+        for u, t in enumerate(templates):
+            for kind, size, _sc in t.local_volumes:
+                if kind == "LVM":
+                    lvm_req[u] += size
+                elif kind in ("SSD", "HDD"):
+                    media = MEDIA_SSD if kind == "SSD" else MEDIA_HDD
+                    dev_req[u, media] = max(dev_req[u, media], size)
+                    dev_req_count[u, media] += 1
+                    per_media[u][media].append(float(size))
+        Mv = max([len(v) for row in per_media for v in row] + [1])
+        dev_req_sizes = np.zeros((U, 2, Mv), dtype=np.float32)
+        for u in range(U):
+            for media in (0, 1):
+                for i, size in enumerate(sorted(per_media[u][media], reverse=True)):
+                    dev_req_sizes[u, media, i] = size
+        sp.set(lvm_templates=int((lvm_req > 0).sum()), device_claims=int(dev_req_count.sum()))
     return lvm_req, dev_req, dev_req_count, dev_req_sizes

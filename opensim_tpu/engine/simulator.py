@@ -1103,6 +1103,7 @@ def finish_decode(
     # bucket (chosen never points at an invalid node)
     pod_lists = [node_pods.get(n) for n in node_names]
     gpu_ids = _gpu_device_ids(prep, chosen, gpu_take, decode_drops, engine_name)
+    _count_local_volumes(prep, chosen, decode_drops, engine_name)
     # a kernel result kept under reasons=False: its count rows are zeros no engine filled
     attributed = engine.attribution != "not_asked"
 
@@ -1351,14 +1352,66 @@ def _decode(
     return _node_statuses(cluster.nodes, node_pods, out, meta)
 
 
+def _local_storage_annotations(n_nodes: int, final_state, meta: ClusterMeta) -> Dict[int, str]:
+    """The simon/node-local-storage annotation of every node that has a VG or
+    a device, by node index: each VG's bytes requested and each device's
+    allocation at the end of the run (open-local.go:175-254). Traced as
+    ``decode.local`` where some node has storage."""
+    from ..obs import trace as obs
+
+    rows = [i for i, (v, d) in enumerate(zip(meta.node_vg_names[:n_nodes], meta.node_dev_names[:n_nodes])) if v or d]
+    if not rows:
+        return {}
+    out: Dict[int, str] = {}
+    with obs.span("decode.local", nodes=len(rows)):
+        vg_free = np.asarray(final_state.vg_free)
+        dev_free = np.asarray(final_state.dev_free)
+        for idx in rows:
+            vgs = []
+            for j, name in enumerate(meta.node_vg_names[idx]):
+                cap = float(meta.node_vg_cap[idx, j])
+                vgs.append({"name": name, "capacity": int(cap), "requested": int(cap - vg_free[idx, j])})
+            devices = []
+            for j, name in enumerate(meta.node_dev_names[idx]):
+                devices.append(
+                    {
+                        "name": name,
+                        "device": name,
+                        "capacity": int(meta.node_dev_cap[idx, j]),
+                        "mediaType": "ssd" if int(meta.node_dev_media[idx, j]) == 0 else "hdd",
+                        "isAllocated": bool(dev_free[idx, j] == 0 and meta.node_dev_cap[idx, j] > 0),
+                    }
+                )
+            out[idx] = json.dumps({"vgs": vgs, "devices": devices})
+    return out
+
+
+def _count_local_volumes(prep, chosen, drop_pods, engine_name: str) -> None:
+    """Counts the open-local claims of the placed pods on ``/metrics`` by the
+    rung that answered and by kind: ``lvm``, ``ssd`` and ``hdd`` device."""
+    if not prep.features.local:
+        return
+    from ..obs.metrics import RECORDER
+
+    templates = prep.encoder.ts.templates
+    kinds = ("LVM", "SSD", "HDD")
+    per_template = np.array([[sum(1 for v in t.local_volumes if v[0] == k) for k in kinds] for t in templates])
+    placed = np.asarray(chosen) >= 0
+    dropm = _drop_mask(drop_pods, len(placed))
+    if dropm is not None:
+        placed &= ~dropm
+    pods = np.bincount(np.asarray(prep.tmpl_ids)[placed], minlength=len(templates))
+    claims = pods @ per_template
+    RECORDER.count_local_volumes(engine_name, {k.lower(): int(n) for k, n in zip(kinds, claims)})
+
+
 def _node_statuses(nodes, node_pods, out, meta: ClusterMeta) -> List[NodeStatus]:
     """Write final storage/GPU usage back into node annotations — parity
     with the Bind plugins updating the fake cluster's node objects
     (open-local.go:175-254 writes simon/node-local-storage;
     open-gpu-share.go Reserve writes simon/node-gpu-share)."""
-    vg_free = np.asarray(out.final_state.vg_free)
-    dev_free = np.asarray(out.final_state.dev_free)
     gpu_free = np.asarray(out.final_state.gpu_free)
+    local = _local_storage_annotations(len(nodes), out.final_state, meta)
 
     statuses: List[NodeStatus] = []
     for idx, orig in enumerate(nodes):
@@ -1370,25 +1423,8 @@ def _node_statuses(nodes, node_pods, out, meta: ClusterMeta) -> List[NodeStatus]
         node.metadata.annotations = dict(orig.metadata.annotations)
         node.metadata.labels = dict(orig.metadata.labels)
         pods = node_pods[node.metadata.name]
-        vg_names = meta.node_vg_names[idx] if idx < len(meta.node_vg_names) else []
-        dev_names = meta.node_dev_names[idx] if idx < len(meta.node_dev_names) else []
-        if vg_names or dev_names:
-            vgs = []
-            for j, name in enumerate(vg_names):
-                cap = float(meta.node_vg_cap[idx, j])
-                vgs.append({"name": name, "capacity": int(cap), "requested": int(cap - vg_free[idx, j])})
-            devices = []
-            for j, name in enumerate(dev_names):
-                devices.append(
-                    {
-                        "name": name,
-                        "device": name,
-                        "capacity": int(meta.node_dev_cap[idx, j]),
-                        "mediaType": "ssd" if int(meta.node_dev_media[idx, j]) == 0 else "hdd",
-                        "isAllocated": bool(dev_free[idx, j] == 0 and meta.node_dev_cap[idx, j] > 0),
-                    }
-                )
-            node.metadata.annotations[ANNO_NODE_LOCAL_STORAGE] = json.dumps({"vgs": vgs, "devices": devices})
+        if idx in local:
+            node.metadata.annotations[ANNO_NODE_LOCAL_STORAGE] = local[idx]
         gpu_count = int(meta.node_gpu_count[idx]) if meta.node_gpu_count is not None else 0
         if gpu_count > 0:
             devs = {}

@@ -207,6 +207,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Pods placed with a gpu-share request by the engine that answered and by kind: a fraction of a device, one whole device, several slots",
         "counter",
     ),
+    # engine: megakernel | native | xla; kind: lvm | ssd | hdd
+    "simon_local_volumes_total": (
+        "open-local claims of the placed pods by the engine that answered and by kind: an LVM volume, a whole ssd or hdd device",
+        "counter",
+    ),
     # loader: c | python (models/expand.py: libyaml where PyYAML has it)
     "simon_yaml_documents_total": (
         "YAML documents read from files and rendered charts by the parser that read them", "counter",
@@ -614,6 +619,8 @@ class MetricsRecorder:
         # pods placed with a gpu-share request, by answering engine and kind
         # (fraction of a device, one whole device, several slots)
         self.gpushare_pods = make_counter("simon_gpushare_pods_total", ("engine", "kind"))
+        # open-local claims of the placed pods, by answering engine and kind
+        self.local_volumes = make_counter("simon_local_volumes_total", ("engine", "kind"))
         # documents by the YAML parser that read them (models/expand.py);
         # "python" on a host whose PyYAML has libyaml means the fast parser is not engaged
         self.yaml_documents = make_counter("simon_yaml_documents_total", ("loader",))
@@ -712,6 +719,12 @@ class MetricsRecorder:
                 if n:
                     self.gpushare_pods.inc((engine, kind), int(n))
 
+    def count_local_volumes(self, engine: str, by_kind: Dict[str, int]) -> None:
+        with self.lock:
+            for kind, n in by_kind.items():
+                if n:
+                    self.local_volumes.inc((engine, kind), int(n))
+
     def count_yaml_documents(self, loader: str, n: int) -> None:
         with self.lock:
             self.yaml_documents.inc((loader,), n)
@@ -729,6 +742,7 @@ class MetricsRecorder:
                 + self.engine_declined.render_lines()
                 + self.engine_profile.render_lines()
                 + self.gpushare_pods.render_lines()
+                + self.local_volumes.render_lines()
                 + self.yaml_documents.render_lines()
                 + self.phase_seconds.render_lines()
                 + self.request_seconds.render_lines()
@@ -749,6 +763,7 @@ class MetricsRecorder:
             self.engine_declined.reset()
             self.engine_profile.reset()
             self.gpushare_pods.reset()
+            self.local_volumes.reset()
             self.yaml_documents.reset()
             self.watch_apply.reset()
 

@@ -23,11 +23,14 @@ _OFF = dict(has_interpod=False, has_gpu=False, has_local=False, has_ports=False,
             has_tt=False, has_avoid=False, gc_row=-1)
 # (widths, flags, pod chunks): plan-short's k8s-5k-50k sweep (4,736 nodes, 4
 # resources, 20 templates, 24 selectors, two spread constraints a template)
-# and plan-gpushare's openb-gpushare-1523 (1,664 nodes, 866 templates in
-# big-U mode, eight devices a node)
+# plan-gpushare's openb-gpushare-1523 (1,664 nodes, 866 templates in big-U
+# mode, eight devices a node) and plan-local's k8s-5k-50k-openlocal (plan-short's
+# widths with the open-local rows: one VG and four devices a node, padded to
+# eight, and two claims of a media a template)
 SHAPES = {
     "plan-short": (dict(N=4736, R=4, U=20, A=24, Cs=2), dict(_OFF), 50),
     "plan-gpushare": (dict(N=1664, R=6, U=866, A=8, Cs=1), dict(_OFF, has_gpu=True, big_u=True), 9),
+    "plan-local": (dict(N=4736, R=4, U=20, A=24, Cs=2, Mv=2), dict(_OFF, has_local=True), 50),
 }
 
 
@@ -48,7 +51,7 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", cache)
 
 
-def _inputs(dev, S, N, R, U, A, Cs, K=1, Z=128, X=8):
+def _inputs(dev, S, N, R, U, A, Cs, K=1, Z=128, X=8, Mv=1):
     rows = dict(
         alloc_T=(R, N), used0_T=(R, N), static_pass=(U, N), aff_mask=(U, N), share_raw=(U, N),
         zone_NZ=(K, N, Z), zone_ZN=(K * Z, N), has_zone=(K, N), matches_AU=(A, U), node_valid=(S, 1, N),
@@ -57,7 +60,7 @@ def _inputs(dev, S, N, R, U, A, Cs, K=1, Z=128, X=8):
         **{n: (U, 1) for n in ("at_active", "at_key", "at_sel", "at_self", "an_active", "an_key", "an_sel",
                                "pt_active", "pt_key", "pt_sel", "pt_w")},
         anti_g_key=(X,), prefg_key=(X,), antig_GU=(X, U), gmatch_GU=(X, U), prefg_GU=(X, U), pmatch_GU=(X, U),
-        gpu_mem=(U,), gpu_cnt=(U,), gpu0_DN=(X, N), lvm_req=(U,), dev_req=(U, 2), dev_need=(U, 2), dev_sizes=(U, 2),
+        gpu_mem=(U,), gpu_cnt=(U,), gpu0_DN=(X, N), lvm_req=(U,), dev_req=(U, 2), dev_need=(U, 2), dev_sizes=(U, 2 * Mv),
         vg_cap_VN=(X, N), vg0_VN=(X, N), dev_cap_DN=(X, N), dev0_DN=(X, N), dev_media_DN=(2 * X, N),
         port_HU=(X, U), port_conf_HU=(X, U), na_raw=(U, N), tt_raw=(U, N), avoid_raw=(U, N),
     )

@@ -519,9 +519,12 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
 
 
 def _kernel_flags(prep) -> dict:
-    """The feature flags that pick the generated kernel variant."""
+    """The feature flags that pick the generated kernel variant, and with
+    open-local the VG and device rows a node really has (the widths of the
+    tables build_inputs pads to eight rows, candidate nodes included): the
+    variant's loops walk those alone."""
     f = prep.features
-    return dict(
+    flags = dict(
         has_interpod=bool(f.interpod or f.prefg),
         has_gpu=bool(f.gpu),
         has_local=bool(f.local),
@@ -531,12 +534,30 @@ def _kernel_flags(prep) -> dict:
         has_avoid=bool(f.prefer_avoid),
         gc_row=_gc_row(prep),
     )
+    if f.local:
+        flags.update(n_vg_real=int(prep.st0.vg_free.shape[1]), n_dev_real=int(prep.meta.node_dev_cap.shape[1]))
+    return flags
 
 
 def _gpu_rows(prep, fi: FastInputs) -> int:
     """Rows of the kernel's per-device block ([Gd, N], sublane-padded); 0
     where the variant without gpu-share runs and the block does not exist."""
     return int(fi.gpu0_DN.shape[0]) if prep.features.gpu else 0
+
+
+def _local_attrs(prep, flags: dict, tmpl_ids) -> dict:
+    """`mk.launch`'s open-local attributes, where the variant with the block
+    runs: the VG and device rows its loops walk (`local_vgs`,
+    `local_devices`) and `claim_steps`, the steps of the stream `tmpl_ids`
+    whose template has an LVM or a device claim, the only steps that run the
+    block. None where the variant without it runs."""
+    if not flags["has_local"]:
+        return {}
+    ec = prep.ec_np if prep.ec_np is not None else prep.ec
+    lvm, dev = (np.asarray(a) for a in jax.device_get((ec.lvm_req, ec.dev_req)))
+    claims = (lvm > 0) | (dev > 0).any(axis=1)
+    return dict(local_vgs=flags["n_vg_real"], local_devices=flags["n_dev_real"],
+                claim_steps=int(claims[np.asarray(tmpl_ids)].sum()))
 
 
 def _launch(
@@ -561,14 +582,16 @@ def _launch(
     (0.3 s for one frame on the chip's host, PERF.md §6); the calls after it
     are not touched."""
     S = pod_valid.shape[0]
+    flags = _kernel_flags(prep)
     with launch_span(
         "mk.launch", watch="megakernel", scenarios=S - pad, pods=len(tmpl_ids),
         nodes=fi.alloc_T.shape[1], templates=fi.static_pass.shape[0], big_u=big_u,
         gpu_devices=_gpu_rows(prep, fi), sublanes=sublanes, blocks=S // sublanes, pad_scenarios=pad,
+        **_local_attrs(prep, flags, tmpl_ids),
     ):
         return observed_jit_call(
             "megakernel", run_fast_scan, (fi, tmpl_ids, pod_valid, forced),
-            dict(interpret=interpret, big_u=big_u, sublanes=sublanes, config=config, **_kernel_flags(prep)),
+            dict(interpret=interpret, big_u=big_u, sublanes=sublanes, config=config, **flags),
         )
 
 

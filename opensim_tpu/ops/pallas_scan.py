@@ -54,7 +54,7 @@ SB = 1 the table is the plain [X, N]):
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -282,10 +282,18 @@ def _make_kernel(
     gc_row: int = -1,
     sublanes: int = 1,
     config=None,
+    n_vg_real=None,
+    n_dev_real=None,
 ):
     from ..engine.schedconfig import DEFAULT_CONFIG
 
     SB = sublanes
+    # the open-local loops walk the VG and device rows a node really has; the
+    # tables are padded to n_vg / n_dev rows, and a padding row (free and
+    # capacity 0, media neither) fits no claim and takes no bind
+    n_vg_real = n_vg if n_vg_real is None else n_vg_real
+    n_dev_real = n_dev if n_dev_real is None else n_dev_real
+    assert n_vg_real <= n_vg and n_dev_real <= n_dev, (n_vg_real, n_vg, n_dev_real, n_dev)
     # the score profile (a SchedulerConfig whose filters are all on; select
     # declines the others): its weights are trace-time constants, a weight of
     # one multiplies nothing and the RequestedToCapacityRatio term exists only
@@ -487,6 +495,14 @@ def _make_kernel(
                 return ref[i]
             return column(lambda s: ref[s * CHUNK + i], jnp.int32)
 
+        def on_claim(claimed, part, value):
+            """part(value) for a pod with the claim `claimed` tests, value as
+            it is for one without: the open-local parts where the pod has
+            nothing to place leave every value as it was. `claimed` reads
+            the template's SMEM scalars, the same for every scenario of the
+            block, so the branch is taken once a step for all of them."""
+            return jax.lax.cond(claimed, part, lambda v: v, value)
+
         def _flag_row(flag_ref, n_rows):
             """Expand an SMEM int-flag table into a [1, n_rows] f32 vector
             (loop-invariant: built once, outside the pod loop)."""
@@ -639,25 +655,29 @@ def _make_kernel(
                 # Open-Local filter: LVM fits the best VG; enough exclusive
                 # devices of each media type
                 lvm = lvm_ref[u]
-                best_vg_free = jnp.full((1, N), -1e30, jnp.float32)
-                for v in range(n_vg):
-                    best_vg_free = jnp.maximum(best_vg_free, srows(vg_free_ref, v))
-                feasible = jnp.where(
-                    lvm > 0, feasible * (best_vg_free >= lvm).astype(jnp.float32), feasible
-                )
+
+                def vg_fits(feasible):
+                    best_vg_free = jnp.full((1, N), -1e30, jnp.float32)
+                    for v in range(n_vg_real):
+                        best_vg_free = jnp.maximum(best_vg_free, srows(vg_free_ref, v))
+                    return feasible * (best_vg_free >= lvm).astype(jnp.float32)
+
+                feasible = on_claim(lvm > 0, vg_fits, feasible)
                 # one-device-per-volume matching: the i-th largest volume
                 # needs ≥ i+1 free fitting devices (common.go:290-349)
                 for m in range(2):
                     for vi in range(n_dvol):
                         size = dsz_ref[m * n_dvol + vi, u]
-                        cnt_fit = jnp.zeros((1, N), jnp.float32)
-                        for d in range(n_dev):
-                            free_d = srows(dev_free_ref, d)
-                            media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
-                            cnt_fit = cnt_fit + media_d * ((free_d >= size) & (free_d > 0)).astype(jnp.float32)
-                        feasible = jnp.where(
-                            size > 0, feasible * (cnt_fit >= (vi + 1)).astype(jnp.float32), feasible
-                        )
+
+                        def devs_fit(feasible):
+                            cnt_fit = jnp.zeros((1, N), jnp.float32)
+                            for d in range(n_dev_real):
+                                free_d = srows(dev_free_ref, d)
+                                media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
+                                cnt_fit = cnt_fit + media_d * ((free_d >= size) & (free_d > 0)).astype(jnp.float32)
+                            return feasible * (cnt_fit >= (vi + 1)).astype(jnp.float32)
+
+                        feasible = on_claim(size > 0, devs_fit, feasible)
 
             # --- PodTopologySpread
             aff_row = (s_aff[:] if big_u else affm_ref[pl.ds(u, 1), :]) * valid_row
@@ -898,41 +918,53 @@ def _make_kernel(
                 score = score + weighted(cfg.w_simon + cfg.w_gpu_share, share_norm)
             if has_local and cfg.w_local:
                 # Open-Local binpack score (local_score in kernels.py):
-                # mean over units of used/capacity × 10, min-max normalized
+                # mean over units of used/capacity × 10, min-max normalized.
+                # A pod with no claim scores 0 on every node, so its range is
+                # 0 and the term adds nothing
                 lvm = lvm_ref[u]
                 big_f = jnp.float32(1e30)
-                best_free = jnp.full((1, N), big_f, jnp.float32)
-                best_cap = jnp.zeros((1, N), jnp.float32)
-                for v in range(n_vg):
-                    free_v = srows(vg_free_ref, v)
-                    fits_v = free_v >= lvm
-                    better = fits_v & (free_v < best_free)
-                    best_free = jnp.where(better, free_v, best_free)
-                    best_cap = jnp.where(better, vgcap_ref[pl.ds(v, 1), :], best_cap)
-                parts = jnp.where(
-                    (lvm > 0) & (best_free < big_f), div32(lvm, jnp.maximum(best_cap, 1.0)), 0.0
-                )
-                count = jnp.where(lvm > 0, 1.0, 0.0)
-                for m in range(2):
-                    size = dreq_ref[m, u]
-                    need = dneed_ref[m, u]
-                    first_cap = jnp.full((1, N), big_f, jnp.float32)
-                    for d in range(n_dev):
-                        free_d = srows(dev_free_ref, d)
-                        media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
-                        fitting = (media_d > 0) & (free_d >= size) & (free_d > 0)
-                        first_cap = jnp.where(
-                            fitting, jnp.minimum(first_cap, devcap_ref[pl.ds(d, 1), :]), first_cap
-                        )
-                    parts = parts + jnp.where(size > 0, div32(need * size, jnp.maximum(first_cap, 1.0)), 0.0)
-                    count = count + jnp.where(size > 0, need, 0.0)
-                local_raw = jnp.where(count > 0, div32(parts, jnp.maximum(count, 1.0)) * 10.0, 0.0)
-                l_lo = vmin(jnp.where(feas_b, local_raw, big_f))
-                l_hi = vmax(jnp.where(feas_b, local_raw, -big_f))
-                l_rng = l_hi - l_lo
-                score = score + weighted(
-                    cfg.w_local, jnp.where(l_rng > 0, div32((local_raw - l_lo) * MAX_SCORE, l_rng), 0.0)
-                )
+
+                def vg_part(parts):
+                    best_free = jnp.full((1, N), big_f, jnp.float32)
+                    best_cap = jnp.zeros((1, N), jnp.float32)
+                    for v in range(n_vg_real):
+                        free_v = srows(vg_free_ref, v)
+                        fits_v = free_v >= lvm
+                        better = fits_v & (free_v < best_free)
+                        best_free = jnp.where(better, free_v, best_free)
+                        best_cap = jnp.where(better, vgcap_ref[pl.ds(v, 1), :], best_cap)
+                    return parts + jnp.where(best_free < big_f, div32(lvm, jnp.maximum(best_cap, 1.0)), 0.0)
+
+                def local_term(score):
+                    parts = on_claim(lvm > 0, vg_part, jnp.zeros((SB, N), jnp.float32))
+                    count = jnp.where(lvm > 0, 1.0, 0.0)
+                    for m in range(2):
+                        size = dreq_ref[m, u]
+                        need = dneed_ref[m, u]
+
+                        def dev_part(parts):
+                            first_cap = jnp.full((1, N), big_f, jnp.float32)
+                            for d in range(n_dev_real):
+                                free_d = srows(dev_free_ref, d)
+                                media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
+                                fitting = (media_d > 0) & (free_d >= size) & (free_d > 0)
+                                first_cap = jnp.where(
+                                    fitting, jnp.minimum(first_cap, devcap_ref[pl.ds(d, 1), :]), first_cap
+                                )
+                            return parts + div32(need * size, jnp.maximum(first_cap, 1.0))
+
+                        parts = on_claim(size > 0, dev_part, parts)
+                        count = count + jnp.where(size > 0, need, 0.0)
+                    local_raw = jnp.where(count > 0, div32(parts, jnp.maximum(count, 1.0)) * 10.0, 0.0)
+                    l_lo = vmin(jnp.where(feas_b, local_raw, big_f))
+                    l_hi = vmax(jnp.where(feas_b, local_raw, -big_f))
+                    l_rng = l_hi - l_lo
+                    return score + weighted(
+                        cfg.w_local, jnp.where(l_rng > 0, div32((local_raw - l_lo) * MAX_SCORE, l_rng), 0.0)
+                    )
+
+                any_claim = (lvm > 0) | (dreq_ref[0, u] > 0) | (dreq_ref[1, u] > 0)
+                score = on_claim(any_claim, local_term, score)
             if has_avoid and cfg.w_prefer_avoid:
                 # NodePreferAvoidPods (w=10000, no NormalizeScore): raw
                 # 0/100 static table, same shape class as na_raw
@@ -1008,58 +1040,55 @@ def _make_kernel(
                         if SB == 1:
                             gpu_take_ref[d, i] = jnp.sum(take_d * onehot)
                 if has_local:
-                    # LVM: tightest-fitting VG (first among equals)
                     lvm = lvm_ref[u]
-                    big_f = jnp.float32(1e30)
-                    best_free = jnp.full((1, N), big_f, jnp.float32)
-                    for v in range(n_vg):
-                        free_v = srows(vg_free_ref, v)
-                        best_free = jnp.where(free_v >= lvm, jnp.minimum(best_free, free_v), best_free)
-                    taken_vg = jnp.zeros((1, N), jnp.float32)
-                    for v in range(n_vg):
-                        free_v = srows(vg_free_ref, v)
-                        take_v = (
-                            (free_v >= lvm) & (free_v == best_free)
-                        ).astype(jnp.float32) * (1.0 - jnp.minimum(taken_vg, 1.0))
-                        taken_vg = taken_vg + take_v
-                        set_srows(vg_free_ref, v, free_v - jnp.maximum(lvm, 0.0) * take_v * onehot)
+
+                    @pl.when(lvm > 0)
+                    def _():
+                        # LVM: tightest-fitting VG (first among equals)
+                        best_free = jnp.full((1, N), jnp.float32(1e30), jnp.float32)
+                        for v in range(n_vg_real):
+                            free_v = srows(vg_free_ref, v)
+                            best_free = jnp.where(free_v >= lvm, jnp.minimum(best_free, free_v), best_free)
+                        taken_vg = jnp.zeros((1, N), jnp.float32)
+                        for v in range(n_vg_real):
+                            free_v = srows(vg_free_ref, v)
+                            take_v = (
+                                (free_v >= lvm) & (free_v == best_free)
+                            ).astype(jnp.float32) * (1.0 - jnp.minimum(taken_vg, 1.0))
+                            taken_vg = taken_vg + take_v
+                            set_srows(vg_free_ref, v, free_v - lvm * take_v * onehot)
+
                     # exclusive devices: one device per volume, smallest
                     # volume onto the smallest-capacity fitting free device
                     # (common.go:290-349; ties by lowest device index) —
-                    # must mirror the XLA bind exactly
-                    big_cap = jnp.float32(1e30)
-                    taken_rows = [jnp.zeros((1, N), jnp.float32) for _ in range(n_dev)]
+                    # must mirror the XLA bind exactly. A device a smaller
+                    # volume took in this step is free 0 on the chosen node
+                    # after its write, which fits no volume, so it is not
+                    # taken twice there; elsewhere the one-hot writes nothing
                     for m in range(2):
                         for vi in reversed(range(n_dvol)):  # ascending sizes
                             size = dsz_ref[m * n_dvol + vi, u]
-                            best_cap = jnp.full((1, N), big_cap, jnp.float32)
-                            for d in range(n_dev):
-                                free_d = srows(dev_free_ref, d)
-                                media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
-                                cand_d = (
-                                    (media_d > 0) & (free_d >= size) & (free_d > 0)
-                                    & (taken_rows[d] == 0)
-                                )
-                                best_cap = jnp.where(
-                                    cand_d,
-                                    jnp.minimum(best_cap, devcap_ref[pl.ds(d, 1), :]),
-                                    best_cap,
-                                )
-                            assigned = jnp.zeros((1, N), jnp.float32)
-                            for d in range(n_dev):
-                                free_d = srows(dev_free_ref, d)
-                                media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
-                                cand_d = (
-                                    (media_d > 0) & (free_d >= size) & (free_d > 0)
-                                    & (taken_rows[d] == 0)
-                                )
-                                take_d = (
-                                    cand_d & (devcap_ref[pl.ds(d, 1), :] == best_cap)
-                                ).astype(jnp.float32) * (1.0 - jnp.minimum(assigned, 1.0))
-                                take_d = take_d * jnp.where(size > 0, 1.0, 0.0)
-                                assigned = assigned + take_d
-                                taken_rows[d] = jnp.maximum(taken_rows[d], take_d)
-                                set_srows(dev_free_ref, d, free_d * (1.0 - take_d * onehot))
+
+                            @pl.when(size > 0)
+                            def _():
+                                def cand(d):
+                                    free_d = srows(dev_free_ref, d)
+                                    media_d = media_ref[pl.ds(m * n_dev + d, 1), :]
+                                    return free_d, (media_d > 0) & (free_d >= size) & (free_d > 0)
+
+                                best_cap = jnp.full((1, N), jnp.float32(1e30), jnp.float32)
+                                for d in range(n_dev_real):
+                                    best_cap = jnp.where(
+                                        cand(d)[1], jnp.minimum(best_cap, devcap_ref[pl.ds(d, 1), :]), best_cap
+                                    )
+                                assigned = jnp.zeros((1, N), jnp.float32)
+                                for d in range(n_dev_real):
+                                    free_d, cand_d = cand(d)
+                                    take_d = (
+                                        cand_d & (devcap_ref[pl.ds(d, 1), :] == best_cap)
+                                    ).astype(jnp.float32) * (1.0 - jnp.minimum(assigned, 1.0))
+                                    assigned = assigned + take_d
+                                    set_srows(dev_free_ref, d, free_d * (1.0 - take_d * onehot))
                 if has_interpod:
                     a_col = col_of(s_antig) if big_u else _dot(antig_ref[:], onehot_u)
                     add_outer(anti_node_ref, a_col, onehot)
@@ -1111,7 +1140,7 @@ def _make_kernel(
 # what selects the generated kernel; everything else run_fast_scan reads
 # comes from the shapes of its traced arguments
 _STATIC = ("has_interpod", "has_gpu", "has_local", "has_ports", "has_na", "has_tt",
-           "has_avoid", "interpret", "big_u", "gc_row", "sublanes", "config")
+           "has_avoid", "interpret", "big_u", "gc_row", "sublanes", "config", "n_vg_real", "n_dev_real")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -1132,6 +1161,8 @@ def run_fast_scan(
     gc_row: int = -1,
     sublanes: int = 1,
     config=None,
+    n_vg_real: Optional[int] = None,
+    n_dev_real: Optional[int] = None,
 ):
     """Execute the megakernel over S scenarios in ONE dispatch: tmpl_ids is
     [P] (P a multiple of CHUNK, shared), pod_valid/forced are [S, P],
@@ -1157,7 +1188,11 @@ def run_fast_scan(
     that signature is a cache lookup, a transfer of the streams and an
     enqueue. The casts and layout changes below and the normalisation of the
     outputs are part of the same program. `run_fast_scan.__wrapped__` is the
-    plain function (the tests compare the two)."""
+    plain function (the tests compare the two).
+
+    `n_vg_real` / `n_dev_real` are the VG and device rows a node really has
+    (None: every row of `fi.vg0_VN` / `fi.dev0_DN`); the open-local block of
+    the `has_local` variant walks those alone, the tables stay padded."""
     SB = sublanes
     assert SB in (1, 8), SB
     P = tmpl_ids.shape[0]
@@ -1330,6 +1365,7 @@ def run_fast_scan(
         _make_kernel(
             has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid,
             G, Gp, Gd, Vg, Dv, fi.dev_sizes.shape[1] // 2, big_u, K, gc_row, SB, config,
+            n_vg_real, n_dev_real,
         ),
         grid=grid,
         out_shape=tuple(out_shape),

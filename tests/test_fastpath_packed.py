@@ -10,11 +10,15 @@ import os
 import numpy as np
 import pytest
 
+import jax.numpy as jnp
+
 from opensim_tpu.engine import fastpath
+from opensim_tpu.engine.scheduler import pad_pod_stream, schedule_pods
 from opensim_tpu.engine.simulator import AppResource, prepare
 from opensim_tpu.models import ResourceTypes, fixtures as fx
 from opensim_tpu.obs import trace as tracing
 from opensim_tpu.obs.metrics import RECORDER
+from opensim_tpu.ops.pallas_scan import CHUNK
 
 _INTERPRET = os.environ.get("OPENSIM_TEST_BACKEND") != "tpu"
 ZONE = "topology.kubernetes.io/zone"
@@ -93,6 +97,41 @@ def _local():
     return cluster, app
 
 
+def _claims(*claims):
+    return [{"metadata": {"name": f"v{j}"}, "spec": {"storageClassName": cls, "resources": {"requests": {"storage": size}}}}
+            for j, (cls, size) in enumerate(claims)]
+
+
+def _local_claims():
+    """The open-local block's real rows and claim branches: a VG on three
+    nodes and none on the others, at most three devices a node of mixed
+    media (both tables padded to eight rows from neither one nor eight), a
+    node with no storage; pods with no claim, an LVM claim, one ssd device
+    claim and two hdd device claims in turn, and last an ssd claim that only
+    an hdd device is large enough for."""
+    ssd = lambda cap: {"capacity": cap * 1024**3, "mediaType": "ssd"}
+    hdd = lambda cap: {"capacity": cap * 1024**3, "mediaType": "hdd"}
+    shapes = [([100], [ssd(80), hdd(200), hdd(150)])] * 3 + [([], [hdd(200), hdd(120), ssd(40)])] * 2 + [([], [])]
+    cluster = ResourceTypes()
+    for i, (vgs, devs) in enumerate(shapes):
+        storage = fx.with_node_local_storage(
+            vgs=[{"name": "pool0", "capacity": cap * 1024**3} for cap in vgs],
+            devices=[dict(dev, device=f"/dev/vd{'bcd'[j]}") for j, dev in enumerate(devs)])
+        cluster.nodes.append(fx.make_fake_node(f"l{i}", "16", "32Gi", "110", storage))
+    app = ResourceTypes()
+    kinds = (("web", 2, []), ("lvm", 2, [("open-local-lvm", "45Gi")]), ("ssd", 1, [("open-local-device-ssd", "50Gi")]),
+             ("hdd", 1, [("open-local-device-hdd", "100Gi"), ("open-local-device-hdd", "150Gi")]))
+    for k in range(3):
+        for name, n, claims in kinds:
+            sts = fx.make_fake_stateful_set(f"{name}{k}", n, "500m", "1Gi")
+            sts.volume_claim_templates = _claims(*claims)
+            app.stateful_sets.append(sts)
+    sts = fx.make_fake_stateful_set("cross", 1, "500m", "1Gi")
+    sts.volume_claim_templates = _claims(("open-local-device-ssd", "180Gi"))
+    app.stateful_sets.append(sts)
+    return cluster, app
+
+
 def _interpod():
     cluster = ResourceTypes()
     for i in range(10):
@@ -128,11 +167,12 @@ SIGNATURES = {
     "gpu_fraction": lambda: _gpu([("4Gi", "1", 10), ("10Gi", "1", 8), ("20Gi", "1", 4)]),
     "gpu_whole_multi": lambda: _gpu([("32Gi", "1", 5), ("6Gi", "2", 4), ("8Gi", "3", 3), ("32Gi", "2", 2)]),
     "local": _local,
+    "local_claims": _local_claims,
     "interpod": _interpod,
     "big_u": _big_u,  # the template tables in HBM, one DMA a step for the whole block
 }
 CASES = [("plain_spread", S) for S in (2, 8, 9, 15, 17)] + [
-    ("ports", 9), ("gpu_fraction", 15), ("gpu_whole_multi", 9), ("local", 17),
+    ("ports", 9), ("gpu_fraction", 15), ("gpu_whole_multi", 9), ("local", 17), ("local_claims", 9),
     ("interpod", 2), ("interpod", 9), ("big_u", 8), ("big_u", 15),
 ]
 
@@ -167,7 +207,7 @@ def test_a_packed_sweep_answers_as_the_unpacked_one_bit_for_bit(monkeypatch, kin
     prep = _prep(kind)
     assert fastpath.applicable(prep)
     big_u = kind == "big_u"
-    assert prep.features.gpu == kind.startswith("gpu") and prep.features.local == (kind == "local")
+    assert prep.features.gpu == kind.startswith("gpu") and prep.features.local == kind.startswith("local")
     masks = _masks(prep, S)
     monkeypatch.setattr(fastpath, "sweep_sublanes", lambda prep, S: 1)
     one = _sweep(prep, masks, big_u)
@@ -183,6 +223,58 @@ def test_a_packed_sweep_answers_as_the_unpacked_one_bit_for_bit(monkeypatch, kin
         # scenarios differ, and where two place a pod on different nodes the
         # lowest index among equal scores was taken in each sublane alone
         assert len({tuple(row) for row in chosen}) > 1
+
+
+def _kernel_states(prep, masks, sublanes):
+    """Every scenario of `masks` on the kernel, `sublanes` a step: chosen
+    [S, P], used [S, N, R], VG free [S, N, Vg] and device free [S, N, Dv]."""
+    nodes, pods, forced = masks
+    S, P = pods.shape
+    fi, _meta = fastpath.build_inputs(prep)
+    fi, _nv = fastpath._scenario_rows(prep, fi, nodes)
+    pad = (-P) % CHUNK
+    tmpl = np.concatenate([np.asarray(prep.tmpl_ids), np.zeros(pad, np.int32)])
+    stream = lambda m: np.concatenate([m, np.zeros((S, pad), bool)], axis=1)
+    chosen, used, _gt, _gf, vg, dev = fastpath._launch(
+        prep, fi, tmpl, stream(pods), stream(forced), _INTERPRET, False, sublanes)
+    Vg, Dv = prep.st0.vg_free.shape[1], prep.st0.dev_free.shape[1]
+    return (np.asarray(chosen)[:, :P], np.asarray(used).transpose(0, 2, 1),
+            np.asarray(vg)[:, :Vg].transpose(0, 2, 1), np.asarray(dev)[:, :Dv].transpose(0, 2, 1))
+
+
+def _xla_states(prep, masks):
+    """The same scenarios on the XLA scan, one at a time."""
+    nodes, pods, forced = masks
+    P = pods.shape[1]
+    outs = []
+    for nv, pv, fm in zip(nodes, pods, forced):
+        out = schedule_pods(prep.ec._replace(node_valid=jnp.asarray(nv)), prep.st0,
+                            *pad_pod_stream(np.asarray(prep.tmpl_ids), pv, fm), features=prep.features)
+        st = out.final_state
+        outs.append((np.asarray(out.chosen)[:P], np.asarray(st.used), np.asarray(st.vg_free), np.asarray(st.dev_free)))
+    return [np.stack(col) for col in zip(*outs)]
+
+
+@pytest.mark.parametrize("sublanes", [1, 8])
+@pytest.mark.parametrize("kind", ["local", "local_claims"])
+def test_the_local_block_answers_as_the_xla_scan_bit_for_bit(kind, sublanes):
+    """The kernel's open-local block walks a node's real VG and device rows
+    only, and runs each part only for a claim the pod has: placements,
+    usage, unscheduled pods and the VG and device state are the XLA scan's,
+    bit for bit, one scenario a step and eight."""
+    prep = _prep(kind)
+    nodes, pods, forced = masks = _masks(prep, 8)
+    nodes[0] = np.asarray(prep.ec_np.node_valid)  # and the whole cluster
+    kernel, xla = _kernel_states(prep, masks, sublanes), _xla_states(prep, masks)
+    for name, a, b in zip(("chosen", "used", "vg_free", "dev_free"), kernel, xla):
+        assert a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    unscheduled = lambda chosen: ((chosen < 0) & pods).sum(axis=1)
+    np.testing.assert_array_equal(unscheduled(kernel[0]), unscheduled(xla[0]))
+    assert (kernel[0] >= 0).any() and (kernel[3] != np.asarray(prep.st0.dev_free)).any()
+    if kind == "local_claims":
+        # the ssd claim only an hdd device could hold stays unplaced
+        assert (kernel[0][:, -1] == -1).all()
 
 
 def _launch_attrs(fn):
@@ -211,6 +303,29 @@ def test_a_sweep_packs_eight_a_block_and_counts_its_blocks():
         ]
     finally:
         RECORDER.reset()
+
+
+def test_a_local_launch_says_its_real_rows_and_its_claim_steps():
+    """`mk.launch` of the open-local variant carries the VG and device rows
+    its loops walk and the steps whose template has a claim; a launch of
+    another variant carries none of the three."""
+    prep = _prep("local_claims")
+    tr = tracing.start_trace("test", force=True)
+    with tracing.trace_scope(tr):
+        _sweep(prep, _masks(prep, 9))
+    tr.finish()
+    (launch,) = [sp for sp in tr.walk() if sp.name == "mk.launch"]
+    ec = prep.ec_np
+    claims = (np.asarray(ec.lvm_req) > 0) | (np.asarray(ec.dev_req) > 0).any(axis=1)
+    assert not claims[0]  # the stream's padding repeats template 0, which has none
+    assert int(claims[np.asarray(prep.tmpl_ids)].sum()) == 13  # six LVM, three ssd, three two-hdd, one cross
+    assert (launch.attrs["local_vgs"], launch.attrs["local_devices"], launch.attrs["claim_steps"]) == (1, 3, 13)
+    tr = tracing.start_trace("test", force=True)
+    with tracing.trace_scope(tr):
+        _sweep(_prep("plain_spread"), _masks(_prep("plain_spread"), 2))
+    tr.finish()
+    (launch,) = [sp for sp in tr.walk() if sp.name == "mk.launch"]
+    assert not {"local_vgs", "local_devices", "claim_steps"} & set(launch.attrs)
 
 
 def test_a_schedule_is_one_block_of_one_scenario():

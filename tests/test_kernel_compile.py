@@ -26,11 +26,13 @@ _OFF = dict(has_interpod=False, has_gpu=False, has_local=False, has_ports=False,
 # plan-gpushare's openb-gpushare-1523 (1,664 nodes, 866 templates in big-U
 # mode, eight devices a node) and plan-local's k8s-5k-50k-openlocal (plan-short's
 # widths with the open-local rows: one VG and four devices a node, padded to
-# eight, and two claims of a media a template)
+# eight, which the kernel's loops walk alone, and two claims of a media a
+# template)
 SHAPES = {
     "plan-short": (dict(N=4736, R=4, U=20, A=24, Cs=2), dict(_OFF), 50),
     "plan-gpushare": (dict(N=1664, R=6, U=866, A=8, Cs=1), dict(_OFF, has_gpu=True, big_u=True), 9),
-    "plan-local": (dict(N=4736, R=4, U=20, A=24, Cs=2, Mv=2), dict(_OFF, has_local=True), 50),
+    "plan-local": (dict(N=4736, R=4, U=20, A=24, Cs=2, Mv=2),
+                   dict(_OFF, has_local=True, n_vg_real=1, n_dev_real=4), 50),
 }
 
 

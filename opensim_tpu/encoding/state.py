@@ -388,16 +388,21 @@ class ClusterEncoder:
         self._encode_node_rows(arrays, 0, K, Tt)
 
         # topology domains, raw ids (-1 = label absent); the trash-row
-        # substitution happens at assemble time once D is final
+        # substitution happens at assemble time once D is final. Numbered key
+        # by key, each key's nodes in node order, so a key with a value a node
+        # (the hostname) holds node n at its first id + n and the XLA scan
+        # reads its counts as a slice (kernels.count_keys_of)
         n_topo = vb.n_topo_keys
         domain_ids: Dict[Tuple[int, int], int] = {}
         node_domain = np.full((N, n_topo), -1, dtype=np.int32)
         label_val = arrays["label_val"]
         topo_key_to_label = [vb.label_keys.get(k) for k in vb.topo_keys.items()]
-        for i in range(len(self.nodes)):
-            for tki in range(n_topo):
-                lk = topo_key_to_label[tki]
-                vid = label_val[i, lk] if lk >= 0 else -1
+        for tki in range(n_topo):
+            lk = topo_key_to_label[tki]
+            if lk < 0:
+                continue
+            for i in range(len(self.nodes)):
+                vid = label_val[i, lk]
                 if vid >= 0:
                     node_domain[i, tki] = domain_ids.setdefault(
                         (tki, int(vid)), len(domain_ids)

@@ -606,7 +606,7 @@ def _run_engine_ladder(
     # and on /metrics: the active feature flags and the rows of the
     # inter-pod term tables the stream's pods are counted under; and
     # whether the stream runs over a masked subset of the prepared nodes
-    features = "+".join(n for n, on in zip(prep.features._fields, prep.features) if on) or "none"
+    features = "+".join(n for n, on in zip(prep.features._fields, prep.features) if on and n != "count_keys") or "none"
     shape = {
         "features": features, "interpod_terms": prep.meta.interpod_terms,
         "masked": nv_mask is not None, "profile": profile_of(sched_config),

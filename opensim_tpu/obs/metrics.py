@@ -212,6 +212,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "open-local claims of the placed pods by the engine that answered and by kind: an LVM volume, a whole ssd or hdd device",
         "counter",
     ),
+    # path: slice | select | gather (ops/kernels.py count_reads)
+    "simon_count_read_keys_total": (
+        "Topology keys of the XLA scans' selector-count reads by how a step reads a key's counts: a slice of a key whose "
+        "domains are its nodes in order, a compare-select over a key's few domains, or a per-node gather", "counter",
+    ),
     # loader: c | python (models/expand.py: libyaml where PyYAML has it)
     "simon_yaml_documents_total": (
         "YAML documents read from files and rendered charts by the parser that read them", "counter",
@@ -621,6 +626,9 @@ class MetricsRecorder:
         self.gpushare_pods = make_counter("simon_gpushare_pods_total", ("engine", "kind"))
         # open-local claims of the placed pods, by answering engine and kind
         self.local_volumes = make_counter("simon_local_volumes_total", ("engine", "kind"))
+        # topology keys of every XLA scan by the path its count reads take;
+        # gather in a cluster with hostname labels means the slice did not engage
+        self.count_read_keys = make_counter("simon_count_read_keys_total", ("path",))
         # documents by the YAML parser that read them (models/expand.py);
         # "python" on a host whose PyYAML has libyaml means the fast parser is not engaged
         self.yaml_documents = make_counter("simon_yaml_documents_total", ("loader",))
@@ -725,6 +733,11 @@ class MetricsRecorder:
                 if n:
                     self.local_volumes.inc((engine, kind), int(n))
 
+    def count_read_keys_by_path(self, by_path: Dict[str, int]) -> None:
+        with self.lock:
+            for path, n in by_path.items():
+                self.count_read_keys.inc((path,), int(n))
+
     def count_yaml_documents(self, loader: str, n: int) -> None:
         with self.lock:
             self.yaml_documents.inc((loader,), n)
@@ -743,6 +756,7 @@ class MetricsRecorder:
                 + self.engine_profile.render_lines()
                 + self.gpushare_pods.render_lines()
                 + self.local_volumes.render_lines()
+                + self.count_read_keys.render_lines()
                 + self.yaml_documents.render_lines()
                 + self.phase_seconds.render_lines()
                 + self.request_seconds.render_lines()
@@ -764,6 +778,7 @@ class MetricsRecorder:
             self.engine_profile.reset()
             self.gpushare_pods.reset()
             self.local_volumes.reset()
+            self.count_read_keys.reset()
             self.yaml_documents.reset()
             self.watch_apply.reset()
 

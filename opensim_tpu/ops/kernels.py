@@ -250,7 +250,7 @@ def fit_filter(ec, st, u, alloc=None, ignored_cols: tuple = ()):
     return ~jnp.any(insufficient, axis=-1), insufficient
 
 
-def spread_filter(ec, st, u, node_aff_mask):
+def spread_filter(ec, st, u, node_aff_mask, keys=None):
     """PodTopologySpread DoNotSchedule constraints
     (podtopologyspread/filtering.go:276): for each hard constraint,
     matchCount(domain) + selfMatch - minMatch(eligible domains) <= maxSkew."""
@@ -262,7 +262,7 @@ def spread_filter(ec, st, u, node_aff_mask):
 
     dom = ec.node_domain[:, jnp.maximum(topo, 0)]  # [N, Cs]
     has_label = dom < ec.domain_topo.shape[0] - 1  # trash row = missing label
-    cnt = domain_counts(st.dom_sel, dom, sel)  # [N, Cs]
+    cnt = domain_counts(st.dom_sel, dom, sel, jnp.maximum(topo, 0), keys)  # [N, Cs]
     self_match = ec.matches_sel[u, sel]  # [Cs]
 
     # min matchNum over eligible domains: nodes passing node affinity with the
@@ -275,7 +275,7 @@ def spread_filter(ec, st, u, node_aff_mask):
     return jnp.all(ok | ~active[None, :], axis=-1)
 
 
-def interpod_filter(ec, st, u):
+def interpod_filter(ec, st, u, keys=None):
     """InterPodAffinity filter (interpodaffinity/filtering.go:378):
     1) incoming pod's required anti-affinity: no existing pod in the
        candidate's topology domain may match;
@@ -289,7 +289,7 @@ def interpod_filter(ec, st, u):
     an_topo = ec.an_topo[u]
     an_active = an_sel >= 0
     dom = ec.node_domain[:, an_topo]  # [N, Tn]
-    anti_cnt = domain_counts(st.dom_sel, dom, jnp.maximum(an_sel, 0))  # [N, Tn]
+    anti_cnt = domain_counts(st.dom_sel, dom, jnp.maximum(an_sel, 0), an_topo, keys)  # [N, Tn]
     # k8s: a node missing the topology label forms no topology pair, so the
     # anti-affinity term is vacuously satisfied there.
     has_label = dom < D_trash
@@ -316,7 +316,7 @@ def interpod_filter(ec, st, u):
     at_topo = ec.at_topo[u]
     at_active = at_sel >= 0
     dom_a = ec.node_domain[:, at_topo]  # [N, Ti]
-    aff_cnt = domain_counts(st.dom_sel, dom_a, jnp.maximum(at_sel, 0))  # [N, Ti]
+    aff_cnt = domain_counts(st.dom_sel, dom_a, jnp.maximum(at_sel, 0), at_topo, keys)  # [N, Ti]
     has_label_a = dom_a < D_trash
     dom_is_key = ec.domain_topo[None, :] == at_topo[:, None]  # [Ti, D+1]
     total = jnp.sum(jnp.where(dom_is_key, selector_columns(st.dom_sel, jnp.maximum(at_sel, 0)).T, 0.0), axis=-1)
@@ -505,7 +505,7 @@ def taint_toleration_raw(ec, u):
     return jnp.sum((t_eff == V.EFFECT_PREFER_NO_SCHEDULE) & ~tolerated, axis=-1).astype(jnp.float32)
 
 
-def interpod_score(ec, st, u, feasible):
+def interpod_score(ec, st, u, feasible, keys=None):
     """InterPodAffinity score (interpodaffinity/scoring.go): incoming
     preferred terms against existing pods + existing pods' symmetric
     preferred/hard-affinity terms against the incoming pod, min-max
@@ -518,7 +518,7 @@ def interpod_score(ec, st, u, feasible):
     pt_w = ec.pt_w[u]
     dom = ec.node_domain[:, pt_topo]  # [N, Tpp]
     has_label = dom < D_trash
-    cnt = domain_counts(st.dom_sel, dom, jnp.maximum(pt_sel, 0))
+    cnt = domain_counts(st.dom_sel, dom, jnp.maximum(pt_sel, 0), pt_topo, keys)
     incoming = jnp.sum(
         jnp.where((pt_sel[None, :] >= 0) & has_label, cnt * pt_w[None, :], 0.0), axis=-1
     )
@@ -540,7 +540,7 @@ def interpod_score(ec, st, u, feasible):
     return jnp.where(rng > 0, div32(MAX_NODE_SCORE * (raw - lo), jnp.maximum(rng, 1.0)), 0.0)
 
 
-def spread_score(ec, stat: StaticTables, st, u, feasible):
+def spread_score(ec, stat: StaticTables, st, u, feasible, keys=None):
     """PodTopologySpread score (podtopologyspread/scoring.go:175-248):
     ScheduleAnyway constraints; score_n = Σ_c cnt*log-weight + (maxSkew-1),
     inverted-normalized so spreading wins. The log(size+2) normalizing
@@ -554,7 +554,7 @@ def spread_score(ec, stat: StaticTables, st, u, feasible):
     D_trash = ec.domain_topo.shape[0] - 1
     dom = ec.node_domain[:, jnp.maximum(topo, 0)]  # [N, Cs]
     has_label = dom < D_trash
-    cnt = domain_counts(st.dom_sel, dom, sel)  # [N, Cs]
+    cnt = domain_counts(st.dom_sel, dom, sel, jnp.maximum(topo, 0), keys)  # [N, Cs]
 
     ignored = feasible & ~jnp.all(has_label | ~soft[None, :], axis=-1)  # [N]
     scored = feasible & ~ignored
@@ -995,6 +995,9 @@ class Features(NamedTuple):
     # while gpushare devices exist: the allocatable column follows the device
     # state (Reserve rewrite) instead of the static table
     gc_dyn: bool = False
+    # how the XLA scan reads each topology key's selector counts (not a flag:
+    # the encoding's domain layout, count_keys_of); None reads by gather
+    count_keys: "CountKeys | None" = None
 
     @property
     def sel_counts(self) -> bool:
@@ -1034,6 +1037,7 @@ def features_of(ec_np) -> Features:
             and np.asarray(ec_np.gc_mask).any()
             and (np.asarray(ec_np.req)[:, np.asarray(ec_np.gc_mask)] > 0).any()
         ),
+        count_keys=count_keys_of(ec_np),
     )
 
 
@@ -1084,9 +1088,9 @@ def score_parts(
             MAX_NODE_SCORE,
         )
     if (feat.prefg or feat.interpod) and cfg.w_interpod:
-        parts["InterPodAffinity"] = cfg.w_interpod * interpod_score(ec, st, u, feasible)
+        parts["InterPodAffinity"] = cfg.w_interpod * interpod_score(ec, st, u, feasible, feat.count_keys)
     if feat.spread_soft and cfg.w_spread:
-        parts["PodTopologySpread"] = cfg.w_spread * spread_score(ec, stat, st, u, feasible)
+        parts["PodTopologySpread"] = cfg.w_spread * spread_score(ec, stat, st, u, feasible, feat.count_keys)
     if cfg.w_simon + cfg.w_gpu_share:
         # Simon + Open-Gpu-Share share the same formula and normalization
         share_row = stat.share_raw[u]
@@ -1144,11 +1148,11 @@ def pod_step(  # opensim-lint: jit-region
         fit_mask, insufficient = true_mask, jnp.zeros_like(ec.alloc, dtype=bool)
     masks.append(fit_mask)
     masks.append(
-        spread_filter(ec, st, u, aff_mask & valid)
+        spread_filter(ec, st, u, aff_mask & valid, feat.count_keys)
         if feat.spread_hard and cfg.f_spread
         else true_mask
     )
-    masks.append(interpod_filter(ec, st, u) if feat.interpod and cfg.f_interpod else true_mask)
+    masks.append(interpod_filter(ec, st, u, feat.count_keys) if feat.interpod and cfg.f_interpod else true_mask)
     masks.append(gpu_filter(ec, st, u) if feat.gpu and cfg.f_gpu else true_mask)
     masks.append(local_filter(ec, st, u) if feat.local and cfg.f_local else true_mask)
     extra_filter = true_mask
@@ -1354,54 +1358,171 @@ def bind_update(ec: EncodedCluster, st: ScanState, u, node, apply,
 #: columns of the selector-count carry in one window: one lane tile
 COUNT_WINDOW = 128
 
+#: domains a topology key may have, beside the trash row, for a step to read
+#: its counts by compare-select (``CountKeys``)
+SELECT_DOMAINS = 8
+
+
+def _count_column(counts, col):
+    """``counts[:, col]``, the [D+1] counts of one selector ``col`` (a traced
+    scalar, at least 0) of the per-domain selector counts ``counts`` [D+1, A]:
+    a select over the lanes of the window of ``COUNT_WINDOW`` columns that
+    holds it (past one window a dynamic slice of whole rows) and a sum of one
+    count and zeros, so the same float32 count."""
+    A, lanes = counts.shape[1], COUNT_WINDOW
+    if A > lanes:
+        start = jnp.clip(col - col % lanes, 0, A - lanes)
+        counts, col = jax.lax.dynamic_slice_in_dim(counts, start, lanes, axis=1), col - start
+    return jnp.sum(jnp.where(jnp.arange(counts.shape[1]) == col, counts, 0.0), axis=1)
+
 
 def selector_columns(counts, cols):
     """``counts[:, cols]``: the [D+1, C] slab of the per-domain selector
     counts ``counts`` [D+1, A] under the terms' selectors ``cols`` [C] (each
-    at least 0). Past one window, each column is taken from the window of
-    ``COUNT_WINDOW`` columns that holds it: a dynamic slice of whole rows,
-    then a select over the window's lanes and a sum of one count and zeros,
-    so the same float32 counts. A gather of the columns makes XLA lay the
-    carry out by columns, and the bind's row update then walks every tile of
-    a row (35 us more a step at [5,002, 5,300] on a v5e); a window keeps it
-    by rows. A carry of one window is read as it is."""
-    A, lanes = counts.shape[1], COUNT_WINDOW
-    if A <= lanes:
+    at least 0). Past one window, each column is :func:`_count_column`'s. A
+    gather of the columns makes XLA lay the carry out by columns, and the
+    bind's row update then walks every tile of a row (35 us more a step at
+    [5,002, 5,300] on a v5e); a window keeps it by rows. A carry of one window
+    is read as it is."""
+    if counts.shape[1] <= COUNT_WINDOW:
         return counts[:, cols]
-    start = jnp.clip(cols - cols % lanes, 0, A - lanes)  # [C]
-    hit = jnp.arange(lanes)[None, :] == (cols - start)[:, None]  # [C, lanes]
-    return jnp.stack([
-        jnp.sum(jnp.where(hit[c], jax.lax.dynamic_slice_in_dim(counts, start[c], lanes, axis=1), 0.0), axis=1)
-        for c in range(cols.shape[0])
-    ], axis=1)
+    return jnp.stack([_count_column(counts, cols[c]) for c in range(cols.shape[0])], axis=1)
 
 
-def domain_counts(counts, dom, cols):
+class CountKeys(NamedTuple):
+    """How an XLA step reads each topology key's counts, from the domain
+    numbering of an encoding (:func:`count_keys_of`); a trace-time constant of
+    the scan, in :class:`Features`. Key k is *node-ordered* where ``base[k]``
+    is at least 0: node n below ``nodes`` is in domain ``base[k] + n`` and
+    every later node in the trash domain, so a node's counts are a slice of
+    the column. Else it is *small*: ``ids[k]``, at most ``SELECT_DOMAINS``
+    domains, holds every node that is not in the trash domain, so a node's
+    counts are a select over those domains' counts."""
+
+    nodes: int
+    base: tuple  # [Tk] i32, -1 for a small key
+    ids: tuple  # [Tk] tuples of domain ids, None for a node-ordered key
+
+    def paths(self) -> dict:
+        """Keys by the read each takes: ``slice`` and ``select``."""
+        sliced = sum(b >= 0 for b in self.base)
+        return {"slice": sliced, "select": len(self.base) - sliced}
+
+
+def count_keys_of(ec_np) -> "CountKeys | None":
+    """The :class:`CountKeys` of a (host-side numpy) encoded cluster; None
+    where a key is neither node-ordered nor small (a rack label of hundreds of
+    values, a node without a hostname label, two nodes with one hostname),
+    and every key is then read by gather (:func:`domain_counts`)."""
+    import numpy as np
+
+    node_domain = np.asarray(ec_np.node_domain)
+    trash = int(np.asarray(ec_np.domain_topo).shape[0]) - 1
+    nodes = int(np.asarray(ec_np.node_valid).sum())
+    base, ids = [], []
+    for col in node_domain.T:
+        first = int(col[0]) if nodes else trash
+        if (
+            first + nodes <= trash
+            and (col[:nodes] == first + np.arange(nodes)).all()
+            and (col[nodes:] == trash).all()
+        ):
+            base.append(first)
+            ids.append(None)
+            continue
+        domains = np.unique(col[col != trash])
+        if len(domains) > SELECT_DOMAINS:
+            return None
+        base.append(-1)
+        ids.append(tuple(int(d) for d in domains))
+    return CountKeys(nodes=nodes, base=tuple(base), ids=tuple(ids))
+
+
+def _keyed_counts(counts, dom, cols, topo, keys: CountKeys):
+    """:func:`domain_counts` with no per-node read of the counts: each term's
+    [D+1] column (:func:`_count_column`), then for every node a slice of it
+    under a node-ordered key, or under a small key a select over the key's
+    domains, whose counts are read one by one. A term's key ``topo`` [C] is
+    traced, so where the encoding has keys of both kinds both reads are made
+    and a select keeps its key's. Nodes in the trash domain, pad nodes among
+    them, read its count, as the gather does."""
+    import numpy as np
+
+    trash, n_nodes = counts.shape[0] - 1, dom.shape[0]
+    sliced = np.array([b >= 0 for b in keys.base])
+    width = max([len(d) for d in keys.ids if d is not None], default=0)
+    table = np.full((len(keys.ids), width), trash, np.int32)
+    for k, d in enumerate(keys.ids):
+        if d:
+            table[k, : len(d)] = d
+    base = jnp.asarray(np.maximum(np.array(keys.base, np.int32), 0))[topo]  # [C]
+    key_sliced = jnp.asarray(sliced)[topo]  # [C]
+    ids = jnp.asarray(table)[topo]  # [C, W]
+    out = []
+    for c in range(cols.shape[0]):
+        col = _count_column(counts, cols[c])  # [D+1]
+        fill = jnp.broadcast_to(col[trash], (n_nodes,))
+        read = fill
+        for j in range(width):
+            count = jax.lax.dynamic_index_in_dim(col, ids[c, j], keepdims=False)
+            read = jnp.where(dom[:, c] == ids[c, j], count, read)
+        if sliced.any():
+            ordered = jnp.concatenate(
+                [jax.lax.dynamic_slice_in_dim(col, base[c], keys.nodes), fill[keys.nodes:]]
+            )
+            read = jnp.where(key_sliced[c], ordered, read) if not sliced.all() else ordered
+        out.append(read)
+    return jnp.stack(out, axis=1)
+
+
+def domain_counts(counts, dom, cols, topo=None, keys=None):
     """``counts[dom, cols[None, :]]``: each node's count in its own domain
-    under each term's topology key (``dom`` [N, C] the nodes' domains).
-    Past one window, gathered node by node within :func:`selector_columns`'
-    [D+1, C] slab: a point gather of ``counts`` itself reads N·C cells
-    scattered over the whole carry at every step, [5,002, 5,300] (106 MB)
-    for 5,300 spread selectors, 188 us of a 239 us step on a v5e. A carry of
-    one window is gathered from directly: a slab would hold all of it, and
-    on a v5e the served what-if's scan (A = 1) took 45 % more device time
-    through one."""
+    under each term's topology key (``dom`` [N, C] the nodes' domains, ``topo``
+    [C] the terms' keys). Where the encoding's :class:`CountKeys` ``keys``
+    says every key is node-ordered or small, read by slice and select
+    (:func:`_keyed_counts`): the per-node gather below costs by the element
+    on a v5e, 87.6 us of a 159 us step at plan-cl2's 5,120 nodes and two
+    terms. Else, past one window, gathered node by node within
+    :func:`selector_columns`' [D+1, C] slab: a point gather of ``counts``
+    itself reads N·C cells scattered over the whole carry at every step,
+    [5,002, 5,300] (106 MB) for 5,300 spread selectors, 188 us of a 239 us
+    step on a v5e. A carry of one window is gathered from directly: a slab
+    would hold all of it, and on a v5e the served what-if's scan (A = 1) took
+    45 % more device time through one."""
+    if keys is not None:
+        return _keyed_counts(counts, dom, cols, topo, keys)
     if counts.shape[1] <= COUNT_WINDOW:
         return counts[dom, cols[None, :]]
     return jnp.take_along_axis(selector_columns(counts, cols), dom, axis=0, mode="promise_in_bounds")
 
 
 def count_reads(ec, feat: Features) -> dict:
-    """How wide an XLA step's reads of the selector-count carry are, for the
-    spans of the rungs that run the XLA scan: ``count_columns``, the columns
-    of ``dom_sel`` one step reads through :func:`selector_columns` for the
+    """How an XLA step reads the selector-count carry, for the spans of the
+    rungs that run the XLA scan: ``count_columns``, the columns of
+    ``dom_sel`` one step reads through :func:`selector_columns` for the
     features that are on (the spread constraints' Cs, one slab for filter and
     score; the inter-pod anti and affinity terms' Tn + Ti; the incoming
-    preferred terms' Tpp), and ``count_table_bytes``, the carry's (D+1)·A·4."""
+    preferred terms' Tpp); ``count_table_bytes``, the carry's (D+1)·A·4; and
+    ``count_keys_sliced``, ``count_keys_selected`` and
+    ``count_keys_gathered``, the topology keys a step reads by each path of
+    :func:`domain_counts` (none where no column is read). Called once a scan
+    as its span opens, it adds the keys to ``simon_count_read_keys_total``."""
+    from ..obs.metrics import RECORDER
+
     spread = ec.spr_topo.shape[1] if feat.spread_hard or feat.spread_soft else 0
     required = ec.an_sel.shape[1] + ec.at_sel.shape[1] if feat.interpod else 0
     preferred = ec.pt_sel.shape[1] if feat.interpod or feat.prefg else 0
+    columns = int(spread + required + preferred)
+    paths = {"slice": 0, "select": 0, "gather": 0}
+    if columns and feat.count_keys is not None:
+        paths.update(feat.count_keys.paths())
+    elif columns:
+        paths["gather"] = int(ec.node_domain.shape[1])
+    RECORDER.count_read_keys_by_path({k: n for k, n in paths.items() if n})
     return {
-        "count_columns": int(spread + required + preferred),
+        "count_columns": columns,
         "count_table_bytes": int(ec.domain_topo.shape[0] * ec.matches_sel.shape[1] * 4),
+        "count_keys_sliced": paths["slice"],
+        "count_keys_selected": paths["select"],
+        "count_keys_gathered": paths["gather"],
     }

@@ -274,6 +274,20 @@ def test_the_spans_of_the_expansion_and_of_the_match_matrix(tmp_path, monkeypatc
     # count table of 24 hostname domains, one zone and the trash row by 64 selectors
     (rung,) = find(tr, "engine.xla")
     assert (rung.attrs["count_columns"], rung.attrs["count_table_bytes"]) == (2, (24 + 1 + 1) * 64 * 4)
+    # hostname's counts are read as a slice, the one zone's by compare-select, nothing by gather
+    reads = tuple(rung.attrs[f"count_keys_{path}"] for path in ("sliced", "selected", "gathered"))
+    assert reads == (1, 1, 0)
+
+
+def test_the_count_read_paths_are_counted_scan_by_scan(tmp_path, monkeypatch):
+    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
+    RECORDER.reset()
+    traced_plan(drive(tmp_path, TINY, 7))
+    lines = RECORDER.render_lines()
+    assert 'simon_count_read_keys_total{path="slice"} 1' in lines
+    assert 'simon_count_read_keys_total{path="select"} 1' in lines
+    assert not [line for line in lines if line.startswith('simon_count_read_keys_total{path="gather"}')]
+    RECORDER.reset()
 
 
 def test_a_run_the_kernel_declines_says_so_on_the_rung_that_runs(tmp_path, monkeypatch):

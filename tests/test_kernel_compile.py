@@ -128,8 +128,9 @@ def test_the_xla_scan_reads_plan_cl2s_count_carry_by_row_windows_on_the_chip(one
     passes no gather reads the [D+1, A] count carry (the point gather of
     10,240 cells a step that `kernels.domain_counts` replaced, or a column
     gather, for which XLA lays the carry out by columns): it is read by
-    windows of 128 whole rows, keeps its row-major layout everywhere, and the
-    per-node gather reads the [D+1, C] slab."""
+    windows of 128 whole rows and keeps its row-major layout everywhere; nor
+    does any gather read a column of it node by node: hostname's counts are a
+    slice, the zone's a compare-select."""
     import functools
     import importlib
     import json
@@ -166,5 +167,5 @@ def test_the_xla_scan_reads_plan_cl2s_count_carry_by_row_windows_on_the_chip(one
     assert not [g for g in gathers(hlo) if g[0] == "f32[5002,5300]"]
     assert re.search(r"dynamic-slice\(.*dynamic_slice_sizes=\{5002,128\}", hlo)
     assert set(re.findall(r"f32\[5002,5300\]\{([\d,]+)", hlo)) == {"1,0"}
-    slab = f"f32[5002,{ec.spr_topo.shape[1]}]"
-    assert any(operand == slab and sizes == (1, 1) for operand, sizes in gathers(hlo))
+    assert prep.features.count_keys.paths() == {"slice": 1, "select": 1}
+    assert not [g for g in gathers(hlo) if str(g[0]).startswith("f32[5002")], gathers(hlo)

@@ -75,36 +75,6 @@ def _fmt(n: int) -> str:
     return f"{n // 1000}k" if n >= 1000 and n % 1000 == 0 else str(n)
 
 
-def _serial_floors(config: str, pods: int, nodes: int):
-    """Measured serial baselines (tools/serial_baseline.py) for the same
-    workload at the same shape, if recorded. Returns (python_rec, cxx_rec),
-    either None. The python-serial floor UNDERSTATES the Go reference's
-    speed; the c++-serial row (native/serial_engine.cc) is the measured
-    stand-in for the Go constant factor. bench's `plan` config and the
-    baseline tool's `synthetic` use the same generators, so either key
-    matches by shape."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "BASELINE_MEASURED.json")
-    try:
-        with open(path) as f:
-            measured = json.load(f)
-    except (OSError, ValueError):
-        return None, None
-    cfgs = {"plan": ("plan", "synthetic")}.get(config, (config,))
-
-    def find(cxx):
-        for rec in measured.values():
-            if not isinstance(rec, dict) or rec.get("config") not in cfgs:
-                continue
-            # classify by the record's own impl field, not key naming
-            if str(rec.get("impl", "")).startswith("c++") != cxx:
-                continue
-            if rec.get("pods") == pods and rec.get("nodes") == nodes:
-                return rec
-        return None
-
-    return find(False), find(True)
-
-
 def synthetic_cluster(n_nodes: int) -> ResourceTypes:
     rt = ResourceTypes()
     zones = [f"zone-{z}" for z in range(4)]
@@ -285,49 +255,6 @@ def local_pv_apps(n_pods: int) -> ResourceTypes:
     return rt
 
 
-def _verify_envelope(cluster, apps) -> dict:
-    """ISSUE 19 in-row bit-equality gates (gpu / local-pv configs): one
-    shared Prepared encoding driven through the incremental C++ path, the
-    forced-generic C++ path, and the XLA scan — placements, failure
-    attribution, and final state must agree element-for-element."""
-    from opensim_tpu.engine import nativepath
-    from opensim_tpu.engine.scheduler import pad_pod_stream, schedule_pods
-    from opensim_tpu.engine.simulator import prepare
-
-    prep = prepare(cluster, apps, node_pad=128)
-    P = len(prep.ordered)
-    pv = np.ones(P, bool)
-    inc = nativepath.schedule(prep, pv)
-    prior = os.environ.get("OPENSIM_NATIVE_FORCE_GENERIC")
-    os.environ["OPENSIM_NATIVE_FORCE_GENERIC"] = "1"
-    try:
-        gen = nativepath.schedule(prep, pv)
-    finally:
-        if prior is None:
-            del os.environ["OPENSIM_NATIVE_FORCE_GENERIC"]
-        else:
-            os.environ["OPENSIM_NATIVE_FORCE_GENERIC"] = prior
-    t, v, f = pad_pod_stream(prep.tmpl_ids, pv, prep.forced)
-    xout = schedule_pods(prep.ec, prep.st0, t, v, f, features=prep.features)
-    inc_stats = inc.native_stats or {}
-    gen_stats = gen.native_stats or {}
-    return {
-        "verify_native_path": inc_stats.get("path"),
-        "verify_classes": (inc_stats.get("steps") or {}).get("classes") or {},
-        "placements_identical_generic": int(
-            gen_stats.get("path") == "generic"
-            and np.array_equal(inc.chosen, gen.chosen)
-            and np.array_equal(inc.fail_counts, gen.fail_counts)
-            and np.array_equal(inc.final_state.used, gen.final_state.used)
-        ),
-        "placements_identical_xla": int(
-            np.array_equal(np.asarray(xout.chosen)[:P], inc.chosen)
-            and np.array_equal(np.asarray(xout.fail_counts)[:P], inc.fail_counts)
-            and np.array_equal(np.asarray(xout.final_state.used), inc.final_state.used)
-        ),
-    }
-
-
 def bench_defrag(n_scenarios: int, n_nodes: int, n_pods: int, warmup: bool) -> int:
     """BASELINE.md config 5: parallel what-if node-drain scenarios.
     Metric: scenarios/sec/chip."""
@@ -349,11 +276,6 @@ def bench_defrag(n_scenarios: int, n_nodes: int, n_pods: int, warmup: bool) -> i
         "drainable": len(result.drainable()),
         "wall_s": round(dt, 2),
     }
-    serial, cxx = _serial_floors("defrag", n_pods, n_nodes)
-    if serial and serial.get("scenarios_per_sec"):
-        record["vs_serial"] = round(record["value"] / serial["scenarios_per_sec"], 1)
-    if cxx and cxx.get("scenarios_per_sec"):
-        record["vs_serial_cxx"] = round(record["value"] / cxx["scenarios_per_sec"], 1)
     _emit(record)
     return 0
 
@@ -893,8 +815,7 @@ def main() -> int:
             "plan = capacity-plan wall-clock (headline); defrag = drain-scenario "
             "sweep; affinity = interpod+spread heavy; gpu = all-GPU-share + "
             "whole-GPU (gc_dyn) envelope row; local-pv = all-open-local "
-            "LVM/device envelope row (both carry in-row bit-equality gates "
-            "vs the generic C++ path and the XLA scan); example/gpushare = the "
+            "LVM/device envelope row; example/gpushare = the "
             "shipped example simon configs; bigu = 1000 distinct templates "
             "(big-U megakernel mode); forced = live-cluster replay (90%% "
             "pre-bound pods); steady = repeated re-simulation of one cluster "
@@ -933,7 +854,7 @@ def main() -> int:
             "run the measured simulation with the decision audit enabled "
             "(plan-family configs): the JSON line gains filter_rejects (nodes "
             "rejected per filter across all steps) and unschedulable_reasons. "
-            "Forces the C++ generic path / XLA count_all scan, so the wall "
+            "Forces the XLA count_all scan, so the wall "
             "time measures the audited path, not the headline"
         ),
     )
@@ -944,7 +865,7 @@ def main() -> int:
         help=(
             "write a Chrome-trace/Perfetto JSON of the measured run (plan-"
             "family configs): every phase span — prepare, encode, engine "
-            "attempts, decode — with the C++ engine's profile attached. "
+            "attempts, decode. "
             "Load at chrome://tracing or ui.perfetto.dev"
         ),
     )
@@ -1059,11 +980,6 @@ def main() -> int:
         record["engine"] = result.engine.name
         if result.engine.skipped:
             record["engine_skipped"] = result.engine.skipped
-        # C++ engine path attribution (ISSUE 4): incremental vs generic —
-        # a cache disengage must be visible in the record, never inferred
-        if result.engine.native_path is not None:
-            record["native_path"] = result.engine.native_path
-            record["native_steps"] = result.engine.native_steps
         # decision audit (--explain): per-filter reject totals + pods by
         # primary unschedulable reason, straight off the EngineDecision
         if args.explain and result.engine.filter_rejects is not None:
@@ -1077,39 +993,6 @@ def main() -> int:
                     key = code.name.lower() if code is not None else e.status
                     reason_hist[key] = reason_hist.get(key, 0) + 1
             record["unschedulable_reasons"] = reason_hist
-    if args.config in ("gpu", "local-pv"):
-        # ISSUE 19 in-row gates: the measured (incremental) placements must
-        # be bit-identical to the generic C++ path AND the XLA scan, and the
-        # incremental envelope must actually have engaged — a row that went
-        # generic measures the wrong thing even when it is fast enough
-        _stage("verify")
-        gates = _verify_envelope(cluster, apps)
-        record["native_engaged"] = int(
-            result.engine is not None
-            and result.engine.native_path == "incremental"
-            and gates.pop("verify_native_path") == "incremental"
-            and bool(gates.pop("verify_classes"))
-        )
-        record.update(gates)
-    if os.environ.get("OPENSIM_NATIVE_PROFILE"):
-        # per-stage engine timings as structured data (still ONE JSON line);
-        # populated by the C++ engine when profiling is enabled
-        from opensim_tpu.engine import nativepath as _np_path
-
-        prof = _np_path.last_profile()
-        if prof is not None:
-            record["native_profile"] = prof
-    serial, cxx = _serial_floors(
-        args.config, scheduled + len(result.unscheduled_pods), args.nodes
-    )
-    if serial and serial.get("schedule_s") and dt > 0:
-        record["vs_serial"] = round(serial["schedule_s"] / dt, 1)
-        record["serial_schedule_s"] = serial["schedule_s"]
-    if cxx and cxx.get("schedule_s") and dt > 0:
-        # the headline honest ratio: vectorized wall-clock vs the measured
-        # compiled-serial (Go-cost stand-in) schedule time
-        record["vs_serial_cxx"] = round(cxx["schedule_s"] / dt, 1)
-        record["cxx_serial_schedule_s"] = cxx["schedule_s"]
     if tr is not None:
         tracing.write_chrome(tr, args.trace)
         # the measured wall time and the trace's root span, side by side —

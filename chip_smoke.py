@@ -19,8 +19,8 @@ repo's headline size, and checks what comes out by the repo's own means:
   server        simon server --backend tpu against a stub apiserver holding
                 3,000 nodes / 30,000 bound pods; a handful of deploy-apps
                 requests (two of them concurrent), /healthz, /metrics, SIGTERM.
-                Pass: every answer 200 with placements, a device engine span
-                and no engine.native span in the flight recorder, exit 0.
+                Pass: every answer 200 with placements and a device engine
+                span in the flight recorder, exit 0.
 
 This process never initializes a JAX backend: every phase is ONE child process
 that owns the chip, one after another (a chip belongs to one process at a
@@ -197,7 +197,7 @@ def child_env(rehearse: bool) -> dict:
     env = dict(os.environ, PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
     env.pop("OPENSIM_FASTPATH", None)  # the interpreter is never a chip result
     if rehearse:
-        env.update(JAX_PLATFORMS="cpu", OPENSIM_FASTPATH="interpret", OPENSIM_DISABLE_NATIVE="1")
+        env.update(JAX_PLATFORMS="cpu", OPENSIM_FASTPATH="interpret")
     return env
 
 
@@ -256,7 +256,7 @@ def trace_passes(path: str) -> list:
     spans = [
         e for e in events
         if e.get("ph") == "X"
-        and re.fullmatch(r"(engine\.(megakernel|xla|native)|sweep\.\w+)(\.skipped)?", e["name"])
+        and re.fullmatch(r"(engine\.(megakernel|xla)|sweep\.\w+)(\.skipped)?", e["name"])
     ]
     spans.sort(key=lambda e: e.get("ts", 0))
     out = []
@@ -314,9 +314,6 @@ def check_on_chip(phase: str, res: dict, rehearse: bool) -> None:
         return  # a rehearsal is on the CPU by construction, and never a pass
     if res["platform"] != "tpu":
         raise PhaseFailed(f"{phase}: ran on platform {res['platform']!r}, not tpu")
-    ran = [p for p in res["passes"] if re.match(r"(engine|sweep)\.native\b(?!\.skipped)", p)]
-    if ran or res["engine"].startswith("native"):
-        raise PhaseFailed(f"{phase}: the C++ engine ran ({ran or res['engine']}) — that is a CPU result")
 
 
 # ---------------------------------------------------------------------------
@@ -412,7 +409,7 @@ def run_server(out: str, size: dict, rehearse: bool, port: int, timeout_s: float
             if st != 200:
                 raise PhaseFailed(f"server: no flight-recorder trace for request {rid!r} ({st})")
             doc = json.loads(tree)
-            mine = {n for n in span_names(doc["spans"]) if re.fullmatch(r"engine\.(megakernel|xla|native)", n)}
+            mine = {n for n in span_names(doc["spans"]) if re.fullmatch(r"engine\.(megakernel|xla)", n)}
             if doc.get("engine"):
                 # a batch rider's tree carries the batch's engine as an
                 # attribute (the engine span sits on the batch's own trace)
@@ -420,8 +417,6 @@ def run_server(out: str, size: dict, rehearse: bool, port: int, timeout_s: float
             mine = sorted(mine)
             say(f"[server]   {name}: 200, {placed} pods placed, {secs:.2f}s, spans {mine}, "
                 f"engine {str(doc.get('engine'))[:200]!r}")
-            if "engine.native" in mine:
-                raise PhaseFailed(f"server: request {name} ran the C++ engine — a CPU result")
             if not set(mine) & set(DEVICE_ENGINES):
                 raise PhaseFailed(f"server: request {name} shows no device engine span ({mine})")
             engines |= set(mine)

@@ -42,8 +42,6 @@ Rules (short name = suppression id; see docs/static-analysis.md):
     OSL1603 input-taint       untrusted input (HTTP/CLI/YAML) reaches a
                               filesystem/subprocess sink without a
                               registered @sanitizer validator
-    OSL1604 abi-parity        C++/Python ABI declarations drifted
-                              (ScanArgs layout, abi version, serial wire)
     OSL1701 shm-discipline    shared-memory segment create/attach/unlink
                               outside server/fleet.py (the fleet's
                               /dev/shm hygiene owner)
@@ -54,19 +52,19 @@ Rules (short name = suppression id; see docs/static-analysis.md):
     OSL1803 shape-contract    rank/axis-order mismatch vs the declared
                               (dtype, axes) contract
     OSL1804 contract-abi-parity  contract registry / dtypes policy /
-                              native ScanArgs widths out of three-way sync
+                              arena struct fields out of sync
 
 The OSL12xx family is whole-program (symbol table + call graph + lock
 graph across all linted files); its runtime counterpart is the lock-order
 sanitizer ``analysis/lockwatch.py`` (`make tsan`, ``OPENSIM_LOCKWATCH=1``).
 The OSL16xx family runs on the interprocedural dataflow engine
 (``analysis/dataflow.py``: per-function CFGs + reaching definitions,
-call-graph effect fixpoint, forward taint lattice) and the cross-language
-ABI parser (``analysis/abi.py``); see docs/static-analysis.md. The
+call-graph effect fixpoint, forward taint lattice); see
+docs/static-analysis.md. The
 OSL18xx family is the array-contract engine (``analysis/arrays.py``): an
 abstract interpreter computing a (dtype, rank, symbolic-axis) lattice
 over the same CFGs, checked against the contract registry in
-``encoding/dtypes.py`` and the C++ ``ScanArgs`` widths.
+``encoding/dtypes.py``.
 """
 
 from .core import (  # noqa: F401

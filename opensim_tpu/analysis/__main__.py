@@ -40,9 +40,7 @@ def pyproject_defaults(path: str = "pyproject.toml") -> Dict[str, List[str]]:
 
 def _git_changed_files(roots: List[str]) -> Optional[List[str]]:
     """Lintable files with uncommitted changes (``git status
-    --porcelain``), scoped to the configured lint roots. A modified
-    ``.cc`` engine source pulls in the native package next to it so the
-    ABI rules (OSL1604/OSL1804) re-check the boundary. Returns None when
+    --porcelain``), scoped to the configured lint roots. Returns None when
     not in a git checkout (caller falls back to a full run)."""
     import subprocess
 
@@ -74,10 +72,6 @@ def _git_changed_files(roots: List[str]) -> Optional[List[str]]:
             continue
         if path.endswith(".py") or os.path.isdir(path):
             out.append(path)
-        elif path.endswith(".cc"):
-            mirror = os.path.join(os.path.dirname(path), "__init__.py")
-            if os.path.isfile(mirror):
-                out.append(mirror)
     return sorted(set(out))
 
 
@@ -150,8 +144,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         "--changed",
         action="store_true",
         help="lint only files with uncommitted git changes under the "
-        "configured paths (plus the native package when a .cc engine "
-        "source changed) — the fast pre-commit loop; whole-program rules "
+        "configured paths — the fast pre-commit loop; whole-program rules "
         "see just this subset and cache in their own project slot",
     )
     ap.add_argument(

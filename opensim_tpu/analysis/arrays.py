@@ -7,7 +7,7 @@ checked against the contract registry in ``encoding/dtypes.py``
 
 Abstract value
     ``ArrayVal(dtype, axes, creations, widenings)``. ``dtype`` is one of
-    the ABI width tags (``bool/u8/i32/i64/f32/f64``) or ``None`` =
+    the width tags (``bool/u8/i32/i64/f32/f64``) or ``None`` =
     unknown; ``axes`` is a tuple of canonical axis names (``"?"`` =
     unknown axis) or ``None`` = unknown rank. ``creations`` records
     array-creation sites (``np.zeros`` without a policy dtype, explicit
@@ -75,7 +75,7 @@ _EVENT_CAP = 4
 _MAX_ROUNDS = 4
 
 #: modules analyzed / reported on — the arena pipeline
-_SCOPE = ("encoding/", "engine/", "parallel/", "native/", "ops/")
+_SCOPE = ("encoding/", "engine/", "parallel/", "ops/")
 
 _NP_NAME_TO_TAG = {
     "bool": "bool", "bool_": "bool", "uint8": "u8", "int32": "i32",
@@ -251,7 +251,6 @@ class Contracts:
     )
     struct_params: Dict[str, str] = field(default_factory=dict)
     axis_aliases: Dict[str, str] = field(default_factory=dict)
-    buffer_aliases: Dict[str, str] = field(default_factory=dict)
     entry_lines: Dict[str, int] = field(default_factory=dict)  # field -> line
     source_path: Optional[str] = None
     problems: List[str] = field(default_factory=list)
@@ -318,8 +317,7 @@ def _parse_dtypes_module(tree: ast.Module, path: str) -> Contracts:
                 out.policies[target] = tag
             continue
         if target in _CONTRACT_BLOCKS + (
-            "KERNEL_ARG_CONTRACTS", "AXIS_ALIASES", "BUFFER_FIELD_ALIASES",
-            "STRUCT_PARAM_NAMES",
+            "KERNEL_ARG_CONTRACTS", "AXIS_ALIASES", "STRUCT_PARAM_NAMES",
         ):
             try:
                 lit = ast.literal_eval(value)
@@ -334,8 +332,6 @@ def _parse_dtypes_module(tree: ast.Module, path: str) -> Contracts:
                 out.kernel_args = lit
             elif target == "AXIS_ALIASES":
                 out.axis_aliases = lit
-            elif target == "BUFFER_FIELD_ALIASES":
-                out.buffer_aliases = lit
             else:
                 out.struct_params = lit
             if target in _CONTRACT_BLOCKS and isinstance(value, ast.Dict):
@@ -362,7 +358,6 @@ def _live_contracts() -> Contracts:
     out.state = dict(D.STATE_CONTRACTS)
     out.kernel_args = {k: dict(v) for k, v in D.KERNEL_ARG_CONTRACTS.items()}
     out.axis_aliases = dict(D.AXIS_ALIASES)
-    out.buffer_aliases = dict(D.BUFFER_FIELD_ALIASES)
     out.struct_params = dict(D.STRUCT_PARAM_NAMES)
     out._build_vocab()
     return out

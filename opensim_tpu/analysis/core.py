@@ -1341,21 +1341,6 @@ def lint_paths(
     return findings
 
 
-def _companion_files(py_paths: Sequence[str]) -> List[str]:
-    """Non-Python inputs whole-program rules consult (today: the ``.cc``
-    engine sources living beside linted files), sorted for stable
-    digests."""
-    dirs = sorted({os.path.dirname(p) for p in py_paths})
-    out: List[str] = []
-    for d in dirs:
-        try:
-            names = os.listdir(d or ".")
-        except OSError:
-            continue
-        out.extend(os.path.join(d, n) for n in sorted(names) if n.endswith(".cc"))
-    return out
-
-
 def _lint_file_worker(item: Tuple[str, str, Tuple[str, ...]]) -> Tuple[str, List[dict]]:
     """Per-file rule tier for ONE file — the process-pool unit. Top-level
     so the executor can pickle it; re-parses the source (ASTs don't cross
@@ -1437,17 +1422,6 @@ def _lint_paths_cached(
     for fpath in order:
         h.update(fpath.encode())
         h.update(shas[fpath].encode())
-    # companion sources the project rules read but the walker does not
-    # lint: the abi-parity pass (OSL1604) parses the C++ engine sources
-    # next to the native package, so a C++-only ABI edit must invalidate
-    # the cached project pass too
-    for comp in _companion_files(order):
-        h.update(comp.encode())
-        try:
-            with open(comp, "rb") as fh:
-                h.update(hashlib.sha256(fh.read()).hexdigest().encode())
-        except OSError:
-            h.update(b"<unreadable>")
     h.update(",".join(sorted(r.code for r in global_rules)).encode())
     h.update(analyzer_fingerprint().encode())
     project_digest = h.hexdigest()

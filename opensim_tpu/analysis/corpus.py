@@ -8,7 +8,7 @@ holds, per rule:
 - ``<CODE>_fire.py`` — a minimal fixture the rule MUST fire on;
 - ``<CODE>_clean.py`` — the paired clean variant it MUST stay quiet on;
 - or ``<CODE>_fire/`` / ``<CODE>_clean/`` directories for rules that need
-  more than one file (OSL1604 ships a mutated ``native/`` tree).
+  more than one file (OSL1804 ships a registry and its arena structs).
 
 Because many rules are path-scoped (``paths = ("engine/", ...)``), a
 fixture's FIRST line may declare the virtual path it should be linted

@@ -91,7 +91,7 @@ def hold_exempt() -> Tuple[str, ...]:
     suppression convention — the by-design long holders (REST
     single-flight/probe locks, prep-cache per-entry lock, watch flush
     lock, all of which span engine work whose latency is gated by
-    perf-smoke/loadgen-smoke instead) are marked that way. Inversions
+    loadgen-smoke instead) are marked that way. Inversions
     are NEVER exempt either way."""
     raw = envknobs.raw("OPENSIM_LOCKWATCH_HOLD_EXEMPT")
     return tuple(s.strip() for s in raw.split(",") if s.strip())

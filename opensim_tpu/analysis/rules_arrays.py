@@ -2,7 +2,7 @@
 
 Built on :mod:`analysis.arrays` (abstract interpretation of numpy/jax
 values over the dataflow CFGs, checked against the contract registry in
-``encoding/dtypes.py``) and :mod:`analysis.abi`. Four rules:
+``encoding/dtypes.py``). Four rules:
 
 - **OSL1801 array-off-policy** — an array created without (or with a
   non-policy) dtype reaches an ``EncodedCluster``/``ScanState``/
@@ -24,23 +24,18 @@ values over the dataflow CFGs, checked against the contract registry in
   builder-local aliases in ``AXIS_ALIASES``. Unknown axes (``?``) never
   fire — only a known-vs-known mismatch does.
 
-- **OSL1804 contract-abi-parity** — the three-way sync: the contract
+- **OSL1804 contract-abi-parity** — the registry's own sync: the contract
   registry in ``encoding/dtypes.py`` vs the policy constants it names vs
-  the ``EncodedCluster``/``ScanState`` field sets vs the native
-  ``_BUFFERS`` packing and the C++ ``ScanArgs`` widths. OSL1604 gates
-  scan_engine.cc against the ctypes mirror; this rule closes the
-  remaining drift axis — BOTH native sides narrowed while the Python
-  contract stays wide (or vice versa) — naming the exact field.
+  the ``EncodedCluster``/``ScanState`` field sets, naming the exact
+  field.
 """
 
 from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Dict, Iterable, Optional, Tuple
+from typing import Iterable, Optional
 
-from . import abi
-from .abi import _module_lists
 from .arrays import Contracts, _parse_dtypes_module, get_array_findings
 from .core import FileContext, Finding, ProjectContext, Rule, register
 
@@ -51,7 +46,7 @@ class _Site:
     col_offset: int
 
 
-_SCOPE_PATHS = ("encoding/", "engine/", "parallel/", "native/", "ops/")
+_SCOPE_PATHS = ("encoding/", "engine/", "parallel/", "ops/")
 
 
 class _ArrayRuleBase(Rule):
@@ -95,35 +90,25 @@ class ShapeContractRule(_ArrayRuleBase):
     )
 
 
-def _compatible(tag: str, width: str) -> bool:
-    """Contract tag vs marshalled width. bool masks cross the ctypes
-    boundary as u8 (``np.bool_`` is 1 byte) — that pairing is the one
-    sanctioned widening."""
-    return width == tag or (tag == "bool" and width == "u8")
-
-
 @register
 class ContractAbiParityRule(Rule):
     name = "contract-abi-parity"
     code = "OSL1804"
     description = (
-        "contract registry, dtypes policy, EncodedCluster/ScanState fields, "
-        "native packing and C++ ScanArgs widths drifted out of three-way sync"
+        "contract registry, dtypes policy and EncodedCluster/ScanState "
+        "fields drifted out of sync"
     )
     project_rule = True
 
     def project_check(self, project: ProjectContext) -> Iterable[Finding]:
         dtypes_ctx: Optional[FileContext] = None
         state_ctx: Optional[FileContext] = None
-        native_ctx: Optional[FileContext] = None
         for ctx in project.contexts:
             p = ctx.path.replace(os.sep, "/")
             if p.endswith("encoding/dtypes.py"):
                 dtypes_ctx = ctx
             elif p.endswith("encoding/state.py"):
                 state_ctx = ctx
-            elif p.endswith("native/__init__.py"):
-                native_ctx = ctx
         if dtypes_ctx is None:
             return
         con = _parse_dtypes_module(dtypes_ctx.tree, dtypes_ctx.path)
@@ -159,10 +144,6 @@ class ContractAbiParityRule(Rule):
         # 2. registry key sets == the NamedTuple field sets
         if state_ctx is not None:
             yield from self._check_fields(dtypes_ctx, state_ctx, con, anchor)
-
-        # 3. native packing + C++ ScanArgs widths vs the contract tags
-        if native_ctx is not None:
-            yield from self._check_native(dtypes_ctx, native_ctx, con, anchor)
 
     # -- registry keys vs encoding/state.py -----------------------------------
 
@@ -200,62 +181,3 @@ class ContractAbiParityRule(Rule):
                         f"{table_name} entry `{fname}` names no {cls_name} "
                         "field (stale contract after a field removal/rename?)",
                     )
-
-    # -- native packing + C++ widths vs contracts ------------------------------
-
-    def _contract_for(self, con: Contracts, buf_name: str) -> Optional[Tuple[str, str]]:
-        """(policy name, resolved tag) for a native buffer name, or None
-        when the buffer carries no Python-side contract (outputs,
-        profile/debug arrays)."""
-        fname = con.buffer_aliases.get(buf_name, buf_name)
-        entry = con.arena.get(fname) or con.state.get(fname)
-        if entry is None:
-            for params in con.kernel_args.values():
-                if fname in params:
-                    entry = params[fname]
-                    break
-        if entry is None:
-            return None
-        policy = entry[0]
-        tag = con.policies.get(policy)
-        return (policy, tag) if tag is not None else None
-
-    def _check_native(self, dtypes_ctx, native_ctx, con: Contracts, anchor):
-        buffers = _module_lists(native_ctx.tree).get("_BUFFERS", [])
-        for item in buffers:
-            if not isinstance(item, tuple):
-                continue
-            buf_name, width = item
-            got = self._contract_for(con, buf_name)
-            if got is None:
-                continue
-            policy, tag = got
-            if not _compatible(tag, width):
-                yield self.finding(
-                    dtypes_ctx.path, anchor(con.buffer_aliases.get(buf_name, buf_name)),
-                    f"contract-ABI width drift: `{buf_name}` is contracted "
-                    f"{policy} ({tag}) but native/__init__.py packs it as "
-                    f"{width} — narrow/widen the contract and the native "
-                    "packing together",
-                )
-        cc_path = os.path.join(os.path.dirname(native_ctx.path), "scan_engine.cc")
-        if not os.path.isfile(cc_path):
-            return
-        with open(cc_path, "r", encoding="utf-8") as fh:
-            cc_fields, _problems = abi.parse_cc_struct(fh.read())
-        for cc_name, kind in cc_fields:
-            if not kind.startswith("ptr:"):
-                continue  # scalar dims/weights carry no array contract
-            width = kind[len("ptr:"):]
-            got = self._contract_for(con, cc_name)
-            if got is None:
-                continue
-            policy, tag = got
-            if not _compatible(tag, width):
-                yield self.finding(
-                    dtypes_ctx.path, anchor(con.buffer_aliases.get(cc_name, cc_name)),
-                    f"contract-ABI width drift: `{cc_name}` is contracted "
-                    f"{policy} ({tag}) but C++ ScanArgs (scan_engine.cc) "
-                    f"declares {kind} — the contract registry and the native "
-                    "engine disagree on this field's width",
-                )

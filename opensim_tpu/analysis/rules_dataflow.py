@@ -1,8 +1,7 @@
 """OSL16xx — the interprocedural dataflow rule pack.
 
 Built on :mod:`analysis.dataflow` (per-function CFGs, effect inference,
-taint lattice, jit-region tracking) and :mod:`analysis.abi` (cross-
-language struct parsing). Four rules:
+taint lattice, jit-region tracking). Three rules:
 
 - **OSL1601 jit-impurity** — OSL101 generalized from one syntactic file
   to call-graph depth: any function transitively reachable from a
@@ -24,24 +23,14 @@ language struct parsing). Four rules:
   carrying a ``@sanitizer`` decorator — see ``utils/validate.py``).
   Flow-sensitive per function, interprocedural through call-graph
   summaries.
-
-- **OSL1604 abi-parity** — parses the ``ScanArgs`` struct declaration in
-  ``native/scan_engine.cc`` and the packing order in
-  ``native/__init__.py`` and gates field count, order, and width
-  equality; also cross-checks ``opensim_abi_version()`` against
-  ``ABI_VERSION`` and the serial wire magic/version between
-  ``native/serial.py`` and ``serial_engine.cc``. The abi-v4 "keep order
-  in sync" comment is now a build-failing check.
 """
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import Iterable
 
-from . import abi
-from .core import FileContext, Finding, ProjectContext, Rule, register
+from .core import Finding, ProjectContext, Rule, register
 from .dataflow import get_engine, get_taint_hits, get_tracer_leaks
 
 
@@ -137,113 +126,3 @@ class InputTaintRule(Rule):
                 f"`{hit.unit.rsplit('.', 1)[-1]}` ({hit.desc}); route it "
                 "through a registered validator (@sanitizer, utils/validate.py)",
             )
-
-
-@register
-class AbiParityRule(Rule):
-    name = "abi-parity"
-    code = "OSL1604"
-    description = (
-        "C++/Python ABI declarations drifted (ScanArgs layout, abi version, "
-        "serial wire tag)"
-    )
-    project_rule = True
-
-    def project_check(self, project: ProjectContext) -> Iterable[Finding]:
-        for ctx in project.contexts:
-            p = ctx.path.replace(os.sep, "/")
-            if p.endswith("native/__init__.py"):
-                yield from self._check_scan(ctx)
-            elif p.endswith("native/serial.py"):
-                yield from self._check_serial(ctx)
-
-    # -- ScanArgs struct + abi version ---------------------------------------
-
-    def _check_scan(self, ctx: FileContext) -> Iterable[Finding]:
-        py_fields, py_problems = abi.parse_py_layout(ctx.tree)
-        if not py_fields and not py_problems:
-            return
-        # skip ONLY the no-mirror case (a native/__init__.py without a
-        # ScanArgs class); any other parse problem — a packing list that
-        # stopped being a module-level list literal, an unknown ctype —
-        # must FAIL the gate, not silently disable it
-        if (
-            py_problems
-            and not py_fields
-            and py_problems[0].startswith("class ScanArgs not found")
-        ):
-            return
-        anchor = _Site(self._class_line(ctx, "ScanArgs"), 0)
-        cc_path = os.path.join(os.path.dirname(ctx.path), "scan_engine.cc")
-        if not os.path.isfile(cc_path):
-            yield self.finding(
-                ctx.path, anchor,
-                "cannot verify ScanArgs ABI: scan_engine.cc not found next to "
-                "the ctypes mirror",
-            )
-            return
-        with open(cc_path, "r", encoding="utf-8") as fh:
-            cc_text = fh.read()
-        cc_fields, cc_problems = abi.parse_cc_struct(cc_text)
-        for msg in py_problems + cc_problems:
-            yield self.finding(ctx.path, anchor, f"ABI parse problem: {msg}")
-        for msg in abi.compare_layouts(cc_fields, py_fields):
-            yield self.finding(
-                ctx.path, anchor,
-                f"ScanArgs ABI drift between scan_engine.cc and the ctypes "
-                f"mirror: {msg}",
-            )
-        v_cc = abi.parse_cc_abi_version(cc_text)
-        v_py = abi.parse_py_abi_version(ctx.tree)
-        if v_py is None:
-            yield self.finding(
-                ctx.path, anchor,
-                "ABI_VERSION constant missing from native/__init__.py (the "
-                "machine-readable anchor for opensim_abi_version())",
-            )
-        elif v_cc is not None and v_cc != v_py:
-            yield self.finding(
-                ctx.path, anchor,
-                f"ABI version drift: opensim_abi_version() returns {v_cc} but "
-                f"native/__init__.py declares ABI_VERSION = {v_py}",
-            )
-
-    # -- serial wire tag -----------------------------------------------------
-
-    def _check_serial(self, ctx: FileContext) -> Iterable[Finding]:
-        magic_py, ver_py = abi.parse_py_serial_wire(ctx.tree)
-        anchor = _Site(1, 0)
-        cc_path = os.path.join(os.path.dirname(ctx.path), "serial_engine.cc")
-        if not os.path.isfile(cc_path):
-            return
-        if magic_py is None or ver_py is None:
-            yield self.finding(
-                ctx.path, anchor,
-                "WIRE_MAGIC/WIRE_VERSION constants missing from "
-                "native/serial.py (the machine-readable anchors for the "
-                "serial_engine.cc header guards)",
-            )
-            return
-        with open(cc_path, "r", encoding="utf-8") as fh:
-            magic_cc, ver_cc = abi.parse_cc_serial_wire(fh.read())
-        if magic_cc is not None and magic_cc != magic_py:
-            yield self.finding(
-                ctx.path, anchor,
-                f"serial wire magic drift: serial_engine.cc expects "
-                f"{magic_cc:#x}, serial.py writes {magic_py:#x}",
-            )
-        if ver_cc is not None and ver_cc != ver_py:
-            yield self.finding(
-                ctx.path, anchor,
-                f"serial wire version drift: serial_engine.cc expects "
-                f"{ver_cc}, serial.py writes {ver_py}",
-            )
-
-    @staticmethod
-    def _class_line(ctx: FileContext, name: str) -> int:
-        import ast
-
-        for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.ClassDef) and node.name == name:
-                return node.lineno
-        return 1

@@ -39,12 +39,11 @@ def build_parser() -> argparse.ArgumentParser:
     backend_parent.add_argument(
         "--backend",
         default="auto",
-        choices=["auto", "tpu", "cpu", "xla", "native"],
+        choices=["auto", "tpu", "cpu", "xla"],
         help=(
-            "auto = accelerator if reachable (Pallas fast path on TPU, C++ "
-            "engine on CPU); tpu = require the accelerator; cpu = force host "
-            "CPU; xla = disable the Pallas/C++ engines (pure XLA scan); "
-            "native = force the C++ scan engine"
+            "auto = accelerator if reachable (Pallas fast path on TPU, XLA "
+            "scan on CPU); tpu = require the accelerator; cpu = force host "
+            "CPU; xla = disable the Pallas engine (pure XLA scan)"
         ),
     )
 
@@ -80,8 +79,8 @@ def build_parser() -> argparse.ArgumentParser:
         "--tie-break", default="lowest", metavar="lowest|sample[:seed]",
         help="equal-score node selection: deterministic lowest index "
         "(default) or the reference's sampled tie-break, seeded for "
-        "reproducible distribution-comparison runs (C++ engine or XLA "
-        "scan; the Pallas megakernel stays lowest-index)",
+        "reproducible distribution-comparison runs (XLA scan; the Pallas "
+        "megakernel stays lowest-index)",
     )
     apply_p.add_argument(
         "--explain", action="store_true",
@@ -406,7 +405,7 @@ def build_parser() -> argparse.ArgumentParser:
             "repo-specific static analyzer (docs/static-analysis.md): AST "
             "rules, whole-program lock-discipline checks, and the "
             "interprocedural dataflow pack (jit-impurity, tracer-leak, "
-            "input-taint, C++/Python abi-parity). Exit 1 on findings."
+            "input-taint). Exit 1 on findings."
         ),
     )
     lint_p.add_argument(
@@ -472,7 +471,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = parser.parse_args(argv)
 
     # the platform is whatever JAX selects from JAX_PLATFORMS; only an
-    # explicit --backend cpu|native overrides it in code
+    # explicit --backend cpu overrides it in code
     backend = getattr(args, "backend", "auto")
     if backend != "auto":
         _select_backend(backend)
@@ -551,12 +550,10 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"simon explain: {e}", file=sys.stderr)
             return 1
     if args.command == "server":
-        from .. import native
         from ..server.rest import serve
 
         if args.access_log:
             os.environ["OPENSIM_ACCESS_LOG"] = "1"
-        native.available()  # warm the C++ engine build before the first request
         try:
             return serve(
                 kubeconfig=args.kubeconfig, master=args.master, port=args.port,
@@ -1041,31 +1038,6 @@ def run_profile(args) -> int:
                 + f", {promo} starvation promotion(s)",
                 file=out,
             )
-    native = payload.get("native") or {}
-    steps = native.get("steps") or {}
-    if any(steps.values()):
-        inc = int(steps.get("incremental", 0))
-        gen = int(steps.get("generic", 0))
-        total = inc + gen
-        pct = (100.0 * inc / total) if total else 0.0
-        print(
-            f"\nC++ engine paths: {inc} incremental / {gen} generic "
-            f"step(s) ({pct:.1f}% incremental)",
-            file=out,
-        )
-        classes = native.get("classes") or {}
-        if classes:
-            print(
-                "incremental carry classes: "
-                + ", ".join(f"{k}={n}" for k, n in sorted(classes.items())),
-                file=out,
-            )
-        bails = native.get("bails") or {}
-        if bails:
-            rows = [["Bail reason", "Count"]]
-            for reason, n in sorted(bails.items(), key=lambda kv: (-kv[1], kv[0])):
-                rows.append([reason, str(n)])
-            _table(rows, out)
     return 0
 
 
@@ -1266,30 +1238,14 @@ def run_explain(args) -> int:
 def _select_backend(backend: str) -> None:
     """--backend plumbing (the BASELINE north star's `--backend=tpu` knob):
     auto picks the best engine for the platform (Pallas megakernel on TPU,
-    C++ engine on CPU); cpu forces the host platform; xla disables both the
-    Pallas and C++ engines (pure XLA scan); native forces the C++ engine
-    (implies the CPU platform for the JAX side); tpu requires the
-    accelerator."""
+    XLA scan on CPU); cpu forces the host platform; xla disables the Pallas
+    engine (pure XLA scan); tpu requires the accelerator."""
     import jax
 
     if backend == "cpu":
         jax.config.update("jax_platforms", "cpu")
-        from .. import native
-
-        native.available()  # warm the g++ build before the first request
     elif backend == "xla":
         os.environ["OPENSIM_DISABLE_FASTPATH"] = "1"
-        os.environ["OPENSIM_DISABLE_NATIVE"] = "1"
-    elif backend == "native":
-        from .. import native
-
-        if not native.available():
-            print(f"simon: --backend native unavailable: {native.load_error()}", file=sys.stderr)
-            raise SystemExit(1)
-        os.environ["OPENSIM_NATIVE"] = "1"
-        # the C++ engine is the no-accelerator path; keep the JAX side
-        # (encoding + static precompute) off the device too
-        jax.config.update("jax_platforms", "cpu")
     elif backend == "tpu":
         from ..utils import envknobs
 

@@ -11,10 +11,10 @@ This module is also the **array contract registry** (ISSUE 17): every
 ``(policy dtype name, symbolic axis names)`` here, and the XLA kernel
 entry points declare boundary contracts for their array arguments. The
 OSL18xx rule family (``analysis/arrays.py``) checks the encoder and
-engine against these declarations, and OSL1804 gates the registry, the
-policy constants above it, and the C++ ``ScanArgs`` widths into one
-three-way sync — so narrowing a dtype here without updating the native
-ABI (or vice versa) fails the build naming the exact field.
+engine against these declarations, and OSL1804 gates the registry against
+the policy constants above it and the arena struct fields — so a contract
+naming no policy, or a field with no contract, fails the build naming the
+exact field.
 
 Contract convention (docs/static-analysis.md "Array contracts"):
 
@@ -49,7 +49,7 @@ INT64_DTYPE = np.int64
 
 #: Accumulation dtype for the log(k+2) topology-spread weight table — the
 #: one sanctioned float64 in the encoder. The table is computed in float64
-#: and cast to FLOAT_DTYPE so the XLA scan, the numpy precompute and the
+#: and cast to FLOAT_DTYPE so the XLA scan, the megakernel and the
 #: sweeps gather bitwise-identical weights (XLA:CPU's f32 log and numpy's
 #: differ by 1 ulp on ~3% of inputs, enough to flip score ties).
 LOG_ACC_DTYPE = np.float64
@@ -69,8 +69,8 @@ def log_size_table(n: int) -> np.ndarray:
 # --------------------------------------------------------------------------
 
 #: Structural dtypes for mask/byte arenas. Not "policy" in the narrowing
-#: sense — bool masks marshal to the native engine as u8 — but contracts
-#: name them so every arena field resolves through this module.
+#: sense, but contracts name them so every arena field resolves through
+#: this module.
 BOOL_DTYPE = np.bool_
 UINT8_DTYPE = np.uint8
 
@@ -179,14 +179,6 @@ STATE_CONTRACTS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dev_free": ("FLOAT_DTYPE", ("N", "Dv")),
 }
 
-#: ctypes-pack buffer name → arena/state field name, where they differ.
-#: ``nativepath.schedule`` renames ``node_gpu_mem`` to the engine's
-#: ``node_gpu_cap``; OSL1804 follows this map when cross-checking
-#: ``_BUFFERS``/``ScanArgs`` widths against the contracts above.
-BUFFER_FIELD_ALIASES: Dict[str, str] = {
-    "node_gpu_cap": "node_gpu_mem",
-}
-
 #: Boundary contracts for the XLA kernel entries and the jit wrapper:
 #: array-typed parameters that cross into traced/compiled code. Values are
 #: (policy-constant name, symbolic axes); ``P`` is the padded pod-stream
@@ -205,15 +197,6 @@ KERNEL_ARG_CONTRACTS: Dict[str, Dict[str, Tuple[str, Tuple[str, ...]]]] = {
         "tmpl_ids": ("INT_DTYPE", ("P",)),
         "pod_valid": ("BOOL_DTYPE", ("P",)),
         "forced": ("BOOL_DTYPE", ("P",)),
-    },
-    # native scan attribution buffers (abi v5): marshalled by
-    # nativepath.schedule into ScanArgs.bail_out/class_steps; contracting
-    # them here lets OSL1804 gate the ctypes packing AND the C++ pointer
-    # width against one declared policy (counts accumulate in i64 like
-    # filter_rejects — a 32-bit slot would wrap on long campaign runs)
-    "run_scan": {
-        "bail_out": ("INT64_DTYPE", ("B",)),
-        "class_steps": ("INT64_DTYPE", ("K",)),
     },
 }
 

@@ -114,8 +114,8 @@ class EncodedCluster(NamedTuple):
     node_dev_media: np.ndarray  # [N, Dv] i32 0=ssd 1=hdd (-1 pad)
     # log(k+2) lookup over possible per-key domain counts (k = 0..N): the
     # topology-spread normalizing weight is a GATHER from this table in
-    # every engine, so the XLA scan, the numpy precompute (native path) and
-    # the sweeps produce bitwise-identical weights — XLA:CPU's f32 log and
+    # every engine, so the XLA scan, the megakernel and the sweeps produce
+    # bitwise-identical weights — XLA:CPU's f32 log and
     # numpy's differ by 1 ulp on ~3% of inputs, enough to flip score ties.
     log_sizes: np.ndarray  # [N+1] f32
 

@@ -26,11 +26,14 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from ..ops import kernels
 from . import reasons
 from .reasons import PlacementExplanation, Reason
+
+_precompute_static = jax.jit(kernels.precompute_static, static_argnames=("cfg",))
 
 
 def rebuild_counts(prep, chosen: np.ndarray, upto: Optional[int] = None):
@@ -222,9 +225,8 @@ class ExplainContext:
 
 def audit_rejects(static_fail, sf_rows, fail_counts, mask) -> np.ndarray:
     """Aggregate 11-slot per-filter reject totals (kernel filter-index
-    order) from per-pod attribution rows — the XLA-path counterpart of the
-    C++ engine's in-engine ``filter_rejects`` accumulator. ``mask`` selects
-    the audited pods (valid, unforced)."""
+    order) from per-pod attribution rows. ``mask`` selects the audited pods
+    (valid, unforced)."""
     rej = np.zeros(kernels.NUM_FILTERS, np.int64)
     mask = np.asarray(mask, dtype=bool)
     if mask.any():
@@ -304,14 +306,11 @@ def explain_pod(ctx: ExplainContext, i: int) -> PlacementExplanation:
     cfg = ctx.config_for(i)
     st = replay_state(prep, ctx.chosen, ctx.gpu_take, upto=i)
     st_dev = ScanState(*[jnp.asarray(a) for a in st])
-    from . import nativepath
-
     ec = prep.ec_np
-    nv = None
     if ctx.node_valid is not None:
-        nv = np.ascontiguousarray(ctx.node_valid, dtype=bool)
-        ec = ec._replace(node_valid=nv)
-    stat = nativepath._stat_np(prep, cfg, node_valid=nv)
+        ec = ec._replace(node_valid=np.ascontiguousarray(ctx.node_valid, dtype=bool))
+    # the XLA scan's own static tables, jit-cached per shape and config
+    stat = _precompute_static(ec, cfg)
     res = kernels.pod_step(
         ec, stat, st_dev, u, feat=prep.features, cfg=cfg,
         extra=ctx.extra_plugins,

@@ -47,38 +47,42 @@ def applicable(prep, config=None) -> bool:
     return select.policy().off["megakernel"] is None and why_not(prep, config) is None
 
 
-def why_not(prep, config=None) -> Optional[str]:
-    """Envelope check for the megakernel: returns None when the prepared
-    simulation can run on it, else a one-line reason (surfaced as engine
-    attribution — VERDICT r4 #3). The kernel covers: static filters + fit +
-    least/balanced/share + topology spread + inter-pod terms, hostname plus
-    at most four other topology keys (stacked per-key count blocks), and a
-    config's score weights and RequestedToCapacityRatio term."""
+def why_not(prep, config=None) -> Optional[Tuple[str, str]]:
+    """Envelope check for the megakernel: None when the prepared simulation
+    can run on it, else ``(token, reason)``: the reason a one-line sentence
+    (surfaced as engine attribution), the token what ``select.turned_away``
+    counts: the table axes over their caps joined by ``+`` (``U``, ``A``,
+    ``R``), ``vmem``, ``topo_keys``, ``sched_config:<gap>``, or ``features``
+    for a feature's table the kernel has no rows for. The kernel covers:
+    static filters + fit + least/balanced/share + topology spread + inter-pod
+    terms, hostname plus at most four other topology keys (stacked per-key
+    count blocks), and a config's score weights and RequestedToCapacityRatio
+    term."""
     gap = kernel_gap(config)
     if gap is not None:
-        return f"a scheduler config the kernel cannot compute ({gap})"
+        return f"sched_config:{gap}", f"a scheduler config the kernel cannot compute ({gap})"
     f = prep.features
     ec = prep.ec_np if prep.ec_np is not None else prep.ec
     if f.ports and int(ec.ports.max() if ec.ports.size else -1) >= 64:
-        return "port-vocab ids >=64 exceed the 64 padded port rows"
+        return "features", "port-vocab ids >=64 exceed the 64 padded port rows"
     if f.gpu and int(ec.node_gpu_mem.shape[1]) > 8:
-        return f"{int(ec.node_gpu_mem.shape[1])} GPUs/node > 8 supported"
+        return "features", f"{int(ec.node_gpu_mem.shape[1])} GPUs/node > 8 supported"
     if f.local and (
         int(ec.node_vg_cap.shape[1]) > 8
         or int(ec.node_dev_cap.shape[1]) > 8
         or int(ec.dev_req_sizes.shape[2]) > 8
     ):
-        return "open-local VG/device axes > 8 supported"
+        return "features", "open-local VG/device axes > 8 supported"
     # inter-pod terms are supported with bounded table sizes
     if f.interpod or f.prefg:
         if int(ec.anti_g_sel.shape[0]) > 16 or int(ec.prefg_sel.shape[0]) > 16:
-            return "inter-pod global term tables > 16 rows"
+            return "features", "inter-pod global term tables > 16 rows"
         if (
             int(ec.at_sel.shape[1]) > 4
             or int(ec.an_sel.shape[1]) > 4
             or int(ec.pt_sel.shape[1]) > 4
         ):
-            return "inter-pod per-template terms > 4 per pod"
+            return "features", "inter-pod per-template terms > 4 per pod"
     # N is padded to a 128-lane multiple at marshalling time
     # (build_inputs), so any encoder node_pad is acceptable
     N = 128 * math.ceil(int(ec.node_valid.shape[0]) / 128)
@@ -88,20 +92,19 @@ def why_not(prep, config=None) -> Optional[str]:
     # beyond 512 templates the kernel switches to big-U mode (template
     # tables in HBM, one DMA per step — see use_big_u/run_fast_scan);
     # 2048 bounds the SMEM scalar tables
-    if R > 8 or U > 2048 or A > 64:
-        over = [
-            f"{label}={val} > {cap} supported"
-            for label, val, cap in (("R", R, 8), ("U", U, 2048), ("A", A, 64))
-            if val > cap
-        ]
-        return "table sizes outside envelope: " + ", ".join(over)
+    over = [(label, val, cap) for label, val, cap in (("R", R, 8), ("U", U, 2048), ("A", A, 64)) if val > cap]
+    if over:
+        return (
+            "+".join(label for label, _, _ in over),
+            "table sizes outside envelope: " + ", ".join(f"{label}={val} > {cap} supported" for label, val, cap in over),
+        )
     vocab = prep.meta.vocab
     topo_keys = vocab.topo_keys.items()
     non_host = [k for k in topo_keys if k != HOSTNAME]
     if len(non_host) > 4:
         # hostname + up to four zone-like keys (compile-time unrolled
         # per-key loops; beyond that the XLA scan wins anyway)
-        return f"{len(non_host)} non-hostname topology keys > 4 supported"
+        return "topo_keys", f"{len(non_host)} non-hostname topology keys > 4 supported"
     # hostname domains must be node-identity (each valid node carries its
     # own hostname label) for the per-node count layout to be exact
     if HOSTNAME in topo_keys:
@@ -110,12 +113,12 @@ def why_not(prep, config=None) -> Optional[str]:
         nv = np.asarray(ec.node_valid)
         trash = np.asarray(ec.domain_topo).shape[0] - 1
         if (nd[nv] == trash).any():
-            return "some valid nodes carry no hostname label"
+            return "features", "some valid nodes carry no hostname label"
         if len(np.unique(nd[nv])) != int(nv.sum()):
-            return "hostname domains are not node-identity (duplicate hostname labels)"
+            return "features", "hostname domains are not node-identity (duplicate hostname labels)"
     vmem = vmem_estimate(prep)
     if vmem > _VMEM_BUDGET:
-        return f"VMEM estimate {vmem / 1e6:.1f} MB exceeds the {_VMEM_BUDGET / 1e6:.0f} MB budget"
+        return "vmem", f"VMEM estimate {vmem / 1e6:.1f} MB exceeds the {_VMEM_BUDGET / 1e6:.0f} MB budget"
     return None
 
 

@@ -5,7 +5,7 @@ kube-scheduler FitError phrasings for the 11 filter plugins, plus the
 non-filter outcomes (missing pinned node, unknown scheduler profile,
 preemption victim). ``opensim-lint`` rule OSL901 enforces the registration:
 an inline reason literal at an ``UnscheduledPod(...)`` construction site is
-a lint error, so the XLA scan, the C++ engine, and every report/endpoint
+a lint error, so the XLA scan, the megakernel, and every report/endpoint
 render byte-identical diagnostics from one table.
 
 :class:`PlacementExplanation` is the typed per-pod decision-audit record the

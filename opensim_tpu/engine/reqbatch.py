@@ -14,9 +14,9 @@ each demultiplexed result is bit-identical to running that request alone —
 the same mask-flip argument ``drop_pods`` and the scenario sweeps rest on,
 and gated end-to-end by ``tests/test_admission.py``.
 
-Engine routing is ``select.batch``'s: the vmapped XLA scan (one compiled
-dispatch for the whole batch, request axis prepended by ``jax.vmap``) or
-sequential C++ scans. Either way the decode demultiplexes through the same
+The batch runs on the vmapped XLA scan: one compiled dispatch for the whole
+batch, request axis prepended by ``jax.vmap`` (the megakernel declines the
+request axis, ``select.DECLINES``). The decode demultiplexes through the same
 ``simulator.finish_decode`` tail the solo path uses, restoring bind state
 between requests so shared pod objects never leak one request's binds into
 another's report.
@@ -80,10 +80,9 @@ class BatchItem:
     drops: set = field(default_factory=set)
     explain: bool = False
     # the rider's request deadline (NOTES.md rough edge, ISSUE 9
-    # satellite): enforced BETWEEN sequential C++ rider scans, so an
-    # in-flight batch sheds expired riders with the typed 504 instead of
-    # running them to completion (the vmapped XLA path is one atomic
-    # dispatch and keeps queue-boundary-only enforcement)
+    # satellite): a rider expired before the dispatch is shed with the
+    # typed 504 (the vmapped XLA scan is one atomic dispatch, so
+    # enforcement stops at the queue boundary)
     deadline: Optional[Deadline] = None
 
 
@@ -201,22 +200,18 @@ def run_request_batch(
     state it mutates. Results are bit-identical to solo runs of each
     request (mask-invalid foreign pods never touch engine state).
 
-    Deadline shedding (ISSUE 9 satellite + ISSUE 15 satellite): on the
-    sequential C++ path the rider's :class:`Deadline` is re-checked
-    between scans — an expired rider's slot comes back as a typed
-    :class:`DeadlineExceeded` (``phase="schedule"``) instead of a result,
-    and its scan never runs. On the vmapped XLA path, riders already
-    expired BEFORE the dispatch are dropped from the request mask the
-    same way (their lane schedules nothing), so one slow queue wait can
-    never ride a whole batch; a batch already IN FLIGHT stays atomic by
-    design — the vmapped scan is one compiled dispatch with no host
-    boundary to shed at (the C++ sequential path has those boundaries and
-    sheds there).
+    Deadline shedding (ISSUE 9 satellite + ISSUE 15 satellite): riders
+    already expired BEFORE the dispatch are dropped from the request mask
+    (their lane schedules nothing) and come back as a typed
+    :class:`DeadlineExceeded` (``phase="schedule"``), so one slow queue
+    wait can never ride a whole batch; a batch already IN FLIGHT stays
+    atomic by design — the vmapped scan is one compiled dispatch with no
+    host boundary to shed at.
 
     Batched explain (ISSUE 15 satellite): a rider with ``explain=True``
     rides the shared dispatch like any other — the batch runs the
-    count_all scan variant (or the C++ generic path) so its per-pod fail
-    rows exist, and only that rider's decode pays the audit build."""
+    count_all scan variant so its per-pod fail rows exist, and only that
+    rider's decode pays the audit build."""
     return decode_request_batch(prep, items, dispatch_request_batch(prep, items))
 
 
@@ -226,13 +221,12 @@ def dispatch_request_batch(prep: Prepared, items: List[BatchItem]) -> BatchDispa
     function touches ONLY the derived prep's arrays and device buffers —
     never the shared pod objects, never the base entry's bind state — so
     the caller runs it WITHOUT the base-entry lock while the next batch's
-    prep (which does hold it) overlaps. The C++/XLA engines release the
-    GIL inside."""
-    from . import nativepath
-
+    prep (which does hold it) overlaps. The XLA scan releases the GIL
+    inside."""
     P = len(prep.ordered)
     pod_valid = _request_masks(prep, items)
-    engine_name, skips = select.batch(prep)
+    engine_name = "xla"
+    skips = {"megakernel": select.ladder(prep, select.Ask(shape="batch"))["megakernel"]}
 
     def _shed_rider(s: int, dl: Deadline) -> DeadlineExceeded:
         obs.event(
@@ -246,73 +240,57 @@ def dispatch_request_batch(prep: Prepared, items: List[BatchItem]) -> BatchDispa
             phase="schedule",
         )
 
-    outs: List[Optional[ScheduleOutput]] = []
     shed: Dict[int, BaseException] = {}
-    if engine_name == "native":
-        with obs.span("engine.native", requests=len(items), pods=P):
-            for s in range(len(items)):
-                dl = items[s].deadline
-                if dl is not None and dl.expired():
-                    # shed BEFORE this rider's scan: its deadline died while
-                    # earlier riders ran — same typed 504 a solo run's
-                    # schedule boundary raises, without the wasted scan
-                    shed[s] = _shed_rider(s, dl)
-                    outs.append(None)
-                    continue
-                outs.append(
-                    nativepath.schedule(prep, pod_valid[s], explain=items[s].explain)
-                )
-    else:
-        # pre-dispatch deadline shedding (ISSUE 15 satellite): an already-
-        # expired rider never enters the compiled dispatch — its lane's
-        # mask is all-invalid (it schedules nothing and cannot perturb the
-        # others, whose masks never included its pods anyway). Once the
-        # dispatch is running the batch is atomic by design: the vmapped
-        # scan has no host boundary to shed at.
-        for s, it in enumerate(items):
-            dl = it.deadline
-            if dl is not None and dl.expired():
-                shed[s] = _shed_rider(s, dl)
-                pod_valid[s, :] = False
-        # computed AFTER shedding: a shed rider's audit has no consumer,
-        # and the count_all variant is its own jit cache entry — an
-        # expired explain rider must not force that compile on the batch
-        explain_any = any(
-            it.explain for s, it in enumerate(items) if s not in shed
-        )
-        tmpl_p, _pv0, forced_p = pad_pod_stream(
-            prep.tmpl_ids, pod_valid[0], prep.forced
-        )
-        pv_all = np.zeros((pod_valid.shape[0], len(tmpl_p)), dtype=bool)
-        pv_all[:, :P] = pod_valid
-        pv_all = _pad_batch(pv_all)
-        with obs.span("engine.xla", requests=len(items), pods=P):
-            import jax.numpy as jnp
+    # pre-dispatch deadline shedding (ISSUE 15 satellite): an already-
+    # expired rider never enters the compiled dispatch — its lane's
+    # mask is all-invalid (it schedules nothing and cannot perturb the
+    # others, whose masks never included its pods anyway). Once the
+    # dispatch is running the batch is atomic by design: the vmapped
+    # scan has no host boundary to shed at.
+    for s, it in enumerate(items):
+        dl = it.deadline
+        if dl is not None and dl.expired():
+            shed[s] = _shed_rider(s, dl)
+            pod_valid[s, :] = False
+    # computed AFTER shedding: a shed rider's audit has no consumer,
+    # and the count_all variant is its own jit cache entry — an
+    # expired explain rider must not force that compile on the batch
+    explain_any = any(
+        it.explain for s, it in enumerate(items) if s not in shed
+    )
+    tmpl_p, _pv0, forced_p = pad_pod_stream(
+        prep.tmpl_ids, pod_valid[0], prep.forced
+    )
+    pv_all = np.zeros((pod_valid.shape[0], len(tmpl_p)), dtype=bool)
+    pv_all[:, :P] = pod_valid
+    pv_all = _pad_batch(pv_all)
+    with obs.span("engine.xla", requests=len(items), pods=P):
+        import jax.numpy as jnp
 
-            from ..obs.profile import launch_span, observed_jit_call
+        from ..obs.profile import launch_span, observed_jit_call
 
-            # the batch dispatch is the outer jit boundary: the compile
-            # watch observes it HERE, on the host, never under the trace
-            with launch_span("xla.launch", requests=len(items), pods=len(tmpl_p)):
-                batched = observed_jit_call(
-                    "batched_schedule",
-                    _batched_schedule,
-                    args=(
-                        prep.ec, prep.st0, jnp.asarray(tmpl_p), jnp.asarray(pv_all),
-                        jnp.asarray(forced_p),
-                    ),
-                    static={
-                        "features": prep.features, "unroll": scan_unroll(),
-                        "explain": explain_any,
-                    },
-                )
-            with obs.span("xla.wait"):
-                jax.block_until_ready(batched.chosen)
-        # ONE device→host conversion per output field for the whole batch
-        # (N redundant full-tensor transfers before — the vectorized path)
-        outs = list(_slice_outputs(batched, len(items), P))
-        for s in shed:
-            outs[s] = None
+        # the batch dispatch is the outer jit boundary: the compile
+        # watch observes it HERE, on the host, never under the trace
+        with launch_span("xla.launch", requests=len(items), pods=len(tmpl_p)):
+            batched = observed_jit_call(
+                "batched_schedule",
+                _batched_schedule,
+                args=(
+                    prep.ec, prep.st0, jnp.asarray(tmpl_p), jnp.asarray(pv_all),
+                    jnp.asarray(forced_p),
+                ),
+                static={
+                    "features": prep.features, "unroll": scan_unroll(),
+                    "explain": explain_any,
+                },
+            )
+        with obs.span("xla.wait"):
+            jax.block_until_ready(batched.chosen)
+    # ONE device→host conversion per output field for the whole batch
+    # (N redundant full-tensor transfers before — the vectorized path)
+    outs = list(_slice_outputs(batched, len(items), P))
+    for s in shed:
+        outs[s] = None
     return BatchDispatch(
         outs=outs, shed=shed, engine_name=engine_name, skips=skips,
         pod_valid=pod_valid,
@@ -339,13 +317,7 @@ def decode_request_batch(
                 results.append(shed[s])
                 continue
             out = outs[s]
-            nstats = getattr(out, "native_stats", None)
-            engine = EngineDecision(
-                name=dispatch.engine_name,
-                skipped=dict(dispatch.skips),
-                native_path=nstats["path"] if nstats else None,
-                native_steps=dict(nstats["steps"]) if nstats else None,
-            )
+            engine = EngineDecision(name=dispatch.engine_name, skipped=dict(dispatch.skips))
             # the drop mask by slice assignment (vectorized): foreign
             # riders' app ranges + this rider's own report-level drops —
             # the old per-rider `set(drops) | _foreign(...)` built and

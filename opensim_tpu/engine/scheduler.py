@@ -14,13 +14,13 @@ lowest node index by default. Structural results (counts, feasibility) are
 identical. The opt-in ``tie_seed`` (CLI ``--tie-break=sample[:seed]``)
 reproduces the reference's sampled distribution — seeded and reproducible —
 for distribution-level comparison runs; it forces the XLA scan (the
-megakernel and C++ engines stay lowest-index).
+megakernel stays lowest-index).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -54,15 +54,6 @@ class ScheduleOutput(NamedTuple):
     gpu_take: jnp.ndarray  # [P, Gd] f32 GPU slots packed per device
     static_fail: jnp.ndarray  # [U, 4] i32 — static filters (pin/unsched/taint/affinity)
     final_state: ScanState
-    # C++ engine only: which evaluation path ran ({"path", "steps",
-    # "profile"?}) — attribution so a silent incremental-cache disengage
-    # can never masquerade as a tuned number (None on the XLA/fast paths)
-    native_stats: Optional[dict] = None
-    # decision audit (explain mode, ISSUE 7): 11-slot per-filter reject
-    # totals accumulated across every scheduled step. The C++ engine fills
-    # this in-engine (ScanArgs.filter_rejects); the XLA path derives it
-    # host-side from the count_all per-pod rows (simulator._audit_rejects)
-    filter_rejects: Optional[object] = None
 
 
 def _step(ec: EncodedCluster, stat, feat, cfg, extra, st: ScanState, x, select_key=None,  # opensim-lint: jit-region

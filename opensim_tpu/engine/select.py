@@ -1,20 +1,20 @@
 """Which engine runs, in what order, and why not the others.
 
-The one module under ``opensim_tpu/`` that reads the six engine knobs and asks
-JAX for the platform and the device count in order to choose an engine. It
-holds the policy (:func:`policy`), the table of what each engine declines
-(:data:`DECLINES`) and the functions from a ``Prepared`` and an :class:`Ask` to
-the answer (:func:`ladder`, :func:`carry`, :func:`batch`), with
+The one module under ``opensim_tpu/`` that reads the engine knobs and asks
+JAX for the platform and the device count in order to choose an engine. The
+ladder has two rungs, the Pallas megakernel and the XLA scan, which serves
+everything. This module holds the policy (:func:`policy`), the table of what
+each engine declines (:data:`DECLINES`) and the functions from a ``Prepared``
+and an :class:`Ask` to the answer (:func:`ladder`, :func:`carry`), with
 :func:`kernel_failed` for a kernel that raised. Callers keep the running:
-spans, breakers, the ``try`` round the kernel. The envelopes stay with their
-engines (``fastpath.why_not``, ``nativepath.why_not``) and are asked last, so a
-run the kernel cannot take never imports Pallas (~1 s).
+spans, breakers, the ``try`` round the kernel. The kernel's envelope stays
+with it (``fastpath.why_not``) and is asked last, so a run the kernel cannot
+take never imports Pallas (~1 s).
 """
 
 from __future__ import annotations
 
 import logging
-import re
 from typing import Dict, NamedTuple, Optional, Set, Tuple
 
 import jax
@@ -30,7 +30,6 @@ class Policy(NamedTuple):
     devices: int
     interpret: bool  # OPENSIM_FASTPATH=interpret: the Pallas interpreter, asked for by name
     strict: bool  # OPENSIM_REQUIRE_TPU=1 (--backend tpu): a kernel failure is fatal
-    forced_native: bool  # OPENSIM_NATIVE=1 (--backend native)
     off: Dict[str, Optional[str]]  # rung -> the words of what switched it off
 
 
@@ -39,22 +38,15 @@ def policy() -> Policy:
     ``jax.default_backend``."""
     platform = jax.default_backend()
     interpret = envknobs.raw("OPENSIM_FASTPATH") == "interpret"
-    forced_native = envknobs.raw("OPENSIM_NATIVE") == "1"
-    megakernel = native = None
+    megakernel = None
     if platform != "tpu" and not interpret:
         # the kernel compiles for a TPU only; its interpreter is slower than the XLA scan
         megakernel = f"no TPU backend (jax.default_backend()={platform!r})"
     elif envknobs.raw("OPENSIM_DISABLE_FASTPATH"):
         megakernel = "disabled by --backend xla (OPENSIM_DISABLE_FASTPATH)"
-    elif forced_native:
-        megakernel = "disabled by --backend native (OPENSIM_NATIVE=1)"
-    if envknobs.raw("OPENSIM_DISABLE_NATIVE"):
-        native = "disabled by --backend xla (OPENSIM_DISABLE_NATIVE)"
-    elif platform == "tpu" and not forced_native:
-        native = "TPU backend present (the megakernel/XLA scan own the accelerator)"
     return Policy(
         platform, len(jax.devices()), interpret, envknobs.raw("OPENSIM_REQUIRE_TPU") == "1",
-        forced_native, {"megakernel": megakernel, "native": native},
+        {"megakernel": megakernel},
     )
 
 
@@ -77,23 +69,20 @@ class Ask(NamedTuple):
 _SHARDED = "{devices} devices: the sweep is sharded across them on the XLA scan"
 # What each engine declines, as (ask, reason) in the order its reasons take
 # precedence; what is not listed is served, and the XLA scan serves everything.
-# The C++ scan's other two (extra_plugins, a config with fit_ignored_cols) are
-# in nativepath.why_not, with the engine.
 DECLINES = {
     "megakernel": (
-        ("batch", "request-axis batches run on the vmapped XLA scan (or sequential C++ scans)"),
+        ("batch", "request-axis batches run on the vmapped XLA scan"),
         ("many_devices", _SHARDED),
         ("segments", "segmented multi-profile stream ({segments} segments)"),
-        # only the C++ generic path and the XLA count_all scan emit per-filter verdicts
-        ("explain", "explain mode audits per-filter verdicts (C++/XLA engines)"),
+        # only the XLA count_all scan emits per-filter verdicts
+        ("explain", "explain mode audits per-filter verdicts (XLA scan)"),
         # the profile's weights and its RequestedToCapacityRatio are served
         ("sched_config", "a scheduler config the kernel cannot compute ({gap})"),
         ("extra_plugins", "out-of-tree extra_plugins run on the XLA scan"),
-        ("tie_seed", "sampled tie-break runs on the C++ engine or XLA scan"),
-        ("start_state", "a stream from the caller's scan state runs on the C++ engine or XLA scan"),
+        ("tie_seed", "sampled tie-break runs on the XLA scan"),
+        ("start_state", "a stream from the caller's scan state runs on the XLA scan"),
         # a node mask is served: validity is a runtime row of the kernel
     ),
-    "native": (("many_devices", _SHARDED),),
     # the resident carry's reasons are the tokens of the xla.resident span and its counter
     "resident": (
         ("segments", "segments"),
@@ -129,9 +118,9 @@ def _declined(engine: str, asked: Set[str], **words) -> Optional[str]:
 
 
 def ladder(prep, ask: Ask = Ask(), pol: Optional[Policy] = None) -> Dict[str, Optional[str]]:
-    """For each rung (``megakernel``, ``native``, ``xla``): None, or the reason
-    it cannot run what is asked. The table first, then the policy, then the
-    engine's own envelope."""
+    """For each rung (``megakernel``, ``xla``): None, or the reason it cannot
+    run what is asked. The table first, then the policy, then the kernel's
+    own envelope."""
     pol = pol or policy()
     asked = _asked(prep, ask, pol.devices)
     words = {"segments": ask.segments, "devices": pol.devices, "gap": kernel_gap(ask.sched_config)}
@@ -139,57 +128,30 @@ def ladder(prep, ask: Ask = Ask(), pol: Optional[Policy] = None) -> Dict[str, Op
     if megakernel is None:
         from . import fastpath
 
-        megakernel = fastpath.why_not(prep)
-        if megakernel is not None:
+        miss = fastpath.why_not(prep)
+        if miss is not None:
+            megakernel = miss[1]
             log.info("megakernel envelope miss (%s): %s", ask.shape, megakernel)
-    native = _declined("native", asked, **words) or pol.off["native"]
-    if native is None:
-        from . import nativepath
-
-        native = nativepath.why_not(prep, ask.sched_config, ask.extra_plugins, ask.tie_seed)
-    return {"megakernel": megakernel, "native": native, "xla": None}
-
-
-_OVER = re.compile(r"\b([RUA])=\d+ > \d+ supported")
-
-
-def _envelope_token(engine: str, reason: str) -> str:
-    """An envelope's reason as a short token. The kernel's: the table axes
-    over their caps joined by ``+`` (``U``, ``A``, ``R``), ``vmem``,
-    ``topo_keys``, or ``features`` for a feature's table it has no rows for.
-    The C++ scan's: ``extra_plugins``, ``fit_ignored_cols``, ``rtcr``,
-    ``not_built``."""
-    if engine == "megakernel":
-        over = _OVER.findall(reason)
-        if over:
-            return "+".join(over)
-        if reason.startswith("VMEM estimate"):
-            return "vmem"
-        return "topo_keys" if "topology keys" in reason else "features"
-    if reason.startswith("engine not built"):
-        return "not_built"
-    if "RequestedToCapacityRatio" in reason:
-        return "rtcr"
-    return "extra_plugins" if "extra_plugins" in reason else "fit_ignored_cols"
+    return {"megakernel": megakernel, "xla": None}
 
 
 def turned_away(prep, ask: Ask, pol: Policy, rungs: Dict[str, Optional[str]]) -> Optional[Tuple[str, str]]:
-    """The first rung the policy left on that declined the run, with its
-    reason as a short token: the row's name in :data:`DECLINES` (the
+    """The megakernel, when the policy left it on and it declined the run,
+    with its reason as a short token: the row's name in :data:`DECLINES` (the
     ``sched_config`` row's with what the kernel cannot compute,
-    ``sched_config:disabled_filter``), else the token of the engine's envelope. None when that rung serves the run; a
-    rung the policy switched off turned nothing away."""
-    asked = _asked(prep, ask, pol.devices)
-    for engine in ("megakernel", "native"):
-        if pol.off[engine] is not None:
-            continue
-        if rungs[engine] is None:
-            return None
-        row = next((name for name, _why in DECLINES[engine] if name in asked), None)
-        if row == "sched_config":  # which part of the config: a token, not the prose of the reason
-            row = f"sched_config:{kernel_gap(ask.sched_config)}"
-        return engine, row or _envelope_token(engine, rungs[engine])
-    return None
+    ``sched_config:disabled_filter``), else the token of the kernel's envelope
+    (``fastpath.why_not``). None when the kernel serves the run; a kernel the
+    policy switched off turned nothing away."""
+    if pol.off["megakernel"] is not None or rungs["megakernel"] is None:
+        return None
+    row = next((name for name, _why in DECLINES["megakernel"] if name in _asked(prep, ask, pol.devices)), None)
+    if row == "sched_config":  # which part of the config: a token, not the prose of the reason
+        row = f"sched_config:{kernel_gap(ask.sched_config)}"
+    if row is None:
+        from . import fastpath
+
+        row = fastpath.why_not(prep)[0]
+    return "megakernel", row
 
 
 def decline_attrs(prep, away: Optional[Tuple[str, str]]) -> Dict[str, object]:
@@ -212,31 +174,6 @@ def carry(prep, ask: Ask = Ask()) -> Optional[str]:
     """The token under which the resident carry declines what is asked, None
     when it serves it (``resident.fetch`` then looks at its own data)."""
     return _declined("resident", _asked(prep, ask))
-
-
-def batch(prep) -> Tuple[str, Dict[str, str]]:
-    """A request batch's engine and the skip map its riders report. Under
-    ``OPENSIM_BATCH_ENGINE=auto`` sequential C++ scans where they can run and
-    win (one device, or ``--backend native``: ms-scale a request, no XLA
-    compile), else one vmapped XLA dispatch; ``xla`` / ``native`` force one."""
-    mode = envknobs.raw("OPENSIM_BATCH_ENGINE", "auto").strip().lower() or "auto"
-    if mode not in ("auto", "xla", "native"):
-        raise ValueError(f"OPENSIM_BATCH_ENGINE must be auto|xla|native, got {mode!r}")
-    pol = policy()
-    rungs = ladder(prep, Ask(shape="batch"), pol)
-    skips = {"megakernel": rungs["megakernel"]}
-    if mode == "native" and rungs["native"] is not None:
-        raise RuntimeError(
-            f"OPENSIM_BATCH_ENGINE=native but the C++ engine cannot run this stream: {rungs['native']}"
-        )
-    if mode == "native" or (
-        mode == "auto" and rungs["native"] is None and (pol.forced_native or pol.devices == 1)
-    ):
-        skips["xla"] = "OPENSIM_BATCH_ENGINE routed the batch to the C++ engine"
-        return "native", skips
-    if rungs["native"] is None:
-        skips["native"] = "request-axis batching dispatches ONE vmapped scan"
-    return "xla", skips
 
 
 _KERNEL = {  # the call's shape -> (what failed, what it falls back to)

@@ -72,19 +72,11 @@ class NodeStatus:
 class EngineDecision:
     """Which scheduling engine actually ran and why the others were skipped
     (VERDICT r4 #3: no silent engine fallbacks). ``name`` is one of
-    ``megakernel`` (Pallas), ``native`` (C++), ``xla`` (lax.scan);
-    ``skipped`` maps each engine that did NOT run to a one-line reason.
-
-    For the C++ engine, ``native_path`` names the evaluation path that
-    served the scheduled steps (``incremental`` / ``generic`` / ``mixed``)
-    and ``native_steps`` carries the per-path step counts — a silent
-    incremental-cache disengage is an attribution fact, not a guess from
-    wall-clock (ISSUE 4)."""
+    ``megakernel`` (Pallas), ``xla`` (lax.scan); ``skipped`` maps each
+    engine that did NOT run to a one-line reason."""
 
     name: str
     skipped: Dict[str, str] = field(default_factory=dict)
-    native_path: Optional[str] = None
-    native_steps: Optional[Dict[str, int]] = None
     # observability (ISSUE 5): the serving request's propagated
     # X-Simon-Request-Id, stamped by the REST layer so a decision can be
     # joined back to its flight-recorder trace; None for library callers
@@ -105,11 +97,10 @@ class EngineDecision:
     attribution: Optional[str] = None
 
     def describe(self) -> str:
-        base = self.name if self.native_path is None else f"{self.name}/{self.native_path}"
         if not self.skipped:
-            return base
+            return self.name
         why = "; ".join(f"{k}: {v}" for k, v in sorted(self.skipped.items()))
-        return f"{base} (skipped {why})"
+        return f"{self.name} (skipped {why})"
 
 
 @dataclass
@@ -496,19 +487,20 @@ def _xla_scan(ec, st, tmpl_ids, pod_valid, forced, nv_mask, head=None, **kwargs)
     return out if head is None else head.in_front_of(out)
 
 
-def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, skips, log):
+def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask):
     """Consecutive scans over contiguous same-profile segments, sharing the
     scheduling carry — the segmented multi-profile path
     (``utils.go:304-381``). Each segment runs the full padded stream with
     out-of-segment pods masked invalid (engines skip them without touching
     state), so binds happen in exact stream order; the final state of
-    segment k seeds segment k+1. Returns (ScheduleOutput, engine_name);
-    the output's static_fail is PER POD ([P, n_static], callers index it
+    segment k seeds segment k+1. Each segment runs on the XLA scan (the
+    megakernel declines segments). Returns the ScheduleOutput; its
+    static_fail is PER POD ([P, n_static], callers index it
     with sf_rows=arange) because static filter tables are config-dependent
     and failure attribution resolves per segment."""
     from ..obs import trace as obs
     from ..obs.metrics import RECORDER
-    from . import nativepath, resident
+    from . import resident
 
     P = len(tmpl_ids)
     n_dyn = kernels.NUM_FILTERS - kernels.F_PORTS
@@ -521,37 +513,19 @@ def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, ski
     gpu_take = np.zeros((P, Gd), np.float32)
     sf_pod = np.zeros((P, n_static), np.int32)
 
-    misses = {
-        select.ladder(prep, ask._replace(sched_config=cfg))["native"] for cfg, _, _ in segments
-    } - {None}
-    use_native = not misses
-    if misses:
-        skips["native"] = "; ".join(sorted(misses))
-        log.info("segmented run on the XLA scan: %s", skips["native"])
-
     st = prep.st0
     final_state = None
-    seg_stats = []
     for cfg, lo, hi in segments:
         seg_valid = np.zeros((P,), dtype=bool)
         seg_valid[lo:hi] = pod_valid[lo:hi]
-        engine = "native" if use_native else "xla"
-        RECORDER.count_engine_profile(engine, profile_of(cfg))
-        with obs.span(f"engine.{engine}", segment=f"{lo}:{hi}", profile=profile_of(cfg)):
-            if use_native:
-                out = nativepath.schedule(
-                    prep, seg_valid, config=cfg, node_valid=nv_mask,
-                    tie_seed=ask.tie_seed, st0=st, explain=ask.explain,
-                )
-                if out.native_stats is not None:
-                    seg_stats.append(out.native_stats)
-            else:
-                resident.fetch(prep, seg_valid, ask)  # declines, and says so
-                out = _xla_scan(
-                    prep.ec, st, tmpl_ids, seg_valid, forced, nv_mask,
-                    features=prep.features, config=cfg, extra_plugins=ask.extra_plugins,
-                    tie_seed=ask.tie_seed, explain=ask.explain,
-                )
+        RECORDER.count_engine_profile("xla", profile_of(cfg))
+        with obs.span("engine.xla", segment=f"{lo}:{hi}", profile=profile_of(cfg)):
+            resident.fetch(prep, seg_valid, ask)  # declines, and says so
+            out = _xla_scan(
+                prep.ec, st, tmpl_ids, seg_valid, forced, nv_mask,
+                features=prep.features, config=cfg, extra_plugins=ask.extra_plugins,
+                tie_seed=ask.tie_seed, explain=ask.explain,
+            )
         chosen[lo:hi] = np.asarray(out.chosen)[lo:hi]
         fail_counts[lo:hi] = np.asarray(out.fail_counts)[lo:hi]
         insufficient[lo:hi] = np.asarray(out.insufficient)[lo:hi]
@@ -563,33 +537,21 @@ def _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, ski
 
     from .scheduler import ScheduleOutput
 
-    merged_stats = None
-    if seg_stats:
-        counts = {"incremental": 0, "generic": 0, "full_evals": 0}
-        for st_ in seg_stats:
-            for k in counts:
-                counts[k] += int(st_["steps"].get(k, 0))
-        inc, gen = counts["incremental"], counts["generic"]
-        path = "mixed" if inc and gen else "incremental" if inc else "generic" if gen else "none"
-        merged_stats = {"path": path, "steps": counts}
-
-    stitched = ScheduleOutput(
+    return ScheduleOutput(
         chosen=chosen,
         fail_counts=fail_counts,
         insufficient=insufficient,
         gpu_take=gpu_take,
         static_fail=sf_pod,  # per POD, not per template (sf_rows=arange)
         final_state=final_state,
-        native_stats=merged_stats,
     )
-    return stitched, ("native" if use_native else "xla")
 
 
 def _run_engine_ladder(
     prep, segments, sched_config, pod_valid, forced, tmpl_ids, extra_plugins,
     tie_seed, nv_mask, ec, st0, log, explain=False, reasons=True,
 ):
-    """The engine fallback ladder (megakernel → C++ native → XLA scan) for
+    """The engine fallback ladder (megakernel → XLA scan) for
     one prepared stream: ``select`` says which rungs may run; here are the
     breaker gating and the runtime demotion. Returns ``(out, engine_name,
     skips, sf_rows, attribution)``, the last as ``EngineDecision`` has it.
@@ -621,9 +583,7 @@ def _run_engine_ladder(
     sf_rows = tmpl_ids  # decode: static_fail row per pod
     if segments is not None:
         skips["megakernel"] = rungs["megakernel"]
-        out, engine_name = _run_segments(
-            prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask, skips, log
-        )
+        out = _run_segments(prep, segments, pod_valid, forced, tmpl_ids, ask, nv_mask)
         sf_rows = np.arange(len(tmpl_ids), dtype=np.int32)
     elif rungs["megakernel"] is not None:
         skips["megakernel"] = rungs["megakernel"]
@@ -688,40 +648,6 @@ def _run_engine_ladder(
                     attribution = "rescan"
             rung.set(attribution=attribution)
             RECORDER.count_megakernel_attribution(attribution)
-    if out is None:
-        from . import nativepath
-
-        miss = rungs["native"]
-        native_breaker = breakers.engine_breaker("native")
-        if miss is None and not native_breaker.allow():
-            miss = native_breaker.describe_block()
-        if miss is None:
-            # C++ scan engine: identical placements to the XLA scan with
-            # exact in-stream failure attribution; the default on hosts
-            # without an accelerator (tests/test_native.py asserts parity).
-            # A RUNTIME failure (ABI drift past the size check, injected
-            # engine.compile fault, a crash in the .so) demotes this
-            # request to the XLA scan and counts against the breaker —
-            # the fallback ladder's bottom rung never silently loses work.
-            try:
-                with obs.span("engine.native"):
-                    out = nativepath.schedule(
-                        prep, pod_valid, config=sched_config, node_valid=nv_mask,
-                        tie_seed=tie_seed, explain=explain,
-                    )
-                native_breaker.record_success()
-                engine_name = "native"
-            except Exception as e:
-                native_breaker.record_failure(e)
-                skips["native"] = f"{type(e).__name__}: {e}"
-                log.warning(
-                    "native engine failed (%s: %s); falling back to the "
-                    "XLA scan", type(e).__name__, e,
-                )
-                out = None
-        else:
-            skips["native"] = miss
-            log.info("native engine skipped: %s", miss)
     if out is None:
         from . import resident
         away = select.turned_away(prep, ask, pol, rungs)
@@ -863,10 +789,9 @@ def simulate(
     `explain` (decision audit, ISSUE 7): attach one typed
     ``PlacementExplanation`` per pod plus the per-filter reject totals to
     ``result.engine`` (``explanations`` / ``filter_rejects`` /
-    ``explain_ctx`` for the deep evaluator). Runs on the C++ generic path
-    or the XLA count_all scan — engine-consistent by the reason-parity
-    gate — and costs nothing when False (the default compiled scan and the
-    incremental C++ path are untouched).
+    ``explain_ctx`` for the deep evaluator). Runs on the XLA count_all scan
+    and costs nothing when False (the default compiled scan is
+    untouched).
 
     `reasons=False`: the caller reads which pods failed and never why (the
     planner's first pass when a newNode template exists). A megakernel
@@ -976,21 +901,13 @@ def simulate(
             extra_plugins, tie_seed, nv_mask, ec, st0, log, explain=explain,
             reasons=reasons or enable_preemption,
         )
-        nstats = getattr(out, "native_stats", None)
-        engine = EngineDecision(
-            name=engine_name,
-            skipped=skips,
-            native_path=nstats["path"] if nstats else None,
-            native_steps=dict(nstats["steps"]) if nstats else None,
-            attribution=attribution,
-        )
+        engine = EngineDecision(name=engine_name, skipped=skips, attribution=attribution)
         # every rung that did NOT run is an instant demotion span, so
         # the flight-recorder tree carries exactly the attribution
         # EngineDecision.skipped reports (tests assert they match)
         for k, v in sorted(skips.items()):
             obs.event(f"engine.{k}.skipped", status="demoted", engine=k, reason=v)
-        engine_label = engine_name if nstats is None else f"{engine_name}/{nstats['path']}"
-        _sched_span.set(engine=engine_label)
+        _sched_span.set(engine=engine_name)
         if not enable_preemption:
             # preemption rewrites `chosen` in decode: emitting here
             # would report pods the preempt pass later schedules
@@ -1134,16 +1051,11 @@ def finish_decode(
         engine.explanations = explain_mod.build_explanations(
             ctx, custom_reasons, victims_of, drops
         )
-        # per-filter reject totals across ALL audited steps: the C++
-        # engine accumulated them in-engine (ScanArgs.filter_rejects,
-        # abi v4); the XLA/segmented paths derive the identical vector
-        # from the count_all per-pod rows
-        rejects_vec = getattr(out, "filter_rejects", None)
-        if rejects_vec is None:
-            rejects_vec = explain_mod.audit_rejects(
-                static_fail, sf_rows, fail_counts, pod_valid & ~forced
-            )
-        engine.filter_rejects = reasons.rejects_dict(rejects_vec)
+        # per-filter reject totals across ALL audited steps, from the
+        # count_all per-pod rows
+        engine.filter_rejects = reasons.rejects_dict(
+            explain_mod.audit_rejects(static_fail, sf_rows, fail_counts, pod_valid & ~forced)
+        )
     return unscheduled, statuses
 
 

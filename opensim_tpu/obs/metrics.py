@@ -99,9 +99,6 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_snapshot_fetch_retries_total": ("Snapshot fetch retry attempts", "counter"),
     "simon_snapshot_stale_served_total": ("Requests served from a stale snapshot", "counter"),
     "simon_stale_prep_retries_total": ("Stale prep-cache internal retries", "counter"),
-    "simon_native_steps_total": ("C++ engine scheduled steps by evaluation path", "counter"),
-    # cardinality contract: reason ∈ nativepath._BAIL_REASONS (11 values)
-    "simon_native_bail_total": ("Incremental-carry envelope bails by gate/flip reason", "counter"),
     "simon_engine_breaker_trips_total": ("Engine circuit-breaker trips", "counter"),
     "simon_engine_breaker_open": ("Engine breaker open (1) or closed (0)", "gauge"),
     "simon_faults_injected_total": ("Chaos faults injected by point", "counter"),
@@ -173,12 +170,12 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_resident_carry_total": (
         "XLA scans by outcome of the resident carry: started from it, built it first, or replayed in full", "counter",
     ),
-    # engine: megakernel | native | xla; features: the active
+    # engine: megakernel | xla; features: the active
     # feature flags joined by "+" (ops/kernels.py Features), or "none"
     "simon_engine_features_total": (
         "Scheduled streams by the engine that answered and the feature set that chose its kernels", "counter",
     ),
-    # engine: megakernel | native | xla
+    # engine: megakernel | xla
     "simon_masked_pass_total": (
         "Simulations over a masked node set (the planner's prep reuse) by the engine that answered", "counter",
     ),
@@ -192,22 +189,22 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Scenario blocks the megakernel's sweeps walked the pod stream for, by the scenarios a step holds: a packed sweep "
         "of S scenarios is ceil(S/8) blocks of 8 sublanes, an unpacked one S blocks of 1", "counter",
     ),
-    # engine: megakernel | native | xla; profile: default | weights | rtcr
+    # engine: megakernel | xla; profile: default | weights | rtcr
     # (engine/schedconfig.py profile_of)
     "simon_engine_profile_total": (
         "Scans of a stream or a sweep by the engine that ran them and the score profile of their scheduler config", "counter",
     ),
-    # engine: megakernel | native, the rung that turned the run away; reason:
+    # engine: megakernel, the rung that turned the run away; reason:
     # a row of select.DECLINES, or the envelope's token (U, A, R, vmem, topo_keys, ...)
     "simon_engine_declined_total": (
         "Streams and sweeps a faster engine turned away, by that engine and the short token of its reason", "counter",
     ),
-    # engine: megakernel | native | xla; kind: fraction | whole | multi
+    # engine: megakernel | xla; kind: fraction | whole | multi
     "simon_gpushare_pods_total": (
         "Pods placed with a gpu-share request by the engine that answered and by kind: a fraction of a device, one whole device, several slots",
         "counter",
     ),
-    # engine: megakernel | native | xla; kind: lvm | ssd | hdd
+    # engine: megakernel | xla; kind: lvm | ssd | hdd
     "simon_local_volumes_total": (
         "open-local claims of the placed pods by the engine that answered and by kind: an LVM volume, a whole ssd or hdd device",
         "counter",
@@ -285,7 +282,7 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
     "simon_jitcache_persistent_bytes": ("Bytes in the persistent XLA compile cache dir", "gauge"),
     "simon_jitcache_events_total": ("jax compilation-cache monitoring events by leaf name", "counter"),
     # aggregate phase profiles (obs/profile.py) — span names are the fixed
-    # instrumentation vocabulary (phases, engine rungs, native sub-phases)
+    # instrumentation vocabulary (phases, engine rungs, their sub-phases)
     "simon_phase_profile_calls_total": ("Spans folded into the cumulative profile, by span name", "counter"),
     "simon_phase_profile_seconds_total": ("Cumulative inclusive span seconds by span name", "counter"),
     "simon_phase_profile_exclusive_seconds_total": (

@@ -5,9 +5,7 @@ carries a tree of :class:`Span` objects through the whole serving path —
 snapshot fetch, prepare, encode, schedule (with one child per engine-ladder
 rung actually attempted), decode — plus instant *events* for the things the
 resilience layer does on the way: snapshot retries, breaker trips, engine
-demotions, prep-cache invalidations, fault injections. The C++ engine's
-``profile_out`` phase timings attach as child spans, so C++ scan time and
-host encode time appear in one tree.
+demotions, prep-cache invalidations, fault injections.
 
 Design constraints (the tentpole's "allocation-light and dormant-cheap"):
 
@@ -100,9 +98,9 @@ class Span:
     def child_from_seconds(self, name: str, seconds: float, status: str = "ok",
                            **attrs: Any) -> "Span":
         """Attach a synthetic completed child of ``seconds`` duration, laid
-        out sequentially from this span's start — how the C++ engine's
-        ``profile_out`` phase timings (measured inside the .so, no start
-        timestamps) appear in the same tree as host-side spans."""
+        out sequentially from this span's start — how work timed as one
+        total (a batch's shared phases, no start timestamps) appears in the
+        same tree as host-side spans."""
         child = Span(name, self._lay, attrs or None)
         child.end = self._lay + seconds
         child.status = status

@@ -211,7 +211,7 @@ def ports_filter(ec, st, u):
 def gc_row_of(ec) -> int:
     """Host-side resource-axis row of alibabacloud.com/gpu-count, -1 when
     absent. The single source for the engines' static `gc_row` parameter —
-    keep fastpath/nativepath/preemption in lockstep through this."""
+    keep fastpath/preemption in lockstep through this."""
     import numpy as np
 
     mask = np.asarray(ec.gc_mask)
@@ -696,252 +696,6 @@ def precompute_static(ec: EncodedCluster, cfg=None) -> StaticTables:  # opensim-
         spread_weight=ec.log_sizes[
             jnp.clip(sizes.astype(jnp.int32), 0, ec.log_sizes.shape[0] - 1)
         ],
-    )
-
-
-def _unique_rows_np(*arrays):
-    """(index, inverse) of the unique joint rows of per-template field
-    arrays — live-cluster replays dedup pods per PINNED NODE (U ≈ N
-    templates differing only in `pin`), but none of the static-table
-    computations read the pin, so computing on unique field rows and
-    scattering back turns an O(U·N·…) broadcast into O(U_eff·N·…) with
-    U_eff = the handful of genuinely distinct specs."""
-    import numpy as np
-
-    packed = np.concatenate(
-        [
-            np.ascontiguousarray(a.reshape(a.shape[0], -1))
-            .view(np.uint8)
-            .reshape(a.shape[0], -1)
-            for a in arrays
-        ],
-        axis=1,
-    )
-    _, idx, inv = np.unique(packed, axis=0, return_index=True, return_inverse=True)
-    return idx, inv
-
-
-def precompute_core_np(ec):
-    """The node_valid- and config-INDEPENDENT half of
-    :func:`precompute_static_np`: per-(template, node) filter masks and raw
-    score tables. Scenario sweeps compute this ONCE and re-fold each
-    scenario's node_valid through :func:`precompute_static_np` (the fold is
-    O(U·N); this core is the expensive broadcast part)."""
-    import numpy as np
-
-    f32 = np.float32
-    label_val = np.asarray(ec.label_val)
-    label_num = np.asarray(ec.label_num)
-    U = int(np.asarray(ec.req).shape[0])
-    N = int(label_val.shape[0])
-
-    def requirements_match(keys, ops, vals, nums):
-        # keys/ops/nums [Uc, ...]; vals [Uc, ..., Vv] → bool [Uc, N, ...]
-        keys = np.asarray(keys)
-        node_val = np.moveaxis(label_val[:, np.maximum(keys, 0)], 0, 1)
-        node_num = np.moveaxis(label_num[:, np.maximum(keys, 0)], 0, 1)
-        present = node_val >= 0
-        vals = np.asarray(vals)
-        in_set = (node_val[..., None] == vals[:, None]).any(-1)
-        ops_b = np.asarray(ops)[:, None]
-        nums_b = np.asarray(nums)[:, None]
-        res = np.ones_like(present)
-        with np.errstate(invalid="ignore"):
-            res = np.where(ops_b == V.OP_IN, present & in_set, res)
-            res = np.where(ops_b == V.OP_NOT_IN, ~(present & in_set), res)
-            res = np.where(ops_b == V.OP_EXISTS, present, res)
-            res = np.where(ops_b == V.OP_DOES_NOT_EXIST, ~present, res)
-            res = np.where(ops_b == V.OP_GT, node_num > nums_b, res)
-            res = np.where(ops_b == V.OP_LT, node_num < nums_b, res)
-        return res
-
-    t_key = np.asarray(ec.taint_key)
-    t_val = np.asarray(ec.taint_val)
-    t_eff = np.asarray(ec.taint_effect)
-
-    def taints_of(sl):
-        tol_valid = np.asarray(ec.tol_valid[sl])
-        tol_key = np.asarray(ec.tol_key[sl])[:, None, None, :]
-        tol_op = np.asarray(ec.tol_op[sl])[:, None, None, :]
-        tol_val = np.asarray(ec.tol_val[sl])[:, None, None, :]
-        tol_eff = np.asarray(ec.tol_effect[sl])[:, None, None, :]
-        key_ok = (tol_key == -1) | (tol_key == t_key[None, :, :, None])
-        eff_ok = (tol_eff == -1) | (tol_eff == t_eff[None, :, :, None])
-        val_ok = np.where(tol_op == V.TOL_EXISTS, True, tol_val == t_val[None, :, :, None])
-        empty_key_bad = (tol_key == -1) & (tol_op != V.TOL_EXISTS)
-        tolerated = (
-            key_ok & eff_ok & val_ok & ~empty_key_bad & tol_valid[:, None, None, :]
-        ).any(-1)  # [Uc, N, Tt]
-        blocking = (t_eff == V.EFFECT_NO_SCHEDULE) | (t_eff == V.EFFECT_NO_EXECUTE)
-        mask = ~((blocking[None] & ~tolerated).any(-1))
-        ttr = ((t_eff[None] == V.EFFECT_PREFER_NO_SCHEDULE) & ~tolerated).sum(
-            -1
-        ).astype(f32)
-        return mask, ttr
-
-    def affinity_of(sl):
-        ns_key = np.asarray(ec.ns_key[sl])
-        ns_val = np.asarray(ec.ns_val[sl])
-        nv = np.moveaxis(label_val[:, np.maximum(ns_key, 0)], 0, 1)
-        sel_ok = ((ns_key[:, None, :] < 0) | (nv == ns_val[:, None, :])).all(-1)
-        req_ok = requirements_match(
-            ec.aff_key[sl], ec.aff_op[sl], ec.aff_val[sl], ec.aff_num[sl]
-        )
-        term_ok = req_ok.all(-1)
-        any_term = (term_ok & np.asarray(ec.aff_term_valid[sl])[:, None, :]).any(-1)
-        return sel_ok & np.where(np.asarray(ec.has_req_aff[sl])[:, None], any_term, True)
-
-    def na_raw_of(sl):
-        req_ok = requirements_match(
-            ec.pna_key[sl], ec.pna_op[sl], ec.pna_val[sl], ec.pna_num[sl]
-        )
-        term_ok = req_ok.all(-1)  # [Uc, N, Pp]
-        w = np.asarray(ec.pna_weight[sl], f32)[:, None, :]
-        return np.where(term_ok, w, f32(0)).sum(-1, dtype=f32)
-
-    # chunk the U axis: the taint/affinity broadcasts are [Uc, N, X, Y]
-    per_u = max(
-        N * max(int(t_key.shape[1]) * int(np.asarray(ec.tol_key).shape[1]), 1),
-        N
-        * max(int(np.asarray(ec.aff_key).shape[1]), 1)
-        * max(int(np.asarray(ec.aff_key).shape[2]), 1)
-        * max(int(np.asarray(ec.aff_val).shape[3]), 1),
-    )
-    chunk = max(1, int(4e7 // max(per_u, 1)))
-
-    def dedup(fields, compute, outs):
-        """Compute per unique field rows, scatter to [U, ...] outputs."""
-        idx, inv = _unique_rows_np(*[np.asarray(f) for f in fields])
-        ueff = idx.shape[0]
-        parts = [np.empty((ueff,) + o.shape[1:], o.dtype) for o in outs]
-        for lo in range(0, ueff, chunk):
-            sel = idx[lo : lo + chunk]
-            vals = compute(sel)
-            if not isinstance(vals, tuple):
-                vals = (vals,)
-            for p, v in zip(parts, vals):
-                p[lo : lo + chunk] = v
-        for o, p in zip(outs, parts):
-            o[:] = p[inv]
-
-    taint = np.empty((U, N), bool)
-    aff = np.empty((U, N), bool)
-    na_raw = np.empty((U, N), f32)
-    tt_raw = np.empty((U, N), f32)
-    dedup(
-        (ec.tol_valid, ec.tol_key, ec.tol_op, ec.tol_val, ec.tol_effect),
-        taints_of, (taint, tt_raw),
-    )
-    dedup(
-        (ec.ns_key, ec.ns_val, ec.has_req_aff, ec.aff_term_valid,
-         ec.aff_key, ec.aff_op, ec.aff_val, ec.aff_num),
-        affinity_of, (aff,),
-    )
-    dedup(
-        (ec.pna_weight, ec.pna_key, ec.pna_op, ec.pna_val, ec.pna_num),
-        na_raw_of, (na_raw,),
-    )
-
-    # share_raw (see the jnp version for the formula provenance)
-    req_full = np.asarray(ec.req, f32)
-    alloc = np.asarray(ec.alloc, f32)
-    has_dev = (np.asarray(ec.node_gpu_mem) > 0).any(-1)
-    gc_mask = np.asarray(ec.gc_mask, bool)
-    dyn_active = bool((np.asarray(ec.gpu_mem) > 0).any()) and bool(
-        (np.where(gc_mask[None, :], req_full, 0.0) > 0).any()
-    )
-    share_tbl = np.empty((U, N), f32)
-
-    def share_of(sel):
-        req = req_full[sel].copy()
-        req[:, V.RES_PODS] = 0.0
-        with np.errstate(divide="ignore", invalid="ignore"):
-            avail = alloc[None] - req[:, None, :]
-            share = np.where(
-                avail == 0,
-                np.where(req[:, None, :] == 0, f32(0), f32(1)),
-                req[:, None, :] / avail,
-            )
-        share = np.where(alloc[None] > 0, share, f32(0))
-        share = np.where(
-            gc_mask[None, None, :] & has_dev[None, :, None] & dyn_active,
-            f32(0), share,
-        )
-        raw = np.maximum(share.max(-1), f32(0)) * f32(MAX_NODE_SCORE)
-        return np.where((req > 0).any(-1)[:, None], raw, f32(MAX_NODE_SCORE))
-
-    dedup((req_full,), share_of, (share_tbl,))
-
-    return {
-        "taint": taint,
-        "aff": aff,
-        "na_raw": na_raw,
-        "tt_raw": tt_raw,
-        "share_raw": share_tbl.astype(f32),
-    }
-
-
-def precompute_static_np(ec: EncodedCluster, cfg=None, core=None) -> StaticTables:
-    """Numpy mirror of :func:`precompute_static`, op-for-op in float32, so
-    the native C++ path builds its static tables with ZERO XLA compiles
-    (``--backend native`` must stay ms-scale cold — a 4.7 s precompute
-    compile dwarfed the 27 ms scan on small configs). Every arithmetic step
-    is either exact in f32 (integer-valued sums/counts, single IEEE
-    divisions, max-reductions) or a shared-table gather (spread weights),
-    so the tables are BITWISE equal to the jitted ones —
-    tests/test_native.py asserts it. Keep the two implementations in
-    lockstep. `core` reuses :func:`precompute_core_np` output across the
-    scenarios of one sweep."""
-    import numpy as np
-
-    from ..engine.schedconfig import DEFAULT_CONFIG
-
-    cfg = cfg or DEFAULT_CONFIG
-    f32 = np.float32
-    if core is None:
-        core = precompute_core_np(ec)
-    taint, aff = core["taint"], core["aff"]
-
-    node_valid = np.asarray(ec.node_valid, bool)
-    unsched = np.broadcast_to(~np.asarray(ec.unschedulable, bool)[None, :], taint.shape)
-    true_m = np.ones_like(taint)
-    fails = []
-    passed = np.broadcast_to(node_valid[None, :], taint.shape)
-    for m, enabled in (
-        (true_m, True),  # pin column stays zero (forced-bind path)
-        (unsched, cfg.f_unschedulable),
-        (taint, cfg.f_taints),
-        (aff, cfg.f_node_affinity),
-    ):
-        m = m if enabled else true_m
-        fails.append((passed & ~m).sum(-1))
-        passed = passed & m
-
-    Dp1 = int(np.asarray(ec.domain_topo).shape[0])
-    Tk = int(np.asarray(ec.node_domain).shape[1])
-    dom_present = np.zeros((Dp1,), f32)
-    nd = np.where(node_valid[:, None], np.asarray(ec.node_domain), Dp1 - 1)
-    dom_present[np.unique(nd)] = 1.0
-    domain_topo = np.asarray(ec.domain_topo)
-    sizes = np.array(
-        [
-            np.where(domain_topo[: Dp1 - 1] == tk, dom_present[: Dp1 - 1], 0.0).sum()
-            for tk in range(Tk)
-        ]
-    )
-    log_sizes = np.asarray(ec.log_sizes)
-    spread_weight = log_sizes[
-        np.clip(sizes.astype(np.int32), 0, log_sizes.shape[0] - 1)
-    ]
-
-    return StaticTables(
-        static_pass=passed,
-        aff_mask=aff,
-        static_fail=np.stack(fails, axis=-1).astype(np.int32),
-        na_raw=core["na_raw"],
-        tt_raw=core["tt_raw"],
-        share_raw=core["share_raw"],
-        spread_weight=spread_weight.astype(f32),
     )
 
 

@@ -96,9 +96,9 @@ def sweep_auto(
     forced_masks: Optional[np.ndarray] = None,
     config=None,
 ) -> SweepResult:
-    """A scenario sweep on the rung ``select`` gives it: sequential C++ scans,
-    ALL scenarios in one batched Pallas dispatch (the scenario axis rides the
-    kernel grid), or the vmapped XLA scan sharded across the devices."""
+    """A scenario sweep on the rung ``select`` gives it: ALL scenarios in one
+    batched Pallas dispatch (the scenario axis rides the kernel grid), or the
+    vmapped XLA scan sharded across the devices."""
     S = node_valid_masks.shape[0]
     if forced_masks is None:
         forced_masks = np.broadcast_to(prep.forced, (S, len(prep.forced)))
@@ -134,20 +134,6 @@ def sweep_auto(
     pol, ask = select.policy(), select.Ask(shape="sweep", sched_config=config)
     rungs = select.ladder(prep, ask, pol)
     profile = profile_of(config)
-    if rungs["native"] is None:
-        from ..engine import nativepath
-
-        # no XLA scan compile; the incremental template cache makes each
-        # scenario ms-scale on small configs
-        RECORDER.count_engine_profile("native", profile)
-        with obs.span("sweep.native", scenarios=S):
-            unscheduled, used, chosen, vg_used = nativepath.sweep(
-                prep, node_valid_masks, pod_valid_masks, forced_masks, config=config
-            )
-        return SweepResult(
-            unscheduled=jnp.asarray(unscheduled), used=jnp.asarray(used),
-            chosen=jnp.asarray(chosen), vg_used=jnp.asarray(vg_used),
-        )
     if rungs["megakernel"] is None:
         from ..engine import fastpath
 
@@ -220,10 +206,8 @@ def sweep_segmented(
     to the scenario axis. Out-of-segment pods are mask-invalid per scan, so
     binds happen in exact stream order and placements per scenario equal a
     solo segmented simulate of that scenario (gated by
-    tests/test_parallel.py). Sequential C++ scans where ``select`` gives
-    every segment that rung (chaining ``st0`` between segments), the
-    vmapped XLA scan with a batched carry otherwise."""
-    from ..engine import nativepath
+    tests/test_parallel.py). Each segment is one vmapped XLA dispatch with a
+    batched carry."""
     from ..engine.schedconfig import DEFAULT_CONFIG
 
     S = node_valid_masks.shape[0]
@@ -232,45 +216,15 @@ def sweep_segmented(
         (None if c == DEFAULT_CONFIG else c, lo, hi) for c, lo, hi in segments
     ]
     chosen = np.full((S, P), -1, dtype=np.int32)
-    ask = select.Ask(shape="sweep", segments=len(segments))
-    use_native = all(
-        select.ladder(prep, ask._replace(sched_config=cfg))["native"] is None for cfg, _, _ in segments
-    )
     vg0 = np.asarray(prep.st0.vg_free)
     nv_np = np.asarray(node_valid_masks, dtype=bool)
     from ..engine.schedconfig import profile_of
     from ..obs.metrics import RECORDER
 
     for cfg, _lo, _hi in segments:
-        RECORDER.count_engine_profile("native" if use_native else "xla", profile_of(cfg))
-    if use_native:
-        used = np.zeros((S,) + np.asarray(prep.st0.used).shape, np.float32)
-        vg_used = np.zeros((S,), np.float32)
-        for s in range(S):
-            st = prep.st0
-            pv_s = np.asarray(pod_valid_masks[s], dtype=bool)
-            for cfg, lo, hi in segments:
-                seg_valid = np.zeros((P,), dtype=bool)
-                seg_valid[lo:hi] = pv_s[lo:hi]
-                out = nativepath.schedule(
-                    prep, seg_valid, config=cfg, node_valid=nv_np[s],
-                    forced=np.asarray(forced_masks[s], bool), st0=st,
-                )
-                chosen[s, lo:hi] = np.asarray(out.chosen)[lo:hi]
-                st = out.final_state
-            used[s] = np.asarray(st.used)
-            vg_used[s] = float(
-                ((vg0 - np.asarray(st.vg_free)) * nv_np[s][:, None]).sum()
-            )
-        unscheduled = (
-            (chosen < 0) & np.asarray(pod_valid_masks, bool)
-        ).sum(axis=1).astype(np.int32)
-        return SweepResult(
-            unscheduled=jnp.asarray(unscheduled), used=jnp.asarray(used),
-            chosen=jnp.asarray(chosen), vg_used=jnp.asarray(vg_used),
-        )
-    # XLA path: batched carry across segments (each segment is one vmapped
-    # dispatch; S scenarios advance in lockstep through the profile chain)
+        RECORDER.count_engine_profile("xla", profile_of(cfg))
+    # batched carry across segments (each segment is one vmapped dispatch;
+    # S scenarios advance in lockstep through the profile chain)
     st_batch = jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(jnp.asarray(a), (S,) + jnp.asarray(a).shape),
         prep.st0,

@@ -23,8 +23,8 @@ Execution contract (``OPENSIM_CAMPAIGN_EXEC``):
   step-fingerprint equality, which proves the delta path bit-equal to a
   per-step full prepare.
 
-Both modes schedule through the same engines as ``simulate()`` (C++ scan
-on accelerator-less hosts, XLA scan otherwise), and a step's scheduling
+Both modes schedule through the same engine, the XLA scan of
+``simulate()``, and a step's scheduling
 set is always processed in campaign stream order, so placements — and the
 step fingerprints derived from them — are mode-independent.
 
@@ -959,16 +959,10 @@ class _Executor:
         return replay_state(prep, chosen, gpu, upto=P)
 
     def _run_engine(self, prep, pod_valid, forced_vec, nv, st0):
-        """The C++ scan where ``select`` gives that rung, else the XLA scan: a
-        step starts from its own carry and forced vector, which only they take."""
-        from ..engine import select, simulator
+        """The XLA scan: a step starts from its own carry and forced vector,
+        which the megakernel does not take (``select.DECLINES``)."""
+        from ..engine import simulator
 
-        if select.ladder(prep, select.Ask(node_mask=True, start_state=True))["native"] is None:
-            from ..engine import nativepath
-
-            return nativepath.schedule(
-                prep, pod_valid, node_valid=nv, forced=forced_vec, st0=st0
-            )
         return simulator._xla_scan(
             prep.ec, st0, prep.tmpl_ids, pod_valid, forced_vec, nv, features=prep.features
         )

@@ -12,7 +12,7 @@ modules here make the serving path survive faults:
   enforced at phase boundaries (snapshot, prepare, encode, schedule, decode)
   so an exhausted budget becomes a typed 504, not a hang;
 - ``breaker``   — per-engine :class:`CircuitBreaker` behind the megakernel →
-  C++ native → XLA scan fallback ladder: a *runtime* engine failure demotes
+  XLA scan fallback ladder: a *runtime* engine failure demotes
   the request and counts against the engine; repeated failures open the
   breaker (skip the doomed attempt), with half-open probing after a cooldown;
 - ``retry``     — :func:`retry_call`, bounded attempts with jittered

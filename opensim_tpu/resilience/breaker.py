@@ -1,9 +1,9 @@
 """Per-engine circuit breakers for the scheduling-engine fallback ladder.
 
-Which rungs of the ladder (megakernel → C++ native → XLA scan) may run is
+Which rungs of the ladder (megakernel → XLA scan) may run is
 ``engine/select.py``'s, before anything runs; this module is the
 *runtime*-failure half: when an engine that ``select`` passed fails
-while running (Mosaic compile error, ``ScanArgs`` ABI drift, device loss),
+while running (Mosaic compile error, device loss),
 ``engine/simulator.simulate()`` records the failure here and demotes the
 request one rung. After ``threshold`` consecutive failures the breaker opens
 — later requests skip the doomed attempt outright (the skip reason lands in
@@ -164,7 +164,7 @@ _REGISTRY_LOCK = threading.Lock()
 
 
 def engine_breaker(name: str) -> CircuitBreaker:
-    """Process-global breaker for engine ``name`` (megakernel/native/xla —
+    """Process-global breaker for engine ``name`` (megakernel/xla —
     the XLA scan is the floor of the ladder and never consults its breaker,
     but keeping it registered makes /metrics uniform)."""
     with _REGISTRY_LOCK:

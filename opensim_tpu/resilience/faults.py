@@ -15,7 +15,7 @@ point                  fires inside
                        attempts)
 ``prep.encode``        ``engine/simulator._prepare_inner`` before the encoder
                        build
-``engine.compile``     ``fastpath.schedule`` / ``nativepath.schedule`` entry
+``engine.compile``     ``fastpath.schedule`` entry
                        (a runtime engine failure → fallback ladder)
 ``engine.device_put``  ``engine/scheduler.to_device``
 ``cache.stale``        ``PrepareCache.check_fresh`` (raises

@@ -34,8 +34,8 @@ discipline in front of the engines:
   the batch lifecycle runs as a three-stage pipeline instead of one serial
   inline call. The dispatcher thread runs batch k+1's HOST PREP
   (expand + ``derive_with_app_slices`` + mask build, under the base-entry
-  lock) while the engine thread runs batch k's DISPATCH (the C++/XLA
-  engines release the GIL; dispatch reads only the derived prep's arrays,
+  lock) while the engine thread runs batch k's DISPATCH (the XLA scan
+  releases the GIL; dispatch reads only the derived prep's arrays,
   which generation swaps never mutate in place — ``twin_pod_delta`` builds
   a NEW entry from a forked encoder), and the decode thread demultiplexes
   batch k-1's results back onto its tickets. Stage handoffs are depth-1

@@ -9,9 +9,8 @@ unbounded thread creation.
 Two modes (``OPENSIM_WORKERS_MODE``):
 
 - ``thread`` (the ``auto`` default): a ``ThreadPoolExecutor``. The engine
-  phase already parallelizes past the GIL here — the C++ scan engine runs
-  through ctypes (which releases the GIL for the call) and XLA dispatches
-  block off-thread — so threads buy real concurrency for the dominant
+  phase already parallelizes past the GIL here — XLA dispatches block
+  off-thread — so threads buy real concurrency for the dominant
   cost. Host prep (expand + encode, pure Python/numpy) still contends.
 - ``process``: a forked worker pool for the GIL-bound host half. Workers
   are forked at pool start, inheriting the server's warm NodeArenas and

@@ -104,16 +104,6 @@ class _Metrics:
         self.snapshot_retries = 0
         self.snapshot_stale_served = 0
         self.stale_prep_retries = 0
-        # C++ engine path attribution (ISSUE 4): scheduled steps served by
-        # the incremental cache vs the generic re-evaluation — a silent
-        # cache disengage shows up here, not just in wall-clock
-        self.native_steps = {"incremental": 0, "generic": 0}
-        # bail-reason attribution (abi v5): WHY the incremental envelope
-        # disengaged, keyed by nativepath._BAIL_REASONS (sparse — only
-        # reasons actually seen), and which carry classes the incremental
-        # steps actually exercised (nativepath._CARRY_CLASSES)
-        self.native_bails: dict = {}
-        self.native_classes: dict = {}
 
     def record(self, endpoint: str, result: SimulateResult) -> None:
         # simulate wall time is no longer hand-summed here: the request
@@ -124,32 +114,6 @@ class _Metrics:
             self.simulations += 1
             self.pods_scheduled += sum(len(ns.pods) for ns in result.node_status)
             self.pods_unscheduled += len(result.unscheduled_pods)
-            if result.engine is not None and result.engine.native_steps:
-                for path in ("incremental", "generic"):
-                    self.native_steps[path] += int(
-                        result.engine.native_steps.get(path, 0)
-                    )
-                bails = result.engine.native_steps.get("bails") or {}
-                for reason, n in bails.items():
-                    self.native_bails[reason] = (
-                        self.native_bails.get(reason, 0) + int(n)
-                    )
-                classes = result.engine.native_steps.get("classes") or {}
-                for klass, n in classes.items():
-                    self.native_classes[klass] = (
-                        self.native_classes.get(klass, 0) + int(n)
-                    )
-
-    def native_snapshot(self) -> dict:
-        """Cumulative C++ path attribution for ``/api/debug/profile``
-        (rendered by ``simon profile``): step counts by evaluation path,
-        bail reasons, and per-carry-class incremental step counts."""
-        with self.lock:
-            return {
-                "steps": dict(self.native_steps),
-                "bails": dict(self.native_bails),
-                "classes": dict(self.native_classes),
-            }
 
     def bump(self, counter: str, n: int = 1) -> None:
         with self.lock:
@@ -206,16 +170,6 @@ class _Metrics:
                 f"simon_snapshot_stale_served_total {self.snapshot_stale_served}",
                 *hdr("simon_stale_prep_retries_total"),
                 f"simon_stale_prep_retries_total {self.stale_prep_retries}",
-                *hdr("simon_native_steps_total"),
-                *(
-                    f'simon_native_steps_total{{path="{esc(p)}"}} {n}'
-                    for p, n in sorted(self.native_steps.items())
-                ),
-                *hdr("simon_native_bail_total"),
-                *(
-                    f'simon_native_bail_total{{reason="{esc(r)}"}} {n}'
-                    for r, n in sorted(self.native_bails.items())
-                ),
             ]
         breakers = sorted(breaker_mod.all_breakers().items())
         lines += hdr("simon_engine_breaker_trips_total")
@@ -1183,8 +1137,8 @@ class SimonServer:
                         # audit is built from its own count_all fail rows
                         # over the shared derive
                         explain=tickets[s].explain,
-                        # in-flight shedding (ISSUE 9 satellite): the C++
-                        # sequential path re-checks this between rider scans
+                        # pre-dispatch shedding (ISSUE 9 satellite): a rider
+                        # expired before the dispatch schedules nothing
                         deadline=tickets[s].deadline,
                     )
                 )
@@ -1250,8 +1204,8 @@ class SimonServer:
         run_s = state.dispatch_s + (_time.monotonic() - t1)
         for t, res in zip(tickets, results):
             if isinstance(res, BaseException):
-                # a rider shed mid-batch (deadline expired between C++
-                # scans): transported like any executor error — the REST
+                # a rider shed from the batch (deadline expired before
+                # the dispatch): transported like any executor error — the REST
                 # thread re-raises into its typed ladder (504 phase=schedule)
                 t.resolve(error=res, stale=stale)
                 continue
@@ -1672,10 +1626,6 @@ def make_handler(server: SimonServer):
 
                 try:
                     payload = profile_mod.debug_payload()
-                    # C++ path attribution (abi v5): envelope engagement,
-                    # bail reasons, and carry-class coverage for the
-                    # `simon profile` native table
-                    payload["native"] = METRICS.native_snapshot()
                     adm = server.admission
                     if adm is not None:
                         # pipelined-admission stage aggregates (ISSUE 16):

@@ -1,6 +1,5 @@
 """Corpus mini contract registry (OSL1804 clean fixture): the registry,
-the policy constants, the struct field sets and both native sides all
-agree on every width."""
+the policy constants and the struct field sets all agree."""
 
 import numpy as np
 
@@ -19,8 +18,6 @@ ARENA_CONTRACTS = {
 STATE_CONTRACTS = {
     "used": ("FLOAT_DTYPE", ("N", "R")),
 }
-
-BUFFER_FIELD_ALIASES = {}
 
 KERNEL_ARG_CONTRACTS = {}
 

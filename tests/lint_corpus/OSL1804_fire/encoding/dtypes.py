@@ -1,8 +1,7 @@
 """Corpus mini contract registry (OSL1804 fire fixture).
 
-``node_domain`` is contracted INT_DTYPE (i32), but BOTH native sides in
-this fixture tree marshal it as i64 — the drift axis OSL1604 cannot see
-(the ctypes mirror and the C++ struct agree with each other)."""
+``node_valid`` is a field of the EncodedCluster next door but has no
+ARENA_CONTRACTS entry here: a field added without its contract."""
 
 import numpy as np
 
@@ -21,8 +20,6 @@ ARENA_CONTRACTS = {
 STATE_CONTRACTS = {
     "used": ("FLOAT_DTYPE", ("N", "R")),
 }
-
-BUFFER_FIELD_ALIASES = {}
 
 KERNEL_ARG_CONTRACTS = {}
 

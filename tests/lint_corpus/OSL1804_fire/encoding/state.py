@@ -1,4 +1,4 @@
-"""Corpus mini arena structs — field sets mirror the registry keys."""
+"""Corpus mini arena structs — ``node_valid`` has no registry entry."""
 
 from typing import NamedTuple
 
@@ -8,6 +8,7 @@ import numpy as np
 class EncodedCluster(NamedTuple):
     alloc: np.ndarray
     node_domain: np.ndarray
+    node_valid: np.ndarray
 
 
 class ScanState(NamedTuple):

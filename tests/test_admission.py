@@ -638,7 +638,6 @@ def test_xla_batch_sheds_expired_riders_before_dispatch(monkeypatch):
     from opensim_tpu.engine.simulator import AppResource, prepare
     from opensim_tpu.resilience.deadline import DeadlineExceeded
 
-    monkeypatch.setenv("OPENSIM_BATCH_ENGINE", "xla")
     cluster = _cluster()
     base = prepcache.CacheEntry("b|base", prepare(cluster, []))
     apps = []

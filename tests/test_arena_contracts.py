@@ -31,12 +31,6 @@ def test_every_contract_names_a_policy_constant():
             assert isinstance(axes, tuple), f"{fname}: axes must be a tuple"
 
 
-def test_buffer_aliases_point_at_contracted_fields():
-    for buf, fname in D.BUFFER_FIELD_ALIASES.items():
-        assert fname in D.ARENA_CONTRACTS or fname in D.STATE_CONTRACTS, (
-            f"alias {buf} -> {fname} names no contracted field")
-
-
 @pytest.fixture(scope="module")
 def prepared():
     from opensim_tpu.engine.simulator import AppResource, prepare

@@ -29,7 +29,6 @@ from opensim_tpu.obs.timeline import Sample, Timeline
 def _clean_state(monkeypatch):
     monkeypatch.delenv("OPENSIM_HEADROOM_PROFILES", raising=False)
     monkeypatch.delenv("OPENSIM_CAPACITY_TOPK", raising=False)
-    monkeypatch.delenv("OPENSIM_BATCH_ENGINE", raising=False)
     RECORDER.reset()
     yield
     RECORDER.reset()
@@ -408,65 +407,13 @@ def test_sampling_is_generation_keyed():
 
 
 # ---------------------------------------------------------------------------
-# in-flight batch deadline shedding (NOTES.md rough edge)
+# batch deadline shedding (NOTES.md rough edge)
 # ---------------------------------------------------------------------------
-
-
-def test_batch_sheds_expired_rider_between_native_scans(monkeypatch):
-    from opensim_tpu import native
-    from opensim_tpu.engine import reqbatch
-    from opensim_tpu.resilience.deadline import Deadline, DeadlineExceeded
-
-    if not native.available():
-        pytest.skip("C++ engine unavailable (sequential-scan path only)")
-    monkeypatch.setenv("OPENSIM_BATCH_ENGINE", "native")
-
-    cluster = _cluster()
-    apps = []
-    for name in ("app-a", "app-b", "app-c"):
-        rt = ResourceTypes()
-        rt.add(fx.make_fake_deployment(name, 2, "250m", "512Mi"))
-        apps.append(AppResource(name, rt))
-    prep = prepare(cluster, apps)
-    assert prep is not None and prep.app_slices is not None
-
-    clock = lambda: 100.0
-    live = Deadline(expires_at=10_000.0, budget_s=10_000.0, clock=clock)
-    dead = Deadline(expires_at=50.0, budget_s=1.0, clock=clock)
-    items = [
-        reqbatch.BatchItem(
-            cluster=cluster, apps=[apps[i]],
-            lo=prep.app_slices[i][0], hi=prep.app_slices[i][1],
-            deadline=[live, dead, live][i],
-        )
-        for i in range(3)
-    ]
-    results = reqbatch.run_request_batch(prep, items)
-    assert isinstance(results[1], DeadlineExceeded)
-    assert results[1].phase == "schedule"
-    # survivors ran to completion with their pods placed
-    for s in (0, 2):
-        assert not isinstance(results[s], BaseException)
-        placed = sum(len(ns.pods) for ns in results[s].node_status)
-        assert placed >= 2  # its own 2 replicas landed (plus base pods)
-
-    # bit-identity of a surviving rider vs a solo run of the same app
-    solo = simulate(cluster, [apps[0]])
-    def shape(res):
-        return sorted(
-            (ns.node.metadata.name, len(ns.pods)) for ns in res.node_status
-        )
-    assert shape(results[0]) == shape(solo)
 
 
 def test_rest_batch_transports_rider_shed_as_504(monkeypatch):
     """End-to-end through the admission batch executor: a rider whose
     deadline dies in flight resolves as the typed 504, the others as 200s."""
-    from opensim_tpu import native
-
-    if not native.available():
-        pytest.skip("C++ engine unavailable (sequential-scan path only)")
-    monkeypatch.setenv("OPENSIM_BATCH_ENGINE", "native")
     from opensim_tpu.resilience.deadline import Deadline, DeadlineExceeded
     from opensim_tpu.server import admission as admission_mod
     from opensim_tpu.server import rest
@@ -485,7 +432,7 @@ def test_rest_batch_transports_rider_shed_as_504(monkeypatch):
             )
         )
     # mark the dead ticket as NOT pre-expired so it reaches the batch (the
-    # in-flight case: alive at admission, dead between scans)
+    # in-flight case: alive at admission, dead before the dispatch)
     tickets[1]._expired_at_admission = False
     server._admitted_batch(tickets)
     assert tickets[0].error is None and tickets[0].result is not None

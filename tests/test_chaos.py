@@ -214,34 +214,40 @@ def test_prepare_stage_fault_fails_closed_then_recovers(point):
 # ---------------------------------------------------------------------------
 
 
-def _require_native():
-    from opensim_tpu import native
+@pytest.fixture
+def kernel_on_the_chip(monkeypatch):
+    """The megakernel as the chip's policy has it: a TPU backend, no
+    interpret mode (so a kernel failure demotes instead of raising), the
+    stream inside the envelope. The kernel itself runs in the interpreter."""
+    import jax
 
-    if not native.available():
-        pytest.skip("C++ native engine not built on this host")
+    from opensim_tpu.engine import fastpath
+
+    monkeypatch.delenv("OPENSIM_FASTPATH", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fastpath, "why_not", lambda prep, config=None: None)
+    monkeypatch.setattr(fastpath, "_resolve_interpret", lambda explicit: True)
 
 
-def test_engine_compile_fault_demotes_to_xla_with_identical_placements():
-    _require_native()
+def test_engine_compile_fault_demotes_to_xla_with_identical_placements(kernel_on_the_chip):
     cluster, apps = _cluster(), _apps()
     res0 = simulate(cluster, apps)
-    assert res0.engine.name == "native"
+    assert res0.engine.name == "megakernel"
 
     faults.inject("engine.compile", count=1, exc="runtime")
     res1 = simulate(_cluster(), _apps())
     # demoted one rung, visibly, with identical placements
     assert res1.engine.name == "xla"
-    assert "injected fault at engine.compile" in res1.engine.skipped["native"]
+    assert "injected fault at engine.compile" in res1.engine.skipped["megakernel"]
 
     assert _result_shape(res1) == _result_shape(res0)
-    # one failure does not open the breaker (threshold 3): next run is native
-    br = breaker_mod.engine_breaker("native")
+    # one failure does not open the breaker (threshold 3): next run is the kernel's
+    br = breaker_mod.engine_breaker("megakernel")
     assert br.failures_total == 1 and br.state() == "closed"
-    assert simulate(_cluster(), _apps()).engine.name == "native"
+    assert simulate(_cluster(), _apps()).engine.name == "megakernel"
 
 
-def test_breaker_trips_after_threshold_then_half_open_recovers(monkeypatch):
-    _require_native()
+def test_breaker_trips_after_threshold_then_half_open_recovers(monkeypatch, kernel_on_the_chip):
     from opensim_tpu.server.rest import METRICS
 
     monkeypatch.setenv("OPENSIM_BREAKER_THRESHOLD", "2")
@@ -250,24 +256,24 @@ def test_breaker_trips_after_threshold_then_half_open_recovers(monkeypatch):
     faults.inject("engine.compile", count=2, exc="runtime")
     for _ in range(2):
         assert simulate(_cluster(), _apps()).engine.name == "xla"
-    br = breaker_mod.engine_breaker("native")
+    br = breaker_mod.engine_breaker("megakernel")
     assert br.state() == "open" and br.trips_total == 1
 
-    # breaker open: the native attempt is skipped outright (no fault armed,
+    # breaker open: the kernel attempt is skipped outright (no fault armed,
     # yet the engine still demotes — the skip reason says breaker)
     res = simulate(_cluster(), _apps())
     assert res.engine.name == "xla"
-    assert "circuit breaker open" in res.engine.skipped["native"]
+    assert "circuit breaker open" in res.engine.skipped["megakernel"]
 
     # the trip is visible at /metrics
     text = METRICS.render()
-    assert 'simon_engine_breaker_trips_total{engine="native"} 1' in text
-    assert 'simon_engine_breaker_open{engine="native"} 1' in text
+    assert 'simon_engine_breaker_trips_total{engine="megakernel"} 1' in text
+    assert 'simon_engine_breaker_open{engine="megakernel"} 1' in text
 
     # cooldown elapses → half-open probe runs the real engine and closes
     br.clock = lambda: br._opened_at + br.cooldown_s + 1.0
     res = simulate(_cluster(), _apps())
-    assert res.engine.name == "native"
+    assert res.engine.name == "megakernel"
     assert br.state() == "closed"
 
 

@@ -31,9 +31,8 @@ FIVE = dict(TINY, nodes=30)
 DAEMONSET = "kube-system/daemonset-0"
 #: how a test asks for an engine on the CPU, and what the report then names
 ENGINES = {
-    "xla": ({"OPENSIM_DISABLE_NATIVE": "1"}, "xla"),
+    "xla": ({}, "xla"),
     "megakernel": ({"OPENSIM_FASTPATH": "interpret"}, "megakernel"),
-    "native": ({}, "native"),
 }
 NOTHING_DIFFERS = {
     "misplaced_pods": 0, "worst_score_gap": 0.0, "infeasible_pods": 0, "unscheduled_diff": 0,
@@ -66,7 +65,7 @@ def loaded(driver):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("seed", [5, 3000000023])
+@pytest.mark.parametrize("seed", [5, 3000000023, 17])
 @pytest.mark.parametrize("engine", list(ENGINES))
 def test_the_plan_replays_through_the_reference_pod_for_pod(tmp_path, monkeypatch, engine, seed):
     env, named = ENGINES[engine]
@@ -259,7 +258,6 @@ def find(tr, name):
 
 
 def test_the_spans_of_the_expansion_and_of_the_match_matrix(tmp_path, monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     tr = traced_plan(drive(tmp_path, TINY, 7))
     (expand,) = find(tr, "prep.expand")
     (daemons,) = find(tr, "expand.daemonsets")  # the cluster's; the apps have none
@@ -280,7 +278,6 @@ def test_the_spans_of_the_expansion_and_of_the_match_matrix(tmp_path, monkeypatc
 
 
 def test_the_count_read_paths_are_counted_scan_by_scan(tmp_path, monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     RECORDER.reset()
     traced_plan(drive(tmp_path, TINY, 7))
     lines = RECORDER.render_lines()
@@ -292,7 +289,6 @@ def test_the_count_read_paths_are_counted_scan_by_scan(tmp_path, monkeypatch):
 
 def test_a_run_the_kernel_declines_says_so_on_the_rung_that_runs(tmp_path, monkeypatch):
     monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     RECORDER.reset()
     driver = drive(tmp_path, FIVE, 7)
     tr = traced_plan(driver)
@@ -308,7 +304,6 @@ def test_a_run_the_kernel_declines_says_so_on_the_rung_that_runs(tmp_path, monke
 
 
 def test_a_run_the_kernel_takes_carries_no_such_attribute(tmp_path, monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")  # the kernel is off by policy on the CPU: nothing turned away
     RECORDER.reset()
     tr = traced_plan(drive(tmp_path, TINY, 7))
     (rung,) = find(tr, "engine.xla")

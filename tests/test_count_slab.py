@@ -236,7 +236,6 @@ def _run(ec, stat, carries, feasible, form, keys=None):
 def _readers_case(monkeypatch, window):
     """The cluster's preparation, its static tables, carries of counts and a
     feasible set; the carry read through windows of `window` columns."""
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     prep = prepare(*_cluster())
     ec, st0 = prep.ec, prep.st0
     f = prep.features
@@ -357,7 +356,6 @@ def test_the_scan_body_reads_the_count_carry_by_windows_of_whole_rows(selectors,
     from benchmarks.drivers import Context
     from opensim_tpu.engine.scheduler import _schedule_pods_jit, pad_pod_stream
     from opensim_tpu.planner.apply import Applier, Options
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
     with open(os.path.join(bench, "configs", "cl2-load-5k.json")) as f:
         config = json.load(f)

@@ -2,13 +2,12 @@
 and reaching definitions, effect inference as a call-graph fixpoint, jit
 region tracking through decorators/partials/markers, the forward taint
 lattice (flow-sensitive, sanitizer-aware, interprocedural via summaries),
-the tracer-leak pass, and the cross-language ABI parsers."""
+and the tracer-leak pass."""
 
 import ast
 import os
 import textwrap
 
-from opensim_tpu.analysis import abi
 from opensim_tpu.analysis import dataflow as dfm
 from opensim_tpu.analysis.core import ProjectContext, _make_context
 
@@ -452,40 +451,3 @@ def test_tracer_leak_instance_and_module_state():
     assert len(leaks) == 2
     assert any("self.last" in s for s in sinks)
     assert any("_LAST" in s for s in sinks)
-
-
-# ---------------------------------------------------------------------------
-# ABI parsers against the real abi-v5 sources
-# ---------------------------------------------------------------------------
-
-
-def test_abi_parsers_agree_on_real_sources():
-    cc = open(os.path.join(REPO, "opensim_tpu/native/scan_engine.cc")).read()
-    py = ast.parse(open(os.path.join(REPO, "opensim_tpu/native/__init__.py")).read())
-    cc_fields, cc_problems = abi.parse_cc_struct(cc)
-    py_fields, py_problems = abi.parse_py_layout(py)
-    assert cc_problems == [] and py_problems == []
-    assert len(cc_fields) == len(py_fields) > 100
-    assert abi.compare_layouts(cc_fields, py_fields) == []
-    assert abi.parse_cc_abi_version(cc) == abi.parse_py_abi_version(py) == 5
-
-
-def test_abi_compare_names_the_drifted_field():
-    cc = [("N", "i64"), ("R", "i64"), ("buf", "ptr:f32")]
-    swapped = [("R", "i64"), ("N", "i64"), ("buf", "ptr:f32")]
-    msgs = abi.compare_layouts(cc, swapped)
-    assert msgs and "order drift" in msgs[0] and "`N`" in msgs[0]
-    widened = [("N", "i64"), ("R", "i64"), ("buf", "ptr:f64")]
-    msgs = abi.compare_layouts(cc, widened)
-    assert msgs and "width drift" in msgs[0] and "`buf`" in msgs[0]
-    missing = cc[:-1]
-    msgs = abi.compare_layouts(cc, missing)
-    assert any("count drift" in m for m in msgs)
-    assert any("buf" in m for m in msgs)
-
-
-def test_abi_serial_wire_parsers():
-    cc = open(os.path.join(REPO, "opensim_tpu/native/serial_engine.cc")).read()
-    py = ast.parse(open(os.path.join(REPO, "opensim_tpu/native/serial.py")).read())
-    assert abi.parse_cc_serial_wire(cc) == (0x53524C31, 1)
-    assert abi.parse_py_serial_wire(py) == (0x53524C31, 1)

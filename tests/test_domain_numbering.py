@@ -33,11 +33,6 @@ HOST = "kubernetes.io/hostname"
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
 
 
-@pytest.fixture(autouse=True)
-def _xla_only(monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
-
-
 def node_major(monkeypatch):
     """Number the arenas' domains as the encoder did before: node by node,
     each node's keys in order."""

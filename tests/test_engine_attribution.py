@@ -29,28 +29,27 @@ def _apps(n_pods=6, opts=()):
 
 
 def test_engine_recorded_on_cpu_host():
-    """On an accelerator-less host the C++ engine owns the run; the
+    """On an accelerator-less host the XLA scan owns the run; the
     megakernel skip reason names the missing TPU backend."""
     res = simulate(_mini_cluster(), _apps())
     assert res.engine is not None
-    assert res.engine.name in ("native", "xla")
-    assert "megakernel" in res.engine.skipped
+    assert res.engine.name == "xla"
+    assert list(res.engine.skipped) == ["megakernel"]
     assert "no TPU backend" in res.engine.skipped["megakernel"]
-    if res.engine.name == "xla":  # native engine failed to build on this host
-        assert "native" in res.engine.skipped
     # the decision renders as one human-readable line (report footer)
     line = res.engine.describe()
     assert res.engine.name in line and "megakernel" in line
 
 
-def test_extra_plugins_force_xla_with_reasons():
+def test_extra_plugins_force_xla_with_reasons(monkeypatch):
     import jax.numpy as jnp
 
+    # the kernel switched on, so that it is the plugins that turn the run away
+    monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
     noop = ("filter", lambda ec, st, u: jnp.ones((ec.node_valid.shape[0],), bool))
     res = simulate(_mini_cluster(), _apps(), extra_plugins=(noop,))
     assert res.engine.name == "xla"
-    assert "extra_plugins" in res.engine.skipped["megakernel"]
-    assert "extra_plugins" in res.engine.skipped["native"]
+    assert res.engine.skipped == {"megakernel": "out-of-tree extra_plugins run on the XLA scan"}
 
 
 def test_envelope_miss_is_logged(monkeypatch, caplog):
@@ -84,7 +83,7 @@ def test_envelope_miss_is_logged(monkeypatch, caplog):
         )
     with caplog.at_level(logging.INFO, logger="opensim_tpu"):
         res = simulate(cluster, [AppResource("a", apps)])
-    assert res.engine.name in ("native", "xla")
+    assert res.engine.name == "xla"
     assert "topology keys" in res.engine.skipped["megakernel"]
     assert any("envelope miss" in r.message for r in caplog.records)
 

@@ -1,7 +1,7 @@
 """engine/select.py is the one place that says which engine runs: its table,
 one case per (ask, policy) row. The expected rungs and reason strings are the
 ones the gates wrote before they moved there (simulator._run_engine_ladder,
-fastpath.why_not, nativepath.why_not, resident._why_not, scenarios.sweep_auto,
+fastpath.why_not, resident._why_not, scenarios.sweep_auto,
 reqbatch.dispatch_request_batch at PR 30), not read back from the table."""
 
 import ast
@@ -12,31 +12,23 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
-from opensim_tpu import native
-from opensim_tpu.engine import select
+from opensim_tpu.engine import fastpath, reqbatch, select
 from opensim_tpu.engine.schedconfig import DEFAULT_CONFIG
 from opensim_tpu.engine.simulator import AppResource, prepare
 from opensim_tpu.models import ResourceTypes
 from opensim_tpu.models import fixtures as fx
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
-KNOBS = (
-    "OPENSIM_NATIVE", "OPENSIM_DISABLE_NATIVE", "OPENSIM_DISABLE_FASTPATH",
-    "OPENSIM_FASTPATH", "OPENSIM_REQUIRE_TPU", "OPENSIM_BATCH_ENGINE",
-)
+KNOBS = ("OPENSIM_DISABLE_FASTPATH", "OPENSIM_FASTPATH", "OPENSIM_REQUIRE_TPU")
 
 NO_TPU = "no TPU backend (jax.default_backend()='cpu')"
-TPU_OWNS = "TPU backend present (the megakernel/XLA scan own the accelerator)"
 XLA_OFF_MK = "disabled by --backend xla (OPENSIM_DISABLE_FASTPATH)"
-XLA_OFF_NATIVE = "disabled by --backend xla (OPENSIM_DISABLE_NATIVE)"
-NATIVE_OFF_MK = "disabled by --backend native (OPENSIM_NATIVE=1)"
-BATCH_MK = "request-axis batches run on the vmapped XLA scan (or sequential C++ scans)"
-BATCH_NATIVE = "request-axis batching dispatches ONE vmapped scan"
-BATCH_XLA = "OPENSIM_BATCH_ENGINE routed the batch to the C++ engine"
+BATCH_MK = "request-axis batches run on the vmapped XLA scan"
 PLUGINS_MK = "out-of-tree extra_plugins run on the XLA scan"
-PLUGINS_NATIVE = "out-of-tree extra_plugins are jittable callables (XLA scan only)"
+EXPLAIN_MK = "explain mode audits per-filter verdicts (XLA scan)"
 WEIGHTED = DEFAULT_CONFIG._replace(w_least=3.0)
 BINPACK = DEFAULT_CONFIG._replace(w_least=0.0, w_rtcr=1.0, rtcr_shape=((0.0, 0.0), (100.0, 100.0)),
                                   rtcr_resources=((0, 1.0), (1, 1.0)))
@@ -48,8 +40,6 @@ PLUGIN = (("filter", lambda ec, st, u: None),)
 
 @pytest.fixture(scope="module")
 def prep():
-    if not native.available():  # pragma: no cover - no C++ toolchain
-        pytest.skip("C++ engine unavailable")
     cluster = ResourceTypes()
     for i in range(4):
         cluster.nodes.append(fx.make_fake_node(f"n{i}", "8", "16Gi"))
@@ -58,7 +48,7 @@ def prep():
     return prepare(cluster, [AppResource("a", app)], node_pad=128)
 
 
-def _policy(monkeypatch, env=(), platform=None, devices=None, built=True):
+def _policy(monkeypatch, env=(), platform=None, devices=None):
     for name in KNOBS:
         monkeypatch.delenv(name, raising=False)
     for name, value in dict(env).items():
@@ -67,103 +57,87 @@ def _policy(monkeypatch, env=(), platform=None, devices=None, built=True):
         monkeypatch.setattr(jax, "default_backend", lambda: platform)
     if devices is not None:
         monkeypatch.setattr(jax, "devices", lambda *a: [object()] * devices)
-    if not built:
-        monkeypatch.setattr(native, "available", lambda: False)
-        monkeypatch.setattr(native, "load_error", lambda: "no compiler")
 
 
 INTERPRET = {"OPENSIM_FASTPATH": "interpret"}
-BACKEND_XLA = {"OPENSIM_DISABLE_FASTPATH": "1", "OPENSIM_DISABLE_NATIVE": "1"}
-BACKEND_NATIVE = {"OPENSIM_NATIVE": "1"}
+BACKEND_XLA = {"OPENSIM_DISABLE_FASTPATH": "1"}
+BACKEND_TPU = {"OPENSIM_REQUIRE_TPU": "1"}
 
-# id -> (policy, ask, megakernel, native); the XLA scan always takes it
+# id -> (policy, ask, megakernel); the XLA scan always takes it
 LADDER = {
     # the platform, and the two ways onto the kernel
-    "plain_cpu": ({}, {}, NO_TPU, None),
-    "plain_tpu": ({"platform": "tpu"}, {}, None, TPU_OWNS),
-    "plain_interpret": ({"env": INTERPRET}, {}, None, None),
+    "plain_cpu": ({}, {}, NO_TPU),
+    "plain_tpu": ({"platform": "tpu"}, {}, None),
+    "plain_interpret": ({"env": INTERPRET}, {}, None),
     # each ask alone: the asks come before the platform
-    "segments": ({}, {"segments": 2}, "segmented multi-profile stream (2 segments)", None),
-    "explain": ({}, {"explain": True}, "explain mode audits per-filter verdicts (C++/XLA engines)", None),
+    "segments": ({}, {"segments": 2}, "segmented multi-profile stream (2 segments)"),
+    "explain": ({}, {"explain": True}, EXPLAIN_MK),
     # a config declines the kernel only for what the kernel cannot compute: its score
     # weights and RequestedToCapacityRatio are served, a disabled filter and ignored fit columns are not
-    "sched_config": ({}, {"sched_config": NO_TAINTS}, GAP_FILTER, None),
-    "sched_config_weights": ({"env": INTERPRET}, {"sched_config": WEIGHTED}, None, None),
-    "sched_config_rtcr": (
-        {"env": INTERPRET}, {"sched_config": BINPACK}, None,
-        "RequestedToCapacityRatio runs on the megakernel or the XLA scan",
-    ),
+    "sched_config": ({}, {"sched_config": NO_TAINTS}, GAP_FILTER),
+    "sched_config_weights": ({"env": INTERPRET}, {"sched_config": WEIGHTED}, None),
+    "sched_config_rtcr": ({"env": INTERPRET}, {"sched_config": BINPACK}, None),
     # the default config is no config
-    "sched_config_default_is_still_a_config": ({"env": INTERPRET}, {"sched_config": DEFAULT_CONFIG}, None, None),
-    "fit_ignored_cols": (
-        {}, {"sched_config": DEFAULT_CONFIG._replace(fit_ignored_cols=(2,))},
-        GAP_COLS,
-        "NodeResourcesFitArgs ignoredResources need the XLA scan's per-column skip",
-    ),
-    "extra_plugins": ({}, {"extra_plugins": PLUGIN}, PLUGINS_MK, PLUGINS_NATIVE),
-    "tie_seed": ({}, {"tie_seed": 7}, "sampled tie-break runs on the C++ engine or XLA scan", None),
+    "sched_config_default_is_still_a_config": ({"env": INTERPRET}, {"sched_config": DEFAULT_CONFIG}, None),
+    "fit_ignored_cols": ({}, {"sched_config": DEFAULT_CONFIG._replace(fit_ignored_cols=(2,))}, GAP_COLS),
+    "extra_plugins": ({}, {"extra_plugins": PLUGIN}, PLUGINS_MK),
+    "tie_seed": ({}, {"tie_seed": 7}, "sampled tie-break runs on the XLA scan"),
     # the first gate that applies is the one recorded
-    "explain_before_tie_seed": (
-        {"env": INTERPRET}, {"explain": True, "tie_seed": 7},
-        "explain mode audits per-filter verdicts (C++/XLA engines)", None,
-    ),
+    "explain_before_tie_seed": ({"env": INTERPRET}, {"explain": True, "tie_seed": 7}, EXPLAIN_MK),
     "segments_before_all": (
         {"platform": "tpu"}, {"segments": 3, "explain": True, "extra_plugins": PLUGIN},
-        "segmented multi-profile stream (3 segments)", TPU_OWNS,
+        "segmented multi-profile stream (3 segments)",
     ),
     # a node mask is the kernel's validity row: served (PR 30)
-    "node_mask_interpret": ({"env": INTERPRET}, {"node_mask": True}, None, None),
-    "node_mask_tpu": ({"platform": "tpu"}, {"node_mask": True}, None, TPU_OWNS),
-    # a campaign step's own carry and forced vector: the C++ or XLA scan, as campaign._run_engine had it
+    "node_mask_interpret": ({"env": INTERPRET}, {"node_mask": True}, None),
+    "node_mask_tpu": ({"platform": "tpu"}, {"node_mask": True}, None),
+    # a campaign step's own carry and forced vector: the XLA scan, as campaign._run_engine has it
     "start_state": (
         {"env": INTERPRET}, {"node_mask": True, "start_state": True},
-        "a stream from the caller's scan state runs on the C++ engine or XLA scan", None,
+        "a stream from the caller's scan state runs on the XLA scan",
     ),
     # a caller that reads no failure reasons (ISSUE 36): every rung serves it as before; what it changes
     # is whether the ladder re-scans a kernel result with a mid-stream failure, not which rung runs
-    "no_reasons_cpu": ({}, {"reasons": False}, NO_TPU, None),
-    "no_reasons_interpret": ({"env": INTERPRET}, {"reasons": False, "node_mask": True}, None, None),
-    "no_reasons_tpu": ({"platform": "tpu"}, {"reasons": False}, None, TPU_OWNS),
-    "no_reasons_explain": (
-        {"env": INTERPRET}, {"reasons": False, "explain": True},
-        "explain mode audits per-filter verdicts (C++/XLA engines)", None,
+    "no_reasons_cpu": ({}, {"reasons": False}, NO_TPU),
+    "no_reasons_interpret": ({"env": INTERPRET}, {"reasons": False, "node_mask": True}, None),
+    "no_reasons_tpu": ({"platform": "tpu"}, {"reasons": False}, None),
+    "no_reasons_explain": ({"env": INTERPRET}, {"reasons": False, "explain": True}, EXPLAIN_MK),
+    # --backend cpu / xla / tpu
+    "backend_cpu": ({"platform": "cpu"}, {}, NO_TPU),
+    "backend_cpu_interpret": ({"env": INTERPRET, "platform": "cpu"}, {}, None),
+    "backend_xla_cpu": ({"env": BACKEND_XLA}, {}, NO_TPU),
+    "backend_xla_tpu": ({"env": BACKEND_XLA, "platform": "tpu"}, {}, XLA_OFF_MK),
+    "backend_xla_interpret": ({"env": {**BACKEND_XLA, **INTERPRET}}, {}, XLA_OFF_MK),
+    "backend_xla_sweep": ({"env": BACKEND_XLA, "platform": "tpu", "devices": 1}, {"shape": "sweep"}, XLA_OFF_MK),
+    "backend_tpu": ({"env": BACKEND_TPU, "platform": "tpu"}, {}, None),
+    "backend_tpu_batch": ({"env": BACKEND_TPU, "platform": "tpu"}, {"shape": "batch"}, BATCH_MK),
+    "backend_tpu_segments": (
+        {"env": BACKEND_TPU, "platform": "tpu"}, {"segments": 2}, "segmented multi-profile stream (2 segments)",
     ),
-    # --backend xla / native / tpu
-    "backend_xla_cpu": ({"env": BACKEND_XLA}, {}, NO_TPU, XLA_OFF_NATIVE),
-    "backend_xla_tpu": ({"env": BACKEND_XLA, "platform": "tpu"}, {}, XLA_OFF_MK, XLA_OFF_NATIVE),
-    "backend_native_cpu": ({"env": BACKEND_NATIVE}, {}, NO_TPU, None),
-    "backend_native_tpu": ({"env": BACKEND_NATIVE, "platform": "tpu"}, {}, NATIVE_OFF_MK, None),
-    "backend_native_unbuilt": (
-        {"env": BACKEND_NATIVE, "built": False}, {}, NO_TPU, "engine not built: no compiler",
-    ),
-    "cpu_unbuilt": ({"built": False}, {}, NO_TPU, "engine not built: no compiler"),
-    "tpu_never_builds": ({"platform": "tpu", "built": False}, {}, None, TPU_OWNS),
-    "backend_tpu": ({"env": {"OPENSIM_REQUIRE_TPU": "1"}, "platform": "tpu"}, {}, None, TPU_OWNS),
-    # a scenario sweep: the kernel and the C++ scans for exactly one device
-    "sweep_1_cpu": ({"devices": 1}, {"shape": "sweep"}, NO_TPU, None),
-    "sweep_1_interpret": ({"env": INTERPRET, "devices": 1}, {"shape": "sweep"}, None, None),
-    "sweep_1_tpu": ({"platform": "tpu", "devices": 1}, {"shape": "sweep"}, None, TPU_OWNS),
-    "sweep_4_tpu": ({"platform": "tpu", "devices": 4}, {"shape": "sweep"}, "4 devices", "4 devices"),
-    "sweep_8_cpu": ({}, {"shape": "sweep"}, "8 devices", "8 devices"),
-    "sweep_1_config": ({"env": INTERPRET, "devices": 1}, {"shape": "sweep", "sched_config": WEIGHTED}, None, None),
+    # a scenario sweep: the kernel for exactly one device
+    "sweep_1_cpu": ({"devices": 1}, {"shape": "sweep"}, NO_TPU),
+    "sweep_1_interpret": ({"env": INTERPRET, "devices": 1}, {"shape": "sweep"}, None),
+    "sweep_1_tpu": ({"platform": "tpu", "devices": 1}, {"shape": "sweep"}, None),
+    "sweep_4_tpu": ({"platform": "tpu", "devices": 4}, {"shape": "sweep"}, "4 devices"),
+    "sweep_8_cpu": ({}, {"shape": "sweep"}, "8 devices"),
+    "sweep_1_config": ({"env": INTERPRET, "devices": 1}, {"shape": "sweep", "sched_config": WEIGHTED}, None),
     "sweep_1_config_the_kernel_cannot_compute": (
-        {"env": INTERPRET, "devices": 1}, {"shape": "sweep", "sched_config": NO_TAINTS}, GAP_FILTER, None,
+        {"env": INTERPRET, "devices": 1}, {"shape": "sweep", "sched_config": NO_TAINTS}, GAP_FILTER,
     ),
 }
 
 
 @pytest.mark.parametrize("case", sorted(LADDER))
 def test_ladder(case, prep, monkeypatch):
-    pol, ask, megakernel, native_reason = LADDER[case]
+    pol, ask, want = LADDER[case]
     _policy(monkeypatch, **pol)
     got = select.ladder(prep, select.Ask(**ask))
-    assert list(got) == ["megakernel", "native", "xla"]
+    assert list(got) == ["megakernel", "xla"]
     assert got["xla"] is None
-    for rung, want in (("megakernel", megakernel), ("native", native_reason)):
-        if want is None or not want.endswith("devices"):
-            assert got[rung] == want, rung
-        else:  # a sweep's skips are never reported: the words are this PR's
-            assert got[rung].startswith(want), rung
+    if want is None or not want.endswith("devices"):
+        assert got["megakernel"] == want
+    else:  # a sweep's skips are never reported: the words are this PR's
+        assert got["megakernel"].startswith(want)
 
 
 # id -> (ask, has a base, the token of the xla.resident span)
@@ -193,45 +167,24 @@ def test_carry(case, prep):
     assert select.carry(prep, select.Ask(**ask, reasons=False)) == want
 
 
-# id -> (policy, engine, skips) or (policy, the error)
+# id -> policy: a request batch runs on the vmapped XLA scan under every policy,
+# and its riders report the megakernel's skip
 BATCH = {
-    "auto_8_cpu": ({}, "xla", {"megakernel": BATCH_MK, "native": BATCH_NATIVE}),
-    "auto_1_cpu": ({"devices": 1}, "native", {"megakernel": BATCH_MK, "xla": BATCH_XLA}),
-    "auto_1_tpu": ({"devices": 1, "platform": "tpu"}, "xla", {"megakernel": BATCH_MK}),
-    "auto_backend_native": ({"env": BACKEND_NATIVE}, "native", {"megakernel": BATCH_MK, "xla": BATCH_XLA}),
-    "auto_backend_xla": ({"env": BACKEND_XLA, "devices": 1}, "xla", {"megakernel": BATCH_MK}),
-    "xla": (
-        {"env": {"OPENSIM_BATCH_ENGINE": "xla"}, "devices": 1}, "xla",
-        {"megakernel": BATCH_MK, "native": BATCH_NATIVE},
-    ),
-    "native": (
-        {"env": {"OPENSIM_BATCH_ENGINE": " Native "}}, "native", {"megakernel": BATCH_MK, "xla": BATCH_XLA},
-    ),
-    "native_cannot_run": (
-        {"env": {"OPENSIM_BATCH_ENGINE": "native", "OPENSIM_DISABLE_NATIVE": "1"}},
-        (RuntimeError, "OPENSIM_BATCH_ENGINE=native but the C\\+\\+ engine cannot run this stream: disabled by"),
-    ),
-    "bad_value": (
-        {"env": {"OPENSIM_BATCH_ENGINE": "pallas"}},
-        (ValueError, "OPENSIM_BATCH_ENGINE must be auto\\|xla\\|native, got 'pallas'"),
-    ),
+    "cpu": {},
+    "interpret": {"env": INTERPRET},
+    "tpu": {"platform": "tpu"},
+    "backend_xla": {"env": BACKEND_XLA},
+    "backend_tpu": {"env": BACKEND_TPU, "platform": "tpu"},
 }
 
 
 @pytest.mark.parametrize("case", sorted(BATCH))
 def test_batch(case, prep, monkeypatch):
-    pol, *want = BATCH[case]
-    _policy(monkeypatch, **pol)
-    if len(want) == 1:
-        with pytest.raises(want[0][0], match=want[0][1]):
-            select.batch(prep)
-    else:
-        assert select.batch(prep) == tuple(want)
-
-
-def test_a_bad_batch_mode_does_not_reach_a_plain_stream(prep, monkeypatch):
-    _policy(monkeypatch, env={"OPENSIM_BATCH_ENGINE": "pallas"})
-    assert select.ladder(prep)["native"] is None
+    _policy(monkeypatch, **BATCH[case])
+    rider = reqbatch.BatchItem(cluster=None, apps=[], lo=0, hi=len(prep.ordered))
+    dispatch = reqbatch.dispatch_request_batch(prep, [rider])
+    assert (dispatch.engine_name, dispatch.skips) == ("xla", {"megakernel": BATCH_MK})
+    assert (dispatch.outs[0].chosen >= 0).all() and dispatch.shed == {}
 
 
 class _Breaker:
@@ -281,13 +234,13 @@ def test_kernel_failed(case, monkeypatch):
 def test_policy_is_read_afresh(monkeypatch):
     _policy(monkeypatch)
     first = select.policy()
-    assert (first.platform, first.interpret, first.strict, first.forced_native) == ("cpu", False, False, False)
-    _policy(monkeypatch, env={**INTERPRET, "OPENSIM_REQUIRE_TPU": "1", **BACKEND_NATIVE}, platform="tpu", devices=4)
+    assert (first.platform, first.interpret, first.strict) == ("cpu", False, False)
+    _policy(monkeypatch, env={**INTERPRET, **BACKEND_TPU}, platform="tpu", devices=4)
     second = select.policy()
-    assert second[:5] == ("tpu", 4, True, True, True)
+    assert second[:4] == ("tpu", 4, True, True)
     # exact values: `1` and `interpret`, as the knobs' registry says
-    _policy(monkeypatch, env={"OPENSIM_FASTPATH": "1", "OPENSIM_REQUIRE_TPU": "true", "OPENSIM_NATIVE": "yes"})
-    assert select.policy()[2:5] == (False, False, False)
+    _policy(monkeypatch, env={"OPENSIM_FASTPATH": "1", "OPENSIM_REQUIRE_TPU": "true"})
+    assert select.policy()[2:4] == (False, False)
 
 
 def _code_strings(tree):
@@ -305,7 +258,7 @@ def _code_strings(tree):
 
 def test_select_is_the_only_reader():
     """No module but engine/select.py (and the registry, and the CLI that
-    writes them) names one of the six knobs in code, and nothing in engine/,
+    writes them) names one of the three knobs in code, and nothing in engine/,
     parallel/ or planner/ asks the platform but select."""
     allowed = {"engine/select.py", "utils/envknobs.py", "cli/main.py"}
     offenders = []
@@ -336,7 +289,7 @@ def test_a_plain_simulate_on_the_cpu_does_not_import_pallas():
         "cluster.nodes.append(fx.make_fake_node('n0', '8', '16Gi'))\n"
         "app.deployments.append(fx.make_fake_deployment('web', 2, '1', '1Gi'))\n"
         "res = simulate(cluster, [AppResource('a', app)])\n"
-        "assert res.engine.name in ('native', 'xla'), res.engine\n"
+        "assert res.engine.name == 'xla', res.engine\n"
         "assert 'no TPU backend' in res.engine.skipped['megakernel']\n"
         "loaded = [m for m in sys.modules if m.endswith(('ops.pallas_scan', 'engine.fastpath'))]\n"
         "assert not loaded, loaded\n"
@@ -352,8 +305,8 @@ def test_a_plain_simulate_on_the_cpu_does_not_import_pallas():
 # id -> (policy, ask, the rung that turned the run away and the token of its reason)
 TURNED_AWAY = {
     "the_kernel_serves_it": ({"env": INTERPRET}, {}, None),
-    # a rung the policy switched off turned nothing away; the next one is asked
-    "no_tpu_and_the_cpp_scan_serves_it": ({}, {}, None),
+    # a kernel the policy switched off turned nothing away
+    "no_tpu": ({}, {}, None),
     "backend_xla": ({"env": BACKEND_XLA}, {"explain": True}, None),
     # the row's name in DECLINES
     "batch": ({"env": INTERPRET}, {"shape": "batch"}, ("megakernel", "batch")),
@@ -371,14 +324,11 @@ TURNED_AWAY = {
     "tie_seed": ({"env": INTERPRET}, {"tie_seed": 7}, ("megakernel", "tie_seed")),
     "start_state": ({"env": INTERPRET}, {"start_state": True}, ("megakernel", "start_state")),
     "the_first_row_that_applies": ({"env": INTERPRET}, {"explain": True, "tie_seed": 7}, ("megakernel", "explain")),
-    # with the kernel switched off, the C++ scan is the rung that can turn a run away
-    "native_many_devices": ({}, {"shape": "sweep"}, ("native", "many_devices")),
-    "native_extra_plugins": ({}, {"extra_plugins": PLUGIN}, ("native", "extra_plugins")),
-    "native_fit_ignored_cols": (
-        {}, {"sched_config": DEFAULT_CONFIG._replace(fit_ignored_cols=(2,))}, ("native", "fit_ignored_cols"),
-    ),
-    "native_rtcr": ({}, {"sched_config": BINPACK}, ("native", "rtcr")),
-    "native_not_built": ({"built": False}, {}, ("native", "not_built")),
+    # on the chip's policy: the kernel is on, so a row turns the run away
+    "tpu_batch": ({"platform": "tpu"}, {"shape": "batch"}, ("megakernel", "batch")),
+    "tpu_segments": ({"env": BACKEND_TPU, "platform": "tpu"}, {"segments": 2}, ("megakernel", "segments")),
+    "tpu_one_device_sweep_is_served": ({"platform": "tpu", "devices": 1}, {"shape": "sweep"}, None),
+    "backend_xla_on_tpu": ({"env": BACKEND_XLA, "platform": "tpu"}, {"shape": "batch"}, None),
 }
 
 
@@ -396,26 +346,115 @@ def test_turned_away(case, prep, monkeypatch):
         assert attrs == {"declined": ":".join(want), "templates": 1, "selectors": 1, "pinned_pods": 0}
 
 
-# the words of fastpath.why_not -> the token
+def _shaped(prep, **shapes):
+    """``prep`` with encoder tables of other shapes: the envelope reads
+    only their widths, so each is a broadcast zero that holds no memory."""
+    arrays = {name: np.broadcast_to(np.zeros((1,) * len(shape), bool), shape) for name, shape in shapes.items()}
+    return dataclasses.replace(prep, ec_np=prep.ec_np._replace(**arrays))
+
+
+def _over_vmem(prep, monkeypatch):
+    monkeypatch.setattr(fastpath, "vmem_estimate", lambda prep, sublanes=1: 12_300_000)
+    return prep
+
+
+def _high_port(prep, monkeypatch):
+    ports = np.full(prep.ec_np.ports.shape, 64, prep.ec_np.ports.dtype)
+    return dataclasses.replace(
+        prep, features=prep.features._replace(ports=True), ec_np=prep.ec_np._replace(ports=ports)
+    )
+
+
+def _unlabeled_node(prep, monkeypatch):
+    ec = prep.ec_np
+    node_domain = np.array(ec.node_domain)
+    node_domain[0, prep.meta.vocab.topo_keys.items().index(fastpath.HOSTNAME)] = ec.domain_topo.shape[0] - 1
+    return dataclasses.replace(prep, ec_np=ec._replace(node_domain=node_domain))
+
+
+def _five_zone_keys(prep, monkeypatch):
+    keys = [f"example.com/k{i}" for i in range(5)]
+    cluster = ResourceTypes()
+    for i in range(4):
+        labels = {key: f"d{i % 2}" for key in keys}
+        cluster.nodes.append(fx.make_fake_node(f"n{i}", "8", "16Gi", "110", fx.with_labels(labels)))
+    spread = [
+        {"maxSkew": 1, "topologyKey": key, "whenUnsatisfiable": "ScheduleAnyway",
+         "labelSelector": {"matchLabels": {"app": "web"}}}
+        for key in keys
+    ]
+    app = ResourceTypes()
+    app.deployments.append(fx.make_fake_deployment(
+        "web", 4, "1", "1Gi", fx.with_pod_labels({"app": "web"}), fx.with_topology_spread(spread)))
+    return prepare(cluster, [AppResource("a", app)], node_pad=128)
+
+
+def _duplicate_hostname(prep, monkeypatch):
+    ec = prep.ec_np
+    node_domain = np.array(ec.node_domain)
+    host = prep.meta.vocab.topo_keys.items().index(fastpath.HOSTNAME)
+    node_domain[1, host] = node_domain[0, host]
+    return dataclasses.replace(prep, ec_np=ec._replace(node_domain=node_domain))
+
+
+def _feature(flag, **shapes):
+    """A feature switched on with one of its tables wider than the kernel's rows."""
+
+    def build(prep, monkeypatch):
+        shaped = _shaped(prep, **shapes)
+        return dataclasses.replace(shaped, features=prep.features._replace(**{flag: True}))
+
+    return build
+
+
+def _tables(**shapes):
+    def build(prep, monkeypatch):
+        ec, grown = prep.ec_np, {}
+        U, A, R = shapes.get("U"), shapes.get("A"), shapes.get("R")
+        if U is not None:
+            grown["req"] = (U,) + ec.req.shape[1:]
+        if A is not None or U is not None:
+            grown["matches_sel"] = (U or ec.matches_sel.shape[0], A or ec.matches_sel.shape[1])
+        if R is not None:
+            grown["alloc"] = (ec.alloc.shape[0], R)
+        return _shaped(prep, **grown)
+
+    return build
+
+
+# fastpath.why_not's words -> (its token, what takes the prep there); the words are the
+# ones the declined attribute and the engine's skip map carry, unchanged since PR 36
 ENVELOPE = {
-    "table sizes outside envelope: U=10450 > 2048 supported, A=5300 > 64 supported": "U+A",
-    "table sizes outside envelope: U=3001 > 2048 supported": "U",
-    "table sizes outside envelope: A=80 > 64 supported": "A",
-    "table sizes outside envelope: R=9 > 8 supported, U=2120 > 2048 supported": "R+U",
-    "VMEM estimate 12.3 MB exceeds the 10 MB budget": "vmem",
-    "5 non-hostname topology keys > 4 supported": "topo_keys",
-    "port-vocab ids >=64 exceed the 64 padded port rows": "features",
-    "some valid nodes carry no hostname label": "features",
+    "table sizes outside envelope: U=10450 > 2048 supported, A=5300 > 64 supported": ("U+A", _tables(U=10450, A=5300)),
+    "table sizes outside envelope: U=3001 > 2048 supported": ("U", _tables(U=3001)),
+    "table sizes outside envelope: A=80 > 64 supported": ("A", _tables(A=80)),
+    "table sizes outside envelope: R=9 > 8 supported": ("R", _tables(R=9)),
+    "table sizes outside envelope: R=9 > 8 supported, U=2120 > 2048 supported": ("R+U", _tables(R=9, U=2120)),
+    "VMEM estimate 12.3 MB exceeds the 10 MB budget": ("vmem", _over_vmem),
+    "5 non-hostname topology keys > 4 supported": ("topo_keys", _five_zone_keys),
+    "port-vocab ids >=64 exceed the 64 padded port rows": ("features", _high_port),
+    "some valid nodes carry no hostname label": ("features", _unlabeled_node),
+    "hostname domains are not node-identity (duplicate hostname labels)": ("features", _duplicate_hostname),
+    "9 GPUs/node > 8 supported": ("features", _feature("gpu", node_gpu_mem=(128, 9))),
+    "open-local VG/device axes > 8 supported": ("features", _feature("local", node_vg_cap=(128, 9))),
+    "inter-pod global term tables > 16 rows": ("features", _feature("interpod", anti_g_sel=(17,))),
+    "inter-pod per-template terms > 4 per pod": ("features", _feature("interpod", at_sel=(1, 5))),
 }
 
 
 @pytest.mark.parametrize("reason", sorted(ENVELOPE))
 def test_the_kernels_envelope_as_a_token(reason, prep, monkeypatch):
-    from opensim_tpu.engine import fastpath
-
+    token, build = ENVELOPE[reason]
     _policy(monkeypatch, env=INTERPRET)
-    monkeypatch.setattr(fastpath, "why_not", lambda prep, config=None: reason)
+    shaped = build(prep, monkeypatch)
+    assert fastpath.why_not(shaped) == (token, reason)
     pol = select.policy()
-    rungs = select.ladder(prep, select.Ask(), pol)
+    rungs = select.ladder(shaped, select.Ask(), pol)
     assert rungs["megakernel"] == reason
-    assert select.turned_away(prep, select.Ask(), pol, rungs) == ("megakernel", ENVELOPE[reason])
+    assert select.turned_away(shaped, select.Ask(), pol, rungs) == ("megakernel", token)
+    assert select.decline_attrs(shaped, ("megakernel", token))["declined"] == f"megakernel:{token}"
+
+
+def test_the_envelope_token_of_a_config_names_its_gap(prep):
+    assert fastpath.why_not(prep, NO_TAINTS) == ("sched_config:disabled_filter", GAP_FILTER)
+    assert fastpath.why_not(prep) is None

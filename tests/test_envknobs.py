@@ -74,7 +74,7 @@ def test_osl1401_flags_raw_reads_and_stays_quiet_on_registry_use():
         "import os\n"
         'a = os.environ.get("OPENSIM_TRACE", "1")\n'
         'b = os.environ["OPENSIM_FAULTS"]\n'
-        'c = os.getenv("OPENSIM_NATIVE")\n'
+        'c = os.getenv("OPENSIM_FASTPATH")\n'
         'd = "OPENSIM_JIT_CACHE" in os.environ\n'
     )
     findings = lint_source(bad, path="opensim_tpu/somewhere.py", rules=["env-registry"])
@@ -86,7 +86,7 @@ def test_osl1401_flags_raw_reads_and_stays_quiet_on_registry_use():
         "from opensim_tpu.utils import envknobs\n"
         'a = envknobs.raw("OPENSIM_TRACE", "1")\n'
         # writes are legal: the CLI arms knobs for downstream code
-        'os.environ["OPENSIM_NATIVE"] = "1"\n'
+        'os.environ["OPENSIM_DISABLE_FASTPATH"] = "1"\n'
         # non-OPENSIM reads are out of scope
         'j = os.environ.get("JAX_PLATFORMS", "cpu")\n'
     )

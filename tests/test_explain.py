@@ -8,6 +8,7 @@ import os
 import re
 import threading
 import urllib.request
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -17,12 +18,6 @@ from opensim_tpu.engine import reasons
 from opensim_tpu.engine.simulator import AppResource, simulate
 from opensim_tpu.models import ResourceTypes, fixtures as fx
 from opensim_tpu.ops import kernels
-from opensim_tpu import native
-
-
-needs_native = pytest.mark.skipif(
-    not native.available(), reason=f"native engine unavailable: {native.load_error()}"
-)
 
 
 # ---------------------------------------------------------------------------
@@ -91,8 +86,10 @@ def canon(e_dict: dict) -> dict:
 
 def run_engine(cluster, apps, engine, explain=True, **kw):
     """One simulate on a forced engine, on deep copies so repeated runs see
-    identical inputs (pod names included — uids are stamped at build)."""
-    env = {"native": {"OPENSIM_NATIVE": "1"}, "xla": {"OPENSIM_DISABLE_NATIVE": "1"}}[engine]
+    identical inputs (pod names included — uids are stamped at build). The
+    megakernel runs in the interpreter; an audited run is the XLA scan's
+    whatever the engine asked for (the kernel declines explain mode)."""
+    env = {"megakernel": {"OPENSIM_FASTPATH": "interpret"}, "xla": {}}[engine]
     saved = {k: os.environ.get(k) for k in env}
     os.environ.update(env)
     try:
@@ -158,49 +155,55 @@ def test_primary_code_precedence():
 # cross-engine explanation parity
 # ---------------------------------------------------------------------------
 
-@needs_native
+def workload_of(pod) -> str:
+    """A pod's namespace/name with its uid segments stripped: the workload
+    it was expanded from (its replicas share it)."""
+    return canon_name(f"{pod.metadata.namespace}/{pod.metadata.name}")
+
+
+def assert_audit_matches_kernel(cluster, apps):
+    """The XLA scan's decision audit against the megakernel's run of the
+    same stream (kept without reasons, so the kernel's placements stand even
+    past a mid-stream failure): each workload's pods land on the same nodes
+    and the same count of them fail."""
+    rm = run_engine(cluster, apps, "megakernel", explain=False, reasons=False)
+    rx = run_engine(cluster, apps, "xla")
+    assert rm.engine.name == "megakernel" and rx.engine.name == "xla"
+    kernel = Counter((workload_of(p), ns.node.metadata.name) for ns in rm.node_status for p in ns.pods)
+    kernel.update((workload_of(u.pod), None) for u in rm.unscheduled_pods)
+    audit = Counter(
+        (canon_name(e.pod), e.node if e.status == "scheduled" else None) for e in rx.engine.explanations
+    )
+    assert kernel == audit
+    return rx
+
+
 def test_explanations_identical_between_engines():
     cluster, apps = small_cluster(), mixed_apps()
-    rn = run_engine(cluster, apps, "native")
-    rx = run_engine(cluster, apps, "xla")
-    assert rn.engine.name == "native" and rn.engine.native_path == "generic"
-    assert rx.engine.name == "xla"
-    assert rn.engine.filter_rejects == rx.engine.filter_rejects
-    en, ex = rn.engine.explanations, rx.engine.explanations
-    assert len(en) == len(ex) == len(rn.engine.explain_ctx.prep.ordered)
-    for a, b in zip(en, ex):
+    rx = assert_audit_matches_kernel(cluster, apps)
+    # asking for the kernel changes nothing of an audited run
+    rk = run_engine(cluster, apps, "megakernel")
+    assert rk.engine.name == "xla" and "explain" in rk.engine.skipped["megakernel"]
+    assert rk.engine.filter_rejects == rx.engine.filter_rejects
+    ek, ex = rk.engine.explanations, rx.engine.explanations
+    assert len(ek) == len(ex) == len(rx.engine.explain_ctx.prep.ordered)
+    for a, b in zip(ek, ex):
         assert canon(a.to_dict()) == canon(b.to_dict())
     # the audit found the infeasible workloads with kube phrasing
-    msgs = [e.message for e in en if e.status == "unschedulable"]
+    msgs = [e.message for e in ex if e.status == "unschedulable"]
     assert any("Insufficient cpu" in m for m in msgs)
     assert any("Insufficient memory" in m for m in msgs)
     assert all(m.startswith("0/6 nodes are available") for m in msgs)
 
 
-@needs_native
-def test_native_in_engine_rejects_match_row_derivation():
-    """The C++ engine's ScanArgs.filter_rejects accumulator (abi v4) must
-    equal the aggregation of its own per-pod attribution rows."""
-    r = run_engine(small_cluster(), mixed_apps(), "native")
-    ctx = r.engine.explain_ctx
-    mask = ctx.prep.forced.copy()
-    mask = ~mask  # every unforced pod is valid in this stream
-    derived = explain_mod.audit_rejects(
-        ctx.static_fail, ctx.sf_rows, ctx.fail_counts, mask
-    )
-    assert r.engine.filter_rejects == reasons.rejects_dict(derived)
-
-
-@needs_native
 def test_explain_disabled_is_unchanged_and_attaches_nothing():
     cluster, apps = small_cluster(), mixed_apps()
-    r0 = run_engine(cluster, apps, "native", explain=False)
-    r1 = run_engine(cluster, apps, "native", explain=True)
+    r0 = run_engine(cluster, apps, "megakernel", explain=False)
+    r1 = run_engine(cluster, apps, "xla", explain=True)
     assert r0.engine.explanations is None
     assert r0.engine.filter_rejects is None
     assert r0.engine.explain_ctx is None
-    # explain=1 forces the generic path but placements are bit-identical
-    assert r0.engine.native_path in ("incremental", "generic", "mixed")
+    # explain=1 runs the count_all scan but placements are bit-identical
     placements0 = {
         ns.node.metadata.name: sorted(canon_name(p.metadata.name) for p in ns.pods)
         for ns in r0.node_status
@@ -215,11 +218,11 @@ def test_explain_disabled_is_unchanged_and_attaches_nothing():
     ]
 
 
-@needs_native
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_reason_parity_fuzz(seed):
-    """ISSUE 7 satellite: random cluster + workload; XLA and C++ generic
-    explanations agree pod-for-pod (reasons, counts, messages, winners)."""
+    """ISSUE 7 satellite: random cluster + workload; the XLA scan's audit
+    agrees pod-for-pod with the megakernel's placements, and with the
+    kernel's own failure reasons where it kept them."""
     rng = np.random.default_rng(seed)
     rt = ResourceTypes()
     zones = [f"z{k}" for k in range(int(rng.integers(1, 4)))]
@@ -279,29 +282,25 @@ def test_reason_parity_fuzz(seed):
     apps_rt.deployments = rt.deployments
     apps = [AppResource("fuzz", apps_rt)]
 
-    rn = run_engine(cluster, apps, "native")
-    rx = run_engine(cluster, apps, "xla")
-    assert rn.engine.filter_rejects == rx.engine.filter_rejects
-    for a, b in zip(rn.engine.explanations, rx.engine.explanations):
-        assert canon(a.to_dict()) == canon(b.to_dict())
-    # per-pod attribution rows agree wherever the pod was audited
-    cn, cx = rn.engine.explain_ctx, rx.engine.explain_ctx
-    unforced = ~cn.prep.forced
-    np.testing.assert_array_equal(
-        cn.fail_counts[unforced], cx.fail_counts[unforced]
-    )
-    np.testing.assert_array_equal(
-        cn.insufficient[unforced], cx.insufficient[unforced]
-    )
+    rx = assert_audit_matches_kernel(cluster, apps)
+    # a kernel run that kept its result reasons its failures as the audit does
+    rm = run_engine(cluster, apps, "megakernel", explain=False)
+    if rm.engine.name == "megakernel":
+        audit = Counter((canon_name(e.pod), e.message) for e in rx.engine.explanations if e.status != "scheduled")
+        assert Counter((workload_of(u.pod), u.reason) for u in rm.unscheduled_pods) == audit
+    # the audit's reject totals are its per-pod rows summed
+    cx = rx.engine.explain_ctx
+    unforced = ~cx.prep.forced
+    derived = explain_mod.audit_rejects(cx.static_fail, cx.sf_rows, cx.fail_counts, unforced)
+    assert rx.engine.filter_rejects == reasons.rejects_dict(derived)
 
 
 # ---------------------------------------------------------------------------
 # deep per-pod audit
 # ---------------------------------------------------------------------------
 
-@needs_native
 def test_deep_explain_scheduled_pod_breakdown():
-    r = run_engine(small_cluster(), mixed_apps(), "native")
+    r = run_engine(small_cluster(), mixed_apps(), "xla")
     ctx = r.engine.explain_ctx
     scheduled = [
         i for i, e in enumerate(r.engine.explanations)
@@ -320,20 +319,60 @@ def test_deep_explain_scheduled_pod_breakdown():
             assert deep.margin is not None and deep.margin >= 0.0
 
 
-@needs_native
 def test_deep_explain_unschedulable_and_engines_agree():
     cluster, apps = small_cluster(), mixed_apps()
-    rn = run_engine(cluster, apps, "native")
-    rx = run_engine(cluster, apps, "xla")
-    for r in (rn, rx):
+    rx = assert_audit_matches_kernel(cluster, apps)
+    rk = run_engine(cluster, apps, "megakernel")
+    for r in (rk, rx):
         ctx = r.engine.explain_ctx
         bad = [i for i, e in enumerate(r.engine.explanations) if e.status == "unschedulable"]
         assert bad
         deep = explain_mod.explain_pod(ctx, bad[0])
         assert deep.reasons and deep.message.startswith("0/6 nodes are available")
-    dn = explain_mod.explain_pod(rn.engine.explain_ctx, 0)
+    dk = explain_mod.explain_pod(rk.engine.explain_ctx, 0)
     dx = explain_mod.explain_pod(rx.engine.explain_ctx, 0)
-    assert canon(dn.to_dict()) == canon(dx.to_dict())
+    assert canon(dk.to_dict()) == canon(dx.to_dict())
+
+
+def _feature_case(name):
+    import random
+    import sys
+
+    sys.path.insert(0, os.path.dirname(__file__))
+    import test_xla_oracle_fuzz as fz
+
+    if name == "ports":
+        return fz._ports_fuzz_case(random.Random(211))
+    if name == "gpu_share":
+        return fz._gpu_fuzz_case(random.Random(307))
+    if name == "local":
+        return fz._local_fuzz_case(random.Random(401))
+    if name == "interpod":
+        return fz._ip_cluster(12), fz._ip_apps()
+    app = ResourceTypes()
+    app.deployments = [d for d in mixed_apps()[0].resources.deployments if d.metadata.name in ("spread", "fits")]
+    return small_cluster(), app
+
+
+@pytest.mark.parametrize("feature", ["ports", "gpu_share", "local", "interpod", "spread"])
+def test_deep_explain_score_parts_rank_the_scans_choice(feature):
+    """The deep audit scores a scheduled pod from the XLA scan's own static
+    tables (kernels.precompute_static) and the replayed carry: its parts sum
+    to its total, and no node outscores the one the scan chose (a tie goes
+    to the lower node index, as the scan's does)."""
+    cluster, app = _feature_case(feature)
+    r = run_engine(cluster, [AppResource("f", app)], "xla")
+    ctx = r.engine.explain_ctx
+    assert getattr(ctx.prep.features, {"gpu_share": "gpu", "spread": "spread_hard"}.get(feature, feature))
+    scheduled = [i for i, e in enumerate(r.engine.explanations) if e.status == "scheduled" and not e.forced]
+    assert len(scheduled) >= 3
+    names = list(ctx.node_names)
+    for i in scheduled[:: max(1, len(scheduled) // 3)][:3]:
+        deep = explain_mod.explain_pod(ctx, i)
+        assert deep.node == r.engine.explanations[i].node == names[int(ctx.chosen[i])]
+        assert deep.scores and abs(sum(deep.scores.values()) - deep.score) < 1e-2
+        if deep.runner_up is not None:
+            assert deep.margin > 0 or (deep.margin == 0 and names.index(deep.runner_up) > names.index(deep.node))
 
 
 def test_deep_explain_forced_pod():

@@ -735,7 +735,6 @@ def test_megakernel_failure_degrades_to_xla(monkeypatch, caplog):
     # interpret/test mode the exception re-raises so CI can't silently
     # validate the fallback engine instead of the kernel)
     monkeypatch.delenv("OPENSIM_FASTPATH", raising=False)
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def boom(*a, **k):

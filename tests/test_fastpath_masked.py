@@ -26,7 +26,6 @@ N_CANDIDATES = 6
 @pytest.fixture(autouse=True)
 def _kernel_on(monkeypatch):
     monkeypatch.delenv("OPENSIM_DISABLE_FASTPATH", raising=False)
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")  # as on the chip's machines: below the kernel is the XLA scan
     if _INTERPRET:
         monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
 

@@ -168,35 +168,28 @@ def test_observed_jit_call_records_compiles_with_cause_attribution():
 
 
 def test_schedule_pods_boundary_is_instrumented():
-    """An XLA-path simulate must show up at the schedule_pods boundary
-    (the C++ engine is bypassed via the env knob)."""
-    import os
-
+    """An XLA-path simulate must show up at the schedule_pods boundary."""
     from opensim_tpu.engine.simulator import AppResource, simulate
     from opensim_tpu.obs import profile
 
     rt = _cluster(nodes=3, bound=0)
     app = ResourceTypes()
     app.pods.append(fx.make_fake_pod("solo", "100m", "128Mi"))
-    os.environ["OPENSIM_DISABLE_NATIVE"] = "1"
-    try:
-        before = (
-            profile.COMPILES.snapshot()["boundaries"]
-            .get("schedule_pods", {})
-            .get("compiles", 0)
-        )
-        res = simulate(rt, [AppResource("a", app)])
-        assert not res.unscheduled_pods
-        after = (
-            profile.COMPILES.snapshot()["boundaries"]
-            .get("schedule_pods", {})
-            .get("compiles", 0)
-        )
-        # at least one compile OR the signature was already warm from an
-        # earlier test in this process — the boundary must exist either way
-        assert "schedule_pods" in profile.COMPILES.snapshot()["boundaries"] or after > before
-    finally:
-        del os.environ["OPENSIM_DISABLE_NATIVE"]
+    before = (
+        profile.COMPILES.snapshot()["boundaries"]
+        .get("schedule_pods", {})
+        .get("compiles", 0)
+    )
+    res = simulate(rt, [AppResource("a", app)])
+    assert not res.unscheduled_pods
+    after = (
+        profile.COMPILES.snapshot()["boundaries"]
+        .get("schedule_pods", {})
+        .get("compiles", 0)
+    )
+    # at least one compile OR the signature was already warm from an
+    # earlier test in this process — the boundary must exist either way
+    assert "schedule_pods" in profile.COMPILES.snapshot()["boundaries"] or after > before
 
 
 def test_jitcache_stats_counts_files(tmp_path, monkeypatch):

@@ -10,7 +10,6 @@ the configuration's path reports."""
 import importlib
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -47,11 +46,9 @@ SIZES = {
 }
 #: how a test asks for an engine on the CPU, what the report then names, and whether big-U is forced
 ENGINES = {
-    "xla": ({"OPENSIM_DISABLE_NATIVE": "1"}, "xla", None),
-    "native": ({}, "native", None),
-    # without the C++ scans, so that the count sweeps run on the kernel too (a sweep tries them first)
-    "megakernel": ({"OPENSIM_FASTPATH": "interpret", "OPENSIM_DISABLE_NATIVE": "1"}, "megakernel", False),
-    "megakernel-big-u": ({"OPENSIM_FASTPATH": "interpret", "OPENSIM_DISABLE_NATIVE": "1"}, "megakernel", True),
+    "xla": ({}, "xla", None),
+    "megakernel": ({"OPENSIM_FASTPATH": "interpret"}, "megakernel", False),
+    "megakernel-big-u": ({"OPENSIM_FASTPATH": "interpret"}, "megakernel", True),
 }
 NOUGHT = {
     "misplaced_pods": 0, "worst_score_gap": 0.0, "infeasible_pods": 0, "unscheduled_diff": 0, "answer_diff": 0,
@@ -130,7 +127,7 @@ def span_attrs(monkeypatch):
 
 
 @pytest.mark.usefixtures("span_attrs")
-@pytest.mark.parametrize("seed", [5, 3000000023])
+@pytest.mark.parametrize("seed", [5, 3000000023, 17])
 @pytest.mark.parametrize("size", ["tiny", "small"])
 @pytest.mark.parametrize("name", list(ENGINES))
 def test_the_plan_replays_through_the_reference_pod_for_pod_and_device_for_device(tmp_path, monkeypatch, name, size, seed):
@@ -158,7 +155,7 @@ def test_the_plan_replays_through_the_reference_pod_for_pod_and_device_for_devic
         assert [sp["attrs"]["attribution"] for sp in spans_of(window, "engine.megakernel")] == ["not_asked", "none"]
 
 
-@pytest.mark.parametrize("name", ["xla", "native", "megakernel"])
+@pytest.mark.parametrize("name", ["xla", "megakernel"])
 def test_a_cluster_that_fits_adds_no_node(tmp_path, monkeypatch, name):
     named, _ = engine(monkeypatch, name)
     driver = drive(tmp_path, SIZES["fits"], 9)
@@ -337,7 +334,7 @@ GREEDY = [(600, 1), (400, 2), (250, 4), (125, 8), (1000, 4), (500, 2), (500, 1)]
 GREEDY_IDS = ["0", "0-1", "1-1-2-2", "2-2-2-2-3-3-3-3", "4-5-6-7", None, "3"]
 
 
-@pytest.mark.parametrize("name", ["xla", "native", "megakernel"])
+@pytest.mark.parametrize("name", ["xla", "megakernel"])
 @pytest.mark.parametrize("case", ["tightest", "greedy"])
 def test_the_device_a_task_sits_on_worked_by_hand(monkeypatch, name, case):
     named, _ = engine(monkeypatch, name)
@@ -391,23 +388,25 @@ def test_a_node_without_a_device_takes_no_gpu_task_and_a_count_of_nought_fits_no
 
 
 @pytest.mark.parametrize("seed", [4, 2147483659])
-def test_the_serial_baseline_and_the_reference_agree(tmp_path, seed):
-    """`tools/serial_baseline.py` (objects, Python floats, kube's NodeInfo
-    design) and `kube_gpushare_reference` (numpy float32 over plain data)
-    share no code and put every task on the same node."""
-    sys.path.insert(0, REPO)
-    from tools.serial_baseline import run_serial
-
+def test_the_xla_scan_and_the_reference_agree(tmp_path, seed):
+    """`simulate` on the XLA scan and `kube_gpushare_reference` (numpy
+    float32 over plain data) share no code and put every task on the same
+    node."""
+    from opensim_tpu.engine.simulator import simulate
     from opensim_tpu.planner.apply import Applier, Options
 
     driver = drive(tmp_path, SIZES["fits"], seed)
     applier = Applier(Options(simon_config=driver.simon_config))
-    scheduled, unscheduled, _e, _s, chosen = run_serial(applier.load_cluster(), applier.load_apps())
+    result = simulate(applier.load_cluster(), applier.load_apps())
+    assert result.engine.name == "xla" and not result.unscheduled_pods
     cluster = driver.inputs["variants"]["short"]["cluster"]
     ref = R.Reference(cluster)
     _placed, left = ref.free_run()
-    assert unscheduled == 0 and not left and scheduled == len(cluster.workloads)
-    assert chosen == [ref.order()[w.name][0] for w in cluster.workloads]
+    assert not left
+    placed = {f"{p.metadata.namespace}/{p.metadata.name}": ns.node.metadata.name
+              for ns in result.node_status for p in ns.pods}
+    want = {w.name: ref.order()[w.name][0] for w in cluster.workloads}
+    assert {name: placed[name] for name in want} == want
 
 
 # ---------------------------------------------------------------------------
@@ -479,8 +478,6 @@ def test_the_path_names_its_devices_its_templates_and_the_pods_it_placed(tmp_pat
 def test_a_stream_without_gpu_requests_opens_no_gpu_decode_and_counts_nothing(monkeypatch):
     from opensim_tpu.engine.simulator import AppResource, simulate
     from opensim_tpu.models import ResourceTypes, fixtures as fx
-
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     rt = ResourceTypes()
     for i in range(3):
         rt.nodes.append(fx.make_fake_node(f"n{i}", "16", "64Gi", "110"))

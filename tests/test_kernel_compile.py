@@ -141,8 +141,6 @@ def test_the_xla_scan_reads_plan_cl2s_count_carry_by_row_windows_on_the_chip(one
     from opensim_tpu.engine.scheduler import _schedule_pods_jit
     from opensim_tpu.engine.simulator import prepare
     from opensim_tpu.planner.apply import Applier, Options
-
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
     with open(os.path.join(bench, "configs", "cl2-load-5k.json")) as f:
         config = json.load(f)

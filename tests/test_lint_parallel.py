@@ -78,30 +78,30 @@ def _git(cwd, *args):
     )
 
 
-def test_git_changed_files_scopes_and_maps_cc_to_native(tmp_path, monkeypatch):
+def test_git_changed_files_scopes_to_lint_roots(tmp_path, monkeypatch):
     repo = str(tmp_path / "repo")
     _write_tree(repo, {
         "pkg/a.py": "a = 1\n",
         "pkg/b.py": "b = 1\n",
-        "pkg/native/__init__.py": "x = 1\n",
-        "pkg/native/engine.cc": "// v1\n",
+        "pkg/engine.cc": "// v1\n",
         "elsewhere/c.py": "c = 1\n",
     })
     _git(repo, "init", "-q")
     _git(repo, "add", ".")
     _git(repo, "commit", "-qm", "seed")
-    # modify one .py, one .cc, one out-of-scope file; add one untracked .py
+    # modify one .py, one non-Python file, one out-of-scope file; add one
+    # untracked .py
     _write_tree(repo, {
         "pkg/a.py": "a = 2\n",
-        "pkg/native/engine.cc": "// v2\n",
+        "pkg/engine.cc": "// v2\n",
         "elsewhere/c.py": "c = 2\n",
         "pkg/new.py": "n = 1\n",
     })
     monkeypatch.chdir(repo)
     changed = _git_changed_files(["pkg"])
-    # a.py (modified), new.py (untracked), and the native package pulled
-    # in by its .cc edit; b.py (clean) and elsewhere/ (out of scope) not
-    assert changed == ["pkg/a.py", "pkg/native/__init__.py", "pkg/new.py"]
+    # a.py (modified) and new.py (untracked); b.py (clean), the .cc (not
+    # lintable) and elsewhere/ (out of scope) not
+    assert changed == ["pkg/a.py", "pkg/new.py"]
 
 
 def test_git_changed_files_outside_checkout_returns_none(tmp_path, monkeypatch):
@@ -136,7 +136,6 @@ INT_DTYPE = np.int32
 AXIS_ALIASES = {}
 ARENA_CONTRACTS = {"alloc": ("FLOAT_DTYPE", ("N", "R"))}
 STATE_CONTRACTS = {}
-BUFFER_FIELD_ALIASES = {}
 KERNEL_ARG_CONTRACTS = {}
 STRUCT_PARAM_NAMES = {}
 """

@@ -441,8 +441,8 @@ def test_chrome_trace_export_round_trip(tmp_path):
             with tracing.span("encode"):
                 pass
         with tracing.span("schedule") as sp:
-            sp.child_from_seconds("native.delta", 0.25, steps=10)
-            sp.child_from_seconds("native.bind", 0.05, steps=10)
+            sp.child_from_seconds("batch.prep", 0.25, steps=10)
+            sp.child_from_seconds("batch.run", 0.05, steps=10)
     tr.finish()
 
     out = tmp_path / "trace.json"
@@ -450,12 +450,12 @@ def test_chrome_trace_export_round_trip(tmp_path):
     doc = json.loads(out.read_text())
     events = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
     by_name = {e["name"]: e for e in events}
-    assert {"bench", "prepare", "encode", "schedule", "native.delta", "native.bind"} <= set(by_name)
+    assert {"bench", "prepare", "encode", "schedule", "batch.prep", "batch.run"} <= set(by_name)
     root = by_name["bench"]
     # every span fits inside the root's window and synthetic children are
     # laid out sequentially
     assert all(e["ts"] >= 0 for e in events)
-    assert by_name["native.bind"]["ts"] >= by_name["native.delta"]["ts"] + by_name["native.delta"]["dur"] - 1e-3
+    assert by_name["batch.run"]["ts"] >= by_name["batch.prep"]["ts"] + by_name["batch.prep"]["dur"] - 1e-3
     assert root["dur"] >= by_name["prepare"]["dur"]
     assert by_name["schedule"]["args"]["status"] == "ok"
 
@@ -491,62 +491,6 @@ def test_unclosed_spans_are_force_closed_on_finish():
     stuck = [sp for sp in tr.walk() if sp.name == "stuck"][0]
     assert stuck.end is not None and stuck.status == "error"
     assert tr.current_span() is tr.root
-
-
-def test_native_profile_attaches_child_spans():
-    from opensim_tpu import native
-
-    if not native.available():
-        pytest.skip("C++ engine not built on this host")
-    import os
-
-    rt = _cluster()
-    app = ResourceTypes()
-    app.deployments.append(fx.make_fake_deployment("prof", 4, "100m", "128Mi"))
-    os.environ["OPENSIM_NATIVE_PROFILE"] = "1"
-    try:
-        tr = tracing.start_trace("profiled", force=True)
-        with tracing.trace_scope(tr):
-            res = simulate(rt, [AppResource("prof", app)])
-        tr.finish()
-    finally:
-        del os.environ["OPENSIM_NATIVE_PROFILE"]
-    if res.engine is None or res.engine.name != "native":
-        pytest.skip(f"native engine did not serve this run ({res.engine})")
-    native_spans = _find_spans(tr, "engine.native")
-    assert native_spans, _span_names(tr)
-    children = {c.name for c in native_spans[0].children}
-    assert any(n.startswith("native.") for n in children), children
-    assert native_spans[0].attrs.get("native_path")
-
-
-def test_native_bail_attribution_reaches_metrics_and_profile(monkeypatch):
-    """Bail-reason attribution (abi v5): a forced-generic run must surface
-    as ``simon_native_bail_total{reason="force_generic"}`` in /metrics and
-    in the cumulative native snapshot served by /api/debug/profile."""
-    from opensim_tpu import native
-    from opensim_tpu.server import rest
-
-    if not native.available():
-        pytest.skip("C++ engine not built on this host")
-    monkeypatch.setenv("OPENSIM_NATIVE_FORCE_GENERIC", "1")
-    server = rest.SimonServer(base_cluster=_cluster())
-    try:
-        code, _body = server.deploy_apps(_payload())
-        assert code == 200
-        snap = rest.METRICS.native_snapshot()
-        if not any(snap["steps"].values()):
-            pytest.skip("native engine did not serve this run")
-        text = rest.METRICS.render()
-        m = re.search(r'simon_native_bail_total\{reason="force_generic"\} (\d+)', text)
-        assert m and int(m.group(1)) > 0, text
-        assert snap["bails"].get("force_generic", 0) > 0
-        assert snap["steps"].get("generic", 0) > 0
-    finally:
-        # METRICS is process-global: unwind this test's contribution
-        with rest.METRICS.lock:
-            rest.METRICS.native_bails.clear()
-            rest.METRICS.native_classes.clear()
 
 
 @pytest.mark.slow

@@ -126,13 +126,9 @@ def _at_own_interval(tr, events, name):
 def profiled(tmp_path_factory):
     """One tiny simulate() on the XLA scan under an ambient trace, inside a
     profiler capture: the request's spans and the host plane's events."""
-    os.environ["OPENSIM_DISABLE_NATIVE"] = "1"
-    try:
-        simulate(_cluster(), _apps())  # compile outside the capture
-        tr, events, res = _captured(lambda: simulate(_cluster(), _apps()), "lib-call",
-                                    str(tmp_path_factory.mktemp("profile")))
-    finally:
-        del os.environ["OPENSIM_DISABLE_NATIVE"]
+    simulate(_cluster(), _apps())  # compile outside the capture
+    tr, events, res = _captured(lambda: simulate(_cluster(), _apps()), "lib-call",
+                                str(tmp_path_factory.mktemp("profile")))
     assert res.engine.name == "xla"
     return tr, events
 
@@ -232,7 +228,6 @@ def test_a_second_plan_of_the_same_shapes_enters_the_kernel_from_the_cache(monke
 
 
 def test_only_the_kernels_launch_says_entry(monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     tr, _ = _traced(lambda: simulate(_cluster(), _apps()))
     (launch,) = _find(tr, "xla.launch")
     assert set(launch.attrs) == {"pods", "backend_compiles", "cache_hits"}
@@ -256,7 +251,6 @@ def test_a_megakernel_sweep_has_the_same_four_parts():
 
 
 def test_the_xla_rung_is_resident_pad_launch_wait(monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     tr, _ = _traced(lambda: simulate(_cluster(), _apps()))
     (rung,) = _find(tr, "engine.xla")
     assert [c.name for c in rung.children] == ["xla.resident", "xla.pad", "xla.launch", "xla.wait"]
@@ -272,9 +266,7 @@ def test_a_rung_says_whether_its_node_set_was_masked_and_metrics_counts_it(monke
     simulations by the engine that answered (an unmasked one is not counted)."""
     from opensim_tpu.server.rest import METRICS
 
-    if engine == "xla":
-        monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
-    else:
+    if engine != "xla":
         monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
     prep = prepare(_cluster(), _apps())
     mask = np.zeros(np.asarray(prep.ec_np.node_valid).shape[0], bool)
@@ -477,9 +469,7 @@ def test_child_at_clamps_to_the_parent_and_keeps_start_order():
 
 @pytest.mark.parametrize("engine", ["xla", "megakernel"])
 def test_without_an_ambient_trace_the_new_call_sites_allocate_no_span(monkeypatch, engine):
-    if engine == "xla":
-        monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
-    else:
+    if engine != "xla":
         monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
     made = []
     init = tracing.Span.__init__

@@ -24,7 +24,6 @@ with open(os.path.join(REPO, "benchmarks", "traffic", "short-local.json")) as f:
 
 @pytest.fixture(autouse=True)
 def _clean(monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     RECORDER.reset()
     yield
     RECORDER.reset()
@@ -51,7 +50,6 @@ def plan(tmp_path_factory):
     from opensim_tpu.planner.apply import Applier, Options
 
     mp = pytest.MonkeyPatch()
-    mp.setenv("OPENSIM_DISABLE_NATIVE", "1")
     RECORDER.reset()
     scratch = str(tmp_path_factory.mktemp("openlocal"))
     ctx = Context(config=CONFIG, traffic=TRAFFIC, seed=7, scratch=scratch, rehearse=True, sizes=CONFIG["tiny"])

@@ -1,8 +1,7 @@
 """The resident carry (opensim_tpu/engine/resident.py): a request's XLA scan
 that starts from the twin's bound pods' carry is held bit-equal to the scan
 that replays them — outputs and every leaf of the final state — and the
-widened count tensors are held to ``explain.rebuild_counts``. The C++ engine
-is kept out the way the other XLA tests do it (OPENSIM_DISABLE_NATIVE)."""
+widened count tensors are held to ``explain.rebuild_counts``."""
 
 import dataclasses
 import json
@@ -26,11 +25,6 @@ LOCAL = dict(
     devices=[{"device": "/dev/vdb", "capacity": 40 * 1024**3, "mediaType": "ssd"}],
 )
 COUNTS = ("port_used", "dom_sel", "dom_anti", "dom_prefw")
-
-
-@pytest.fixture(autouse=True)
-def _xla_only(monkeypatch):
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
 
 
 def _term(labels, key=HOST):

@@ -153,7 +153,7 @@ def test_retry_does_not_retry_foreign_exceptions():
 
 def test_breaker_opens_after_threshold_and_half_open_probes():
     clock = FakeClock()
-    br = CircuitBreaker("native", threshold=3, cooldown_s=30.0, clock=clock)
+    br = CircuitBreaker("megakernel", threshold=3, cooldown_s=30.0, clock=clock)
     assert br.state() == "closed" and br.allow()
     for _ in range(2):
         br.record_failure(RuntimeError("boom"))
@@ -190,11 +190,11 @@ def test_breaker_success_resets_consecutive_failures():
 def test_engine_breaker_registry_env_config(monkeypatch):
     monkeypatch.setenv("OPENSIM_BREAKER_THRESHOLD", "1")
     breaker_mod.reset_breakers()
-    br = breaker_mod.engine_breaker("native")
-    assert br is breaker_mod.engine_breaker("native")  # one per engine
+    br = breaker_mod.engine_breaker("megakernel")
+    assert br is breaker_mod.engine_breaker("megakernel")  # one per engine
     br.record_failure(RuntimeError("x"))
     assert br.state() == "open"  # threshold 1 from env
-    assert "native" in breaker_mod.all_breakers()
+    assert "megakernel" in breaker_mod.all_breakers()
 
 
 # ---------------------------------------------------------------------------

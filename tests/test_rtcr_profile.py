@@ -68,7 +68,6 @@ PROFILES = {"binpack": BINPACK, "three_points": THREE_POINTS, "weights": WEIGHTS
 @pytest.fixture(autouse=True)
 def _kernel_on(monkeypatch):
     monkeypatch.delenv("OPENSIM_DISABLE_FASTPATH", raising=False)
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")  # below the kernel is the XLA scan, as on the chip
     if _INTERPRET:
         monkeypatch.setenv("OPENSIM_FASTPATH", "interpret")
 
@@ -211,7 +210,7 @@ def test_the_default_profile_lowers_to_the_kernel_it_always_was(sublanes):
     assert _lowered_text(DEFAULT_CONFIG._replace(w_least=3.0), sublanes) != plain
 
 
-RUNGS = {f"{kind}.{engine}" for kind in ("engine", "sweep") for engine in ("megakernel", "native", "xla")}
+RUNGS = {f"{kind}.{engine}" for kind in ("engine", "sweep") for engine in ("megakernel", "xla")}
 
 
 def _spans(fn):

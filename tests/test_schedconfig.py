@@ -552,7 +552,7 @@ def test_segmented_two_differing_profiles_schedule(tmp_path):
     rt.deployments.extend([d1, d2])
     res = simulate(cluster, [AppResource("a", rt)], sched_config=cfg)
     assert not res.unscheduled_pods
-    assert res.engine.name in ("native", "xla")
+    assert res.engine.name == "xla"
     assert "segmented multi-profile" in res.engine.skipped["megakernel"]
     by_app = {}
     for ns in res.node_status:

@@ -31,9 +31,8 @@ MID = dict(CONFIG["tiny"], nodes=150, init_pods=60, measure_pods=40)
 SIZES = {"tiny": CONFIG["tiny"], "mid": MID}
 #: how a test asks for an engine on the CPU, and what the report then names
 ENGINES = {
-    "xla": ({"OPENSIM_DISABLE_NATIVE": "1"}, "xla"),
+    "xla": ({}, "xla"),
     "megakernel": ({"OPENSIM_FASTPATH": "interpret"}, "megakernel"),
-    "native": ({}, "native"),
 }
 FEATURES = "interpod+prefg+spread_soft"
 
@@ -56,7 +55,7 @@ def plan(driver):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("seed", [5, 3000000023])
+@pytest.mark.parametrize("seed", [5, 3000000023, 17])
 @pytest.mark.parametrize("size", list(SIZES))
 @pytest.mark.parametrize("engine", list(ENGINES))
 def test_the_plan_replays_through_the_reference_with_nothing_misplaced(tmp_path, monkeypatch, engine, size, seed):
@@ -369,8 +368,6 @@ def traced_simulate(*deployments):
 
 def test_a_stream_without_terms_reports_none_of_them(monkeypatch):
     from opensim_tpu.models import fixtures as fx
-
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     tr, _ = traced_simulate(fx.make_fake_deployment("web", 4, "100m", "128Mi"))
     (rung,) = find(tr, "engine.xla")
     assert rung.attrs["interpod_terms"] == 0 and "interpod" not in rung.attrs["features"]
@@ -382,8 +379,6 @@ def test_terms_and_host_ports_in_one_stream_are_encoded_side_by_side(monkeypatch
     """The term tables are built apart from the template loop that fills the
     host-port table; a stream that carries both still encodes both."""
     from opensim_tpu.models import fixtures as fx
-
-    monkeypatch.setenv("OPENSIM_DISABLE_NATIVE", "1")
     green = {"color": "green"}
     anti = {"podAntiAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [
         {"labelSelector": {"matchLabels": green}, "topologyKey": HOSTNAME}]}}

@@ -3,15 +3,13 @@
 
 Generates a throwaway simon config (6 nodes; one schedulable and one
 infeasible workload), then drives the REAL ``simon explain`` CLI against it
-on both CPU engines and asserts the ISSUE 7 acceptance bar end to end:
+on the XLA scan and asserts the ISSUE 7 acceptance bar end to end:
 
 1. ``simon explain`` renders a kube-style ``0/N nodes are available: …``
    breakdown for the unschedulable workload;
-2. the per-filter rejection counts (and the whole explanation set) are
-   identical between the C++ generic engine and the XLA scan;
+2. the aggregate per-filter reject totals record the fit rejects;
 3. the deep single-pod audit resolves a workload-name query, and its
-   per-plugin score breakdown sums to the reported total on the winner;
-4. the aggregate per-filter reject totals agree between engines.
+   per-plugin score breakdown sums to the reported total on the winner.
 
 Exit 0 on success; 1 with a one-line reason per failed check.
 """
@@ -104,7 +102,7 @@ def write_config(root: str) -> str:
     return cfg
 
 
-_BACKEND_ENV = ("OPENSIM_NATIVE", "OPENSIM_DISABLE_NATIVE", "OPENSIM_DISABLE_FASTPATH")
+_BACKEND_ENV = ("OPENSIM_DISABLE_FASTPATH",)
 
 
 def run_cli(argv) -> str:
@@ -125,40 +123,22 @@ def run_cli(argv) -> str:
     return buf.getvalue()
 
 
-def canon(obj):
-    """Strip expansion-time uid suffixes from pod names so runs compare."""
-    s = json.dumps(obj, sort_keys=True)
-    return json.loads(re.sub(r"-[0-9a-f]{10}", "", s))
-
-
 def main() -> int:
     with tempfile.TemporaryDirectory(prefix="explain-smoke-") as root:
         cfg = write_config(root)
 
-        # 1+2: summary audit, both engines, must agree byte-for-byte
-        out_native = json.loads(run_cli(["explain", "-f", cfg, "--json", "--backend", "native"]))
-        out_xla = json.loads(run_cli(["explain", "-f", cfg, "--json", "--backend", "xla"]))
-        if not out_native["unschedulable"]:
+        # 1+2: summary audit
+        out = json.loads(run_cli(["explain", "-f", cfg, "--json", "--backend", "xla"]))
+        if not out["unschedulable"]:
             return fail("the infeasible workload was reported schedulable")
-        msg = out_native["unschedulable"][0]["message"]
+        msg = out["unschedulable"][0]["message"]
         if not re.match(r"^0/6 nodes are available: .*Insufficient cpu", msg):
             return fail(f"not a kube-style breakdown: {msg!r}")
-        if canon(out_native["unschedulable"]) != canon(out_xla["unschedulable"]):
-            return fail(
-                "engines disagree on the unschedulable explanations:\n"
-                f"  native: {canon(out_native['unschedulable'])}\n"
-                f"  xla:    {canon(out_xla['unschedulable'])}"
-            )
-        if out_native["filter_rejects"] != out_xla["filter_rejects"]:
-            return fail(
-                f"filter-reject totals differ: {out_native['filter_rejects']} "
-                f"vs {out_xla['filter_rejects']}"
-            )
-        if out_native["filter_rejects"].get("fit", 0) < 1:
-            return fail(f"no fit rejects recorded: {out_native['filter_rejects']}")
+        if out["filter_rejects"].get("fit", 0) < 1:
+            return fail(f"no fit rejects recorded: {out['filter_rejects']}")
 
         # 3: deep audit of one scheduled pod by workload name
-        deep = json.loads(run_cli(["explain", "-f", cfg, "--json", "--backend", "native", "web"]))
+        deep = json.loads(run_cli(["explain", "-f", cfg, "--json", "--backend", "xla", "web"]))
         if deep["status"] != "scheduled" or not deep.get("scores"):
             return fail(f"deep audit lacks a score breakdown: {deep}")
         if abs(sum(deep["scores"].values()) - deep["score"]) > 0.01:
@@ -173,8 +153,8 @@ def main() -> int:
             return fail(f"deep audit of the infeasible pod is wrong: {deep_bad}")
 
     print(
-        "explain-smoke: ok — kube-style breakdowns engine-identical "
-        f"({msg!r}), rejects {out_native['filter_rejects']}, deep audit "
+        "explain-smoke: ok — kube-style breakdown "
+        f"({msg!r}), rejects {out['filter_rejects']}, deep audit "
         f"scored {deep['node']} at {deep['score']}"
     )
     return 0

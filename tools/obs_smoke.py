@@ -97,7 +97,7 @@ def main() -> int:
             return fail(f"span tree missing phases {sorted(needed - names)} (got {sorted(names)})")
         # an engine-LADDER rung span specifically — engine.device_put (a
         # child of encode) must not satisfy the attribution check
-        rungs = {"engine.megakernel", "engine.native", "engine.xla"}
+        rungs = {"engine.megakernel", "engine.xla"}
         if not rungs & names:
             return fail(f"span tree has no engine-ladder rung span (got {sorted(names)})")
 

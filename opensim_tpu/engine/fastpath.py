@@ -31,8 +31,13 @@ HOSTNAME = "kubernetes.io/hostname"
 # why_not's resident-row estimate must stay under this; the kernel itself is
 # compiled under pallas_scan.VMEM_LIMIT_BYTES (64 MiB), which leaves the
 # compiler several times the estimate. Checked against the compiler, not
-# assumed: shapes at the budget's edge (plan/affinity/gpu/local-pv at
-# 6.5k-7k nodes) compile for v5e on the installed JAX/libtpu.
+# assumed: with the zone keys read by compare-select, each signature compiles
+# for v5e on the installed JAX/libtpu at the widest node axis its estimate
+# admits, one scenario a step (why_not) and eight (sweep_sublanes): plan-short's
+# tables at 67k and 34k nodes, plan-mixed's inter-pod ones at 33k and 19k,
+# gpu-share's at 50k and 32k, open-local's at 14.6k (21.9k in big-U mode) and
+# 13.7k. A key read through the one-hot blocks ([N, Z] each, Z >= 128) puts
+# the edge at 6.5k-7k nodes, as before compare-select.
 _VMEM_BUDGET = 10 * 1024 * 1024
 # scenarios a kernel step holds in a packed sweep: one a sublane of the
 # vector registers' eight
@@ -148,9 +153,11 @@ def vmem_estimate(prep, sublanes: int = 1) -> int:
     #   +ports: / port_used (Hp)
     #   +na/tt/avoid: one [U, N] table each
     #   packed (sublanes > 1): zone_id (K) and the [SB, CHUNK] chosen window
-    # plus the zone blocks: zone_NZ + zone_ZN (2·K·N·Z) and the per-scenario
-    # [*, Z] scratch counts.
-    if non_host:
+    #   keys read by compare-select (zone_domains): zone_id (K), once
+    # plus, for keys read through them, the zone blocks: zone_NZ + zone_ZN
+    # (2·K·N·Z), and the per-scenario [*, Z] scratch counts.
+    select = zone_domains(prep) > 0
+    if non_host and not select:
         counts = []
         for key in non_host:
             nd = np.asarray(ec.node_domain)[:, topo_keys.index(key)]
@@ -195,11 +202,13 @@ def vmem_estimate(prep, sublanes: int = 1) -> int:
     if f.prefer_avoid:
         rows += U_resident
     window = 0
-    if sublanes > 1:
+    if sublanes > 1 or select:
         rows += K
+    if sublanes > 1:
         window = 2 * sublanes * CHUNK  # double-buffered int32 chosen window
     rows += sublanes * scen_rows
-    return (rows * N + (2 * K * N + sublanes * zone_z_rows) * Z + u_rows * u_cols + window) * 4
+    blocks = 0 if select else 2 * K * N
+    return (rows * N + (blocks + sublanes * zone_z_rows) * Z + u_rows * u_cols + window) * 4
 
 
 def sweep_sublanes(prep, S: int) -> int:
@@ -211,6 +220,30 @@ def sweep_sublanes(prep, S: int) -> int:
     if S >= 2 and 2 * vmem_estimate(prep, SWEEP_SUBLANES) <= VMEM_LIMIT_BYTES:
         return SWEEP_SUBLANES
     return 1
+
+
+def zone_domains(prep) -> int:
+    """Domains over which the kernel reads a zone key's counts by
+    compare-select: the most that any of the cluster's zone keys (the
+    topology keys but the hostname) has, at least 1, where the encoding's
+    ``kernels.CountKeys`` finds every one of them small (at most
+    ``kernels.SELECT_DOMAINS``); else 0, and every zone key is read through
+    the [K, N, Z] one-hot blocks, which a rack label of hundreds of values
+    still needs."""
+    keys = prep.features.count_keys
+    zone_tks = _key_tks(prep)[1:]
+    if keys is None or any(keys.ids[tk] is None for tk in zone_tks):
+        return 0
+    return max([len(keys.ids[tk]) for tk in zone_tks] + [1])
+
+
+def zone_reads(prep) -> dict:
+    """The cluster's zone keys by the read the kernel makes of their counts,
+    for the spans of a kernel run: ``zone_keys_selected`` (compare-select)
+    and ``zone_keys_onehot`` (the one-hot blocks)."""
+    keys = len(_key_tks(prep)) - 1
+    selected = keys if zone_domains(prep) else 0
+    return {"zone_keys_selected": selected, "zone_keys_onehot": keys - selected}
 
 
 def _key_weights(weights, key_tks, K: int) -> np.ndarray:
@@ -309,32 +342,33 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
     trash = np.asarray(ec.domain_topo).shape[0] - 1
     node_domain = _padN(np.asarray(ec.node_domain), axis=0, fill=trash)
 
-    # per-key zone one-hot blocks (dense, shared Z padded to 128 lanes);
-    # topo-idx → key-index map: 0 = hostname, 1..K = zone keys in vocab order
+    # each node's zone under each key (its index among the key's domains, -1
+    # without the label); topo-idx → key-index map: 0 = hostname, 1..K = zone
+    # keys in vocab order
     K = max(len(zone_tks), 1)
-    if zone_tks:
-        Z = max(
-            128,
-            128 * math.ceil(
-                max(len(np.unique(node_domain[:, tk])) for tk in zone_tks) / 128
-            ),
-        )
-    else:
-        Z = 128
-    # zone_NZ is [K, N, Z] (not [N, K*Z]): per-key blocks must start at lane
-    # offset 0 — Mosaic cannot broadcast a vector sliced out of the flat
-    # layout at lane offset k·Z
-    zone_NZ = np.zeros((K, N, Z), np.float32)
+    zone_id = np.full((K, N), -1.0, np.float32)
     has_zone = np.zeros((K, N), np.float32)
     for ki, tk in enumerate(zone_tks):
         zd = node_domain[:, tk]
         _ids, zone_inv = np.unique(zd, return_inverse=True)
         present = zd != trash
-        zone_NZ[ki, np.arange(N)[present], zone_inv[present]] = 1.0
+        zone_id[ki, present] = zone_inv[present]
         has_zone[ki] = present.astype(np.float32)
-    zone_ZN = np.ascontiguousarray(
-        zone_NZ.transpose(0, 2, 1).reshape(K * Z, N)
-    )
+    # per-key zone one-hot blocks (dense, shared Z padded to 128 lanes), for
+    # keys the kernel does not read by compare-select. zone_NZ is [K, N, Z]
+    # (not [N, K*Z]): per-key blocks must start at lane offset 0 — Mosaic
+    # cannot broadcast a vector sliced out of the flat layout at lane offset k·Z
+    Z = 128
+    if zone_domains(prep):
+        zone_NZ = np.zeros((K, 0, Z), np.float32)
+    else:
+        if zone_tks:
+            Z = max(Z, 128 * math.ceil(max(len(np.unique(node_domain[:, tk])) for tk in zone_tks) / 128))
+        zone_NZ = np.zeros((K, N, Z), np.float32)
+        for ki in range(len(zone_tks)):
+            present = zone_id[ki] >= 0
+            zone_NZ[ki, np.arange(N)[present], zone_id[ki, present].astype(np.int64)] = 1.0
+    zone_ZN = np.ascontiguousarray(zone_NZ.transpose(0, 2, 1).reshape(K * Z, zone_NZ.shape[1]))
     key_of_tk = {host_tk: 0}
     for ki, tk in enumerate(zone_tks):
         key_of_tk[tk] = ki + 1
@@ -462,6 +496,7 @@ def build_inputs(prep) -> Tuple[FastInputs, dict]:
         zone_NZ=zone_NZ,
         zone_ZN=zone_ZN,
         has_zone=has_zone,
+        zone_id=zone_id,
         matches_AU=matches_AU,
         node_valid=_padN(ec.node_valid, axis=0).astype(np.float32)[None, :],
         req=req_np,
@@ -536,6 +571,7 @@ def _kernel_flags(prep) -> dict:
         has_tt=bool(f.prefer_taints),
         has_avoid=bool(f.prefer_avoid),
         gc_row=_gc_row(prep),
+        zone_domains=zone_domains(prep),
     )
     if f.local:
         flags.update(n_vg_real=int(prep.st0.vg_free.shape[1]), n_dev_real=int(prep.meta.node_dev_cap.shape[1]))
@@ -586,6 +622,8 @@ def _launch(
     are not touched."""
     S = pod_valid.shape[0]
     flags = _kernel_flags(prep)
+    reads = zone_reads(prep)
+    RECORDER.count_megakernel_zone_keys(reads["zone_keys_selected"], reads["zone_keys_onehot"])
     with launch_span(
         "mk.launch", watch="megakernel", scenarios=S - pad, pods=len(tmpl_ids),
         nodes=fi.alloc_T.shape[1], templates=fi.static_pass.shape[0], big_u=big_u,
@@ -666,7 +704,7 @@ def sweep(
     S_pad = -(-S // sublanes) * sublanes
     with obs.span(
         "mk.inputs", scenarios=S, sublanes=sublanes, blocks=S_pad // sublanes, pad_scenarios=S_pad - S,
-        vmem_estimate_bytes=vmem_estimate(prep, sublanes),
+        vmem_estimate_bytes=vmem_estimate(prep, sublanes), **zone_reads(prep),
     ):
         fi, meta = build_inputs(prep)
         if big_u is None:
@@ -723,7 +761,9 @@ def schedule(
     # fails hard under OPENSIM_REQUIRE_TPU=1 (chaos suite)
     faults.fault_point("engine.compile")
     interpret = _resolve_interpret(interpret)
-    with obs.span("mk.inputs", sublanes=1, blocks=1, pad_scenarios=0, vmem_estimate_bytes=vmem_estimate(prep)):
+    with obs.span(
+        "mk.inputs", sublanes=1, blocks=1, pad_scenarios=0, vmem_estimate_bytes=vmem_estimate(prep), **zone_reads(prep)
+    ):
         fi, meta = build_inputs(prep)
         if big_u is None:
             big_u = use_big_u(*fi.static_pass.shape)

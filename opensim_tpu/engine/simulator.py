@@ -599,7 +599,7 @@ def _run_engine_ladder(
         from . import fastpath
 
         try:  # identical placements at ~4× the XLA scan's step rate
-            with obs.span("engine.megakernel", **shape) as rung:
+            with obs.span("engine.megakernel", **shape, **fastpath.zone_reads(prep)) as rung:
                 f_chosen, f_used, sf, f_take, f_gpu, f_vg, f_dev = fastpath.schedule(
                     prep, tmpl_ids, pod_valid, forced, node_valid=nv_mask, config=sched_config
                 )

@@ -189,6 +189,11 @@ FAMILIES: Dict[str, Tuple[str, str]] = {
         "Scenario blocks the megakernel's sweeps walked the pod stream for, by the scenarios a step holds: a packed sweep "
         "of S scenarios is ceil(S/8) blocks of 8 sublanes, an unpacked one S blocks of 1", "counter",
     ),
+    # path: select | onehot (engine/fastpath.py zone_reads)
+    "simon_megakernel_zone_keys_total": (
+        "Zone keys (topology keys but the hostname) of the megakernel's launches by how a step reads a key's counts: a "
+        "compare-select over the key's few domains, or a dot with the key's one-hot [N, Z] block", "counter",
+    ),
     # engine: megakernel | xla; profile: default | weights | rtcr
     # (engine/schedconfig.py profile_of)
     "simon_engine_profile_total": (
@@ -612,6 +617,9 @@ class MetricsRecorder:
         # scenario blocks of megakernel sweeps by sublanes a step; blocks of
         # 1 mean a sweep that did not pack (fastpath.sweep_sublanes)
         self.megakernel_sweep_blocks = make_counter("simon_megakernel_sweep_blocks_total", ("sublanes",))
+        # zone keys of every kernel launch by the read of their counts; onehot
+        # on a cluster of a few zones means the compare-select did not engage
+        self.megakernel_zone_keys = make_counter("simon_megakernel_zone_keys_total", ("path",))
         # runs that reached a slower rung because a faster one declined them
         # (engine/select.py turned_away): the rung and its reason's token
         self.engine_declined = make_counter("simon_engine_declined_total", ("engine", "reason"))
@@ -710,6 +718,12 @@ class MetricsRecorder:
         with self.lock:
             self.megakernel_sweep_blocks.inc((str(sublanes),), blocks)
 
+    def count_megakernel_zone_keys(self, selected: int, onehot: int) -> None:
+        with self.lock:
+            for path, n in (("select", selected), ("onehot", onehot)):
+                if n:
+                    self.megakernel_zone_keys.inc((path,), n)
+
     def count_engine_declined(self, engine: str, reason: str) -> None:
         with self.lock:
             self.engine_declined.inc((engine, reason))
@@ -749,6 +763,7 @@ class MetricsRecorder:
                 + self.masked_pass.render_lines()
                 + self.megakernel_attribution.render_lines()
                 + self.megakernel_sweep_blocks.render_lines()
+                + self.megakernel_zone_keys.render_lines()
                 + self.engine_declined.render_lines()
                 + self.engine_profile.render_lines()
                 + self.gpushare_pods.render_lines()
@@ -771,6 +786,7 @@ class MetricsRecorder:
             self.masked_pass.reset()
             self.megakernel_attribution.reset()
             self.megakernel_sweep_blocks.reset()
+            self.megakernel_zone_keys.reset()
             self.engine_declined.reset()
             self.engine_profile.reset()
             self.gpushare_pods.reset()

@@ -46,6 +46,7 @@ SB = 1 the table is the plain [X, N]):
   node_valid  [SB, N]      f32  the block's validity rows
   node_cnt    [A*SB, N]    f32  scratch — per-hostname-domain selector counts
   zone_cnt    [K*A*SB, Z]  f32  scratch — per-(zone-key, selector) counts
+  zone_id     [K, N]       f32  each node's domain under each zone key, -1 none
   anti_node   [G*SB, N]    f32  scratch — existing-pod anti-affinity terms
   prefw_node  [Gp*SB, N]   f32  scratch — symmetric preferred-term weights
   matches_AU  [A, U]       f32  selector-match matrix (column = template)
@@ -124,9 +125,12 @@ class FastInputs(NamedTuple):
     static_pass: np.ndarray  # [U, N]
     aff_mask: np.ndarray  # [U, N]
     share_raw: np.ndarray  # [U, N]
-    zone_NZ: np.ndarray  # [K, N, Z] — per-zone-key one-hot blocks (lane offset 0 per key)
+    # per-zone-key one-hot blocks (lane offset 0 per key); empty, [K, 0, Z]
+    # and [K*Z, 0], where the kernel reads the zone keys by compare-select
+    zone_NZ: np.ndarray  # [K, N, Z]
     zone_ZN: np.ndarray  # [K*Z, N]
     has_zone: np.ndarray  # [K, N] f32 — node has key k's label
+    zone_id: np.ndarray  # [K, N] f32 — node's domain index under key k, -1 where it has no label
     matches_AU: np.ndarray  # [A, U]
     node_valid: np.ndarray  # [1, N] f32 (run_fast_scan takes [S, 1, N])
     # SMEM scalar tables
@@ -196,12 +200,15 @@ def _input_layout(
     has_avoid: bool,
     big_u: bool,
     packed: bool = False,
+    zone_select: bool = False,
 ):
     """Ordered (name, kind) list of kernel inputs for one feature-flag
     combination; kind ∈ {stream, smem, vmem, any}. The pallas_call signature
     is generated from this, so a workload with a feature off pays ZERO
-    VMEM/SMEM for that feature's tables — the buffers don't exist. A packed
-    kernel (SB > 1) also reads `zone_id`, each node's zone under each key."""
+    VMEM/SMEM for that feature's tables — the buffers don't exist. A kernel
+    that reads its zone keys by compare-select (`zone_select`) reads
+    `zone_id`, each node's zone under each key, in place of the one-hot
+    blocks; a packed kernel (SB > 1) reads it beside them too."""
     ut = "any" if big_u else "vmem"  # U-scaled tables move to HBM in big-U mode
     L = [
         ("tmpl", "stream"), ("valid", "stream"), ("forced", "stream"),
@@ -227,10 +234,11 @@ def _input_layout(
     L += [
         ("alloc_T", "vmem"), ("used0_T", "vmem"),
         ("static_pass", ut), ("aff_mask", ut), ("share_raw", ut),
-        ("zone_NZ", "vmem"), ("zone_ZN", "vmem"), ("has_zone", "vmem"),
-        ("matches_AU", ut), ("node_valid", "vmem"),
     ]
-    if packed:
+    if not zone_select:
+        L += [("zone_NZ", "vmem"), ("zone_ZN", "vmem")]
+    L += [("has_zone", "vmem"), ("matches_AU", ut), ("node_valid", "vmem")]
+    if packed or zone_select:
         L += [("zone_id", "vmem")]
     if has_interpod:
         L += [("antig_GU", ut), ("gmatch_GU", ut), ("prefg_GU", ut), ("pmatch_GU", ut)]
@@ -284,6 +292,7 @@ def _make_kernel(
     config=None,
     n_vg_real=None,
     n_dev_real=None,
+    zone_domains: int = 0,
 ):
     from ..engine.schedconfig import DEFAULT_CONFIG
 
@@ -306,7 +315,9 @@ def _make_kernel(
 
     def weighted(w, term):
         return term if w == 1.0 else w * term
-    layout = _input_layout(has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u, SB > 1)
+    layout = _input_layout(
+        has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u, SB > 1, zone_domains > 0
+    )
     in_names = [n for n, _ in layout]
     out_names = ["chosen", "used_out"]
     if has_gpu:
@@ -361,10 +372,11 @@ def _make_kernel(
         alloc_ref, used0_ref = Rd["alloc_T"], Rd["used0_T"]
         static_ref, affm_ref, shraw_ref = (
             Rd["static_pass"], Rd["aff_mask"], Rd["share_raw"])
-        zone_nz_ref, zone_zn_ref, has_zone_ref = (
-            Rd["zone_NZ"], Rd["zone_ZN"], Rd["has_zone"])
+        # the one-hot blocks, or (zone_domains > 0) none
+        zone_nz_ref, zone_zn_ref = Rd.get("zone_NZ"), Rd.get("zone_ZN")
+        has_zone_ref = Rd["has_zone"]
         matches_ref, nodevalid_ref = Rd["matches_AU"], Rd["node_valid"]
-        zone_id_ref = Rd.get("zone_id")  # [K, N] f32, a packed kernel's alone
+        zone_id_ref = Rd.get("zone_id")  # [K, N] f32: a packed kernel's, or a compare-select one's
         chosen_ref, used_out_ref = Rd["chosen"], Rd["used_out"]
         used_ref, node_cnt_ref, zone_cnt_ref = (
             Rd["used"], Rd["node_cnt"], Rd["zone_cnt"])
@@ -473,7 +485,7 @@ def _make_kernel(
         ones_1n = jnp.ones((1, N), jnp.float32)
 
         A_rows = node_cnt_ref.shape[0] // SB
-        Zk = zone_zn_ref.shape[0] // n_zkeys
+        Zk = zone_cnt_ref.shape[1]
 
         # a spread constraint's weight is its topology key's, per scenario
         if SB == 1:
@@ -525,6 +537,23 @@ def _make_kernel(
             g_key_col = _flag_col(agh_ref, n_anti)
             p_key_col = _flag_col(pgh_ref, n_pref)
 
+        def zone_read(k, counts):
+            """Each node's count in its own domain under zone key k (0-based),
+            [SB, N], from `counts` [SB, Zk] by domain. A key of at most
+            `zone_domains` domains is read by compare-select over the nodes'
+            `zone_id` row, each domain's count taken out of its lane by an
+            exact sum; else by an exact dot with the key's one-hot block. A
+            node without the key's label reads 0 either way."""
+            if not zone_domains:
+                return _dot(counts, zone_zn_ref[pl.ds(k * Zk, Zk), :])
+            zid = zone_id_ref[pl.ds(k, 1), :]  # [1, N]
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, Zk), 1)
+            out = jnp.zeros((SB, N), jnp.float32)
+            for d in range(zone_domains):
+                count = vsum(jnp.where(lane == d, counts, 0.0))  # [SB, 1] (a scalar when SB = 1)
+                out = jnp.where(zid == d, jnp.broadcast_to(count, (SB, N)), out)
+            return out
+
         def sel_cnt(sel, key):
             """Count of bound pods matching selector `sel` in the candidate
             node's domain under topology key index `key` (0 = hostname,
@@ -532,7 +561,7 @@ def _make_kernel(
             host_cnt = srows(node_cnt_ref, sel)  # [SB, N]
             k = jnp.maximum(key - 1, 0)
             zrow = srows(zone_cnt_ref, k * A_rows + sel)  # [SB, Zk]
-            zone_gather = _dot(zrow, zone_zn_ref[pl.ds(k * Zk, Zk), :])
+            zone_gather = zone_read(k, zrow)
             has = has_zone_ref[pl.ds(k, 1), :]
             return jnp.where(key == 0, host_cnt, zone_gather), jnp.where(
                 key == 0, ones_1n, has
@@ -757,9 +786,7 @@ def _make_kernel(
                 sym_cnt = _dot(kron_row(m_host), anti_node_ref[:])
                 for zk in range(n_zkeys):
                     m_k = m_row * (g_key_row == zk + 1).astype(jnp.float32)
-                    sym_cnt = sym_cnt + _dot(
-                        _dot(kron_row(m_k), anti_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
-                    )
+                    sym_cnt = sym_cnt + zone_read(zk, _dot(kron_row(m_k), anti_zone_ref[:]))
                 feasible = feasible * (1.0 - (sym_cnt > 0).astype(jnp.float32))
                 # score: incoming preferred terms
                 for t in range(Tp):
@@ -778,9 +805,7 @@ def _make_kernel(
                 ip_raw = ip_raw + _dot(kron_row(pm_host), prefw_node_ref[:])
                 for zk in range(n_zkeys):
                     pm_k = pm_row * (p_key_row == zk + 1).astype(jnp.float32)
-                    ip_raw = ip_raw + _dot(
-                        _dot(kron_row(pm_k), prefw_zone_ref[:]), zone_zn_ref[pl.ds(zk * Zk, Zk), :]
-                    )
+                    ip_raw = ip_raw + zone_read(zk, _dot(kron_row(pm_k), prefw_zone_ref[:]))
 
             # --- scores
             cpu_req = cpu_nz_ref[u]
@@ -1101,29 +1126,37 @@ def _make_kernel(
                         key_mask = (p_key_col == zk + 1).astype(jnp.float32)
                         add_outer(prefw_zone_ref, p_col * key_mask, zrow_k[zk])
 
-            if SB == 1:
-                @pl.when(do_bind)
-                def _():
-                    c = jnp.maximum(choice, 0)
-                    # the chosen node's [1, Zk] zone one-hot under each key —
-                    # read from the 3-D [K, N, Z] table so every key's row
-                    # sits at lane offset 0 (a lane-offset slice can't
-                    # broadcast)
-                    _bind((iota_n == c).astype(jnp.float32), lambda zk: zone_nz_ref[zk, pl.ds(c, 1), :])
-            else:
-                # every scenario's bind at once: a scenario that binds
-                # nothing has a zero one-hot row, and every update adds its
-                # product (or, for a device, scales by 1 - it). A zone row is
-                # the one-hot of the chosen node's zone id (-1: none, or no
-                # bind), read by an exact reduction over the node lanes
-                onehot = ((iota_n == choice) & do_bind).astype(jnp.float32)  # [SB, N]
-                iota_z = jax.lax.broadcasted_iota(jnp.int32, (1, zone_nz_ref.shape[2]), 1)
+            def zone_rows_of(onehot):
+                """zone_rows(k) of the chosen nodes' one-hot [SB, N]: the
+                one-hot of each chosen node's zone id (-1: none, or no bind),
+                read by an exact reduction over the node lanes."""
+                iota_z = jax.lax.broadcasted_iota(jnp.int32, (1, Zk), 1)
 
                 def zone_rows(zk):
                     zid = vsum(onehot * (zone_id_ref[pl.ds(zk, 1), :] + 1.0)) - 1.0  # [SB, 1]
                     return (iota_z.astype(jnp.float32) == zid).astype(jnp.float32)
 
-                _bind(onehot, zone_rows)
+                return zone_rows
+
+            if SB == 1:
+                @pl.when(do_bind)
+                def _():
+                    c = jnp.maximum(choice, 0)
+                    onehot = (iota_n == c).astype(jnp.float32)
+                    if zone_domains:
+                        _bind(onehot, zone_rows_of(onehot))
+                        return
+                    # the chosen node's [1, Zk] zone one-hot under each key —
+                    # read from the 3-D [K, N, Z] table so every key's row
+                    # sits at lane offset 0 (a lane-offset slice can't
+                    # broadcast)
+                    _bind(onehot, lambda zk: zone_nz_ref[zk, pl.ds(c, 1), :])
+            else:
+                # every scenario's bind at once: a scenario that binds
+                # nothing has a zero one-hot row, and every update adds its
+                # product (or, for a device, scales by 1 - it)
+                onehot = ((iota_n == choice) & do_bind).astype(jnp.float32)  # [SB, N]
+                _bind(onehot, zone_rows_of(onehot))
             return 0
 
         jax.lax.fori_loop(0, tmpl_ref.shape[0], body, 0)
@@ -1140,7 +1173,8 @@ def _make_kernel(
 # what selects the generated kernel; everything else run_fast_scan reads
 # comes from the shapes of its traced arguments
 _STATIC = ("has_interpod", "has_gpu", "has_local", "has_ports", "has_na", "has_tt",
-           "has_avoid", "interpret", "big_u", "gc_row", "sublanes", "config", "n_vg_real", "n_dev_real")
+           "has_avoid", "interpret", "big_u", "gc_row", "sublanes", "config", "n_vg_real", "n_dev_real",
+           "zone_domains")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -1163,6 +1197,7 @@ def run_fast_scan(
     config=None,
     n_vg_real: Optional[int] = None,
     n_dev_real: Optional[int] = None,
+    zone_domains: int = 0,
 ):
     """Execute the megakernel over S scenarios in ONE dispatch: tmpl_ids is
     [P] (P a multiple of CHUNK, shared), pod_valid/forced are [S, P],
@@ -1192,7 +1227,13 @@ def run_fast_scan(
 
     `n_vg_real` / `n_dev_real` are the VG and device rows a node really has
     (None: every row of `fi.vg0_VN` / `fi.dev0_DN`); the open-local block of
-    the `has_local` variant walks those alone, the tables stay padded."""
+    the `has_local` variant walks those alone, the tables stay padded.
+
+    `zone_domains` (at most kernels.SELECT_DOMAINS) is the most domains a
+    zone key of the cluster has where the kernel reads each node's zone
+    counts by compare-select over ``fi.zone_id`` (the one-hot blocks
+    ``fi.zone_NZ`` / ``fi.zone_ZN`` are then not read, and may be empty);
+    0 reads them through the one-hot blocks."""
     SB = sublanes
     assert SB in (1, 8), SB
     P = tmpl_ids.shape[0]
@@ -1263,13 +1304,17 @@ def run_fast_scan(
                "an_active", "an_key", "an_sel",
                "pt_active", "pt_key", "pt_sel", "pt_w",
                "dev_req", "dev_need", "dev_sizes"}
-    layout = _input_layout(has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u, SB > 1)
+    layout = _input_layout(
+        has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid, big_u, SB > 1, zone_domains > 0
+    )
     in_specs, args = [], []
     for name, kind in layout:
         if kind == "stream":
             src = {"tmpl": tmpl_ids, "valid": pod_valid, "forced": forced}[name]
-        elif name == "zone_id":
+        elif name == "zone_id" and not zone_domains:
             # each node's zone index under each key, -1 where it has no label
+            # (derived from the blocks here, so a packed kernel that reads
+            # them lowers as it did before `fi.zone_id`)
             zone_NZ = jnp.asarray(fi.zone_NZ, jnp.float32)
             src = jnp.sum(zone_NZ * jnp.arange(1, Z + 1, dtype=jnp.float32), axis=-1) - 1.0
         else:
@@ -1365,7 +1410,7 @@ def run_fast_scan(
         _make_kernel(
             has_interpod, has_gpu, has_local, has_ports, has_na, has_tt, has_avoid,
             G, Gp, Gd, Vg, Dv, fi.dev_sizes.shape[1] // 2, big_u, K, gc_row, SB, config,
-            n_vg_real, n_dev_real,
+            n_vg_real, n_dev_real, zone_domains,
         ),
         grid=grid,
         out_shape=tuple(out_shape),

@@ -27,12 +27,15 @@ _OFF = dict(has_interpod=False, has_gpu=False, has_local=False, has_ports=False,
 # mode, eight devices a node) and plan-local's k8s-5k-50k-openlocal (plan-short's
 # widths with the open-local rows: one VG and four devices a node, padded to
 # eight, which the kernel's loops walk alone, and two claims of a media a
-# template)
+# template) and plan-15k's gke-15k-50k sweep (plan-short's widths at 13,800
+# nodes and 128 candidates, 13,952 padded, its four zones read by
+# compare-select: past the one-hot blocks' VMEM edge of about 7,000 nodes)
 SHAPES = {
     "plan-short": (dict(N=4736, R=4, U=20, A=24, Cs=2), dict(_OFF), 50),
     "plan-gpushare": (dict(N=1664, R=6, U=866, A=8, Cs=1), dict(_OFF, has_gpu=True, big_u=True), 9),
     "plan-local": (dict(N=4736, R=4, U=20, A=24, Cs=2, Mv=2),
                    dict(_OFF, has_local=True, n_vg_real=1, n_dev_real=4), 50),
+    "plan-15k": (dict(N=13952, R=4, U=20, A=24, Cs=2, select=True), dict(_OFF, zone_domains=4), 50),
 }
 
 
@@ -53,10 +56,14 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", cache)
 
 
-def _inputs(dev, S, N, R, U, A, Cs, K=1, Z=128, X=8, Mv=1):
+def _inputs(dev, S, N, R, U, A, Cs, K=1, Z=128, X=8, Mv=1, select=False):
+    """Shapes of the kernel's inputs; `select`: the zone keys are read by
+    compare-select, and the one-hot blocks are empty."""
+    Nz = 0 if select else N
     rows = dict(
         alloc_T=(R, N), used0_T=(R, N), static_pass=(U, N), aff_mask=(U, N), share_raw=(U, N),
-        zone_NZ=(K, N, Z), zone_ZN=(K * Z, N), has_zone=(K, N), matches_AU=(A, U), node_valid=(S, 1, N),
+        zone_NZ=(K, Nz, Z), zone_ZN=(K * Z, Nz), has_zone=(K, N), zone_id=(K, N), matches_AU=(A, U),
+        node_valid=(S, 1, N),
         req=(U, R), cpu_nz=(U,), mem_nz=(U,), pin=(U,), key_weight=(S, K + 1),
         **{n: (U, Cs) for n in ("spr_active", "spr_key", "spr_sel", "spr_skew", "spr_hard", "spr_self")},
         **{n: (U, 1) for n in ("at_active", "at_key", "at_sel", "at_self", "an_active", "an_key", "an_sel",
